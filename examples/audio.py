@@ -4,16 +4,14 @@ The audio peer of classify.py — the same converter/filter/decoder
 contract over an audio stream (reference: tensor_converter audio path +
 aggregator windowing).
 
-Run: PYTHONPATH=.. python audio.py   (CPU XLA works; TPU if available)
+Run: PYTHONPATH=.. python audio.py
+(JAX picks the backend: the TPU where there is one; JAX_PLATFORMS=cpu
+forces CPU XLA.)
 """
 
-from nnstreamer_tpu.utils.platform import ensure_jax_platform
-
-ensure_jax_platform()  # fall back to CPU if the preset backend is unusable
-
-import nnstreamer_tpu as nt  # noqa: E402
-from nnstreamer_tpu.filters.jax_backend import register_jax_model  # noqa: E402
-from nnstreamer_tpu.models.audio_classifier import audio_classifier  # noqa: E402
+import nnstreamer_tpu as nt
+from nnstreamer_tpu.filters.jax_backend import register_jax_model
+from nnstreamer_tpu.models.audio_classifier import audio_classifier
 
 SAMPLES = 8000  # 0.5 s window @ 16 kHz
 

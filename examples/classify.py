@@ -3,10 +3,6 @@
 uint8 frame → normalize → MobileNet → argmax runs as ONE XLA program;
 only the label index/score cross back per frame."""
 
-from nnstreamer_tpu.utils.platform import ensure_jax_platform
-
-ensure_jax_platform()  # fall back to CPU if the preset backend is unusable
-
 import nnstreamer_tpu as nt
 from nnstreamer_tpu.filters.jax_backend import register_jax_model
 from nnstreamer_tpu.models.mobilenet_v2 import mobilenet_v2

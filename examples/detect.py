@@ -1,10 +1,6 @@
 """SSD-MobileNet detection — anchor decode + per-class NMS fused on device;
 only [100, 6] box rows leave the chip per frame."""
 
-from nnstreamer_tpu.utils.platform import ensure_jax_platform
-
-ensure_jax_platform()  # fall back to CPU if the preset backend is unusable
-
 import nnstreamer_tpu as nt
 from nnstreamer_tpu.filters.jax_backend import register_jax_model
 from nnstreamer_tpu.models.ssd_mobilenet import ssd_mobilenet

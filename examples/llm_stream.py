@@ -8,28 +8,26 @@ ever reach the host. The reference's tensor_repo enables exactly this
 loop topology (tests/nnstreamer_repo_lstm); the KV-cache-in-HBM part is
 what TPU adds.
 
-Run: PYTHONPATH=.. python llm_stream.py   (CPU XLA works; TPU if available)
+Run: PYTHONPATH=.. python llm_stream.py
+(JAX picks the backend: the TPU where there is one; JAX_PLATFORMS=cpu
+forces CPU XLA.)
 """
 
-from nnstreamer_tpu.utils.platform import ensure_jax_platform
+import jax.numpy as jnp
+import numpy as np
 
-ensure_jax_platform()  # fall back to CPU if the preset backend is unusable
+import nnstreamer_tpu as nt
+from nnstreamer_tpu.elements.repo import GLOBAL_REPO
+from nnstreamer_tpu.filters.jax_backend import register_jax_model
+import jax
 
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-
-import nnstreamer_tpu as nt  # noqa: E402
-from nnstreamer_tpu.elements.repo import GLOBAL_REPO  # noqa: E402
-from nnstreamer_tpu.filters.jax_backend import register_jax_model  # noqa: E402
-import jax  # noqa: E402
-
-from nnstreamer_tpu.models.transformer import (  # noqa: E402
+from nnstreamer_tpu.models.transformer import (
     TransformerConfig,
     build_greedy_stream_step,
     build_prefill,
     init_params,
 )
-from nnstreamer_tpu.tensors.buffer import TensorBuffer  # noqa: E402
+from nnstreamer_tpu.tensors.buffer import TensorBuffer
 
 N_TOKENS = 16
 cfg = TransformerConfig(vocab=256, d_model=64, n_heads=4, n_layers=2,

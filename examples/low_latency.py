@@ -10,10 +10,6 @@ sink. Under overload the budget yields to backpressure and the
 pipeline degrades to plain batching instead of compounding a backlog.
 """
 
-from nnstreamer_tpu.utils.platform import ensure_jax_platform
-
-ensure_jax_platform()
-
 import jax.numpy as jnp
 
 import nnstreamer_tpu as nt

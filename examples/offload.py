@@ -4,10 +4,6 @@ round trips."""
 
 import numpy as np
 
-from nnstreamer_tpu.utils.platform import ensure_jax_platform
-
-ensure_jax_platform()  # fall back to CPU if the preset backend is unusable
-
 import nnstreamer_tpu as nt
 from nnstreamer_tpu.filters import register_custom_easy
 from nnstreamer_tpu.tensors.types import TensorsInfo

@@ -1,10 +1,6 @@
 """Recurrence — LSTM hidden/cell state circulates through a tensor_repo
 slot as device-resident arrays (never leaves HBM between steps)."""
 
-from nnstreamer_tpu.utils.platform import ensure_jax_platform
-
-ensure_jax_platform()  # fall back to CPU if the preset backend is unusable
-
 import jax.numpy as jnp
 import numpy as np
 

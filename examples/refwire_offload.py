@@ -10,10 +10,6 @@ property declares how raw memories reconstruct into typed tensors (it
 is also what the APPROVE reply announces to clients).
 """
 
-from nnstreamer_tpu.utils.platform import ensure_jax_platform
-
-ensure_jax_platform()
-
 import nnstreamer_tpu as nt
 from nnstreamer_tpu.filters.jax_backend import register_jax_model
 

@@ -4,19 +4,17 @@ The fused region runs normalize → encoder-decoder FCN → argmax as one
 XLA program; an [H, W] int32 class map crosses to the host (C× less D2H
 than raw logits), where the image_segment decoder colors it RGBA.
 
-Run: PYTHONPATH=.. python segment.py   (CPU XLA works; TPU if available)
+Run: PYTHONPATH=.. python segment.py
+(JAX picks the backend: the TPU where there is one; JAX_PLATFORMS=cpu
+forces CPU XLA.)
 """
 
-from nnstreamer_tpu.utils.platform import ensure_jax_platform
+import jax.numpy as jnp
+import numpy as np
 
-ensure_jax_platform()  # fall back to CPU if the preset backend is unusable
-
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-
-import nnstreamer_tpu as nt  # noqa: E402
-from nnstreamer_tpu.filters.jax_backend import register_jax_model  # noqa: E402
-from nnstreamer_tpu.models.segmenter import segmenter  # noqa: E402
+import nnstreamer_tpu as nt
+from nnstreamer_tpu.filters.jax_backend import register_jax_model
+from nnstreamer_tpu.models.segmenter import segmenter
 
 SIZE = 256
 apply_fn, params, in_info, out_info = segmenter(num_classes=21,

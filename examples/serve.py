@@ -1,7 +1,9 @@
 """Continuous-batching LM serving: N concurrent prompts share one batched
 KV-cached decode program (serving/engine.py).
 
-Run: PYTHONPATH=.. python serve.py   (CPU XLA works; TPU if available)
+Run: PYTHONPATH=.. python serve.py
+(JAX picks the backend: the TPU where there is one; JAX_PLATFORMS=cpu
+forces CPU XLA.)
 
 Contrast with examples/llm_stream.py (one stream through the tensor_repo
 pipeline loop): the engine multiplexes many streams onto the same device
@@ -9,14 +11,10 @@ program — the TPU-native answer to the reference query server's
 one-request-one-invoke loop (tensor_query_server.c).
 """
 
-from nnstreamer_tpu.utils.platform import ensure_jax_platform
+import time
 
-ensure_jax_platform()  # fall back to CPU if the preset backend is unusable
-
-import time  # noqa: E402
-
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
+import jax.numpy as jnp
+import numpy as np
 
 from nnstreamer_tpu.models.transformer import TransformerConfig, init_params
 from nnstreamer_tpu.serving import ContinuousBatchingEngine

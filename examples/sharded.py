@@ -6,10 +6,6 @@ Run with a virtual 8-device mesh to try it anywhere:
       python examples/sharded.py
 """
 
-from nnstreamer_tpu.utils.platform import ensure_jax_platform
-
-ensure_jax_platform()  # fall back to CPU if the preset backend is unusable
-
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -269,8 +269,8 @@ class TensorAggregator(Element):
         latency optimization, and it only helps while the downstream can
         absorb the extra dispatch. When the link/device is saturated
         (the downstream queue is full), flushing MORE, SMALLER windows
-        compounds the backlog — measured 13x worse p50 on a degraded
-        tunnel. Holding instead lets the window fill toward a full
+        compounds the backlog. Holding instead lets the window fill
+        toward a full
         batch, i.e. budget mode degrades gracefully to plain batching
         under overload. Full windows are exempt: they flush through the
         normal (blocking) path regardless."""

@@ -22,18 +22,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from nnstreamer_tpu.log import get_logger
+
+log = get_logger("flash-attention")
 
 #: scores below this act as -inf without producing exp() NaNs in fully
 #: masked tiles
 _NEG_BIG = -1e30
-
-try:  # pallas import is deferred-safe: CPU-only installs still work
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # noqa: BLE001
-    _HAVE_PALLAS = False
 
 
 def attention_reference(q, k, v, causal: bool = True):
@@ -118,21 +116,13 @@ def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
     # sequentially (measured 500x on a [4,512,8,64] prefill); only the
     # trailing k axis carries the online-softmax accumulator and stays
     # sequential ("arbitrary")
-    semantics = ("parallel", "parallel", "parallel", "arbitrary")
-    if hasattr(pltpu, "CompilerParams"):
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=semantics)
-    elif hasattr(pltpu, "TPUCompilerParams"):  # older jax spelling
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=semantics)
-    else:  # ancient jax: run without the hint (sequential grid)
-        compiler_params = None
     return pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid=grid,
-        **({"compiler_params": compiler_params} if compiler_params
-           else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -152,37 +142,59 @@ def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
     )(q, k, v)
 
 
-def _pallas_ok(q, k, block_q: int, block_k: int) -> bool:
+def _pallas_reject(q, k, block_q: int, block_k: int) -> str | None:
+    """Why these shapes cannot go to the kernel, or None when they can.
+
+    The bounds are what Mosaic (libtpu 0.0.34, TPU v5e) was seen to
+    compile, bf16 and f32: q/k blocks of 8, 16, 24, 40, 128 and 256 rows
+    — bf16 included, although its native tile is 16 rows — and head dims
+    8 to 256 (the head dim is the blocks' lane dimension, legal at any
+    size because it spans the whole array dimension)."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    return (sq % block_q == 0 and sk % block_k == 0 and
-            block_q % 8 == 0 and block_k % 8 == 0 and
-            d % 8 == 0 and d <= 256)
+    if sq % block_q or sk % block_k:
+        return (f"seq ({sq}, {sk}) is not a multiple of the blocks "
+                f"({block_q}, {block_k})")
+    if block_q % 8 or block_k % 8:
+        return f"blocks ({block_q}, {block_k}) are not multiples of 8 rows"
+    if d % 8 or d > 256:
+        return f"head dim {d} is not a multiple of 8 in [8, 256]"
+    return None
+
+
+@functools.lru_cache(maxsize=256)
+def _log_reference_choice(q_shape, k_shape, dtype, why: str) -> None:
+    """Auto mode gave way to the XLA reference ON A TPU: say so, once per
+    shape, so the choice is visible in the serving log."""
+    log.warning("flash_attention%s/%s %s runs the XLA reference, not the "
+                "Pallas kernel: %s", q_shape, k_shape, dtype, why)
 
 
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
                     block_k: int = 256, force: str | None = None):
     """Attention on [batch, seq, heads, dim] tensors.
 
-    ``force``: None (auto), "pallas" (kernel, interpreted off-TPU), or
-    "reference".
+    ``force``: None (auto: the Pallas kernel on a TPU for tileable
+    shapes, else the XLA reference), "pallas" (always the kernel — Mosaic
+    on a TPU, the Pallas interpreter elsewhere, which is how the CPU
+    tests run it), or "reference".
     """
     if force == "reference":
         return attention_reference(q, k, v, causal=causal)
     block_q = min(block_q, q.shape[1])
     block_k = min(block_k, k.shape[1])
     on_tpu = jax.default_backend() == "tpu"
-    tileable = _HAVE_PALLAS and _pallas_ok(q, k, block_q, block_k)
+    why_not = _pallas_reject(q, k, block_q, block_k)
     if force == "pallas":
-        if not _HAVE_PALLAS:
-            raise RuntimeError(
-                "flash_attention: force='pallas' but jax.experimental."
-                "pallas failed to import on this install")
-        if not tileable:
+        if why_not:
             raise ValueError(
                 f"flash_attention: shapes {q.shape}/{k.shape} not tileable "
-                f"by ({block_q},{block_k})")
-    elif not (on_tpu and tileable):
+                f"by ({block_q},{block_k}): {why_not}")
+    elif not on_tpu:
+        return attention_reference(q, k, v, causal=causal)
+    elif why_not:
+        _log_reference_choice(tuple(q.shape), tuple(k.shape), str(q.dtype),
+                              why_not)
         return attention_reference(q, k, v, causal=causal)
     qt = q.swapaxes(1, 2)  # [b, h, s, d]
     kt = k.swapaxes(1, 2)

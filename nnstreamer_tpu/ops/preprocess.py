@@ -18,17 +18,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from nnstreamer_tpu.ops.tiling import BLOCK_ROWS as _BLOCK_ROWS
 from nnstreamer_tpu.ops.tiling import LANES as _LANES
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # noqa: BLE001
-    _HAVE_PALLAS = False
 
 
 def _normalize_reference(x, mean: float, scale: float, out_dtype):
@@ -70,13 +64,8 @@ def normalize_u8(x, mean: float = 127.5, scale: float = 1.0 / 127.5,
     Auto-selects the Pallas kernel on TPU (interpret mode when forced on
     CPU), the XLA reference otherwise.
     """
-    if force == "pallas" and not _HAVE_PALLAS:
-        raise RuntimeError("normalize_u8: force='pallas' but jax."
-                           "experimental.pallas failed to import")
     on_tpu = jax.default_backend() == "tpu"
-    use_pallas = _HAVE_PALLAS and (force == "pallas" or
-                                   (force is None and on_tpu))
-    if not use_pallas or force == "reference":
+    if not (force == "pallas" or (force is None and on_tpu)):
         return _normalize_reference(x, mean, scale, out_dtype)
 
     from nnstreamer_tpu.ops.tiling import pad_to_tiles, unpad_from_tiles

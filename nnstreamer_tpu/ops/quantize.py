@@ -13,17 +13,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from nnstreamer_tpu.ops.tiling import BLOCK_ROWS as _BLOCK_ROWS
 from nnstreamer_tpu.ops.tiling import LANES as _LANES
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # noqa: BLE001
-    _HAVE_PALLAS = False
 
 
 def _quantize_reference(x):
@@ -101,13 +95,8 @@ def quantize_int8(x, seed: int = 0, force: str | None = None):
     streaming quantization doesn't bias activations; reference path is
     deterministic nearest (CPU tests stay reproducible).
     """
-    if force == "pallas" and not _HAVE_PALLAS:
-        raise RuntimeError("quantize_int8: force='pallas' but jax."
-                           "experimental.pallas failed to import")
     on_tpu = jax.default_backend() == "tpu"
-    use_pallas = _HAVE_PALLAS and (force == "pallas" or
-                                   (force is None and on_tpu))
-    if not use_pallas or force == "reference":
+    if not (force == "pallas" or (force is None and on_tpu)):
         return _quantize_reference(x)
 
     from nnstreamer_tpu.ops.tiling import pad_to_tiles, unpad_from_tiles

@@ -31,15 +31,20 @@ itself. Three legs, each with an exact kill switch:
   are meaningless in a new process and re-anchor on the first
   observation.
 
-- **Persistent compilation cache** (:func:`enable_compile_cache`).
+- **Persistent compilation cache** (:func:`arm_compile_cache`).
   Arms JAX's persistent compilation cache so the second boot of the
   same pipeline performs zero XLA compilations on the serving path.
   Hits/misses surface as ``nns_compile_cache_hits_total`` /
   ``nns_compile_cache_misses_total`` via JAX's monitoring events; a
   per-fused-region program-signature manifest (``programs.json``)
   rides in the cache dir so operators can audit what the cache is
-  keyed on. ``NNSTPU_COMPILE_CACHE=<dir>`` arms it standalone; an
-  armed checkpoint dir defaults the cache into ``<dir>/xla-cache``.
+  keyed on. WHERE the cache lives is one rule
+  (:func:`resolve_compile_cache_dir`): ``JAX_COMPILATION_CACHE_DIR``
+  if the environment sets it (JAX reads it itself; this package never
+  overrides it), else ``<checkout>/.jax_cache``. The directory is part
+  of the cache key's stability — it never derives from a temp dir, a
+  pid, a state dir or the clock. ``Pipeline.start()`` arms it when the
+  variable is set or a checkpoint dir is armed.
 
 See docs/robustness.md, "Serving continuity".
 """
@@ -59,14 +64,19 @@ from nnstreamer_tpu.log import get_logger
 log = get_logger("continuity")
 
 CHECKPOINT_ENV = "NNSTPU_CHECKPOINT"
-CACHE_ENV = "NNSTPU_COMPILE_CACHE"
+#: JAX's own variable — read by jax.config at import, never set here
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 #: checkpoint state file name inside the checkpoint dir
 STATE_FILE = "serving_state.pkl"
 #: fused-region program-signature manifest inside the compile-cache dir
 MANIFEST_FILE = "programs.json"
-#: default compile-cache subdir when only a checkpoint dir is armed
-CACHE_SUBDIR = "xla-cache"
+#: the fixed cache location when JAX_COMPILATION_CACHE_DIR is unset:
+#: ``<checkout>/.jax_cache`` (git-ignored). An installed wheel has no
+#: checkout (this is then the parent of site-packages): set the variable
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 #: state-file schema version — bump on any incompatible change
 STATE_VERSION = 1
@@ -123,38 +133,62 @@ def cache_stats() -> Dict[str, int]:
     return {"hits": int(m["hits"].value), "misses": int(m["misses"].value)}
 
 
-def enable_compile_cache(directory: str) -> str:
-    """Arm JAX's persistent compilation cache at ``directory``.
+def _env_cache_dir() -> str:
+    return os.environ.get(JAX_CACHE_ENV, "").strip()
 
-    Idempotent; re-arming with the same directory is a no-op. The size
-    and compile-time floors are zeroed so CI-sized CPU programs persist
-    too — the default floors exist to keep laptop caches small, but a
-    serving cache wants every executable on the serving path."""
+
+def resolve_compile_cache_dir() -> str:
+    """THE cache-location rule: ``JAX_COMPILATION_CACHE_DIR`` when the
+    environment sets it, else ``<checkout>/.jax_cache``. Imports no JAX,
+    so a launcher parent (serving/fleet.py) can hand its children the
+    same directory without touching a backend."""
+    return _env_cache_dir() or DEFAULT_CACHE_DIR
+
+
+def arm_compile_cache() -> str:
+    """Arm JAX's persistent compilation cache where
+    :func:`resolve_compile_cache_dir` says and return that directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set JAX has already taken the
+    directory from it (``jax.config`` reads the variable at import) and
+    this function leaves that alone; only the unset case applies the
+    fixed default. Idempotent — the rule has one answer per process.
+    The size and compile-time floors are zeroed so CI-sized CPU programs
+    persist too — the default floors exist to keep laptop caches small,
+    but a serving cache wants every executable on the serving path."""
     global _cache_dir, _listener_installed
-    directory = os.path.abspath(directory)
     with _cache_lock:
-        if _cache_dir == directory:
-            return directory
-        os.makedirs(directory, exist_ok=True)
+        if _cache_dir is not None:
+            return _cache_dir
         import jax
+        from jax._src import compilation_cache as _cc
+        from jax._src import monitoring as _monitoring
 
-        jax.config.update("jax_compilation_cache_dir", directory)
+        directory = resolve_compile_cache_dir()
+        if not _env_cache_dir():
+            try:
+                os.makedirs(directory, exist_ok=True)
+            except OSError as e:
+                raise OSError(
+                    f"compile cache: cannot create the default directory "
+                    f"{directory} ({e}); set {JAX_CACHE_ENV} to place the "
+                    f"cache elsewhere") from e
+            jax.config.update("jax_compilation_cache_dir", directory)
+        elif (held := jax.config.jax_compilation_cache_dir) != directory:
+            # jax.config read the variable when jax was imported; set
+            # later it names a directory JAX will never write, and
+            # reporting it as armed would hide a cold cache
+            raise RuntimeError(
+                f"compile cache: {JAX_CACHE_ENV}={directory} was set after "
+                f"jax was imported, and jax.config still holds {held!r}; "
+                f"set the variable before the process starts")
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            # JAX latches its use-the-cache decision at the first
-            # compilation; arming after any jit has run (a warm import,
-            # an earlier pipeline) would otherwise be silently inert
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except (ImportError, AttributeError):  # private API moved —
-            # the cache still arms for processes that configure it
-            # before their first compile
-            pass
+        # JAX latches its use-the-cache decision at the first
+        # compilation; arming after any jit has run (a warm import, an
+        # earlier pipeline) would otherwise be silently inert
+        _cc.reset_cache()
         if not _listener_installed:
-            from jax._src import monitoring as _monitoring
-
             _monitoring.register_event_listener(_on_jax_event)
             _listener_installed = True
         _cache_dir = directory
@@ -162,21 +196,19 @@ def enable_compile_cache(directory: str) -> str:
     return directory
 
 
-def maybe_enable_compile_cache_env(pipeline=None) -> Optional[str]:
-    """``Pipeline.start()`` hook: arm the cache from ``NNSTPU_COMPILE_CACHE``,
-    or default it into an armed checkpoint dir's ``xla-cache`` subdir.
-    Both unset ⇒ two env reads, nothing else runs (the kill switch)."""
-    spec = os.environ.get(CACHE_ENV, "").strip()
-    ckpt = None if spec else _effective_checkpoint_dir(pipeline)
-    target = spec or (os.path.join(ckpt, CACHE_SUBDIR) if ckpt else None)
-    if not target:
+def maybe_arm_compile_cache(pipeline=None) -> Optional[str]:
+    """``Pipeline.start()`` hook: arm the cache when the environment
+    names a directory (``JAX_COMPILATION_CACHE_DIR``) or a checkpoint
+    dir is armed (a process that persists serving state wants its
+    programs warm too). Both unset ⇒ two env reads, nothing else runs."""
+    if not (_env_cache_dir() or _effective_checkpoint_dir(pipeline)):
         return None
     try:
-        return enable_compile_cache(target)
-    except OSError as e:  # an uncreatable cache dir must not fail
-        # Pipeline.start() — serving continues cold, which is exactly
-        # what an unarmed cache does
-        log.warning("compile cache dir %s unusable: %s", target, e)
+        return arm_compile_cache()
+    except (OSError, RuntimeError) as e:  # an unusable cache dir must
+        # not fail Pipeline.start() — serving continues cold, which is
+        # exactly what an unarmed cache does, and says so
+        log.warning("compile cache not armed: %s", e)
         return None
 
 
@@ -379,7 +411,6 @@ def checkpoint(pipe, directory: Optional[str] = None) -> str:
         "swap_epochs": {el.name: int(el._swap_epoch)
                         for el in pipe.elements
                         if getattr(el, "_swap_epoch", 0)},
-        "compile_cache_dir": _cache_dir,
     }
     path = os.path.join(directory, STATE_FILE)
     tmp = path + ".tmp"
@@ -395,9 +426,8 @@ def checkpoint(pipe, directory: Optional[str] = None) -> str:
 def restore(pipe, directory: Optional[str] = None) -> Dict[str, Any]:
     """Load ``<dir>/serving_state.pkl`` and re-arm the warm serving
     state: repo slots, scheduler estimates/knobs, residency LRU order,
-    flight-recorder quantiles, query-server dedup windows, swap epochs,
-    and the persistent compile cache. Returns a summary of what was
-    applied."""
+    flight-recorder quantiles, query-server dedup windows and swap
+    epochs. Returns a summary of what was applied."""
     directory = _effective_checkpoint_dir(pipe, directory)
     if not directory:
         raise ValueError(
@@ -440,10 +470,6 @@ def restore(pipe, directory: Optional[str] = None) -> Dict[str, Any]:
         el = pipe.by_name.get(name)
         if el is not None:
             el._swap_epoch = int(epoch)
-    cache = state.get("compile_cache_dir")
-    if cache and os.path.isdir(cache):
-        enable_compile_cache(cache)
-        applied["compile_cache_dir"] = cache
     log.info("%s: restored serving state from %s (%s)", pipe.name, path,
              ", ".join(k for k in applied if k not in ("path", "pipeline")))
     return applied

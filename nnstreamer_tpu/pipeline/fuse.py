@@ -620,14 +620,19 @@ def fuse_pipeline(pipe) -> List[FusedRegion]:
 # --------------------------------------------------------------------------
 # plan-time matched-sharding verification (parallel/serve.py contract)
 # --------------------------------------------------------------------------
-def _element_mesh_spec(el) -> Optional[str]:
-    """The serving-mesh spec this element invokes under, or None. Covers
+def _element_mesh_plan(el):
+    """The serving MeshPlan this element invokes under, or None. Covers
     sharded fused regions (``_mesh_plan`` from _build) and UNFUSED
     tensor_filters whose backend holds a plan (e.g. the budgeted-weights
     invoke path, which region fusion deliberately skips)."""
     plan = getattr(el, "_mesh_plan", None)
     if plan is None:
         plan = getattr(getattr(el, "fw", None), "_mesh_plan", None)
+    return plan
+
+
+def _element_mesh_spec(el) -> Optional[str]:
+    plan = _element_mesh_plan(el)
     return plan.spec if plan is not None else None
 
 
@@ -657,6 +662,58 @@ def verify_mesh_boundaries(pipe) -> None:
             _walk_boundary(el, spec, pad, set())
 
 
+def announce_mesh_upstream(pipe) -> None:
+    """Tell the H2D staging point that feeds each mesh-sharded invoker
+    which plan its uploads must land on (``note_mesh_plan`` hook —
+    ``queue prefetch-device=true``). A staged upload has ONE placement,
+    so the plan travels upstream only along a path on which the meshed
+    invoker is the sole consumer: the walk stops at a host boundary, at
+    any other invoker (its parameters sit where IT placed them) and at
+    any fan-out (a ``tee`` branch without the mesh would be handed a
+    batch spread over devices its parameters are not on). Past those the
+    upload stays on the default device and the sharded invoker re-places
+    it itself, counted in ``nns_reshard_bytes_total``. Every hook is
+    first reset to None so a restart under ``NNSTPU_MESH=0`` (or an
+    edited ``mesh=``) never keeps a stale plan. Runs in
+    ``Pipeline.start()`` next to :func:`verify_mesh_boundaries`."""
+    for el in getattr(pipe, "elements", []):
+        hook = getattr(el, "note_mesh_plan", None)
+        if hook is not None:
+            hook(None)
+    if not _serve.mesh_enabled():
+        return
+    for el in _live_invokers(pipe):
+        plan = _element_mesh_plan(el)
+        if plan is not None:
+            for pad in el.sinkpads:
+                _announce_upstream(plan, pad, set())
+
+
+def _places_own_params(el) -> bool:
+    """A fused region or a tensor_filter: it runs a program against
+    parameters it committed to devices itself."""
+    return isinstance(el, FusedRegion) or hasattr(el, "fw")
+
+
+def _announce_upstream(plan, pad: Pad, seen: set) -> None:
+    peer = pad.peer
+    if peer is None:
+        return
+    el = peer.element
+    if id(el) in seen:
+        return
+    seen.add(id(el))
+    if _places_own_params(el) or \
+            not getattr(el, "DEVICE_PASSTHROUGH", False) or \
+            sum(p.peer is not None for p in el.srcpads) > 1:
+        return  # not ours alone to place: see announce_mesh_upstream
+    hook = getattr(el, "note_mesh_plan", None)
+    if hook is not None:
+        hook(plan)
+    for p in el.sinkpads:
+        _announce_upstream(plan, p, seen)
+
+
 def _live_invokers(pipe):
     """Pipeline elements buffers actually flow through: added elements
     minus fused members, plus the spliced regions themselves (regions
@@ -677,9 +734,7 @@ def pipeline_shard_count(pipe) -> int:
     evenly over dp shards."""
     n = 1
     for el in _live_invokers(pipe):
-        plan = getattr(el, "_mesh_plan", None)
-        if plan is None:
-            plan = getattr(getattr(el, "fw", None), "_mesh_plan", None)
+        plan = _element_mesh_plan(el)
         if plan is not None:
             n = max(n, int(plan.shard_count))
     return n

@@ -16,9 +16,11 @@ Per replica the launcher provides:
 - an isolated state dir (``<state>/replica<i>``) holding the resilient
   dedup-window checkpoint a graceful shutdown writes and the next boot
   restores — the exactly-once half of rolling restarts;
-- a SHARED compile cache (``<state>/compile-cache`` via
-  ``NNSTPU_COMPILE_CACHE``): the first replica pays each XLA
-  compilation, siblings and restarts boot warm;
+- a SHARED compile cache: every replica gets the launcher's own cache
+  directory (``pipeline/continuity.py resolve_compile_cache_dir`` —
+  ``JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache``), so the
+  first replica pays each XLA compilation and siblings, restarts and
+  the next fleet boot warm;
 - crash supervision: an exited replica is relaunched with bounded
   exponential backoff (``nns_fleet_restarts_total`` counts, the backoff
   caps at :data:`RESTART_BACKOFF_MAX_S`, and a replica that stays up
@@ -35,6 +37,16 @@ bench and chaos smoke use it as a deterministic stand-in for a model)
 and arbitrary pipelines via ``--desc`` (launched through ``nns-launch``
 with per-replica checkpoint dirs; ``{index}`` in the description is
 substituted per replica).
+
+One process per chip: a chip belongs to the first process that
+initializes JAX on it, so the launcher itself never initializes a JAX
+backend (keep it so), and on a host with TPU chips it refuses a
+``--desc`` fleet of more than one replica instead of letting the
+replicas crash-loop under the supervisor's backoff
+(:func:`tpu_chips_on_host`): every ``--desc`` replica opens all the
+chips it can see, and the launcher cannot pin one replica to one chip
+yet. ``JAX_PLATFORMS=cpu`` keeps replicas off the chips and lifts the
+limit; a host without TPUs has none.
 
 Kill switches: no fleet process is ever implied — this module only runs
 when invoked. Clients keep their exact single-server path with
@@ -56,6 +68,10 @@ from pathlib import Path
 from typing import Callable, Dict, List, Optional, Tuple
 
 from nnstreamer_tpu.log import get_logger
+from nnstreamer_tpu.pipeline.continuity import (
+    JAX_CACHE_ENV,
+    resolve_compile_cache_dir,
+)
 
 log = get_logger("fleet")
 
@@ -67,6 +83,18 @@ RESTART_BACKOFF_MAX_S = 10.0
 RESTART_RESET_S = 30.0
 #: dedup/continuity checkpoint file inside a replica's state dir
 CHECKPOINT_FILE = "query_server.pkl"
+
+
+def tpu_chips_on_host() -> int:
+    """TPU chips on this host's PCI bus, by the scan JAX itself uses to
+    decide whether to try the TPU. It initializes no backend, so the
+    launcher still does not claim a chip. 0 on a host without TPUs,
+    where JAX runs the replicas on the CPU. (A sandbox may show more
+    chips here than it lets a process open; the launcher only needs to
+    know whether there are any.)"""
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
 
 
 class ReplicaHandle:
@@ -134,6 +162,24 @@ class FleetLauncher:
         self.metrics = bool(metrics)
         self.log_invokes = bool(log_invokes)
         self.extra_env = dict(env or {})
+        if desc and replicas > 1:
+            # each --desc replica is an nns-launch process that opens
+            # every chip it can see unless its environment keeps it on
+            # CPU XLA; env= is fleet-wide, so it cannot pin one replica
+            # to one chip
+            platforms = self.extra_env.get(
+                "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+            chips = tpu_chips_on_host() \
+                if platforms.strip().lower() != "cpu" else 0
+            if chips:
+                raise ValueError(
+                    f"fleet: {replicas} --desc replicas on a host with "
+                    f"{chips} TPU chip(s) on its PCI bus: every replica "
+                    f"would open all the chips it can see, a chip serves "
+                    f"one process at a time, and the launcher cannot pin "
+                    f"replicas to chips yet; run one replica (-n 1), or "
+                    f"set JAX_PLATFORMS=cpu to keep the replicas on CPU "
+                    f"XLA")
         if state_dir:
             self.state_dir = Path(state_dir)
         else:
@@ -157,7 +203,6 @@ class FleetLauncher:
             self.broker_port = self._broker.port
             log.info("fleet broker on 127.0.0.1:%d", self.broker_port)
         self.state_dir.mkdir(parents=True, exist_ok=True)
-        (self.state_dir / "compile-cache").mkdir(exist_ok=True)
         self._stopping.clear()
         for i in range(self.replicas):
             h = ReplicaHandle(i, self.state_dir / f"replica{i}")
@@ -195,7 +240,7 @@ class FleetLauncher:
 
     def _spawn(self, h: ReplicaHandle) -> None:
         env = dict(os.environ)
-        env["NNSTPU_COMPILE_CACHE"] = str(self.state_dir / "compile-cache")
+        env[JAX_CACHE_ENV] = resolve_compile_cache_dir()
         env.update(self.extra_env)
         h.expected_exit = False
         h.started_t = time.monotonic()
@@ -456,8 +501,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "across restarts); 0 = ephemeral ports")
     ap.add_argument("--state-dir", default=None,
                     help="fleet state root: per-replica checkpoint dirs "
-                         "+ the shared compile cache (default: a fresh "
-                         "temp dir)")
+                         "(default: a fresh temp dir)")
     ap.add_argument("--desc", default=None,
                     help="pipeline description to run per replica via "
                          "nns-launch ({index} substituted); default is "

@@ -356,15 +356,20 @@ class TensorBuffer:
         return buf
 
     def to_device(self, device=None, sharding=None) -> "TensorBuffer":
-        """Move all tensors onto a JAX device (or sharding)."""
+        """Move all tensors onto a JAX device (or sharding). ``sharding``
+        may be a callable ``tensor -> sharding`` for placements that
+        depend on each tensor's shape (a serving MeshPlan's
+        ``sharding_for``)."""
         import jax
 
-        tgt = sharding if sharding is not None else device
         t0 = time.monotonic()
         moved = sum(_device_nbytes(t) for t in self.tensors
                     if not is_device_array(t))
-        out = [jax.device_put(t, tgt) if tgt is not None else jax.device_put(t)
-               for t in self.tensors]
+        if callable(sharding):
+            out = [jax.device_put(t, sharding(t)) for t in self.tensors]
+        else:
+            tgt = sharding if sharding is not None else device
+            out = [jax.device_put(t, tgt) for t in self.tensors]
         buf = self.replace(tensors=out)
         if moved:
             _fault_check("transfer.h2d", self.meta)

@@ -158,64 +158,71 @@ class TestConfig:
         assert "torch" in Conf().framework_priority("model.pt")
 
 
-class TestPlatformProbe:
-    """ensure_jax_platform skips the subprocess probe for unset/cpu presets
-    and caches non-CPU probe verdicts (ADVICE r1)."""
+class TestNoCpuFallback:
+    """A process that wants the chip fails without it: nothing probes in
+    a child process, caches a verdict or switches to the CPU on its own
+    (PR 21). bench.py and chip_smoke.py refuse a CPU backend outright."""
 
-    def test_cpu_preset_never_probes(self, monkeypatch):
-        from nnstreamer_tpu.utils import platform as plat
+    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-        def boom(*a, **k):
-            raise AssertionError("probe ran for a cpu preset")
+    @pytest.fixture
+    def repo_on_path(self, monkeypatch):
+        monkeypatch.syspath_prepend(self.REPO)
 
-        monkeypatch.setattr(plat, "probe_jax_platform", boom)
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        assert plat.ensure_jax_platform() == "cpu"
+    def test_bench_entry_refuses_cpu(self, repo_on_path):
+        import bench
 
-    def test_unset_preset_probes_and_caches(self, monkeypatch, tmp_path):
-        """No preset still probes (plugin auto-discovery can wedge the
-        same way an explicit preset can) — but only once per cache TTL."""
-        from nnstreamer_tpu.utils import platform as plat
+        with pytest.raises(SystemExit) as e:
+            bench._require_tpu()
+        assert "backend 'cpu'" in str(e.value)
 
-        import tempfile
-        monkeypatch.setattr(tempfile, "gettempdir", lambda: str(tmp_path))
-        calls = []
-        monkeypatch.setattr(plat, "probe_jax_platform",
-                            lambda *a, **k: calls.append(1) or "cpu")
-        monkeypatch.setenv("JAX_PLATFORMS", "")
-        assert plat.ensure_jax_platform() == "cpu"
-        assert plat.ensure_jax_platform() == "cpu"
-        assert len(calls) == 1
+    def test_bench_process_exits_nonzero_without_a_result(self):
+        import subprocess
+        import sys
 
-    def test_probe_cache_roundtrip(self, monkeypatch, tmp_path):
-        from nnstreamer_tpu.utils import platform as plat
+        proc = subprocess.run(
+            [sys.executable, "bench.py"], cwd=self.REPO,
+            env=dict(os.environ, JAX_PLATFORMS="cpu"),
+            capture_output=True, text=True, timeout=120)
+        assert proc.returncode != 0
+        assert "needs a TPU" in proc.stderr, proc.stderr
+        assert proc.stdout.strip() == "", "a refused bench printed a result"
 
-        monkeypatch.setenv("TMPDIR", str(tmp_path))
-        monkeypatch.delenv("NNSTPU_PROBE_NOCACHE", raising=False)
-        import tempfile
-        monkeypatch.setattr(tempfile, "gettempdir", lambda: str(tmp_path))
-        plat._probe_cache_put("faketpu", "tpu")
-        assert plat._probe_cache_get("faketpu") == {"platform": "tpu"}
-        # failed probes are cached too (repeated startups skip the wait)
-        plat._probe_cache_put("deadtpu", None)
-        assert plat._probe_cache_get("deadtpu") == {"platform": None}
-        # TTL expiry invalidates
-        monkeypatch.setenv("NNSTPU_PROBE_CACHE_TTL", "0")
-        assert plat._probe_cache_get("faketpu") is None
+    def test_unknown_device_kind_is_an_error_not_none(self, repo_on_path):
+        import bench
 
-    def test_cached_verdict_skips_probe(self, monkeypatch, tmp_path):
-        from nnstreamer_tpu.utils import platform as plat
+        with pytest.raises(RuntimeError, match="peaks table"):
+            bench._peak_flops()  # device_kind "cpu" has no bf16 peak
 
-        import tempfile
-        monkeypatch.setattr(tempfile, "gettempdir", lambda: str(tmp_path))
-        calls = []
-        monkeypatch.setattr(plat, "probe_jax_platform",
-                            lambda *a, **k: calls.append(1) or None)
-        monkeypatch.setenv("JAX_PLATFORMS", "bogus_backend")
-        # jax is already initialized on cpu in tests; a failed probe keeps it
-        assert plat.ensure_jax_platform() == "cpu"
-        assert plat.ensure_jax_platform() == "cpu"
-        assert len(calls) == 1  # second call served from the cache
+    def test_smoke_entry_refuses_cpu_and_unknown_chips(self, repo_on_path,
+                                                       monkeypatch):
+        import jax
+
+        import chip_smoke
+
+        with pytest.raises(SystemExit) as e:
+            chip_smoke.require_tpu()
+        assert "backend 'cpu'" in str(e.value)
+
+        class FakeChip:
+            device_kind = "TPU v99"
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(jax, "devices", lambda *a: [FakeChip()])
+        with pytest.raises(SystemExit) as e:
+            chip_smoke.require_tpu()
+        assert "TPU v99" in str(e.value)
+
+    def test_the_fallback_helper_is_gone(self):
+        import glob
+        import importlib.util
+
+        assert importlib.util.find_spec(
+            "nnstreamer_tpu.utils.platform") is None
+        helper = "ensure_jax_" + "platform"  # (kept out of repo greps)
+        for path in glob.glob(os.path.join(self.REPO, "examples", "*.py")):
+            with open(path) as f:
+                assert helper not in f.read(), path
 
 
 class TestEndToEndLatency:

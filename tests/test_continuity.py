@@ -12,8 +12,11 @@ The contract under test, per docs/robustness.md "Serving continuity":
 - every checkpointable component (repo slots, scheduler EWMAs/knobs,
   P2 markers, flight ledger, dedup windows, residency LRU) round-trips
   through its snapshot/restore pair, including under injected faults;
-- ``NNSTPU_CHECKPOINT`` / ``NNSTPU_COMPILE_CACHE`` unset means none of
-  this code runs (byte-identical serving path, no files written);
+- ``NNSTPU_CHECKPOINT`` / ``JAX_COMPILATION_CACHE_DIR`` unset means none
+  of this code runs (byte-identical serving path, no files written);
+- the compile cache lives where ONE rule says: the directory
+  ``JAX_COMPILATION_CACHE_DIR`` names (JAX's own handling, never
+  overridden), else ``<checkout>/.jax_cache``;
 - the persistent compile cache serves re-traces from disk: after
   ``jax.clear_caches()`` the same program loads with zero new XLA
   compiles, visible in ``nns_compile_cache_hits_total``.
@@ -54,6 +57,28 @@ def _clean_injectors():
     yield
     faults.deactivate()
     memory.deactivate()
+
+
+@pytest.fixture(autouse=True)
+def cache_env(tmp_path, monkeypatch):
+    """What ``JAX_COMPILATION_CACHE_DIR=<dir>`` gives a fresh process: the
+    variable set AND ``jax.config`` carrying it. JAX reads the variable
+    at import and this process imported jax long ago, so the fixture
+    does JAX's half by hand (the subprocess test below checks the real
+    thing). Autouse, so every test that arms the cache — directly or
+    through a checkpoint dir — stays out of ``<checkout>/.jax_cache``."""
+    import jax
+    from jax._src import compilation_cache
+
+    directory = str(tmp_path / "jax-cache")
+    compilation_cache.reset_cache()
+    monkeypatch.setenv(continuity.JAX_CACHE_ENV, directory)
+    monkeypatch.setattr(continuity, "_cache_dir", None)
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", directory)
+    yield directory
+    jax.config.update("jax_compilation_cache_dir", before)
+    compilation_cache.reset_cache()
 
 
 def _cval(name, **labels):
@@ -449,8 +474,13 @@ class TestPipelineCheckpointRestore:
 class TestKillSwitches:
     def test_unset_env_writes_nothing(self, swap_models, tmp_path,
                                       monkeypatch):
+        import jax
+
         monkeypatch.delenv(continuity.CHECKPOINT_ENV, raising=False)
-        monkeypatch.delenv(continuity.CACHE_ENV, raising=False)
+        # a process started without the variable: neither the
+        # environment nor jax.config names a cache directory
+        monkeypatch.delenv(continuity.JAX_CACHE_ENV, raising=False)
+        jax.config.update("jax_compilation_cache_dir", None)
         monkeypatch.chdir(tmp_path)
         pipe = parse_launch(
             "videotestsrc num-buffers=4 ! tensor_converter ! "
@@ -459,6 +489,8 @@ class TestKillSwitches:
         assert msg is not None and msg.kind == "eos", msg
         assert pipe.checkpoint_dir is None
         assert not pipe._continuity_restored
+        assert continuity.compile_cache_dir() is None, \
+            "Pipeline.start() armed the cache with nothing asking for it"
         assert list(tmp_path.iterdir()) == [], \
             "unarmed continuity wrote files"
 
@@ -471,7 +503,7 @@ class TestKillSwitches:
         assert not pipe._continuity_restored
 
     def test_env_arms_checkpoint_on_stop(self, swap_models, tmp_path,
-                                         monkeypatch):
+                                         monkeypatch, cache_env):
         monkeypatch.setenv(continuity.CHECKPOINT_ENV, str(tmp_path))
         pipe = parse_launch(
             "videotestsrc num-buffers=2 ! tensor_converter ! "
@@ -480,23 +512,123 @@ class TestKillSwitches:
         assert msg is not None and msg.kind == "eos", msg
         assert os.path.isfile(
             os.path.join(str(tmp_path), continuity.STATE_FILE))
-        # the armed checkpoint dir also defaulted the compile cache in
-        assert continuity.compile_cache_dir() == \
-            os.path.join(str(tmp_path), continuity.CACHE_SUBDIR)
+        # the armed checkpoint dir armed the compile cache too — where
+        # the one rule says, not in a subdirectory of the checkpoint
+        assert continuity.compile_cache_dir() == cache_env
+        assert not os.path.exists(os.path.join(str(tmp_path), "xla-cache"))
 
 
 # -- persistent compile cache -------------------------------------------------
 
 
 class TestCompileCache:
-    def test_cleared_jit_cache_reloads_from_disk(self, tmp_path_factory):
+    def test_variable_set_means_that_directory_and_no_override(
+            self, cache_env, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: JAX's own handling stands — the
+        program never writes ``jax_compilation_cache_dir`` itself."""
         import jax
 
-        cache_dir = str(tmp_path_factory.mktemp("xla-cache"))
-        continuity.enable_compile_cache(cache_dir)
+        assert continuity.resolve_compile_cache_dir() == cache_env
+        writes = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda name, val: (writes.append(name), real_update(name, val)))
+        assert continuity.arm_compile_cache() == cache_env
+        assert "jax_compilation_cache_dir" not in writes
+        assert continuity.compile_cache_dir() == cache_env
+
+    def test_variable_unset_means_checkout_jax_cache(self, tmp_path,
+                                                     monkeypatch):
+        """Unset: ``<checkout>/.jax_cache`` — a fixed path, because the
+        path is part of the cache key and a directory that moves never
+        hits. (Armed against a stand-in so the test stays hermetic.)"""
+        import jax
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert continuity.DEFAULT_CACHE_DIR == os.path.join(repo,
+                                                            ".jax_cache")
+        monkeypatch.delenv(continuity.JAX_CACHE_ENV)
+        assert continuity.resolve_compile_cache_dir() == \
+            continuity.DEFAULT_CACHE_DIR
+        stand_in = str(tmp_path / "checkout" / ".jax_cache")
+        monkeypatch.setattr(continuity, "DEFAULT_CACHE_DIR", stand_in)
+        assert continuity.arm_compile_cache() == stand_in
+        assert jax.config.jax_compilation_cache_dir == stand_in
+        assert os.path.isdir(stand_in)
+
+    def test_variable_set_after_jax_import_is_an_error_not_an_inert_cache(
+            self, tmp_path, monkeypatch):
+        """jax.config took the variable at import; one set later names a
+        directory JAX never writes. Reporting it as armed would print a
+        cache directory in the smoke/bench JSON that is not in use."""
+        late = str(tmp_path / "set-too-late")
+        monkeypatch.setenv(continuity.JAX_CACHE_ENV, late)
+        with pytest.raises(RuntimeError,
+                           match="JAX_COMPILATION_CACHE_DIR.*after"):
+            continuity.arm_compile_cache()
+        assert continuity.compile_cache_dir() is None
+        # Pipeline.start()'s hook serves cold instead of failing the start
+        assert continuity.maybe_arm_compile_cache() is None
+        assert continuity.compile_cache_dir() is None
+
+    def test_uncreatable_default_names_the_variable(self, tmp_path,
+                                                    monkeypatch):
+        blocker = tmp_path / "a-file"
+        blocker.write_text("")
+        monkeypatch.delenv(continuity.JAX_CACHE_ENV)
+        monkeypatch.setattr(continuity, "DEFAULT_CACHE_DIR",
+                            str(blocker / ".jax_cache"))
+        with pytest.raises(OSError, match="JAX_COMPILATION_CACHE_DIR"):
+            continuity.arm_compile_cache()
+
+    def test_cli_rejects_the_old_compile_cache_dir_spelling(
+            self, tmp_path, capsys):
+        """``--compile-cache`` took a DIR before PR 21; the old spelling
+        must not parse the directory as the pipeline description."""
+        from nnstreamer_tpu import cli
+
+        with pytest.raises(SystemExit) as exc:
+            cli.main(["--compile-cache", str(tmp_path),
+                      "videotestsrc num-buffers=1 ! tensor_sink"])
+        assert exc.value.code == 2
+        err = capsys.readouterr().err
+        assert "takes no directory" in err and \
+            "JAX_COMPILATION_CACHE_DIR" in err
+
+    def test_fresh_process_writes_where_the_variable_says(self, tmp_path):
+        """The real thing, no fixture sleight of hand: a new interpreter
+        with the variable set compiles one program and the entry lands
+        in that directory."""
+        import subprocess
+        import sys
+
+        target = tmp_path / "from-env"
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        prog = (
+            "import jax\n"
+            "from nnstreamer_tpu.pipeline import continuity\n"
+            "print(continuity.arm_compile_cache())\n"
+            "jax.jit(lambda x: x * 2.125 + 7.375)(1.0).block_until_ready()\n"
+            "print(continuity.cache_stats()['misses'])\n")
+        proc = subprocess.run(
+            [sys.executable, "-c", prog], cwd=repo, capture_output=True,
+            text=True, timeout=120,
+            env=dict(os.environ, JAX_PLATFORMS="cpu",
+                     JAX_COMPILATION_CACHE_DIR=str(target)))
+        assert proc.returncode == 0, proc.stderr
+        reported, misses = proc.stdout.split()
+        assert reported == str(target) and int(misses) >= 1
+        assert any(target.iterdir()), "no cache entry was written there"
+        assert not os.path.exists(os.path.join(repo, ".jax_cache",
+                                               target.name))
+
+    def test_cleared_jit_cache_reloads_from_disk(self, cache_env):
+        import jax
+
+        continuity.arm_compile_cache()
         # idempotent re-arm is a no-op
-        assert continuity.enable_compile_cache(cache_dir) == \
-            os.path.abspath(cache_dir)
+        assert continuity.arm_compile_cache() == cache_env
 
         # odd constants: a program no other test in this process has
         # compiled yet, so the cold trace is a genuine cache miss
@@ -531,7 +663,7 @@ class TestCompileCache:
                                                      tmp_path):
         import json
 
-        continuity.enable_compile_cache(str(tmp_path / "cache"))
+        continuity.arm_compile_cache()
         pipe = parse_launch(
             "videotestsrc num-buffers=2 ! tensor_converter ! "
             "tensor_transform mode=arithmetic option=typecast:float32 ! "

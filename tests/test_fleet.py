@@ -117,3 +117,133 @@ class TestFleetLauncher:
     def test_replicas_validate(self):
         with pytest.raises(ValueError):
             FleetLauncher(replicas=0)
+
+
+class TestOneProcessPerChip:
+    """PR 21: the launcher hands every replica ITS cache directory (one
+    rule, pipeline/continuity.py), refuses a --desc fleet larger than
+    the host's chip count, and never initializes a JAX backend itself —
+    the first process to do so owns the chip."""
+
+    DESC = "tensor_query_serversrc operation=x ! tensor_query_serversink"
+
+    @pytest.fixture
+    def spawned_envs(self, monkeypatch):
+        """Record what _spawn would start instead of starting it."""
+        from nnstreamer_tpu.serving import fleet as fleet_mod
+
+        seen = []
+
+        class FakeProc:
+            pid = 4242
+            returncode = None
+
+            def poll(self):
+                return None
+
+        def fake_popen(cmd, env=None, **kw):
+            seen.append((cmd, env))
+            return FakeProc()
+
+        monkeypatch.setattr(fleet_mod.subprocess, "Popen", fake_popen)
+        return seen
+
+    def _spawn_one(self, tmp_path, **kw):
+        from nnstreamer_tpu.serving.fleet import ReplicaHandle
+
+        fleet = FleetLauncher(replicas=1, operation="tf-cache",
+                              state_dir=str(tmp_path / "state"), **kw)
+        h = ReplicaHandle(0, tmp_path / "state" / "replica0")
+        h.state_dir.mkdir(parents=True)
+        fleet._spawn(h)
+        return fleet
+
+    def test_children_get_the_parents_cache_directory(
+            self, spawned_envs, tmp_path, monkeypatch):
+        from nnstreamer_tpu.pipeline import continuity
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "shared-cache"))
+        self._spawn_one(tmp_path)
+        _cmd, env = spawned_envs[-1]
+        assert env["JAX_COMPILATION_CACHE_DIR"] == \
+            str(tmp_path / "shared-cache") == \
+            continuity.resolve_compile_cache_dir()
+        assert "NNSTPU_COMPILE_CACHE" not in env
+
+    def test_unset_children_get_checkout_jax_cache_not_the_state_dir(
+            self, spawned_envs, tmp_path, monkeypatch):
+        from nnstreamer_tpu.pipeline import continuity
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        fleet = self._spawn_one(tmp_path)
+        _cmd, env = spawned_envs[-1]
+        assert env["JAX_COMPILATION_CACHE_DIR"] == \
+            continuity.DEFAULT_CACHE_DIR
+        assert str(fleet.state_dir) not in env["JAX_COMPILATION_CACHE_DIR"]
+        assert not (fleet.state_dir / "compile-cache").exists()
+
+    def test_desc_fleet_larger_than_the_chip_count_is_refused(
+            self, monkeypatch, tmp_path):
+        from nnstreamer_tpu.serving import fleet as fleet_mod
+
+        state = str(tmp_path)
+        # the children would open the chips: JAX_PLATFORMS is not cpu.
+        # No replica can be pinned to one chip yet, so as many replicas
+        # as chips crash-loop just like more replicas than chips
+        for chips in (1, 4):
+            monkeypatch.setattr(fleet_mod, "tpu_chips_on_host",
+                                lambda n=chips: n)
+            for replicas in (2, 4, 5):
+                with pytest.raises(
+                        ValueError,
+                        match=f"{replicas} --desc replicas.*{chips} TPU chip"):
+                    FleetLauncher(replicas=replicas, desc=self.DESC,
+                                  state_dir=state, env={"JAX_PLATFORMS": ""})
+            # one replica is fine, and so is any number of replicas kept
+            # on CPU XLA or of built-in echo replicas
+            FleetLauncher(replicas=1, desc=self.DESC, state_dir=state,
+                          env={"JAX_PLATFORMS": ""})
+            FleetLauncher(replicas=3, desc=self.DESC, state_dir=state,
+                          env={"JAX_PLATFORMS": "cpu"})
+            FleetLauncher(replicas=3, state_dir=state,
+                          env={"JAX_PLATFORMS": ""})
+
+    def test_host_without_tpus_runs_any_desc_fleet(
+            self, monkeypatch, tmp_path):
+        """No chip, nothing to fight over: JAX runs the replicas on the
+        CPU without JAX_PLATFORMS having to say so."""
+        from nnstreamer_tpu.serving import fleet as fleet_mod
+
+        assert fleet_mod.tpu_chips_on_host() >= 0  # the real scan runs
+        monkeypatch.setattr(fleet_mod, "tpu_chips_on_host", lambda: 0)
+        FleetLauncher(replicas=3, desc=self.DESC, state_dir=str(tmp_path),
+                      env={"JAX_PLATFORMS": ""})
+
+    def test_launcher_parent_stays_off_jax(self):
+        """Importing the package and running a fleet from it initializes
+        no JAX backend in the launcher process (a parent that touched JAX
+        would hold the chip its replicas need)."""
+        import os
+        import subprocess
+        import sys
+
+        prog = (
+            "import sys\n"
+            "from nnstreamer_tpu.serving.fleet import FleetLauncher\n"
+            "fleet = FleetLauncher(replicas=1, operation='tf-nojax',\n"
+            "                      spin_ms=0.0).start()\n"
+            "try:\n"
+            "    assert len(fleet.endpoints(timeout=20.0)) == 1\n"
+            "finally:\n"
+            "    fleet.stop()\n"
+            "backends = {}\n"
+            "if 'jax' in sys.modules:\n"
+            "    from jax._src import xla_bridge\n"
+            "    backends = xla_bridge._backends\n"
+            "assert not backends, backends\n"
+            "print('jax imported:', 'jax' in sys.modules)\n")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        proc = subprocess.run([sys.executable, "-c", prog], cwd=repo,
+                              capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr

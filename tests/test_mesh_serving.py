@@ -33,6 +33,7 @@ from nnstreamer_tpu.parallel.serve import (
 )
 from nnstreamer_tpu.serving.scheduler import SloScheduler
 from nnstreamer_tpu.tensors import memory
+from nnstreamer_tpu.tensors.buffer import TensorBuffer
 
 jax = pytest.importorskip("jax")
 jnp = pytest.importorskip("jax.numpy")
@@ -113,6 +114,157 @@ class TestPlaceBatch:
         plan = get_mesh_plan("dp8")
         placed = place_batch(np.ones((3, 4), np.float32), plan)
         assert placed.sharding == plan.replicated()
+
+
+# -- staged uploads land on the mesh (prefetch-device + mesh=) ----------------
+
+
+STAGED_DESC = (
+    "appsrc name=src ! "
+    "queue name=stage max-size-buffers=4 prefetch-device=true ! "
+    "tensor_transform mode=arithmetic option=typecast:float32,add:1.0 ! "
+    "tensor_filter framework=jax model=mesh_sv_a name=fa {mesh}! "
+    "queue max-size-buffers=8 materialize-host=true ! "
+    "tensor_sink name=sink to-host=true"
+)
+
+
+class TestStagedUploadLandsOnTheMesh:
+    """The flagship topology stages H2D in a ``prefetch-device`` queue
+    ahead of the fused region. Under ``mesh=`` that upload must land
+    batch-sharded on the mesh straight from the host — staged on the
+    default device it made the region re-place every frame chip 0 →
+    mesh, and nns_reshard_bytes_total counted every byte (PR 21)."""
+
+    def _run(self, mesh, frames=6):
+        pipe = parse_launch(STAGED_DESC.format(
+            mesh=f"mesh={mesh} " if mesh else ""))
+        src, sink = pipe.get("src"), pipe.get("sink")
+        pipe.start()
+        try:
+            plan = pipe.get("stage")._upload_plan
+            for i in range(frames):
+                src.push([np.full((8, 4), i, np.uint8)])
+            src.end_of_stream()
+            msg = pipe.wait(timeout=120)
+            assert msg is not None and msg.kind == "eos", msg
+        finally:
+            pipe.stop()
+        return plan, [np.asarray(b.tensors[0]) for b in sink.buffers]
+
+    def test_staged_frames_move_zero_reshard_bytes(self, chain_models):
+        r0 = serve.reshard_bytes_total()
+        plan, outs = self._run("dp8")
+        assert plan is get_mesh_plan("dp8"), \
+            "the staging queue never learned its consumer's mesh plan"
+        assert serve.reshard_bytes_total() == r0, (
+            "frames were staged on one device and resharded onto the "
+            "mesh by the region")
+        _, ref = self._run("")
+        assert len(outs) == len(ref) == 6
+        for o, r in zip(outs, ref):
+            assert np.array_equal(o, r)
+
+    def test_plan_is_dropped_when_the_mesh_is_switched_off(
+            self, chain_models, monkeypatch):
+        pipe = parse_launch(STAGED_DESC.format(mesh="mesh=dp8 "))
+        pipe.start()
+        try:
+            assert pipe.get("stage")._upload_plan is get_mesh_plan("dp8")
+        finally:
+            pipe.stop()
+        monkeypatch.setenv("NNSTPU_MESH", "0")
+        pipe.start()
+        try:
+            assert pipe.get("stage")._upload_plan is None, \
+                "a restart without the mesh kept the stale plan"
+        finally:
+            pipe.stop()
+
+    # a staged upload has ONE placement, so the queue may take a plan
+    # only when the meshed invoker is its sole consumer: past a fan-out
+    # or another invoker the frames stay on the default device and the
+    # meshed branch reshards them itself (counted), as before PR 21.
+    # The models carry parameters: an unmeshed invoker commits them to
+    # one device and XLA refuses a batch that arrives on eight.
+
+    @pytest.fixture
+    def param_models(self):
+        register_jax_model("mesh_sv_pa", lambda p, x: (x * p,),
+                           params=np.float32(2.0))
+        register_jax_model("mesh_sv_pb", lambda p, x: (x + p,),
+                           params=np.float32(1.0))
+        yield
+        unregister_jax_model("mesh_sv_pa")
+        unregister_jax_model("mesh_sv_pb")
+
+    def _run_desc(self, desc, sinks, frames=4):
+        pipe = parse_launch(desc)
+        src = pipe.get("src")
+        pipe.start()
+        try:
+            plan = pipe.get("stage")._upload_plan
+            for i in range(frames):
+                src.push([np.full((8, 4), i, np.uint8)])
+            src.end_of_stream()
+            msg = pipe.wait(timeout=120)
+            assert msg is not None and msg.kind == "eos", msg
+        finally:
+            pipe.stop()
+        return plan, [[np.asarray(b.tensors[0])
+                       for b in pipe.get(s).buffers] for s in sinks]
+
+    @pytest.mark.parametrize("mesh_a,mesh_b", [
+        ("mesh=dp8", ""),          # mixed: one branch meshed, one not
+        ("mesh=dp8", "mesh=dp4"),  # mismatched meshes
+        ("mesh=dp8", "mesh=dp8"),  # even matched: the walk stops at a tee
+    ])
+    def test_tee_branches_keep_the_upload_off_the_mesh(
+            self, param_models, mesh_a, mesh_b):
+        desc = (
+            "appsrc name=src ! "
+            "queue name=stage max-size-buffers=4 prefetch-device=true ! "
+            "tee name=t "
+            "t. ! tensor_transform mode=arithmetic "
+            "option=typecast:float32,add:1.0 ! "
+            f"tensor_filter framework=jax model=mesh_sv_pa {mesh_a} ! "
+            "tensor_sink name=sa to-host=true "
+            "t. ! tensor_transform mode=arithmetic "
+            "option=typecast:float32,add:1.0 ! "
+            f"tensor_filter framework=jax model=mesh_sv_pb {mesh_b} ! "
+            "tensor_sink name=sb to-host=true")
+        plan, (a, b) = self._run_desc(desc, ("sa", "sb"))
+        assert plan is None, "a fan-out's upload was placed for one branch"
+        assert len(a) == len(b) == 4
+        for i in range(4):
+            assert np.array_equal(a[i], np.full((8, 4), (i + 1) * 2.0))
+            assert np.array_equal(b[i], np.full((8, 4), i + 2.0))
+
+    def test_unmeshed_invoker_in_between_keeps_its_own_placement(
+            self, param_models):
+        desc = (
+            "appsrc name=src ! "
+            "queue name=stage max-size-buffers=4 prefetch-device=true ! "
+            "tensor_transform mode=arithmetic "
+            "option=typecast:float32,add:1.0 ! "
+            "tensor_filter framework=jax model=mesh_sv_pa ! "
+            "queue max-size-buffers=4 ! "
+            "tensor_filter framework=jax model=mesh_sv_pb mesh=dp8 ! "
+            "tensor_sink name=sink to-host=true")
+        plan, (outs,) = self._run_desc(desc, ("sink",))
+        assert plan is None, \
+            "the plan walked through an invoker that is not on the mesh"
+        assert len(outs) == 4
+        for i, o in enumerate(outs):
+            assert np.array_equal(o, np.full((8, 4), (i + 1) * 2.0 + 1.0))
+
+    def test_to_device_takes_a_per_tensor_sharding_callable(self):
+        plan = get_mesh_plan("dp8")
+        buf = TensorBuffer([np.zeros((8, 4), np.float32),
+                            np.zeros((3, 4), np.float32)])
+        dev = buf.to_device(sharding=plan.sharding_for)
+        assert dev.tensors[0].sharding == plan.batched()
+        assert dev.tensors[1].sharding == plan.replicated()  # ragged
 
 
 # -- chained sharded regions: matched boundaries ------------------------------
