@@ -177,9 +177,12 @@ def make_layer_body(cfg: TransformerConfig,
 
     def layer_body(x_and_pos, lp):
         x, positions = x_and_pos
-        q, k, v = _block_qkv(x, lp, positions, dtype)
-        a = attn(q, k, v)                                # [b,s,h,dh]
-        x = _block_tail(x, a, lp, cfg)
+        with jax.named_scope("qkv"):
+            q, k, v = _block_qkv(x, lp, positions, dtype)
+        with jax.named_scope("attend"):
+            a = attn(q, k, v)                            # [b,s,h,dh]
+        with jax.named_scope("ffn"):
+            x = _block_tail(x, a, lp, cfg)
         return (x, positions), (jnp.stack([k, v]) if capture_kv else None)
 
     return layer_body
@@ -403,6 +406,7 @@ def build_decode_step(cfg: TransformerConfig,
     s_max = max_seq or cfg.max_seq
     codec = _kv_codec(cfg, kv_codec)
 
+    @jax.named_scope("nns.decode")
     def step(params, token, cache, pos):
         b = token.shape[0]
         pos = jnp.asarray(pos, jnp.int32)
@@ -417,19 +421,25 @@ def build_decode_step(cfg: TransformerConfig,
         def layer(carry, lp_and_cache):
             x, = carry
             lp, layer_cache = lp_and_cache                # [2,b,S,h,dh]
-            q, k, v = _block_qkv(x, lp, positions, dtype)  # [b,1,h,dh]
-            new_cache = codec.write(layer_cache, jnp.stack([k, v]),
-                                    pos_c, per_stream)
-            slots = jnp.arange(s_max)
-            mask = slots[None, None, None, :] <= (
-                pos_c[:, None, None, None] if per_stream else pos_c)
-            ck, cv = codec.read(new_cache)
-            a = _attend_cache(q, ck, cv, mask, cfg.head_dim, dtype)
-            x = _block_tail(x, a, lp, cfg)
+            with jax.named_scope("qkv"):
+                q, k, v = _block_qkv(x, lp, positions, dtype)  # [b,1,h,dh]
+            with jax.named_scope("kv_write"):
+                new_cache = codec.write(layer_cache, jnp.stack([k, v]),
+                                        pos_c, per_stream)
+            with jax.named_scope("kv_gather"):
+                slots = jnp.arange(s_max)
+                mask = slots[None, None, None, :] <= (
+                    pos_c[:, None, None, None] if per_stream else pos_c)
+                ck, cv = codec.read(new_cache)
+            with jax.named_scope("attend"):
+                a = _attend_cache(q, ck, cv, mask, cfg.head_dim, dtype)
+            with jax.named_scope("ffn"):
+                x = _block_tail(x, a, lp, cfg)
             return (x,), new_cache
 
         (x,), new_cache = lax.scan(layer, (x,), (layer_params, cache))
-        return _final_logits(x, params)[:, 0], new_cache
+        with jax.named_scope("logits"):
+            return _final_logits(x, params)[:, 0], new_cache
 
     return step
 
@@ -529,6 +539,7 @@ def build_paged_decode_step(cfg: TransformerConfig,
             f"positive multiple of block_tokens ({block_tokens})")
     codec = _kv_codec(cfg, kv_codec)
 
+    @jax.named_scope("nns.decode")
     def step(params, token, arena, bt, pos):
         pos = jnp.asarray(pos, jnp.int32)
         pos_c = jnp.minimum(pos, s_max - 1)  # cache-length contract
@@ -542,18 +553,25 @@ def build_paged_decode_step(cfg: TransformerConfig,
         def layer(carry, lp_and_pages):
             x, = carry
             lp, pages = lp_and_pages              # one layer's blocks
-            q, k, v = _block_qkv(x, lp, positions, dtype)  # [b,1,h,dh]
-            pages = codec.paged_write(pages, jnp.stack([k, v]), blk, off)
-            slots = jnp.arange(s_max)
-            mask = slots[None, None, None, :] <= pos_c[:, None, None,
-                                                       None]
-            ck, cv = codec.paged_read(pages, bt)
-            a = _attend_cache(q, ck, cv, mask, cfg.head_dim, dtype)
-            x = _block_tail(x, a, lp, cfg)
+            with jax.named_scope("qkv"):
+                q, k, v = _block_qkv(x, lp, positions, dtype)  # [b,1,h,dh]
+            with jax.named_scope("kv_write"):
+                pages = codec.paged_write(pages, jnp.stack([k, v]), blk,
+                                          off)
+            with jax.named_scope("kv_gather"):
+                slots = jnp.arange(s_max)
+                mask = slots[None, None, None, :] <= pos_c[:, None, None,
+                                                           None]
+                ck, cv = codec.paged_read(pages, bt)
+            with jax.named_scope("attend"):
+                a = _attend_cache(q, ck, cv, mask, cfg.head_dim, dtype)
+            with jax.named_scope("ffn"):
+                x = _block_tail(x, a, lp, cfg)
             return (x,), pages
 
         (x,), new_arena = lax.scan(layer, (x,), (layer_params, arena))
-        return _final_logits(x, params)[:, 0], new_arena
+        with jax.named_scope("logits"):
+            return _final_logits(x, params)[:, 0], new_arena
 
     return step
 
@@ -641,6 +659,7 @@ def build_prefill(cfg: TransformerConfig,
     codec = _kv_codec(cfg, kv_codec)
     layer_body = make_layer_body(cfg, attention_fn, capture_kv=True)
 
+    @jax.named_scope("nns.prefill")
     def prefill(params, tokens, lengths=None):
         b, s = tokens.shape
         positions = jnp.arange(s)[None, :].astype(jnp.int32) * jnp.ones(
@@ -653,16 +672,17 @@ def build_prefill(cfg: TransformerConfig,
         cache = codec.place_prefix(
             codec.init(cfg.n_layers, b, s_max, cfg.n_heads, cfg.head_dim),
             kv)
-        x = _rmsnorm(x, params["ln_f"])
-        if lengths is None:
-            last = x[:, -1]
-        else:
-            idx = (jnp.asarray(lengths, jnp.int32) - 1)[:, None, None]
-            last = jnp.take_along_axis(
-                x, jnp.broadcast_to(idx, (b, 1, x.shape[-1])), axis=1
-            )[:, 0]
-        logits = jnp.einsum("bd,vd->bv", last.astype(jnp.float32),
-                            params["embed"])
+        with jax.named_scope("logits"):
+            x = _rmsnorm(x, params["ln_f"])
+            if lengths is None:
+                last = x[:, -1]
+            else:
+                idx = (jnp.asarray(lengths, jnp.int32) - 1)[:, None, None]
+                last = jnp.take_along_axis(
+                    x, jnp.broadcast_to(idx, (b, 1, x.shape[-1])), axis=1
+                )[:, 0]
+            logits = jnp.einsum("bd,vd->bv", last.astype(jnp.float32),
+                                params["embed"])
         return logits, cache
 
     return prefill

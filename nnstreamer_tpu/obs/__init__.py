@@ -46,7 +46,7 @@ from nnstreamer_tpu.obs.server import MetricsServer  # noqa: F401
 from nnstreamer_tpu.obs.timeline import (  # noqa: F401
     TRACE_SEQ_META,
     Timeline,
-    jax_correlation,
+    device_trace,
     trace_enabled,
     tracing,
 )

@@ -73,6 +73,13 @@ def register_engine_collector(engine, registry: MetricsRegistry = None
                     "prefix_tokens_reused"):
             reg.counter(f"nns_serving_{key}_total", **labels).set_total(
                 eng.stats[key])
+        for key, us in list(eng.stats.items()):
+            if key.startswith("phase_"):
+                reg.counter(
+                    "nns_serving_loop_phase_seconds_total",
+                    "Engine-loop thread time by phase; the phases tile "
+                    "the loop", phase=key[len("phase_"):-len("_us")],
+                    **labels).set_total(us / 1e6)
         return True
 
     reg.register_collector(collect)

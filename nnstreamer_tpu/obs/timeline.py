@@ -62,16 +62,27 @@ frame seq, ``s``/``t``/``f`` flow events linking one frame across
 tracks, and ``b``/``e`` async spans for dispatch-window inflight slots.
 :meth:`stage_breakdown` aggregates the same records into per-stage
 means that must sum to ~e2e; :meth:`variance_report` attributes
-warm-run spread to its dominant stage. :func:`jax_correlation` runs
-``jax.profiler`` over the same window so the XLA device trace can be
-lined up with the frame ledger in one Perfetto session.
+warm-run spread to its dominant stage.
+
+One clock with the device trace
+-------------------------------
+Spans are recorded after the fact on ``time.monotonic()``; the JAX
+profiler stamps device events in Unix-epoch nanoseconds. A timeline
+notes one ``(time.monotonic(), time.time_ns())`` pair when it is made,
+:meth:`Timeline.to_trace_ns` converts with it, and
+:func:`device_trace` runs the profiler (device tracer only) over a
+window and writes the ledger's spans on the trace's clock beside the
+device's program executions, so a gap on the device line lies over the
+host span that caused it.
 """
 
 from __future__ import annotations
 
+import glob
 import itertools
 import json
 import os
+import statistics
 import threading
 import time
 import weakref
@@ -185,30 +196,176 @@ def maybe_export_env() -> None:
     deactivate()
 
 
+#: the device plane's line of program executions in a profiler trace
+_MODULES_LINE = "XLA Modules"
+
+
+def program_events(xplane_path: str) -> List[Tuple[str, str, int, int]]:
+    """``(device, program, start_ns, end_ns)`` of every program execution
+    in a profiler trace, by start time, in Unix-epoch nanoseconds;
+    ``jit_step(123)`` reads ``jit_step``. The device plane counts from
+    the profile's start (looked at by hand, libtpu 0.0.34: the first
+    event of a trace at 41,629,803 ns), and the plane ``Task
+    Environment`` says when that was (``profile_start_time``)."""
+    from jax.profiler import ProfileData
+
+    out, start = [], 0
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if plane.name == "Task Environment":
+            start = int(dict(plane.stats).get("profile_start_time", 0))
+        if not plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            if line.name != _MODULES_LINE:
+                continue
+            for ev in line.events:
+                out.append((plane.name, ev.name.split("(")[0].strip(),
+                            round(ev.start_ns),
+                            round(ev.start_ns + ev.duration_ns)))
+    # whole numbers: a float holds 1.8e18 ns to the nearest 256
+    return sorted(((d, n, start + a, start + b) for d, n, a, b in out),
+                  key=lambda e: e[2])
+
+
+def clock_differences(host: List[Tuple[int, int]],
+                      device: List[Tuple[int, int]]
+                      ) -> List[Tuple[int, int]]:
+    """``(host start - device start, host end - device end)``, the k-th
+    host span against the k-th device event, both as ``(start_ns,
+    end_ns)``. The window's edges may cut the two lists differently, so
+    the pairing is tried shifted by up to three places and the one whose
+    ends lie closest is taken: right as long as the clocks differ by
+    less than half the time between two executions."""
+    best: List[Tuple[int, int]] = []
+
+    def ends_apart(pairs):
+        return abs(statistics.median(p[1] for p in pairs))
+
+    for shift in range(-3, 4):
+        pairs = [(h[0] - device[i + shift][0], h[1] - device[i + shift][1])
+                 for i, h in enumerate(host) if 0 <= i + shift < len(device)]
+        if pairs and (not best or ends_apart(pairs) < ends_apart(best)):
+            best = pairs
+    return best
+
+
+class DeviceTrace:
+    """What :func:`device_trace` yields; filled in when the block ends."""
+
+    def __init__(self, logdir: str, ledger: Optional["Timeline"]):
+        self.logdir = logdir
+        self.ledger = ledger
+        self.window: Tuple[float, float] = (0.0, 0.0)  # time.monotonic()
+        self.xplane: Optional[str] = None       # the profiler's file
+        self.ledger_path: Optional[str] = None  # the ledger, trace clock
+        #: ``(device, program, start_ns, end_ns)`` from the trace
+        self.programs: List[Tuple[str, str, int, int]] = []
+        #: added to ``ledger.to_trace_ns()``: what matching found
+        self.offset_ns = 0
+        #: the matching, before the correction, when ``align`` was given:
+        #: ``n`` pairs, ``lead_ns`` (host start minus device start, the
+        #: largest) and ``lag_min_ns``/``lag_median_ns``/``lag_max_ns``
+        #: (host end minus device end)
+        self.clock_check: Optional[Dict[str, float]] = None
+
+    def to_trace_ns(self, t: float) -> int:
+        return self.ledger.to_trace_ns(t) + self.offset_ns
+
+    def _align(self, span_kind: str, program: str) -> None:
+        t_a, t_b = self.window
+        to_ns = self.ledger.to_trace_ns
+        host = [(to_ns(r[3]), to_ns(r[4])) for r in self.ledger._snapshot()
+                if r[1] == span_kind and r[4] is not None
+                and t_a <= r[3] and r[4] <= t_b]
+        device = [(e[2], e[3]) for e in self.programs if e[1] == program]
+        pairs = clock_differences(host, device)
+        if not pairs:
+            return
+        lead = max(p[0] for p in pairs)
+        lags = [p[1] for p in pairs]
+        self.clock_check = {"n": len(pairs), "lead_ns": lead,
+                            "lag_min_ns": min(lags),
+                            "lag_median_ns": statistics.median(lags),
+                            "lag_max_ns": max(lags)}
+        # the span holds the device event: any offset from -lag to -lead
+        # is possible, the middle is wrong by the least
+        self.offset_ns = -(min(lags) + lead) // 2
+
+    def _write(self) -> None:
+        doc = self.ledger.to_chrome()
+        # the export counts microseconds from the ledger's epoch
+        shift_us = self.to_trace_ns(self.ledger.epoch) / 1e3
+        for ev in doc["traceEvents"]:
+            if "ts" in ev:
+                ev["ts"] += shift_us
+        pids = {dev: 1000 + i for i, dev in enumerate(
+            sorted({e[0] for e in self.programs}))}
+        for dev, pid in pids.items():
+            doc["traceEvents"].append(
+                {"name": "process_name", "ph": "M", "pid": pid,
+                 "args": {"name": dev}})
+            doc["traceEvents"].append(
+                {"name": "thread_name", "ph": "M", "pid": pid, "tid": 1,
+                 "args": {"name": _MODULES_LINE}})
+        for dev, name, start, end in self.programs:
+            doc["traceEvents"].append(
+                {"name": name, "cat": "device", "ph": "X",
+                 "ts": start / 1e3, "dur": (end - start) / 1e3,
+                 "pid": pids[dev], "tid": 1})
+        doc["metadata"]["clock"]["offset_ns"] = self.offset_ns
+        self.ledger_path = os.path.join(self.logdir, "ledger.trace.json")
+        with open(self.ledger_path, "w") as f:
+            json.dump(doc, f)
+
+
 @contextmanager
-def jax_correlation(logdir: str):
-    """Run ``jax.profiler`` over the same window as the active timeline
-    so the XLA device trace and the frame ledger share a wall-clock
-    span and can be loaded side by side in Perfetto. Degrades to a
-    no-op when the profiler is unavailable."""
-    started = False
-    try:
-        import jax
+def device_trace(logdir: str, ledger: Optional["Timeline"] = None,
+                 align: Optional[Tuple[str, str]] = None):
+    """Run ``jax.profiler`` over the block with the DEVICE tracer only
+    (the host and Python tracers slowed a traced pipeline five-fold:
+    PERF.md, PR 24) and, when the block ends, write the ledger's spans
+    on the trace's clock to ``<logdir>/ledger.trace.json`` together with
+    the device's program executions: one file for Perfetto in which a
+    gap on the device line lies over the host span that caused it. The
+    profiler's own ``.xplane.pb`` (every operation) stays where it wrote
+    it. ``ledger`` defaults to the installed timeline.
 
-        jax.profiler.start_trace(logdir)
-        started = True
-    except Exception:  # noqa: BLE001 — profiling is best-effort
-        started = False
+    ``align=(span_kind, program)`` checks the common clock, and sets it
+    right, by matching the k-th ``span_kind`` span of the window against
+    the k-th execution of ``program``. The span has to be one that
+    issues the program and waits for its result: it then holds the
+    device event, with the launch before it and the fetch after. How far
+    the span leads and lags goes to ``clock_check``; the offset that
+    splits the slack evenly goes to ``offset_ns`` and into the written
+    file. On a v5e the device's clock read 0.3 to 3.6 ms behind the
+    host's (PERF.md, PR 25): without ``align`` a span shorter than that
+    can lie beside the gap it caused.
+
+    Yields a :class:`DeviceTrace`."""
+    import jax
+
+    ledger = ledger if ledger is not None else ACTIVE
+    out = DeviceTrace(logdir, ledger)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 0
+    options.enable_hlo_proto = False
+    jax.profiler.start_trace(logdir, profiler_options=options)
+    t_a = time.monotonic()
     try:
-        yield
+        yield out
     finally:
-        if started:
-            try:
-                import jax
-
-                jax.profiler.stop_trace()
-            except Exception:  # nns-lint: disable=NNS104 -- stop_trace after a successful start can only fail at teardown; the ledger export must still proceed
-                pass
+        out.window = (t_a, time.monotonic())
+        jax.profiler.stop_trace()
+    files = glob.glob(os.path.join(
+        logdir, "plugins", "profile", "*", "*.xplane.pb"))
+    if files:
+        out.xplane = max(files, key=os.path.getmtime)
+        out.programs = program_events(out.xplane)
+    if ledger is not None:
+        if align is not None:
+            out._align(*align)
+        out._write()
 
 
 class _RingAnchor:
@@ -230,6 +387,9 @@ class Timeline:
     def __init__(self, capacity: int = 1 << 16):
         self.capacity = int(capacity)
         self.epoch = time.monotonic()
+        #: one reading of both clocks: spans are recorded on the first,
+        #: the device trace is stamped on the second (``to_trace_ns``)
+        self.clock: Tuple[float, int] = (time.monotonic(), time.time_ns())
         self.export_path: Optional[str] = None
         self._env_owned = False
         self._seq = itertools.count()  # next() is GIL-atomic
@@ -286,6 +446,17 @@ class Timeline:
              track: Optional[str] = None, **args) -> None:
         """Record a duration span [t0, t1) attributed to frame ``seq``."""
         self._ring().append((kind, seq, t0, t1, track, args or None))
+
+    def extend_last(self, kind: str, t1: float) -> bool:
+        """Lengthen this thread's newest record to ``t1`` if it is a
+        ``kind`` span, so that consecutive waits are one record and an
+        idle loop does not flush its ring. False if it is not."""
+        ring = self._ring()
+        if not ring or ring[-1][0] != kind or ring[-1][3] is None:
+            return False
+        _, seq, t0, _, track, args = ring[-1]
+        ring[-1] = (kind, seq, t0, t1, track, args)
+        return True
 
     def mark(self, kind: str, seq: Optional[int],
              t: Optional[float] = None, track: Optional[str] = None,
@@ -424,6 +595,11 @@ class Timeline:
     def _us(self, t: float) -> float:
         return round((t - self.epoch) * 1e6, 3)
 
+    def to_trace_ns(self, t: float) -> int:
+        """A ``time.monotonic()`` instant in Unix-epoch nanoseconds, the
+        clock of the profiler's device trace."""
+        return self.clock[1] + int(round((t - self.clock[0]) * 1e9))
+
     def to_chrome(self) -> Dict[str, Any]:
         """Chrome trace-event JSON (Perfetto-loadable): named thread
         tracks, ``X`` slices with frame-seq args, flow events following
@@ -503,7 +679,12 @@ class Timeline:
                                         key=lambda kv: (kv[0][0], kv[1])):
             meta.append({"name": "thread_name", "ph": "M", "pid": pid,
                          "tid": tid, "args": {"name": track}})
-        return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
+        # ``ts`` counts from ``epoch``; the clock pair lets a reader put
+        # them on the device trace's clock (``device_trace`` does)
+        return {"traceEvents": meta + events, "displayTimeUnit": "ms",
+                "metadata": {"clock": {
+                    "monotonic_s": self.clock[0], "unix_ns": self.clock[1],
+                    "epoch_monotonic_s": self.epoch}}}
 
     def export_chrome(self, path: str) -> None:
         with open(path, "w") as f:

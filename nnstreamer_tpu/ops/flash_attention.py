@@ -139,6 +139,7 @@ def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
+        name="nns_flash_prefill",
     )(q, k, v)
 
 

@@ -251,8 +251,9 @@ class FusedRegion(Element):
                 # alternating batch sizes hit jit's per-shape executable
                 # cache instead of retracing every frame
                 count()
-                for f, c in zip(fns, consts):
-                    tensors = f(c, list(tensors))
+                with jax.named_scope("nns.fused"):
+                    for f, c in zip(fns, consts):
+                        tensors = f(c, list(tensors))
                 return list(tensors)
 
             # donate the input tensor slab: the whole-graph program may

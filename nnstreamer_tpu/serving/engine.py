@@ -43,8 +43,64 @@ from typing import Any, Callable, Dict, Iterator, List, Optional
 import numpy as np
 
 from nnstreamer_tpu.log import get_logger
+from nnstreamer_tpu.obs import timeline as _timeline
+from nnstreamer_tpu.obs.quantiles import P2Quantile
 
 log = get_logger("serving")
+
+#: what an iteration of the engine loop is made of; every instant of the
+#: loop thread belongs to exactly one (``ContinuousBatchingEngine._phase``)
+PHASES = ("admit", "first_token", "select", "dispatch", "emit", "idle",
+          "other")
+#: the stall note: an iteration longer than ``STALL_MIN_S`` and than
+#: ``STALL_FACTOR`` x the running median is logged with its phases
+STALL_MIN_S = 1.0
+STALL_FACTOR = 5.0
+
+#: engine name -> (what builds its decode dispatch, K, the shapes of the
+#: program's arguments), of the newest few engines: what
+#: ``decode_program_text`` compiles again. No array and no engine is held.
+#: Module state, because the one reader (the benchmark's per-layer
+#: metrics) runs after the engine is gone and was never handed it.
+_DECODE_PROGRAMS: Dict[str, tuple] = {}
+_DECODE_PROGRAMS_KEPT = 4
+
+
+def decode_program_text(engine: Optional[str] = None) -> Optional[str]:
+    """The optimized HLO text of an engine's K-step decode program (the
+    newest engine's unless named), or None if there is none.
+
+    The profiler's device plane names an operation by its instruction
+    alone (libtpu 0.0.34); each instruction's line in this text carries
+    ``op_name``, the path of ``jax.named_scope`` names it was traced
+    under (``…/nns.decode/…/kv_gather/gather``), which is how device time
+    is put under the scopes (``benchmark/scope_reduce.py``).
+
+    The program the engine runs cannot be asked: JAX leaves such names
+    out of the compile cache's key, so a program found in the cache
+    brings the names of whichever commit compiled it first (on the chip,
+    PR 25: the parent's, with no scope in them). So the program is built
+    and compiled once more, from a new function (JAX also keeps compiled
+    programs in memory, by function) and with the names in the key: the
+    same optimized program, instruction for instruction, under this
+    code's names. A compile, or a cache hit of its own, under a JAX
+    option that is process-wide while it lasts: call it beside a trace,
+    not in a serving path."""
+    import jax
+
+    if engine is None:
+        engine = next(reversed(_DECODE_PROGRAMS), None)
+    entry = _DECODE_PROGRAMS.get(engine)
+    if entry is None:
+        return None
+    build, k, shapes = entry
+    names_in_key = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, names_in_key)
+    jax.config.update(names_in_key, True)
+    try:
+        return build(k).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update(names_in_key, before)
 
 
 class GenerationStream:
@@ -64,6 +120,15 @@ class GenerationStream:
         self.finished = False
         self.finish_reason: Optional[str] = None  # "eos"|"length"|...
         self.cancelled = False
+        #: the request's life on ``time.monotonic()``, stamped by the
+        #: engine: ``submit()``; the loop takes the request to admit it
+        #: (the last attempt, if the pool deferred one); the first token
+        #: is emitted; the stream finishes. ``stream_id`` is the
+        #: identifier its spans share.
+        self.submit_t: Optional[float] = None
+        self.admit_t: Optional[float] = None
+        self.first_t: Optional[float] = None
+        self.finish_t: Optional[float] = None
         self._q: _queue.Queue = _queue.Queue()
 
     def cancel(self) -> None:
@@ -105,6 +170,8 @@ class GenerationStream:
 
     # engine-side
     def _emit(self, tok: int, logprob: float = 0.0):
+        if not self.tokens:
+            self.first_t = _time.monotonic()
         self.tokens.append(tok)
         self.logprobs.append(logprob)
         self._q.put(tok)
@@ -114,6 +181,8 @@ class GenerationStream:
             return  # idempotent: cancel/stop/EOS may race benignly
         self.finished = True
         self.finish_reason = reason
+        if self.finish_t is None:  # the engine stamps before its book-keeping
+            self.finish_t = _time.monotonic()
         self._q.put(self._DONE)
 
 
@@ -195,7 +264,11 @@ class _PendingRequest:
         self.prompt = prompt
         self.max_new = max_new
         self.stream = stream
-        self.submit_t = _time.monotonic()  # → queue-wait histogram
+
+    def who(self) -> Dict[str, int]:
+        """What a span caused by this request carries in its args."""
+        return {"stream": self.stream.stream_id,
+                "prompt": int(self.prompt.size)}
 
 
 class ContinuousBatchingEngine:
@@ -454,7 +527,23 @@ class ContinuousBatchingEngine:
             "prefix_hits": 0, "prefix_tokens_reused": 0,
             "concurrent_streams_max": 0, "kv_sheds": 0, "kv_defers": 0,
             "spec_drafted": 0, "spec_accepted": 0,
+            # where the loop thread's time went, integer microseconds
+            # (``_phase``): the phases tile the loop, so they sum to
+            # ``loop_us``. Every key exists from here on and stays an
+            # ``int``: readers copy this dict from other threads
+            "loop_us": 0, **{f"phase_{name}_us": 0 for name in PHASES},
+            # requests that reached their first token, and for them the
+            # sums of submit -> admit and admit -> first token
+            "admissions": 0, "admit_wait_us": 0, "first_token_us": 0,
+            "stalls": 0,
         }
+        #: the engine's own after-the-fact record of what its loop did:
+        #: one span per closed phase, one async span per request. Used
+        #: while no process-wide timeline is installed (``_ledger``).
+        self.ledger = _timeline.Timeline(4096)
+        self._phase_t = _time.monotonic()   # where the open phase began
+        self._iter_us: Dict[str, int] = {}  # this iteration, by phase
+        self._iter_median = P2Quantile(0.5)
         from nnstreamer_tpu.obs import (
             get_registry,
             register_engine_collector,
@@ -540,7 +629,8 @@ class ContinuousBatchingEngine:
                 def body(carry, _):
                     token, cache, pos, keys = carry
                     logits, cache = decode(params, token, cache, pos)
-                    nxt, keys, lp = sample(logits, keys)
+                    with jax.named_scope("sample"):
+                        nxt, keys, lp = sample(logits, keys)
                     return (nxt, cache, pos + 1, keys), (nxt, lp)
 
                 (token, cache, pos, keys), (toks, lps) = jax.lax.scan(
@@ -565,7 +655,8 @@ class ContinuousBatchingEngine:
                         token, arena, pos, keys = carry
                         logits, arena = paged_decode(params, token, arena,
                                                      bt, pos)
-                        nxt, keys, lp = sample(logits, keys)
+                        with jax.named_scope("sample"):
+                            nxt, keys, lp = sample(logits, keys)
                         return (nxt, arena, pos + 1, keys), (nxt, lp)
 
                     (token, arena, pos, keys), (toks, lps) = jax.lax.scan(
@@ -576,11 +667,9 @@ class ContinuousBatchingEngine:
                 return jax.jit(dispatch, donate_argnums=(2,))
 
             self._build_dispatch = build_paged_dispatch
-            self._dispatch = build_paged_dispatch(self.K)
             self._paged_chunk_jitted = jax.jit(self._paged_chunk_fn,
                                                donate_argnums=(2,))
-        else:
-            self._dispatch = build_dispatch(self.K)
+        self._set_dispatch(self.K)
         self._sample_first = jax.jit(sample)
 
         def insert(cache, cache1, slot):
@@ -617,6 +706,33 @@ class ContinuousBatchingEngine:
         self._spec: Optional[dict] = None
         if int(speculate or 0) > 0:
             self.set_speculate(int(speculate), speculate_layers)
+
+    def _set_dispatch(self, k: int) -> None:
+        """Build the K-step decode program and note what it takes, so
+        that its text can be had later (``decode_program_text``)."""
+        import jax
+        import jax.numpy as jnp
+
+        self.K = k
+        self._dispatch = self._build_dispatch(k)
+
+        def shape(a):
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype,
+                sharding=a.sharding if self._mesh is not None else None)
+
+        def host(*dims, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(dims, dtype)
+
+        kv = self._pool.arena if self.paged else self._cache
+        tables = (host(self.B, self.MB),) if self.paged else ()
+        _DECODE_PROGRAMS.pop(self.obs_name, None)
+        _DECODE_PROGRAMS[self.obs_name] = (self._build_dispatch, k, (
+            jax.tree.map(shape, self.params), host(self.B),
+            jax.tree.map(shape, kv), *tables, host(self.B),
+            host(self.B, 2, dtype=jnp.uint32)))
+        while len(_DECODE_PROGRAMS) > _DECODE_PROGRAMS_KEPT:
+            del _DECODE_PROGRAMS[next(iter(_DECODE_PROGRAMS))]
 
     def _calibrate_k(self) -> None:
         """steps_per_dispatch="auto": pick K from MEASURED costs.
@@ -680,8 +796,7 @@ class ContinuousBatchingEngine:
         log.info("serving: auto K — rtt %.2f ms, step %.3f ms → K=%d",
                  rtt * 1e3, step * 1e3, k)
         if k != self.K:
-            self.K = k
-            self._dispatch = self._build_dispatch(k)
+            self._set_dispatch(k)
 
     # -- public API -----------------------------------------------------------
     def start(self) -> "ContinuousBatchingEngine":
@@ -737,26 +852,26 @@ class ContinuousBatchingEngine:
         # request can't slip into _pending after this drain
         with self._lock:
             if self._partial is not None:
-                self._partial[0].stream._finish("engine-stopped")
+                self._finish_stream(self._partial[0].stream, "engine-stopped")
                 self._partial = None
             for i, st in enumerate(self._slots):
                 if st is self._RESERVED:
                     self._slots[i] = None
                 elif st is not None and not st.finished:
-                    st._finish("engine-stopped")
+                    self._finish_stream(st, "engine-stopped")
                     self._slots[i] = None
             if self.paged:
                 for state in list(self._sstate.values()):
                     self._finish_paged(state, "engine-stopped")
                 if self._held is not None:
-                    self._held.stream._finish("engine-stopped")
+                    self._finish_stream(self._held.stream, "engine-stopped")
                     self._held = None
             while True:
                 try:
                     req = self._pending.get_nowait()
                 except _queue.Empty:
                     break
-                req.stream._finish("engine-stopped")
+                self._finish_stream(req.stream, "engine-stopped")
 
     def submit(self, prompt, max_new_tokens: int = 64) -> GenerationStream:
         """Queue a prompt (sequence of int token ids); returns a
@@ -804,7 +919,7 @@ class ContinuousBatchingEngine:
             sid = self._next_id
             self._next_id += 1
             stream = GenerationStream(sid, prompt.size)
-            stream.submit_t = _time.monotonic()  # → SLO service estimate
+            stream.submit_t = _time.monotonic()
             self._pending.put(_PendingRequest(prompt, int(max_new_tokens),
                                               stream))
         self._wake.set()
@@ -821,6 +936,84 @@ class ContinuousBatchingEngine:
             return len(self._sstate)
         return sum(1 for s in self._slots
                    if s is not None and s is not self._RESERVED)
+
+    # -- the serving path measures itself ---------------------------------------
+    def _ledger(self) -> "_timeline.Timeline":
+        """Where spans go: the installed timeline (an engine inside a
+        traced pipeline shows on the pipeline's ledger), else its own."""
+        return _timeline.ACTIVE or self.ledger
+
+    def _phase(self, name: str, **args) -> float:
+        """Close the loop's open phase as ``name`` and open the next, with
+        one clock read: whatever the loop thread did since the last call
+        is ``name``. A counter and a span; returns the instant."""
+        now = _time.monotonic()
+        t0, self._phase_t = self._phase_t, now
+        us = int(now * 1e6) - int(t0 * 1e6)  # whole numbers tile exactly
+        self.stats[f"phase_{name}_us"] += us
+        self.stats["loop_us"] += us
+        self._iter_us[name] = self._iter_us.get(name, 0) + us
+        led = self._ledger()
+        # consecutive waits are one record: an idle loop keeps its history
+        if not (name == "idle" and led.extend_last("lm_idle", now)):
+            led.span("lm_" + name, None, t0, now, track=self.obs_name,
+                     dispatch=self.stats["dispatches"], **args)
+        return now
+
+    def _end_iteration(self) -> None:
+        """Top of the loop. The iteration that just ended is held against
+        the running median of those before it: the stall note."""
+        phases, self._iter_us = self._iter_us, {}
+        total = sum(phases.values())
+        if not total:
+            return
+        median = self._iter_median.quantile()
+        self._iter_median.observe(total)
+        if median is not None and total > STALL_MIN_S * 1e6 \
+                and total > STALL_FACTOR * median:
+            self.stats["stalls"] += 1
+            log.warning(
+                "serving: %s iteration %d ms: %s", self.obs_name,
+                total // 1000, ", ".join(
+                    f"{k} {v // 1000}" for k, v in sorted(
+                        phases.items(), key=lambda kv: -kv[1])))
+
+    def _begin_admission(self, req: _PendingRequest) -> None:
+        """The loop has taken ``req`` to admit it: the second stamp."""
+        st = req.stream
+        st.admit_t = _time.monotonic()
+        self._m_queue_wait.observe(st.admit_t - st.submit_t)
+
+    def _emit_first(self, st: GenerationStream, tok: int,
+                    logprob: float) -> None:
+        """The first token reaches its stream: the third stamp, and what
+        reads the first three."""
+        st._emit(tok, logprob)
+        self.stats["tokens_generated"] += 1
+        self._lm_stats.observe_ttft(st.first_t - st.submit_t)
+        self.stats["admissions"] += 1
+        self.stats["admit_wait_us"] += int((st.admit_t - st.submit_t) * 1e6)
+        self.stats["first_token_us"] += int((st.first_t - st.admit_t) * 1e6)
+
+    def _finish_stream(self, st: GenerationStream, reason: str) -> None:
+        """Every finish the engine decides: the fourth stamp, the time
+        per output token this client saw, and the request as one async
+        span in the ledger with marks at its admission and first token
+        (recorded whole, after the fact) — all before the client wakes."""
+        if st.finished:
+            return
+        st.finish_t = _time.monotonic()
+        if st.first_t is not None and len(st.tokens) > 1:
+            self._lm_stats.observe_token(
+                (st.finish_t - st.first_t) / (len(st.tokens) - 1))
+        led, track, sid = self._ledger(), self.obs_name, st.stream_id
+        led.async_begin("lm_request", sid, st.submit_t, track)
+        if st.admit_t is not None:
+            led.mark("lm_admitted", None, st.admit_t, track, stream=sid)
+        if st.first_t is not None:
+            led.mark("lm_first", None, st.first_t, track, stream=sid)
+        led.async_end("lm_request", sid, st.finish_t, track)
+        st._finish(reason)
 
     # -- engine internals ------------------------------------------------------
     def _bucket(self, n: int) -> int:
@@ -936,7 +1129,7 @@ class ContinuousBatchingEngine:
         first-token sampling DISPATCH. Returns the activation record for
         :meth:`_activate_commit` — the loop commits a whole admission
         wave with one host sync instead of one round trip per prompt."""
-        self._m_queue_wait.observe(_time.monotonic() - req.submit_t)
+        self._begin_admission(req)
         jnp = self._jnp
         prompt = req.prompt
         n = prompt.size
@@ -994,7 +1187,7 @@ class ContinuousBatchingEngine:
     PREFIX_MIN_REUSE = 4
 
     def _begin_partial(self, req: _PendingRequest, slot: int):
-        self._m_queue_wait.observe(_time.monotonic() - req.submit_t)
+        self._begin_admission(req)
         base = 0
         cache1 = self._init_cache1()
         if self.prefix_cache:
@@ -1027,6 +1220,7 @@ class ContinuousBatchingEngine:
         end = min(start + C, n)
         chunk = np.zeros((1, C), np.int32)
         chunk[0, :end - start] = prompt[start:end]
+        who = req.who()
         try:
             logits, cache1 = self._chunk_jitted(
                 self.params, jnp.asarray(chunk), cache1,
@@ -1034,6 +1228,7 @@ class ContinuousBatchingEngine:
             self.stats["prefill_chunks"] += 1
             if end < n:
                 self._partial = (req, slot, cache1, k + 1, base)
+                self._phase("admit", **who)
                 return
             # final chunk: logits at the prompt's true last position
             self._partial = None
@@ -1045,7 +1240,9 @@ class ContinuousBatchingEngine:
                     self.stats["kv_defers"] += 1
                     self._held = req
                 else:
+                    self._phase("admit", **who)
                     self._activate_commit_paged(rec)
+                    self._phase("first_token", **who)
                 return
             self._prefix_store(prompt, cache1, logits_last)
             self._activate(req, slot, logits_last, cache1)
@@ -1055,7 +1252,7 @@ class ContinuousBatchingEngine:
             self._partial = None
             if slot is not None:
                 self._slots[slot] = None
-            req.stream._finish(f"error: {e}")
+            self._finish_stream(req.stream, f"error: {e}")
 
     def _activate_begin(self, req: _PendingRequest, slot: int, logits,
                         cache1):
@@ -1102,19 +1299,18 @@ class ContinuousBatchingEngine:
         # (a speculative verify chunk writes through pos+K, hence the
         # extra margin; zero when speculation is off)
         self._budget[slot] = min(req.max_new, self.S - n - self.speculate)
-        t0 = getattr(req.stream, "submit_t", None)
-        if t0 is not None:
-            self._lm_stats.observe_ttft(_time.monotonic() - t0)
-        req.stream._emit(first, first_lp)
-        self.stats["tokens_generated"] += 1
+        self._emit_first(req.stream, first, first_lp)
         self._post_emit(slot, first)
 
     def _activate(self, req: _PendingRequest, slot: int, logits, cache1):
         """Single-admission tail (chunked-prefill path): begin + one
         host sync + commit."""
+        who = req.who()
         rec = self._activate_begin(req, slot, logits, cache1)
+        self._phase("admit", **who)
         self._sync_host_state()
         self._activate_commit(rec)
+        self._phase("first_token", **who)
 
     def _post_emit(self, slot: int, tok: int):
         """Budget/EOS bookkeeping after a token reaches its stream. The
@@ -1125,20 +1321,18 @@ class ContinuousBatchingEngine:
         done = (self.eos_id is not None and tok == self.eos_id) or \
             self._budget[slot] <= 0
         if done and self._slo is not None:
-            t0 = getattr(st, "submit_t", None)
-            if t0 is not None:
-                # whole-request service time feeds the admission EWMA
-                # (and the controller's p99 window) — per-REQUEST, since
-                # the engine's admission unit is a request, not a frame
-                now = _time.monotonic()
-                self._slo.observe_completion(now - t0, now, frames=1)
-                self._slo.observe_service(now - t0, frames=1)
+            # whole-request service time feeds the admission EWMA (and
+            # the controller's p99 window) — per-REQUEST, since the
+            # engine's admission unit is a request, not a frame
+            now = _time.monotonic()
+            self._slo.observe_completion(now - st.submit_t, now, frames=1)
+            self._slo.observe_service(now - st.submit_t, frames=1)
         if self.eos_id is not None and tok == self.eos_id:
             self._slots[slot] = None
-            st._finish("eos")
+            self._finish_stream(st, "eos")
         elif self._budget[slot] <= 0:
             self._slots[slot] = None
-            st._finish("length")
+            self._finish_stream(st, "length")
 
     # -- pipelined block processing -------------------------------------------
     def _process_block(self, t0, toks_dev, lps_dev, snapshot):
@@ -1148,15 +1342,13 @@ class ContinuousBatchingEngine:
         or belong to a stream that already finished)."""
         toks = np.asarray(toks_dev)  # the D2H sync; timed below
         lps = np.asarray(lps_dev)
-        dt = _time.monotonic() - t0
-        self.invoke_stats.record(dt)
-        self.stats["dispatches"] += 1
+        # issue of the next block and the wait for this one: the device
+        # decodes throughout
+        self.invoke_stats.record(self._phase("dispatch") - t0)
         self.stats["slot_steps"] += self.B * self.K
-        per_tok = dt / self.K
         for slot, st in snapshot:
             if self._slots[slot] is not st:
                 continue  # freed/replaced while the block was in flight
-            self._lm_stats.observe_token(per_tok)
             self._pos[slot] += self.K
             self._last[slot] = toks[slot, -1]
             for j in range(self.K):
@@ -1167,6 +1359,8 @@ class ContinuousBatchingEngine:
                 self._post_emit(slot, tok)
                 if self._slots[slot] is None:
                     break  # EOS/length mid-block: drop the tail
+        self._phase("emit")
+        self.stats["dispatches"] += 1
 
     def _drain_inflight(self):
         while self._inflight:
@@ -1196,21 +1390,21 @@ class ContinuousBatchingEngine:
             self._inflight.clear()
         self._dev_state = None
         if self._partial is not None:
-            self._partial[0].stream._finish(f"error: {e}")
+            self._finish_stream(self._partial[0].stream, f"error: {e}")
             self._partial = None
         for slot in range(self.B):
             st = self._slots[slot]
             if st is self._RESERVED:
                 self._slots[slot] = None
             elif st is not None:
-                st._finish(f"error: {e}")
+                self._finish_stream(st, f"error: {e}")
                 self._slots[slot] = None
         if self.paged:
             for state in list(self._sstate.values()):
-                state["stream"]._finish(f"error: {e}")
+                self._finish_stream(state["stream"], f"error: {e}")
             self._sstate.clear()
             if self._held is not None:
-                self._held.stream._finish(f"error: {e}")
+                self._finish_stream(self._held.stream, f"error: {e}")
                 self._held = None
             self._lane = [None] * self.B
             # the arena may hold donated-away/error buffers; a fresh one
@@ -1391,7 +1585,7 @@ class ContinuousBatchingEngine:
                     if st is not None and st is not self._RESERVED]
         if not snapshot:
             return
-        t0 = _time.monotonic()
+        t0 = self._phase("select")
         tgt, lps, n_emit, cache, dcache = sp["dispatch"](
             self.params, sp["dparams"], jnp.asarray(self._last),
             self._cache, sp["dcache"], jnp.asarray(self._pos))
@@ -1400,9 +1594,7 @@ class ContinuousBatchingEngine:
         tgt = np.asarray(tgt)
         lps = np.asarray(lps)
         n_emit = np.asarray(n_emit)
-        dt = _time.monotonic() - t0
-        self.invoke_stats.record(dt)
-        self.stats["dispatches"] += 1
+        self.invoke_stats.record(self._phase("dispatch") - t0)
         self.stats["slot_steps"] += self.B * (g + 1)
         for slot, st in snapshot:
             if self._slots[slot] is not st:
@@ -1412,7 +1604,6 @@ class ContinuousBatchingEngine:
             self.stats["spec_accepted"] += m - 1
             self._pos[slot] += m
             self._last[slot] = int(tgt[slot, m - 1])
-            self._lm_stats.observe_token(dt / max(1, m))
             for j in range(m):
                 tok = int(tgt[slot, j])
                 self.stats["tokens_generated"] += 1
@@ -1421,6 +1612,8 @@ class ContinuousBatchingEngine:
                 self._post_emit(slot, tok)
                 if self._slots[slot] is None:
                     break
+        self._phase("emit")
+        self.stats["dispatches"] += 1
 
     def _spec_step_paged(self) -> None:
         jnp = self._jnp
@@ -1443,7 +1636,7 @@ class ContinuousBatchingEngine:
         for st in run:
             last[st["slot"]] = st["last"]
             pos[st["slot"]] = st["pos"]
-        t0 = _time.monotonic()
+        t0 = self._phase("select")
         tgt, lps, n_emit, arena, dcache = sp["dispatch"](
             self.params, sp["dparams"], jnp.asarray(last),
             self._pool.arena, jnp.asarray(self._bt), sp["dcache"],
@@ -1453,9 +1646,7 @@ class ContinuousBatchingEngine:
         tgt = np.asarray(tgt)
         lps = np.asarray(lps)
         n_emit = np.asarray(n_emit)
-        dt = _time.monotonic() - t0
-        self.invoke_stats.record(dt)
-        self.stats["dispatches"] += 1
+        self.invoke_stats.record(self._phase("dispatch") - t0)
         self.stats["slot_steps"] += self.B * (g + 1)
         for st in run:
             if self._sstate.get(st["sid"]) is not st:
@@ -1469,7 +1660,6 @@ class ContinuousBatchingEngine:
             # it is overwritten before it is ever attended
             st["pos"] += m
             st["last"] = int(tgt[slot, m - 1])
-            self._lm_stats.observe_token(dt / max(1, m))
             for j in range(m):
                 tok = int(tgt[slot, j])
                 self.stats["tokens_generated"] += 1
@@ -1478,6 +1668,8 @@ class ContinuousBatchingEngine:
                 self._post_emit_paged(st, tok)
                 if self._sstate.get(st["sid"]) is not st:
                     break
+        self._phase("emit")
+        self.stats["dispatches"] += 1
 
     # -- paged mode (block_tokens > 0) ----------------------------------------
     def _blocks_for(self, n: int) -> int:
@@ -1557,7 +1749,7 @@ class ContinuousBatchingEngine:
         cover the prompt (admission is bounded by FREE BLOCKS, not
         batch slots; the caller holds the request so FIFO order keeps).
         Deferral is cheap: every path allocates before device work."""
-        self._m_queue_wait.observe(_time.monotonic() - req.submit_t)
+        self._begin_admission(req)
         jnp = self._jnp
         prompt = req.prompt
         n = prompt.size
@@ -1655,7 +1847,7 @@ class ContinuousBatchingEngine:
         fresh blocks — no slot is reserved, blocks allocate at
         activation. (Prefix reuse is not wired on this path; chunked
         paged prompts ingest from 0.)"""
-        self._m_queue_wait.observe(_time.monotonic() - req.submit_t)
+        self._begin_admission(req)
         self._partial = (req, None, self._init_cache1(), 0, 0)
 
     def _activate_begin_paged(self, req: _PendingRequest, logits, blocks):
@@ -1672,7 +1864,6 @@ class ContinuousBatchingEngine:
         first_d, key_d, lp_d = self._sample_first(logits,
                                                   jnp.asarray(key))
         n = req.prompt.size
-        now = _time.monotonic()
         slo_s = self._slo.budget_s if self._slo is not None else 60.0
         state = {
             "sid": sid, "stream": stream, "blocks": list(blocks),
@@ -1680,7 +1871,7 @@ class ContinuousBatchingEngine:
             # cap writes inside S (a verify chunk writes through pos+K)
             "budget": min(req.max_new, self.S - n - self.speculate),
             #: absolute deadline feeding the per-token EDF key
-            "deadline_t": getattr(stream, "submit_t", now) + slo_s,
+            "deadline_t": stream.submit_t + slo_s,
             "slot": None,
         }
         self._sstate[sid] = state
@@ -1697,11 +1888,7 @@ class ContinuousBatchingEngine:
         first = int(np.asarray(first_d)[0])
         state["last"] = first
         state["key"] = np.asarray(key_d)[0].copy()
-        t0 = getattr(req.stream, "submit_t", None)
-        if t0 is not None:
-            self._lm_stats.observe_ttft(_time.monotonic() - t0)
-        req.stream._emit(first, float(np.asarray(lp_d)[0]))
-        self.stats["tokens_generated"] += 1
+        self._emit_first(req.stream, first, float(np.asarray(lp_d)[0]))
         self._post_emit_paged(state, first)
 
     def _post_emit_paged(self, state, tok: int) -> None:
@@ -1709,11 +1896,10 @@ class ContinuousBatchingEngine:
         done_eos = self.eos_id is not None and tok == self.eos_id
         done = done_eos or state["budget"] <= 0
         if done and self._slo is not None:
-            t0 = getattr(state["stream"], "submit_t", None)
-            if t0 is not None:
-                now = _time.monotonic()
-                self._slo.observe_completion(now - t0, now, frames=1)
-                self._slo.observe_service(now - t0, frames=1)
+            now = _time.monotonic()
+            t0 = state["stream"].submit_t
+            self._slo.observe_completion(now - t0, now, frames=1)
+            self._slo.observe_service(now - t0, frames=1)
         if done_eos:
             self._finish_paged(state, "eos")
         elif state["budget"] <= 0:
@@ -1733,7 +1919,7 @@ class ContinuousBatchingEngine:
         if state["blocks"]:
             self._pool.release(state["blocks"])
             state["blocks"] = []
-        state["stream"]._finish(reason)
+        self._finish_stream(state["stream"], reason)
 
     def _shed_one(self, keep_sid: int) -> bool:
         """Decode-time block exhaustion: revoke the MOST-LATE admitted
@@ -1831,7 +2017,7 @@ class ContinuousBatchingEngine:
             last[st["slot"]] = st["last"]
             pos[st["slot"]] = st["pos"]
             keys[st["slot"]] = st["key"]
-        t0 = _time.monotonic()
+        t0 = self._phase("select")
         toks, lps, arena, keys_d, _last_d, _pos_d = self._dispatch(
             self.params, jnp.asarray(last), self._pool.arena,
             jnp.asarray(self._bt), jnp.asarray(pos), jnp.asarray(keys))
@@ -1839,11 +2025,10 @@ class ContinuousBatchingEngine:
         toks = np.asarray(toks)
         lps = np.asarray(lps)
         keys_np = np.asarray(keys_d)
-        dt = _time.monotonic() - t0
-        self.invoke_stats.record(dt)
-        self.stats["dispatches"] += 1
+        # from the call until tokens and keys are on the host: the one
+        # phase in which the device works for decoding
+        self.invoke_stats.record(self._phase("dispatch") - t0)
         self.stats["slot_steps"] += self.B * self.K
-        per_tok = dt / self.K
         for st in run:
             if self._sstate.get(st["sid"]) is not st:
                 continue
@@ -1851,7 +2036,6 @@ class ContinuousBatchingEngine:
             st["key"] = keys_np[slot].copy()
             st["pos"] += self.K
             st["last"] = int(toks[slot, -1])
-            self._lm_stats.observe_token(per_tok)
             for j in range(self.K):
                 tok = int(toks[slot, j])
                 self.stats["tokens_generated"] += 1
@@ -1860,6 +2044,8 @@ class ContinuousBatchingEngine:
                 self._post_emit_paged(st, tok)
                 if self._sstate.get(st["sid"]) is not st:
                     break  # EOS/length/shed mid-block: drop the tail
+        self._phase("emit")
+        self.stats["dispatches"] += 1
 
     def _loop_paged(self):
         """Paged engine loop. Dispatch → emit runs synchronously (the
@@ -1868,17 +2054,21 @@ class ContinuousBatchingEngine:
         lane parking/rebinding and EDF preemption a plain host-side
         concern instead of a device-state pipeline hazard."""
         while not self._stop_evt.is_set():
+            self._end_iteration()
+            busy = bool(self._sstate)
             self._reap_condemned()
             for state in list(self._sstate.values()):
                 if state["stream"].cancelled:
                     self._finish_paged(state, "cancelled")
             if self._held is not None and self._held.stream.cancelled:
-                self._held.stream._finish("cancelled")
+                self._finish_stream(self._held.stream, "cancelled")
                 self._held = None
+            if busy:  # else these microseconds go to what comes next
+                self._phase("other")
             progressed = False
             if self._partial is not None:
                 if self._partial[0].stream.cancelled:
-                    self._partial[0].stream._finish("cancelled")
+                    self._finish_stream(self._partial[0].stream, "cancelled")
                     self._partial = None
                 else:
                     self._advance_partial()
@@ -1896,7 +2086,7 @@ class ContinuousBatchingEngine:
                     except _queue.Empty:
                         break
                 if req.stream.cancelled:
-                    req.stream._finish("cancelled")
+                    self._finish_stream(req.stream, "cancelled")
                     continue
                 try:
                     if self.prefill_chunk is not None:
@@ -1907,7 +2097,7 @@ class ContinuousBatchingEngine:
                 except Exception as e:  # noqa: BLE001 — a bad request
                     # must not kill the engine loop
                     log.warning("serving: admit failed: %s", e)
-                    req.stream._finish(f"error: {e}")
+                    self._finish_stream(req.stream, f"error: {e}")
                     continue
                 if rec is None:
                     # pool can't cover this prompt yet: hold the head
@@ -1917,6 +2107,9 @@ class ContinuousBatchingEngine:
                     break
                 admitted.append(rec)
                 progressed = True
+                # host work up to the enqueue of prefill, scatter and
+                # first-token sample
+                self._phase("admit", **req.who())
             for rec in admitted:  # start all fetches before blocking
                 for d in (rec[2], rec[3], rec[4]):
                     start_async = getattr(d, "copy_to_host_async", None)
@@ -1932,13 +2125,16 @@ class ContinuousBatchingEngine:
                     if self._sstate.get(state["sid"]) is state:
                         self._finish_paged(state, f"error: {e}")
                     else:
-                        rec[0].stream._finish(f"error: {e}")
+                        self._finish_stream(rec[0].stream, f"error: {e}")
+                # blocked on the prefill's result, then the first emit
+                self._phase("first_token", **rec[0].who())
             if len(self._sstate) > self.stats["concurrent_streams_max"]:
                 self.stats["concurrent_streams_max"] = len(self._sstate)
             if not self._sstate:
                 if not progressed:
                     self._wake.wait(timeout=0.05)
                     self._wake.clear()
+                    self._phase("idle")
                 continue
             try:
                 if self._spec is not None:
@@ -1950,6 +2146,8 @@ class ContinuousBatchingEngine:
                 self._recover(e)
 
     def _loop(self):
+        self._phase_t = _time.monotonic()
+        self._iter_us = {}
         if self.paged:
             return self._loop_paged()
         return self._loop_mono()
@@ -1957,6 +2155,8 @@ class ContinuousBatchingEngine:
     def _loop_mono(self):
         jnp = self._jnp
         while not self._stop_evt.is_set():
+            self._end_iteration()
+            busy = self.active_streams > 0
             self._reap_condemned()
             # honor cancellations first: active slots free at this block
             # boundary; a half-ingested prompt stops mid-prefill
@@ -1965,12 +2165,14 @@ class ContinuousBatchingEngine:
                 if (st is not None and st is not self._RESERVED
                         and st.cancelled):
                     self._slots[slot] = None
-                    st._finish("cancelled")
+                    self._finish_stream(st, "cancelled")
             if self._partial is not None and self._partial[0].stream.cancelled:
                 _, slot, _, _, _ = self._partial
                 self._slots[slot] = None
-                self._partial[0].stream._finish("cancelled")
+                self._finish_stream(self._partial[0].stream, "cancelled")
                 self._partial = None
+            if busy:  # else these microseconds go to what comes next
+                self._phase("other")
             # in-flight chunked prefill: ONE chunk per iteration, so the
             # decode dispatch below keeps running streams moving while a
             # long prompt ingests.
@@ -2005,7 +2207,7 @@ class ContinuousBatchingEngine:
                         queue_dry = True
                         break
                     if req.stream.cancelled:
-                        req.stream._finish("cancelled")
+                        self._finish_stream(req.stream, "cancelled")
                         continue
                     try:
                         if self.prefill_chunk is not None:
@@ -2013,6 +2215,7 @@ class ContinuousBatchingEngine:
                         else:
                             admitted.append(self._admit(req, slot))
                         progressed = True
+                        self._phase("admit", **req.who())
                         break  # slot filled
                     except Exception as e:  # noqa: BLE001 — a bad request
                         # (or a prefill/cache-alloc failure) must not kill
@@ -2021,7 +2224,7 @@ class ContinuousBatchingEngine:
                         if self._slots[slot] is self._RESERVED:
                             self._slots[slot] = None
                         self._partial = None
-                        req.stream._finish(f"error: {e}")
+                        self._finish_stream(req.stream, f"error: {e}")
             if admitted:
                 try:
                     self._sync_host_state()
@@ -2045,7 +2248,8 @@ class ContinuousBatchingEngine:
                         # this stream; the slot frees for the next prompt
                         log.warning("serving: activate failed: %s", e)
                         self._slots[rec[1]] = None
-                        rec[0].stream._finish(f"error: {e}")
+                        self._finish_stream(rec[0].stream, f"error: {e}")
+                    self._phase("first_token", **rec[0].who())
             if self.active_streams == 0:
                 try:
                     self._sync_host_state()  # late EOS frees the last slot
@@ -2058,6 +2262,7 @@ class ContinuousBatchingEngine:
                     if not progressed:
                         self._wake.wait(timeout=0.05)
                         self._wake.clear()
+                        self._phase("idle")
                     continue
             if self._spec is not None:
                 # speculative rounds replace the K-step dispatch; they
@@ -2070,7 +2275,7 @@ class ContinuousBatchingEngine:
                     self._recover(e)
                 continue
             try:
-                t0 = _time.monotonic()
+                t0 = self._phase("select")
                 if self._dev_state is None:
                     last_d = jnp.asarray(self._last)
                     pos_d = jnp.asarray(self._pos)
@@ -2093,6 +2298,8 @@ class ContinuousBatchingEngine:
                     if st is not None and st is not self._RESERVED]))
                 if len(self._inflight) > 1:
                     self._process_block(*self._inflight.popleft())
+                else:  # the pipeline fills: the issue alone
+                    self._phase("dispatch")
             except Exception as e:  # noqa: BLE001 — a device failure must
                 # not strand clients blocked on their streams
                 self._recover(e)

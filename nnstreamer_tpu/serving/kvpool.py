@@ -80,7 +80,8 @@ def _scatter_prefill_impl(arena, cache1, bids):
         u = jnp.moveaxis(u, 2, 1)                        # [L,MB,2,T,...]
         return a.at[:, bids].set(u.astype(a.dtype), mode="drop")
 
-    return jax.tree.map(leaf, arena, cache1)
+    with jax.named_scope("nns.kv_scatter"):
+        return jax.tree.map(leaf, arena, cache1)
 
 
 def _copy_block_impl(arena, src, dst):

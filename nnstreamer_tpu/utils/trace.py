@@ -1,4 +1,4 @@
-"""Tracing / profiling — per-element tracers + XLA profiler integration.
+"""Tracing / profiling — per-element tracers.
 
 Reference: no in-tree tracer; relies on GStreamer tracer hooks consumed by
 GstShark (proctime / interlatency / framerate tracers,
@@ -11,8 +11,8 @@ is in-tree (SURVEY §5 asks for exactly this):
   tracers the reference's docs describe.
 - Export as Chrome trace-event JSON (``chrome://tracing`` /
   Perfetto-loadable) or aggregate dicts.
-- :func:`xla_profile` wraps ``jax.profiler`` so device-side traces
-  (XPlane) land next to the host-side ones.
+- The device side is ``obs.timeline.device_trace``: the profiler's
+  XPlane and the frame ledger on one clock.
 
 Usage::
 
@@ -176,17 +176,3 @@ class Tracer:
                 trace.append(flow)
         with open(path, "w") as f:
             json.dump({"traceEvents": trace}, f)
-
-
-@contextlib.contextmanager
-def xla_profile(logdir: str):
-    """Capture an XLA device trace around a pipeline run (jax profiler
-    XPlane; view with TensorBoard or xprof). The TPU-side counterpart of
-    :class:`Tracer`'s host-side events."""
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

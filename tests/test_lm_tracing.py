@@ -1,0 +1,458 @@
+"""The LM serving path measures itself (PR 25): request stamps, engine-loop
+phase counters and spans, the stall note, one clock with the device trace,
+and the names inside the programs that the benchmark's readers look for.
+"""
+
+import json
+import re
+import time
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from nnstreamer_tpu.models.transformer import (  # noqa: E402
+    TransformerConfig,
+    build_prefill,
+    init_params,
+)
+from nnstreamer_tpu.obs import get_registry, timeline  # noqa: E402
+from nnstreamer_tpu.serving import ContinuousBatchingEngine  # noqa: E402
+from nnstreamer_tpu.serving import engine as engine_mod  # noqa: E402
+
+CFG = TransformerConfig(vocab=97, d_model=64, n_heads=4, n_layers=2,
+                        d_ff=128, max_seq=64, dtype=jnp.float32)
+PARAMS = init_params(CFG, seed=3)
+LEAF_SCOPES = ("qkv", "kv_write", "kv_gather", "attend", "ffn", "logits",
+               "sample")
+
+
+def _engine(**kw):
+    kw.setdefault("max_streams", 2)
+    kw.setdefault("steps_per_dispatch", 4)
+    return ContinuousBatchingEngine(CFG, PARAMS, **kw)
+
+
+def _prompt(n, start=1):
+    return (np.arange(start, start + n) % CFG.vocab).astype(np.int32)
+
+
+def _serve(eng, prompts, max_new=9):
+    streams = [eng.submit(p, max_new_tokens=max_new) for p in prompts]
+    for s in streams:
+        s.result(timeout=120)
+    return streams
+
+
+ADMISSIONS = {
+    "paged": {"block_tokens": 8},
+    "monolithic": {},
+    "chunked": {"prefill_chunk": 8},
+    "chunked_paged": {"block_tokens": 8, "prefill_chunk": 8},
+    "prefix_hit": {"prefix_cache": 4},
+    "prefix_hit_paged": {"block_tokens": 8, "prefix_cache": 4},
+}
+
+
+@pytest.mark.parametrize("kind", sorted(ADMISSIONS))
+def test_four_stamps_are_set_and_ordered_on_every_finished_stream(kind):
+    eng = _engine(**ADMISSIONS[kind]).start()
+    try:
+        base = _prompt(20)
+        # more requests than lanes (some queue), one prompt twice (an
+        # exact prefix hit where the cache is on) and one that extends it
+        streams = _serve(eng, [base, _prompt(5, 40)])
+        streams += _serve(eng, [base, np.concatenate([base, _prompt(6, 50)]),
+                                _prompt(12, 60)])
+    finally:
+        eng.stop()
+    if "prefix_hit" in kind:
+        assert eng.stats["prefix_hits"] >= 2
+    if "chunked" in kind:
+        assert eng.stats["prefill_chunks"] > 0
+    for s in streams:
+        assert s.finish_reason == "length" and len(s.tokens) == 9
+        assert s.submit_t <= s.admit_t <= s.first_t <= s.finish_t, kind
+    assert eng.stats["admissions"] == len(streams)
+    assert eng.stats["admit_wait_us"] >= 0
+    assert eng.stats["first_token_us"] > 0
+
+
+def test_unadmitted_stream_gets_submit_and_finish_only():
+    eng = _engine(block_tokens=8).start()
+    try:
+        running = eng.submit(_prompt(8), max_new_tokens=40)
+        dropped = eng.submit(_prompt(8, 30), max_new_tokens=4)
+        dropped.cancel()
+        running.result(timeout=120)
+        deadline = time.monotonic() + 30
+        while not dropped.finished and time.monotonic() < deadline:
+            time.sleep(0.01)
+    finally:
+        eng.stop()
+    assert dropped.finished and dropped.finish_t >= dropped.submit_t
+    if dropped.finish_reason == "cancelled" and not dropped.tokens:
+        assert dropped.first_t is None
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "monolithic"])
+def test_phase_counters_are_ints_from_the_start_and_tile_the_loop(paged):
+    eng = _engine(block_tokens=8 if paged else 0)
+    keys = {"loop_us", "admissions", "admit_wait_us", "first_token_us",
+            "stalls"} | {f"phase_{p}_us" for p in engine_mod.PHASES}
+    assert keys <= set(eng.stats)
+    assert all(type(v) is int for v in eng.stats.values())
+    eng.start()
+    try:
+        t0 = time.monotonic()
+        before = dict(eng.stats)
+        _serve(eng, [_prompt(5), _prompt(20), _prompt(12)])
+        time.sleep(0.15)  # a few idle waits
+        after = dict(eng.stats)
+        wall_us = (time.monotonic() - t0) * 1e6
+    finally:
+        eng.stop()
+    assert all(type(v) is int for v in eng.stats.values())
+    grew = {k: after[k] - before[k] for k in keys}
+    phases = sum(grew[f"phase_{p}_us"] for p in engine_mod.PHASES)
+    assert abs(phases - grew["loop_us"]) <= 0.01 * grew["loop_us"]
+    # every instant of the loop thread is in some phase: the loop's
+    # clock keeps up with the wall's, to the phase that was open at each
+    # end (an idle wait of 50 ms, or less)
+    assert 0.8 * wall_us <= grew["loop_us"] <= wall_us + 150_000
+    assert grew["phase_dispatch_us"] > 0 and grew["phase_idle_us"] > 0
+
+
+def _kinds(tl):
+    out = {}
+    for rec in tl._snapshot():
+        out.setdefault(rec[1], []).append(rec)
+    return out
+
+
+def test_ledger_holds_one_dispatch_span_per_dispatch_and_each_request():
+    eng = _engine(block_tokens=8).start()
+    try:
+        streams = _serve(eng, [_prompt(5), _prompt(20), _prompt(12)])
+        time.sleep(0.12)
+    finally:
+        eng.stop()
+    kinds = _kinds(eng.ledger)
+    assert len(kinds["lm_dispatch"]) == eng.stats["dispatches"] > 0
+    for name in ("lm_admit", "lm_first_token", "lm_select", "lm_emit",
+                 "lm_idle"):
+        assert kinds.get(name), name
+    # consecutive idle waits are one record
+    assert len(kinds["lm_idle"]) <= 3
+    for rec in kinds["lm_dispatch"]:
+        _, _, seq, t0, t1, track, args = rec
+        assert seq is None and t1 >= t0 and track == eng.obs_name
+        assert "dispatch" in args
+    admitted = {r[6]["stream"]: r[6]["prompt"] for r in kinds["lm_admit"]}
+    assert admitted == {s.stream_id: s.prompt_len for s in streams}
+    begins = [a for a in eng.ledger._async if a[0] == "b"]
+    ends = [a for a in eng.ledger._async if a[0] == "e"]
+    assert sorted(a[2] for a in begins) == sorted(a[2] for a in ends) \
+        == sorted(s.stream_id for s in streams)
+    assert len(kinds["lm_admitted"]) == len(kinds["lm_first"]) == 3
+    # a dispatch number never lands in a frame's record
+    assert eng.ledger.frame_ledger() == {}
+
+
+def test_spans_go_to_the_installed_timeline_instead():
+    eng = _engine(block_tokens=8)
+    with timeline.tracing() as tl:
+        eng.start()
+        try:
+            _serve(eng, [_prompt(5)])
+        finally:
+            eng.stop()
+    assert len(_kinds(tl)["lm_dispatch"]) == eng.stats["dispatches"] > 0
+    assert "lm_dispatch" not in _kinds(eng.ledger)
+
+
+def test_stall_note_fires_once_with_the_phases(monkeypatch):
+    notes = []
+    monkeypatch.setattr(
+        engine_mod.log, "warning",
+        lambda msg, *args: notes.append(msg % args))
+    eng = _engine(block_tokens=8).start()
+    try:
+        _serve(eng, [_prompt(5)], max_new=30)  # compiles; a median exists
+        monkeypatch.setattr(engine_mod, "STALL_MIN_S", 0.4)
+        monkeypatch.setattr(engine_mod, "STALL_FACTOR", 3.0)
+        before = eng.stats["stalls"]
+        inner, slept = eng._dispatch, []
+
+        def slow(*args):
+            if not slept:
+                slept.append(True)
+                time.sleep(1.0)
+            return inner(*args)
+
+        eng._dispatch = slow
+        del notes[:]
+        _serve(eng, [_prompt(6, 9)], max_new=30)
+        time.sleep(0.12)
+    finally:
+        eng.stop()
+    assert eng.stats["stalls"] - before == 1
+    assert len(notes) == 1 and re.search(r"dispatch 1\d\d\d\b", notes[0]), notes
+    assert notes[0].startswith(f"serving: {eng.obs_name} iteration")
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "monolithic"])
+def test_token_stats_see_one_observation_per_finished_request(paged):
+    eng = _engine(block_tokens=8 if paged else 0).start()
+    try:
+        streams = _serve(eng, [_prompt(5), _prompt(20), _prompt(12)])
+        one = _serve(eng, [_prompt(7)], max_new=1)  # no second token
+    finally:
+        eng.stop()
+    q = eng._lm_stats._q
+    assert q["token"]["p50"].count == len(streams)
+    assert q["ttft"]["p50"].count == len(streams) + len(one)
+    # what a client saw: (finish - first token) / (tokens - 1)
+    seen = sorted((s.finish_t - s.first_t) / (len(s.tokens) - 1)
+                  for s in streams)
+    assert q["token"]["p50"].quantile() == pytest.approx(seen[1])
+
+
+def test_collector_exports_the_phases_as_one_family():
+    eng = _engine(block_tokens=8).start()
+    try:
+        _serve(eng, [_prompt(5)])
+    finally:
+        eng.stop()
+    reg = get_registry()
+    reg.snapshot()  # runs the collectors
+    for phase in engine_mod.PHASES:
+        c = reg.get("nns_serving_loop_phase_seconds_total",
+                    engine=eng.obs_name, phase=phase)
+        assert c is not None, phase
+        assert c.value == pytest.approx(
+            eng.stats[f"phase_{phase}_us"] / 1e6)
+
+
+# -- one clock for the ledger and the device trace ---------------------------
+
+def test_to_trace_ns_round_trip_and_the_pair_in_the_export():
+    tl = timeline.Timeline(16)
+    t, wall = time.monotonic(), time.time_ns()
+    assert abs(tl.to_trace_ns(t) - wall) < 50e6
+    assert tl.to_trace_ns(t + 1.5) - tl.to_trace_ns(t) == 1_500_000_000
+    mono, unix = tl.clock
+    assert tl.to_trace_ns(mono) == unix
+    tl.span("lm_dispatch", None, t, t + 0.25, track="engine9", dispatch=3)
+    doc = tl.to_chrome()
+    clock = doc["metadata"]["clock"]
+    assert (clock["monotonic_s"], clock["unix_ns"]) == tl.clock
+    ev = next(e for e in doc["traceEvents"] if e.get("name") == "lm_dispatch")
+    # a reader of the export gets the trace's clock from the pair
+    back = clock["unix_ns"] + (clock["epoch_monotonic_s"]
+                               - clock["monotonic_s"]) * 1e9 + ev["ts"] * 1e3
+    assert abs(back - tl.to_trace_ns(t)) < 2000
+
+
+def test_extend_last_lengthens_only_a_span_of_that_kind():
+    tl = timeline.Timeline(16)
+    assert not tl.extend_last("lm_idle", 2.0)
+    tl.span("lm_idle", None, 1.0, 2.0, track="e")
+    assert tl.extend_last("lm_idle", 3.0)
+    tl.span("lm_admit", None, 3.0, 3.5, track="e")
+    assert not tl.extend_last("lm_idle", 4.0)
+    spans = [(r[1], r[3], r[4]) for r in tl._snapshot()]
+    assert spans == [("lm_idle", 1.0, 3.0), ("lm_admit", 3.0, 3.5)]
+
+
+def test_clock_differences_pair_kth_with_kth_across_a_cut_edge():
+    device = [(k * 1_000, k * 1_000 + 400) for k in range(1, 5)]
+    host = [(s - 10, e + 30) for s, e in device]
+    assert timeline.clock_differences(host[1:], device) == [(-10, 30)] * 3
+    assert timeline.clock_differences(host[:2], device) == [(-10, 30)] * 2
+    assert timeline.clock_differences([], device) == []
+
+
+def test_align_splits_the_slack_of_a_span_that_holds_its_device_event():
+    tl = timeline.Timeline(16)
+    tr = timeline.DeviceTrace("unused", tl)
+    tr.window = (0.0, 10.0)
+    base = tl.to_trace_ns(0.0)
+    # the device's clock 2 ms ahead of the host's; each span opens 1 ms
+    # before its program starts and closes 3 ms after it ends (true times)
+    ahead = 2_000_000
+    for k in range(1, 4):
+        tl.span("lm_dispatch", None, k - 0.001, k + 0.4 + 0.003, track="e")
+        tr.programs.append(("/device:TPU:0", "jit_dispatch",
+                            base + k * 10**9 + ahead,
+                            base + k * 10**9 + 400_000_000 + ahead))
+    tr.programs.append(("/device:TPU:0", "jit_prefill", base, base + 5))
+    tr._align("lm_dispatch", "jit_dispatch")
+    check = tr.clock_check
+    assert check["n"] == 3
+    assert check["lead_ns"] == pytest.approx(-3_000_000, abs=2_000)
+    assert check["lag_min_ns"] == pytest.approx(1_000_000, abs=2_000)
+    # any offset from 3 ms to -1 ms keeps the event inside: the middle
+    assert tr.offset_ns == pytest.approx(1_000_000, abs=2_000)
+    assert tr.to_trace_ns(1.0) == tl.to_trace_ns(1.0) + tr.offset_ns
+
+
+def test_device_trace_writes_the_ledger_on_the_traces_clock(tmp_path):
+    eng = _engine(block_tokens=8).start()
+    try:
+        _serve(eng, [_prompt(5)])  # compile outside the trace
+        with timeline.device_trace(str(tmp_path), eng.ledger,
+                                   align=("lm_dispatch", "jit_dispatch")) \
+                as tr:
+            _serve(eng, [_prompt(9)])
+    finally:
+        eng.stop()
+    assert tr.xplane and tr.xplane.endswith(".xplane.pb")
+    doc = json.load(open(tr.ledger_path))
+    spans = [e for e in doc["traceEvents"] if e.get("name") == "lm_dispatch"]
+    assert spans
+    now_us = time.time_ns() / 1e3
+    assert all(now_us - 120e6 < e["ts"] <= now_us for e in spans)
+    # the CPU's trace has no device plane: nothing to match, no correction
+    if not tr.programs:
+        assert tr.clock_check is None and tr.offset_ns == 0
+
+
+# -- names inside the programs -----------------------------------------------
+
+def _lowered_text(jitted, *shapes):
+    return jitted.lower(*shapes).as_text(debug_info=True)
+
+
+def _has_scope(text, scope):
+    """A location of the lowered text lies under ``scope``: it starts
+    the location's path or is a part of it."""
+    return f'"{scope}/' in text or f"/{scope}/" in text
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "monolithic"])
+def test_decode_program_is_jit_dispatch_and_holds_every_scope(paged):
+    eng = _engine(block_tokens=8 if paged else 0)
+    build, k, shapes = engine_mod._DECODE_PROGRAMS[eng.obs_name]
+    assert build is eng._build_dispatch and k == eng.K
+    text = _lowered_text(eng._dispatch, *shapes)
+    assert "module @jit_dispatch" in text
+    assert "nns.decode" in text
+    for scope in LEAF_SCOPES:
+        assert _has_scope(text, scope), scope
+    # the optimized program's own text carries them on its instructions
+    compiled = engine_mod.decode_program_text(eng.obs_name)
+    assert 'op_name="jit(dispatch)/' in compiled
+    for scope in LEAF_SCOPES:
+        assert f"/{scope}/" in compiled, scope
+    assert engine_mod.decode_program_text("no-such-engine") is None
+
+
+def test_decode_program_registry_keeps_the_newest_few():
+    engines = [_engine(block_tokens=8)
+               for _ in range(engine_mod._DECODE_PROGRAMS_KEPT + 2)]
+    names = list(engine_mod._DECODE_PROGRAMS)
+    assert names == [e.obs_name
+                     for e in engines[-engine_mod._DECODE_PROGRAMS_KEPT:]]
+
+
+def test_prefill_program_is_jit_prefill_and_holds_every_scope():
+    from nnstreamer_tpu.ops import flash_attention
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, block_q=16, block_k=16,
+                               force="pallas")
+
+    tokens = jax.ShapeDtypeStruct((1, 32), jnp.int32)
+    text = _lowered_text(jax.jit(build_prefill(CFG, attention_fn=flash)),
+                         PARAMS, tokens)
+    assert "module @jit_prefill" in text
+    assert "nns.prefill" in text
+    for scope in ("qkv", "attend", "ffn", "logits"):
+        assert _has_scope(text, scope), scope
+    assert "nns_flash_prefill" in text
+
+
+def test_scatter_program_holds_its_scope():
+    eng = _engine(block_tokens=8)
+    cache1 = jax.eval_shape(eng._init_cache1)
+    arena = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), eng._pool.arena)
+    text = _lowered_text(eng._pool._jit_scatter, arena, cache1,
+                         jax.ShapeDtypeStruct((CFG.max_seq // 8,), jnp.int32))
+    assert "module @jit__scatter_prefill_impl" in text
+    assert "nns.kv_scatter" in text
+
+
+def test_fused_program_is_jit_composed_and_holds_its_scope():
+    from nnstreamer_tpu import parse_launch
+
+    pipe = parse_launch(
+        "appsrc name=src ! tensor_transform mode=arithmetic "
+        "option=typecast:float32,mul:2.0 ! tensor_transform mode=arithmetic "
+        "option=add:1.0 ! tensor_sink name=sink")
+    pipe.start()
+    try:
+        pipe.get("src").push([np.ones((8, 4), np.uint8)])
+        pipe.get("src").end_of_stream()
+        assert pipe.wait(timeout=60).kind == "eos"
+        region = pipe._regions[0]
+        consts, jitted, _ = region._compiled
+        text = _lowered_text(
+            jitted, consts, [jax.ShapeDtypeStruct((8, 4), jnp.uint8)])
+    finally:
+        pipe.stop()
+    assert "module @jit_composed" in text
+    assert "nns.fused" in text
+
+
+@pytest.fixture
+def compile_cache(tmp_path):
+    """A persistent compile cache of the test's own that takes every
+    program, put back as it was afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+    for k, v in zip(keys, (str(tmp_path), 0, -1)):
+        jax.config.update(k, v)
+    cc.reset_cache()
+    try:
+        yield tmp_path
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def test_decode_program_text_has_this_codes_names_past_a_stale_cache(
+        compile_cache, monkeypatch):
+    """The compile cache's key leaves names out, so the program an engine
+    runs can be one that a commit without the scopes compiled (the parent's,
+    on the chip in PR 25). The text is compiled under its own key."""
+    import contextlib
+
+    @contextlib.contextmanager
+    def no_scope(name):
+        yield
+
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", no_scope)
+        old = _engine(block_tokens=8)
+        _, _, shapes = engine_mod._DECODE_PROGRAMS[old.obs_name]
+        old._dispatch.lower(*shapes).compile()  # a commit before the scopes
+    eng = _engine(block_tokens=8)
+    ran = eng._dispatch.lower(*shapes).compile().as_text()
+    assert "kv_gather" not in ran  # found in the cache, with the old names
+    text = engine_mod.decode_program_text(eng.obs_name)
+    for scope in LEAF_SCOPES:
+        assert f"/{scope}/" in text, scope
+
+    def instructions(t):
+        return re.findall(r"^\s*(?:ROOT )?(%[^\s=]+) = ", t, re.M)
+
+    assert instructions(text) == instructions(ran)
