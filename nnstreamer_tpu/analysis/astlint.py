@@ -115,8 +115,10 @@ These rules encode invariants this codebase has already been burned by
   and the zero-block/sentinel invariants all live there, and a raw
   ``arena[...]`` elsewhere silently breaks them (a freed block's bytes
   read as stale history, a donated buffer is use-after-free). The
-  model-side paged builders never see the arena whole; they receive
-  per-layer slices from the decode scan.
+  model-side paged builders (``models/transformer.py``) do take the
+  arena whole, inside the jitted decode program, and address it by
+  ``(layer, block, slot)`` through the codec's ``paged_write`` /
+  ``paged_read`` helpers, which own the sentinel rules there.
 - NNS119: a hard-coded ``host:port`` string literal outside
   ``query/discovery.py``, config modules, and tests. A replicated fleet
   (serving/fleet.py) moves endpoints at every deploy — replicas bind
@@ -738,8 +740,9 @@ class _FileLinter(ast.NodeVisitor):
             f"arena index elsewhere can read a freed block's stale bytes "
             f"or write through a donated buffer",
             hint="go through BlockPool (scatter_prefill/copy_block) or "
-                 "the models/transformer.py paged builders, which take "
-                 "per-layer slices — or justify with a pragma")
+                 "the models/transformer.py paged builders, which "
+                 "address it by (layer, block, slot) inside the jitted "
+                 "program — or justify with a pragma")
 
     def _rule_nns119(self, node: ast.Constant) -> None:
         if self._nns119_exempt:

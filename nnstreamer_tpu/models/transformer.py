@@ -230,25 +230,27 @@ def _slot_write(layer_cache, upd, pos, per_stream):
         layer_cache, upd, (0, 0, pos) + (0,) * (layer_cache.ndim - 3))
 
 
-def _paged_gather(pages, bt):
-    """Per-layer block gather: ``pages [NTOT, 2, T, ...]`` + block table
-    ``bt [b, MB]`` → contiguous ``[b, 2, MB*T, ...]`` k/v in global-slot
-    order. Table entries ≥ NTOT-1 (the pool's unallocated sentinel) clamp
-    onto the pool's permanent ZERO block at index NTOT-1, so unallocated
-    slots read exact zeros — finite, and masked out anyway."""
-    ntot = pages.shape[0]
-    g = pages[jnp.minimum(bt, ntot - 1)]                 # [b,MB,2,T,...]
+def _paged_gather(pages, layer, bt):
+    """Block gather out of the whole arena leaf: ``pages [L, NTOT, 2, T,
+    ...]`` + layer number + block table ``bt [b, MB]`` → that layer's
+    contiguous ``[b, 2, MB*T, ...]`` k/v in global-slot order. Table
+    entries ≥ NTOT-1 (the pool's unallocated sentinel) clamp onto the
+    pool's permanent ZERO block at index NTOT-1, so unallocated slots read
+    exact zeros — finite, and masked out anyway."""
+    ntot = pages.shape[1]
+    g = pages[layer, jnp.minimum(bt, ntot - 1)]          # [b,MB,2,T,...]
     g = jnp.moveaxis(g, 2, 1)                            # [b,2,MB,T,...]
     b, two, mb, t = g.shape[:4]
     return g.reshape((b, two, mb * t) + g.shape[4:])
 
 
-def _paged_scatter(pages, upd, blk, off):
-    """Per-layer block scatter: ``upd [b, c, 2, ...]`` into
-    ``pages[blk, :, off]`` (``blk``/``off`` are ``[b, c]``). Out-of-range
-    block ids (the sentinel) DROP — a masked write, not a clamped one, so
-    the zero block is never corrupted."""
-    return pages.at[blk, :, off].set(upd, mode="drop")
+def _paged_scatter(pages, layer, upd, blk, off):
+    """Block scatter into the whole arena leaf, in place when the leaf is
+    a donated loop carry: ``upd [b, c, 2, ...]`` into ``pages[layer, blk,
+    :, off]`` (``blk``/``off`` are ``[b, c]``). Out-of-range block ids
+    (the sentinel) DROP — a masked write, not a clamped one, so the zero
+    block is never corrupted."""
+    return pages.at[layer, blk, :, off].set(upd, mode="drop")
 
 
 class _RawKVCodec:
@@ -275,18 +277,19 @@ class _RawKVCodec:
             cache, kv.astype(self.dtype), (0, 0, 0, 0, 0, 0))
 
     def paged_init(self, L, ntot, T, h, dh):
-        """Paged arena [L, NTOT, 2, T, h, dh] — leading L so a layer scan
-        carries one block pool slice per layer (serving/kvpool.py owns
-        allocation; index NTOT-1 is the permanent zero block)."""
+        """Paged arena [L, NTOT, 2, T, h, dh]: ONE buffer that the paged
+        builders address whole, the layer one more index beside the block
+        (serving/kvpool.py owns allocation; index NTOT-1 of every layer
+        is the permanent zero block)."""
         return jnp.zeros((L, ntot, 2, T, h, dh), self.dtype)
 
-    def paged_write(self, pages, kv, blk, off):
-        """kv [2, b, c, h, dh] → pages[blk[b,c], :, off[b,c]]."""
+    def paged_write(self, pages, layer, kv, blk, off):
+        """kv [2, b, c, h, dh] → pages[layer, blk[b,c], :, off[b,c]]."""
         upd = jnp.transpose(kv.astype(self.dtype), (1, 2, 0, 3, 4))
-        return _paged_scatter(pages, upd, blk, off)
+        return _paged_scatter(pages, layer, upd, blk, off)
 
-    def paged_read(self, pages, bt):
-        g = _paged_gather(pages, bt)
+    def paged_read(self, pages, layer, bt):
+        g = _paged_gather(pages, layer, bt)
         return g[:, 0], g[:, 1]
 
 
@@ -332,23 +335,24 @@ class _Int8KVCodec:
         return {"q": jnp.zeros((L, ntot, 2, T, h, dh), jnp.int8),
                 "scale": jnp.zeros((L, ntot, 2, T, h), jnp.float32)}
 
-    def paged_write(self, pages, kv, blk, off):
+    def paged_write(self, pages, layer, kv, blk, off):
         """Codec applied per block: each written vector quantizes with the
         same per-vector absmax math as the monolithic write, so paged int8
-        caches are bit-identical to monolithic int8 ones."""
+        caches are bit-identical to monolithic int8 ones. Both leaves
+        take the same ``[layer, blk, :, off]`` index."""
         q, s = self._q(kv)                 # [2,b,c,h,dh], [2,b,c,h]
         return {
-            "q": _paged_scatter(pages["q"],
+            "q": _paged_scatter(pages["q"], layer,
                                 jnp.transpose(q, (1, 2, 0, 3, 4)),
                                 blk, off),
-            "scale": _paged_scatter(pages["scale"],
+            "scale": _paged_scatter(pages["scale"], layer,
                                     jnp.transpose(s, (1, 2, 0, 3)),
                                     blk, off),
         }
 
-    def paged_read(self, pages, bt):
-        gq = _paged_gather(pages["q"], bt)
-        gs = _paged_gather(pages["scale"], bt)
+    def paged_read(self, pages, layer, bt):
+        gq = _paged_gather(pages["q"], layer, bt)
+        gs = _paged_gather(pages["scale"], layer, bt)
         deq = gq.astype(jnp.float32) * gs[..., None]
         return deq[:, 0], deq[:, 1]
 
@@ -521,14 +525,21 @@ def build_paged_decode_step(cfg: TransformerConfig,
     The arena is the pool's ``[L, NTOT, 2, T, h, dh]`` pytree; ``bt`` maps
     each row's logical blocks ``0..MB-1`` (MB = S/T) to physical pool
     blocks, with unallocated entries holding the pool sentinel (≥ NTOT).
-    Each step scatters k/v into physical slot ``(bt[pos//T], pos%T)`` and
-    gathers the row's table back into the contiguous ``[b, S, ...]``
-    layout the shared attention core expects — same slot ordering, same
-    write-before-attend discipline, and masked slots contribute EXACT
-    zeros (−1e30 scores underflow softmax to 0.0), so greedy outputs are
-    bit-identical to the monolithic cache. Rows whose table is all
-    sentinel (empty batch lanes) drop their writes and read the zero
-    block — inert by construction.
+    Each layer scatters k/v into physical slot ``(layer, bt[pos//T],
+    pos%T)`` and gathers the row's table back into the contiguous ``[b,
+    S, ...]`` layout the shared attention core expects — same slot
+    ordering, same write-before-attend discipline, and masked slots
+    contribute EXACT zeros (−1e30 scores underflow softmax to 0.0), so
+    greedy outputs are bit-identical to the monolithic cache. Rows whose
+    table is all sentinel (empty batch lanes) drop their writes and read
+    the zero block — inert by construction.
+
+    The arena is ONE buffer that no scan slices: it rides the layer scan
+    as a carry beside ``x`` and the layer number, and the codec addresses
+    it whole with the layer as one more index. A carry of a donated
+    argument is scattered into in place; as the scan's ``xs``/``ys`` each
+    layer's 1/L of the pool is sliced out and written back every step
+    and the compiler plans the pool twice (PERF.md §6, PR 26).
     """
     dtype = cfg.dtype
     s_max = max_seq or cfg.max_seq
@@ -550,26 +561,26 @@ def build_paged_decode_step(cfg: TransformerConfig,
         layer_params = {k: v for k, v in params.items()
                         if k not in ("embed", "ln_f")}
 
-        def layer(carry, lp_and_pages):
-            x, = carry
-            lp, pages = lp_and_pages              # one layer's blocks
+        def layer(carry, lp):
+            x, li, pages = carry                  # the whole arena
             with jax.named_scope("qkv"):
                 q, k, v = _block_qkv(x, lp, positions, dtype)  # [b,1,h,dh]
             with jax.named_scope("kv_write"):
-                pages = codec.paged_write(pages, jnp.stack([k, v]), blk,
-                                          off)
+                pages = codec.paged_write(pages, li, jnp.stack([k, v]),
+                                          blk, off)
             with jax.named_scope("kv_gather"):
                 slots = jnp.arange(s_max)
                 mask = slots[None, None, None, :] <= pos_c[:, None, None,
                                                            None]
-                ck, cv = codec.paged_read(pages, bt)
+                ck, cv = codec.paged_read(pages, li, bt)
             with jax.named_scope("attend"):
                 a = _attend_cache(q, ck, cv, mask, cfg.head_dim, dtype)
             with jax.named_scope("ffn"):
                 x = _block_tail(x, a, lp, cfg)
-            return (x,), pages
+            return (x, li + 1, pages), None
 
-        (x,), new_arena = lax.scan(layer, (x,), (layer_params, arena))
+        (x, _, new_arena), _ = lax.scan(
+            layer, (x, jnp.int32(0), arena), layer_params)
         with jax.named_scope("logits"):
             return _final_logits(x, params)[:, 0], new_arena
 
@@ -590,7 +601,9 @@ def build_paged_chunk(cfg: TransformerConfig,
     padding) redirect their writes to the sentinel and drop, so a padded
     warm prefix extension never smears pad k/v into pool blocks another
     stream could inherit. Used for prefix-cache extension and speculative
-    verification on the paged path.
+    verification on the paged path. The arena is addressed as in
+    :func:`build_paged_decode_step`: whole, a carry of the layer scan, the
+    layer an index.
     """
     dtype = cfg.dtype
     s_max = max_seq or cfg.max_seq
@@ -615,20 +628,21 @@ def build_paged_chunk(cfg: TransformerConfig,
         layer_params = {k: v for k, v in params.items()
                         if k not in ("embed", "ln_f")}
 
-        def layer(carry, lp_and_pages):
-            x, = carry
-            lp, pages = lp_and_pages
+        def layer(carry, lp):
+            x, li, pages = carry                  # the whole arena
             q, k, v = _block_qkv(x, lp, positions, dtype)  # [b,c,h,dh]
-            pages = codec.paged_write(pages, jnp.stack([k, v]), blk, off)
+            pages = codec.paged_write(pages, li, jnp.stack([k, v]), blk,
+                                      off)
             slots = jnp.arange(s_max)
             mask = slots[None, None, None, :] <= positions[:, None, :,
                                                            None]
-            ck, cv = codec.paged_read(pages, bt)
+            ck, cv = codec.paged_read(pages, li, bt)
             a = _attend_cache(q, ck, cv, mask, cfg.head_dim, dtype)
             x = _block_tail(x, a, lp, cfg)
-            return (x,), pages
+            return (x, li + 1, pages), None
 
-        (x,), new_arena = lax.scan(layer, (x,), (layer_params, arena))
+        (x, _, new_arena), _ = lax.scan(
+            layer, (x, jnp.int32(0), arena), layer_params)
         return _final_logits(x, params), new_arena
 
     return chunk

@@ -7,11 +7,13 @@ into fixed ``block_tokens``-sized blocks instead (the compiler-first
 O(1) autoregressive-caching form, PAPERS.md):
 
 - **Arena** — one device pytree per codec, leaves ``[L, NTOT, 2, T, h,
-  dh]`` (int8 adds a ``[L, NTOT, 2, T, h]`` scale leaf). The leading L
-  axis lets the decode layer scan carry one per-layer block-pool slice,
-  exactly like the monolithic cache's leading L. ``NTOT = num_blocks +
-  1``: index ``num_blocks`` is a permanent ZERO block that is never
-  allocated and never written.
+  dh]`` (int8 adds a ``[L, NTOT, 2, T, h]`` scale leaf). ONE buffer per
+  leaf that the decode program updates in place: it is a carry of the
+  K-step scan and of the layer scan, never a scan's ``xs``/``ys``, and
+  the layer is one more index beside the block (``[layer, block, :,
+  slot]``; why: ``build_paged_decode_step``). ``NTOT = num_blocks + 1``:
+  index ``num_blocks`` of every layer is a permanent ZERO block that is
+  never allocated and never written.
 - **Sentinel** — unallocated block-table entries hold ``SENTINEL =
   NTOT``, deliberately out of bounds: gathers clamp onto the zero block
   (reads are exact zeros, finite and masked anyway) and scatters use
@@ -28,12 +30,15 @@ O(1) autoregressive-caching form, PAPERS.md):
   pressure shows up in ``nns_mem_used_bytes{category="kvcache"}`` and
   rides the same evict → shed → cpu ladder as weights and frames.
 
-Model-side consumers (models/transformer.py paged builders) never index
-the arena directly — they receive per-layer slices from the scan and a
-block table. Direct arena subscripts outside this file are flagged by
-lint rule NNS118: every host-side mutation (prefill scatter, COW block
-copy) must go through the pool so refcounts, donation, and the zero
-block's invariants stay in one place.
+Model-side consumers (models/transformer.py paged builders) take the
+arena whole, with a block table, and hand it to the codec's
+``paged_write``/``paged_read``, which address it by ``(layer, block,
+slot)`` under the sentinel rules above — inside the jitted program,
+where donation makes the write in place. Host-side code never
+subscripts the arena outside this file (lint rule NNS118): every
+host-side mutation (prefill scatter, COW block copy) must go through
+the pool so refcounts, donation, and the zero block's invariants stay
+in one place.
 
 Kill switch: ``NNSTPU_PAGED_KV=0`` (or ``block_tokens=0`` on the
 engine) disables paging entirely — the engine then never imports an

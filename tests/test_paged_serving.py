@@ -13,8 +13,14 @@ The correctness bar, per ISSUE 19's acceptance criteria:
 - under a starved pool the evict -> shed ladder fires, shed streams'
   blocks return to the free list, and surviving streams stay exact;
 - copy-on-write prefix sharing retains blocks once across streams;
-- paging x int8 x mesh=dp2 composes byte-identically (satellite 4).
+- paging x int8 x mesh=dp2 composes byte-identically (satellite 4);
+- the arena is ONE buffer the decode program updates in place (PR 26): a
+  carry of both scans, never a scan's ``xs``/``ys``; every layer's slots,
+  the last included, hold what the monolithic cache holds; an empty lane
+  writes nowhere and the zero block stays zero.
 """
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -26,6 +32,13 @@ from nnstreamer_tpu.serving import ContinuousBatchingEngine  # noqa: E402
 from tests.test_serving import CFG, PARAMS, reference_greedy  # noqa: E402
 
 T = 8
+
+
+def assert_zero_block_is_zero(eng):
+    """Index NTOT-1 of every layer and leaf: never allocated, never
+    written — what the empty lanes and the unallocated tails read."""
+    for leaf in jax.tree.leaves(eng._pool.arena):
+        assert not np.asarray(leaf)[:, eng._pool.ntot - 1].any()
 
 
 def paged_engine(**kw):
@@ -78,6 +91,7 @@ def test_single_stream_matches_reference():
                 reference_greedy(p, 9), f"prompt={p}"
     finally:
         eng.stop()
+    assert_zero_block_is_zero(eng)   # two of the three lanes were empty
 
 
 def test_concurrent_streams_match_isolated_runs():
@@ -109,6 +123,7 @@ def test_int8_paged_matches_int8_monolithic():
     finally:
         eng.stop()
     assert got == want
+    assert_zero_block_is_zero(eng)
 
 
 def test_chunked_prefill_composes_with_paging():
@@ -251,3 +266,132 @@ def test_paged_int8_dp2_mesh_matches_single_device():
         eng.stop()
     assert got == want
     assert conc == want
+
+
+# -- the arena's addressing: one buffer, the layer an index (PR 26) -------
+
+CODECS = pytest.mark.parametrize("codec", [None, "int8"], ids=["raw", "int8"])
+
+
+def _toy():
+    """3 layers (so an index off by one lands on a real layer or off the
+    end), 3 lanes, the last one EMPTY: its table is all sentinel."""
+    from nnstreamer_tpu.models.transformer import init_params
+
+    cfg = dataclasses.replace(CFG, n_layers=3, max_seq=32)
+    mb = cfg.max_seq // T
+    nb = 3 * mb                                   # ntot 13, sentinel 13
+    bt = np.full((3, mb), nb + 1, np.int32)
+    bt[0], bt[1] = [7, 2, 9, 4], [0, 11, 5, 3]    # scrambled on purpose
+    return cfg, init_params(cfg, seed=5), bt, nb
+
+
+def _assert_pool_is_the_monolithic_cache(arena, cache, bt, nb):
+    """Every layer of the pool, the LAST included, holds what the
+    monolithic cache holds for the live lanes, slot for slot; every block
+    no live lane owns — the zero block first — is still zero."""
+    live = sorted(set(bt[:2].ravel()))
+    rest = [i for i in range(nb + 1) if i not in live]
+    assert nb in rest
+    for leaf, mono in zip(jax.tree.leaves(arena), jax.tree.leaves(cache)):
+        leaf, mono = np.asarray(leaf), np.asarray(mono)
+        assert mono[-1].any(), "the last layer wrote nothing to compare"
+        for layer in range(leaf.shape[0]):
+            for lane in (0, 1):
+                blocks = leaf[layer][bt[lane]]         # [MB,2,T,...]
+                view = np.moveaxis(blocks, 1, 0)       # [2,MB,T,...]
+                view = view.reshape((2, -1) + view.shape[3:])
+                np.testing.assert_array_equal(
+                    view, mono[layer, :, lane], f"layer {layer} lane {lane}")
+        assert not leaf[:, rest].any()
+
+
+@CODECS
+@pytest.mark.parametrize("builder", ["step", "chunk"])
+def test_paged_builders_are_bit_identical_to_the_monolithic_ones(
+        builder, codec):
+    from nnstreamer_tpu.models.transformer import (
+        _kv_codec,
+        build_chunk_decode,
+        build_decode_step,
+        build_paged_chunk,
+        build_paged_decode_step,
+        init_cache,
+    )
+
+    cfg, params, bt, nb = _toy()
+    arena = _kv_codec(cfg, codec).paged_init(
+        cfg.n_layers, nb + 1, T, cfg.n_heads, cfg.head_dim)
+    cache = init_cache(cfg, 3, kv_codec=codec)
+    tables = jnp.asarray(bt)
+    rng = np.random.default_rng(11)
+    if builder == "step":
+        paged = jax.jit(build_paged_decode_step(cfg, T, kv_codec=codec))
+        mono = jax.jit(build_decode_step(cfg, kv_codec=codec))
+        for i in range(10):           # lanes 0 and 1 cross a block edge
+            tok = jnp.asarray(rng.integers(1, cfg.vocab, 3), jnp.int32)
+            pos = jnp.asarray([i, i + 3, i + 1], jnp.int32)
+            got, arena = paged(params, tok, arena, tables, pos)
+            want, cache = mono(params, tok, cache, pos)
+            np.testing.assert_array_equal(got[:2], want[:2], f"step {i}")
+            assert np.isfinite(np.asarray(got[2])).all()
+    else:
+        paged = jax.jit(build_paged_chunk(cfg, T, kv_codec=codec))
+        mono = jax.jit(build_chunk_decode(cfg, kv_codec=codec))
+        for i in range(2):
+            toks = jnp.asarray(rng.integers(1, cfg.vocab, (3, 5)), jnp.int32)
+            pos0 = jnp.asarray([5 * i, 5 * i + 3, 5 * i + 1], jnp.int32)
+            got, arena = paged(params, toks, arena, tables, pos0,
+                               jnp.full((3,), 5, jnp.int32))
+            want, cache = mono(params, toks, cache, pos0)
+            np.testing.assert_array_equal(got[:2], want[:2], f"chunk {i}")
+    _assert_pool_is_the_monolithic_cache(arena, cache, bt, nb)
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+@CODECS
+def test_arena_is_a_carry_of_both_scans_and_is_updated_in_place(codec):
+    """What keeps a later edit from putting the slices back: no scan of
+    the decode step or of the K-step dispatch takes the arena as ``xs`` or
+    returns it as ``ys`` (each layer's 1/L of the pool sliced out and
+    written back every step, and a second pool: PERF.md PR 26); it is a
+    carry of both, and the donated buffer is the one that comes back."""
+    eng = ContinuousBatchingEngine(
+        CFG, PARAMS, max_streams=3, steps_per_dispatch=4, temperature=0.0,
+        block_tokens=T, kv_quant=codec)
+    arena = eng._pool.arena
+    pool_shapes = {leaf.shape for leaf in jax.tree.leaves(arena)}
+    bt = np.full((eng.B, eng.MB), eng._pool.SENTINEL, np.int32)
+    bt[0, :2], bt[1, :2] = [3, 1], [0, 2]         # lane 2 stays empty
+    tok = jnp.asarray([5, 9, 0], jnp.int32)
+    pos = jnp.asarray([2, 6, 0], jnp.int32)
+    keys = jnp.zeros((eng.B, 2), jnp.uint32)
+    step = jax.make_jaxpr(eng._paged_decode)(
+        eng.params, tok, arena, jnp.asarray(bt), pos)
+    dispatch = jax.make_jaxpr(eng._build_dispatch(eng.K))(
+        eng.params, tok, arena, jnp.asarray(bt), pos, keys)
+    for jaxpr, n_scans in ((step, 1), (dispatch, 2)):
+        scans = list(_scans(jaxpr.jaxpr))
+        assert len(scans) == n_scans
+        for eqn in scans:
+            nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+            carried = {v.aval.shape for v in eqn.invars[nc:nc + nk]}
+            sliced = {v.aval.shape for v in eqn.invars[nc + nk:]} \
+                | {v.aval.shape for v in eqn.outvars[nk:]}
+            assert pool_shapes <= carried
+            assert not pool_shapes & sliced
+    out = eng._dispatch(eng.params, tok, arena, jnp.asarray(bt), pos, keys)
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(arena))
+    for leaf in jax.tree.leaves(out[2]):
+        leaf = np.asarray(leaf)
+        # the empty lane wrote nowhere and the zero block is still zero
+        assert leaf[:, [0, 1, 2, 3]].any()
+        assert not leaf[:, 4:].any()
