@@ -20,6 +20,12 @@ weights from a seed, nothing downloaded):
   ``tensor_query_client`` pipelines; every client must get exactly
   ``max-new-tokens`` real tokens (never the ``-1`` error token) and
   every prompt's first token must agree with the full forward pass;
+- **hybrid leg** — the hybrid LM family (``models/hybrid.py``: Mamba-2
+  and attention mixers, 8 experts top-3 of which 4 are held) at a small
+  size through the same engine's paged path: each prompt's first token
+  and eight decoded tokens must agree with the family's full forward
+  over prompt + served tokens, and the pool must end with no block and
+  no state slot live;
 - **mesh leg** — on a host with four or more chips, the stream leg again
   with ``mesh=dp4``: the batches the pipeline staged lie two rows each on
   four distinct devices, no byte is resharded, labels equal the
@@ -102,10 +108,12 @@ FULL = dict(
     frames=64, lm_layers=8, max_new=16,
     # per client; buckets hit: 16, 64, 256, 512 (min_bucket 16, x2 steps)
     prompts=((12, 300), (40, 200), (9, 260)),
+    hybrid_prompts=(12, 200, 300),
     flash_shapes=((1, 256, 8, 64), (4, 4096, 8, 64)))
 REHEARSAL = dict(
     frames=16, lm_layers=2, max_new=4,
     prompts=((12, 260), (40,)),
+    hybrid_prompts=(12, 40),
     flash_shapes=((1, 256, 8, 64),))
 
 
@@ -576,6 +584,73 @@ def server_leg(sizes: dict, on_chip: bool) -> dict:
         unregister_engine(ENGINE_NAME)
 
 
+def hybrid_leg(sizes: dict, on_chip: bool) -> dict:
+    """The hybrid LM family (state-space + attention mixers, a share of
+    the routed experts) at a small size through the engine's paged path:
+    the first token and eight decoded tokens of each prompt against the
+    family's own full forward over prompt + served tokens — prefill, the
+    hand-over of the recurrent state at the prompt's last token, and the
+    decode steps through both arenas."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from nnstreamer_tpu.models import hybrid
+    from nnstreamer_tpu.serving import ContinuousBatchingEngine
+
+    cfg = hybrid.HybridConfig(
+        vocab=1024, d_model=256,
+        layer_types=("mamba", "mamba", "attention", "mamba"),
+        n_heads=4, n_kv_heads=2, head_dim=64, attention_scale=0.125,
+        ssm_heads=8, ssm_head_dim=64, ssm_state=128, ssm_chunk=256,
+        num_experts=8, experts_per_token=3, expert_width=128,
+        shared_width=256, experts_held=(0, 4), max_seq=512)
+    params = hybrid.init_params(cfg, seed=0)
+    new = 9
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(1, cfg.vocab, n).astype(np.int32)
+               for n in sizes["hybrid_prompts"]]
+    forward = jax.jit(hybrid.build_forward(cfg))
+    engine = ContinuousBatchingEngine(
+        cfg, params, max_streams=4, steps_per_dispatch=8, temperature=0.0,
+        block_tokens=16).start()
+    try:
+        streams = [engine.submit(p, max_new_tokens=new) for p in prompts]
+        worst_lp = worst_gap = 0.0
+        for p, st in zip(prompts, streams):
+            toks = np.asarray(st.result(timeout=600))
+            who = f"hybrid prompt of {p.size}"
+            check(toks.size == new and (toks >= 0).all()
+                  and (toks < cfg.vocab).all() and st.finish_reason == "length",
+                  f"{who}: tokens {toks.tolist()} ({st.finish_reason})")
+            seq = np.zeros((1, cfg.max_seq), np.int32)
+            seq[0, :p.size] = p
+            seq[0, p.size:p.size + new - 1] = toks[:-1]
+            ref = np.asarray(jax.nn.log_softmax(forward(params, seq)[
+                0, p.size - 1:p.size - 1 + new].astype(jnp.float32)))
+            at = ref[np.arange(new), toks]
+            diff = float(np.abs(at - np.asarray(st.logprobs)).max())
+            gap = float((ref.max(axis=1) - at).max())
+            check(diff <= LOGPROB_TOL and gap <= LOGPROB_TOL,
+                  f"{who}: served logprobs {st.logprobs} against the "
+                  f"forward's {at.tolist()} (best {ref.max(axis=1).tolist()})")
+            worst_lp, worst_gap = max(worst_lp, diff), max(worst_gap, gap)
+        snap = engine._pool.snapshot()
+        check(snap["live_blocks"] == 0 and snap["state_slots_live"] == 0,
+              f"pool still holds {snap}")
+        check(engine.stats["moe_tokens_held"] > 0,
+              "no token reached a held expert")
+        return {"tokens_vs_forward": {
+            "prompts": len(prompts), "tokens_each": new,
+            "max_logprob_diff": round(worst_lp, 5),
+            "max_gap_to_argmax": round(worst_gap, 5)},
+            "state_bytes": snap["state_bytes"],
+            "moe_tokens_held": int(engine.stats["moe_tokens_held"]),
+            "moe_tokens_absent": int(engine.stats["moe_tokens_absent"])}
+    finally:
+        engine.stop()
+
+
 # --------------------------------------------------------------------------
 # driver
 # --------------------------------------------------------------------------
@@ -630,6 +705,7 @@ def run(rehearse: bool) -> dict:
     reference = _reference_labels(apply_fn, params, frames_u8)
     leg("stream", stream_leg, sizes, device.platform, frames_u8, reference)
     leg("server", server_leg, sizes, on_chip)
+    leg("hybrid", hybrid_leg, sizes, on_chip)
     mesh = None
     if n_devices >= 4:
         # same reference as the single-device leg, so equal labels

@@ -45,12 +45,17 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    @property
+    def family(self):
+        """What the serving engine takes from this model
+        (``models/family.py``)."""
+        return DENSE
+
 
 def init_params(cfg: TransformerConfig, seed: int = 0) -> Dict[str, Any]:
     rng = np.random.default_rng(seed)
 
-    def norm(*shape, scale=None):
-        scale = scale or (1.0 / np.sqrt(shape[-2] if len(shape) > 1 else 1))
+    def norm(*shape):
         return jnp.asarray(
             rng.standard_normal(shape).astype(np.float32) * 0.02
         )
@@ -122,15 +127,32 @@ def _block_tail(x, a, lp, cfg):
     return x + _dense_ffn(h2, lp["w_in"], lp["w_out"], dtype)
 
 
-def _attend_cache(q, ck, cv, mask, head_dim, dtype):
+def _attend_cache(q, ck, cv, mask, head_dim, dtype, scale=None):
     """The ONE cached-attention numeric core shared by single-token decode
     and chunk decode: fp32 scores (same scale FORM as attention_reference,
     flash_attention.py:45), fp32 softmax AND fp32 probs×values, rounding
     only the final output — bit-matches the full forward so greedy
-    decode/forward parity holds in bfloat16 configs too."""
+    decode/forward parity holds in bfloat16 configs too.
+
+    ``scale`` defaults to ``head_dim ** -0.5``. A cache with fewer heads
+    than ``q`` is grouped-query attention: query head ``i`` reads
+    key-value head ``i // (query heads / key-value heads)``."""
+    scale = head_dim ** -0.5 if scale is None else scale
+    if q.shape[2] != ck.shape[2]:
+        b, nq, hq, c = q.shape
+        hk = ck.shape[2]
+        scores = jnp.einsum(
+            "bqkgc,bskc->bkgqs",
+            q.astype(jnp.float32).reshape(b, nq, hk, hq // hk, c),
+            ck.astype(jnp.float32)) * scale
+        probs = jax.nn.softmax(
+            jnp.where(mask[:, :, None], scores, -1e30), axis=-1)
+        return jnp.einsum("bkgqs,bskc->bqkgc", probs,
+                          cv.astype(jnp.float32)).reshape(
+                              b, nq, hq, c).astype(dtype)
     scores = jnp.einsum("bqhc,bshc->bhqs", q.astype(jnp.float32),
                         ck.astype(jnp.float32))
-    scores = scores * head_dim ** -0.5
+    scores = scores * scale
     scores = jnp.where(mask, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqs,bshc->bqhc", probs,
@@ -825,6 +847,16 @@ def build_sample_stream_step(cfg: TransformerConfig,
         return nxt, cache2, pos + 1, keys.reshape(2)
 
     return step
+
+
+from nnstreamer_tpu.models.family import ModelFamily  # noqa: E402
+
+#: the dense block as a member of the engine's model family: its programs
+#: are the builders above, a lane holds nothing beside its blocks
+DENSE = ModelFamily(
+    name="dense", init_params=init_params, build_prefill=build_prefill,
+    build_paged_decode_step=build_paged_decode_step,
+    kv_layout=lambda cfg: (cfg.n_layers, cfg.n_heads, cfg.head_dim))
 
 
 def transformer_lm(vocab: int = 32000, d_model: int = 512, n_heads: int = 8,

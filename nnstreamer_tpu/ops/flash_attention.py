@@ -34,13 +34,18 @@ log = get_logger("flash-attention")
 _NEG_BIG = -1e30
 
 
-def attention_reference(q, k, v, causal: bool = True):
+def attention_reference(q, k, v, causal: bool = True, scale=None):
     """Plain XLA attention, [batch, seq, heads, dim] layout; fp32 softmax.
 
     The canonical single-device reference — parallel.ring re-exports this
-    for its unsharded path.
+    for its unsharded path. ``scale`` defaults to ``dim ** -0.5``; ``k``
+    and ``v`` may have fewer heads than ``q`` (grouped queries: query head
+    ``i`` reads key-value head ``i // group``).
     """
-    scale = q.shape[-1] ** -0.5
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
@@ -101,13 +106,17 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret"))
+                                             "interpret", "scale"))
 def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
-                interpret: bool):
-    """Kernel entry on [batch, heads, seq, dim] layout."""
+                interpret: bool, scale=None):
+    """Kernel entry on [batch, heads, seq, dim] layout. ``k``/``v`` with
+    fewer heads than ``q``: the grid walks the query heads and head ``i``
+    streams the tiles of key-value head ``i // group``."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    scale = d ** -0.5
+    group = h // k.shape[1]
+    scale = d ** -0.5 if scale is None else scale
+    kv_head = (lambda ih: ih) if group == 1 else (lambda ih: ih // group)
     grid = (b, h, sq // block_q, sk // block_k)
     kern = functools.partial(_kernel, causal=causal, scale=scale,
                              block_q=block_q, block_k=block_k)
@@ -127,9 +136,9 @@ def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
             pl.BlockSpec((1, 1, block_q, d),
                          lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
+                         lambda ib, ih, iq, ik: (ib, kv_head(ih), ik, 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
+                         lambda ib, ih, iq, ik: (ib, kv_head(ih), ik, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
                                lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -160,6 +169,9 @@ def _pallas_reject(q, k, block_q: int, block_k: int) -> str | None:
         return f"blocks ({block_q}, {block_k}) are not multiples of 8 rows"
     if d % 8 or d > 256:
         return f"head dim {d} is not a multiple of 8 in [8, 256]"
+    if h % k.shape[2]:
+        return (f"{h} query heads are no multiple of {k.shape[2]} "
+                f"key-value heads")
     return None
 
 
@@ -172,8 +184,10 @@ def _log_reference_choice(q_shape, k_shape, dtype, why: str) -> None:
 
 
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
-                    block_k: int = 256, force: str | None = None):
-    """Attention on [batch, seq, heads, dim] tensors.
+                    block_k: int = 256, force: str | None = None,
+                    scale: float | None = None):
+    """Attention on [batch, seq, heads, dim] tensors; ``scale`` and fewer
+    key-value heads as in :func:`attention_reference`.
 
     ``force``: None (auto: the Pallas kernel on a TPU for tileable
     shapes, else the XLA reference), "pallas" (always the kernel — Mosaic
@@ -181,7 +195,7 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
     tests run it), or "reference".
     """
     if force == "reference":
-        return attention_reference(q, k, v, causal=causal)
+        return attention_reference(q, k, v, causal=causal, scale=scale)
     block_q = min(block_q, q.shape[1])
     block_k = min(block_k, k.shape[1])
     on_tpu = jax.default_backend() == "tpu"
@@ -192,14 +206,14 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
                 f"flash_attention: shapes {q.shape}/{k.shape} not tileable "
                 f"by ({block_q},{block_k}): {why_not}")
     elif not on_tpu:
-        return attention_reference(q, k, v, causal=causal)
+        return attention_reference(q, k, v, causal=causal, scale=scale)
     elif why_not:
         _log_reference_choice(tuple(q.shape), tuple(k.shape), str(q.dtype),
                               why_not)
-        return attention_reference(q, k, v, causal=causal)
+        return attention_reference(q, k, v, causal=causal, scale=scale)
     qt = q.swapaxes(1, 2)  # [b, h, s, d]
     kt = k.swapaxes(1, 2)
     vt = v.swapaxes(1, 2)
     out = _flash_bhsd(qt, kt, vt, causal, block_q, block_k,
-                      interpret=not on_tpu)
+                      interpret=not on_tpu, scale=scale)
     return out.swapaxes(1, 2)

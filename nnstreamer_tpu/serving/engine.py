@@ -129,6 +129,9 @@ class GenerationStream:
         self.admit_t: Optional[float] = None
         self.first_t: Optional[float] = None
         self.finish_t: Optional[float] = None
+        #: the decode lane the stream is pinned to for life, where the
+        #: model family keeps state per lane (``BlockPool.lane_state``)
+        self.lane: Optional[int] = None
         self._q: _queue.Queue = _queue.Queue()
 
     def cancel(self) -> None:
@@ -276,7 +279,16 @@ class ContinuousBatchingEngine:
 
     Parameters
     ----------
-    cfg, params: a ``models.transformer`` config + param pytree.
+    cfg, params: a model config + param pytree. The config's ``family``
+        (``models/family.py``) gives the prefill and paged-decode
+        builders and says what a decode lane holds beside its blocks:
+        ``models.transformer`` is the dense member, ``models.hybrid``
+        the one with state-space layers. A family with lane state is
+        served on the paged path only (``block_tokens > 0``), every
+        stream keeps its lane for life, and ``prefix_cache``,
+        ``speculate``, ``prefill_chunk``, ``kv_quant`` and ``mesh=`` are
+        refused at construction: each needs that state snapshotted,
+        rolled back, quantized or sharded, which nothing does yet.
     max_streams: batch slots (B). Static — sizes the cache and programs.
     max_seq: cache length S (defaults to ``cfg.max_seq``).
     steps_per_dispatch: decode steps fused into one device dispatch (K),
@@ -364,13 +376,26 @@ class ContinuousBatchingEngine:
         import jax
         import jax.numpy as jnp
 
-        from nnstreamer_tpu.models.transformer import (
-            build_chunk_decode,
-            build_decode_step,
-            build_prefill,
-            init_cache,
-        )
+        family = cfg.family
+        #: the family keeps state per decode lane: paged path only, a
+        #: stream is pinned to its lane, no option that copies that state
+        self._lane_state = family.lane_state(cfg) is not None
+        if self._lane_state:
+            from nnstreamer_tpu.serving import kvpool as _kvpool
 
+            refused = [name for name, on in (
+                ("prefix_cache", prefix_cache), ("speculate", speculate),
+                ("prefill_chunk", prefill_chunk), ("kv_quant", kv_quant),
+                ("mesh", mesh is not None)) if on]
+            if not (int(block_tokens or 0) > 0 and _kvpool.paged_enabled()):
+                refused.append("block_tokens=0 (the monolithic cache)")
+            if refused:
+                raise ValueError(
+                    f"serving: the {family.name} model family keeps "
+                    f"recurrent state per decode lane and is served on "
+                    f"the paged path alone; it does not yet support "
+                    f"{', '.join(refused)} (ROADMAP.md, \"what the system "
+                    f"cannot run yet\")")
         self.cfg = cfg
         self.params = params
         self.B = int(max_streams)
@@ -408,11 +433,21 @@ class ContinuousBatchingEngine:
             from nnstreamer_tpu.ops import flash_attention
 
             attention_fn = flash_attention  # causal=True is its default
-        self._decode = build_decode_step(cfg, self.S, kv_codec=kv_quant)
-        self._prefill_fn = build_prefill(cfg, self.S,
-                                         attention_fn=attention_fn,
-                                         kv_codec=kv_quant)
-        self._chunk_fn = build_chunk_decode(cfg, self.S, kv_codec=kv_quant)
+        self._prefill_fn = family.build_prefill(
+            cfg, self.S, attention_fn=attention_fn, kv_codec=kv_quant)
+        # the monolithic cache, chunked ingestion and speculation are the
+        # dense block's alone
+        self._decode = self._chunk_fn = None
+        if not self._lane_state:
+            from nnstreamer_tpu.models.transformer import (
+                build_chunk_decode,
+                build_decode_step,
+                init_cache,
+            )
+
+            self._decode = build_decode_step(cfg, self.S, kv_codec=kv_quant)
+            self._chunk_fn = build_chunk_decode(cfg, self.S,
+                                                kv_codec=kv_quant)
         #: in-progress chunked admission: (request, slot, cache1, k) with
         #: k = next chunk index; one at a time, advanced between dispatches
         self._partial = None
@@ -430,17 +465,18 @@ class ContinuousBatchingEngine:
                 raise ValueError(
                     f"serving: block_tokens ({self.block_tokens}) must "
                     f"divide max_seq ({self.S})")
-            from nnstreamer_tpu.models.transformer import (
-                build_paged_chunk,
-                build_paged_decode_step,
-            )
-
             #: block-table width: blocks per stream at full context
             self.MB = self.S // self.block_tokens
-            self._paged_decode = build_paged_decode_step(
+            self._paged_decode = family.build_paged_decode_step(
                 cfg, self.block_tokens, self.S, kv_codec=kv_quant)
-            self._paged_chunk_fn = build_paged_chunk(
-                cfg, self.block_tokens, self.S, kv_codec=kv_quant)
+            self._paged_chunk_fn = None
+            if not self._lane_state:
+                from nnstreamer_tpu.models.transformer import (
+                    build_paged_chunk,
+                )
+
+                self._paged_chunk_fn = build_paged_chunk(
+                    cfg, self.block_tokens, self.S, kv_codec=kv_quant)
             nb = int(kv_blocks) if kv_blocks else self.B * self.MB
             if mesh is not None and "dp" in mesh.axis_names:
                 # arena block axis shards over dp: pad so NTOT divides
@@ -536,7 +572,11 @@ class ContinuousBatchingEngine:
             # sums of submit -> admit and admit -> first token
             "admissions": 0, "admit_wait_us": 0, "first_token_us": 0,
             "stalls": 0,
+            # what the family's decode step counts of itself, summed over
+            # the steps of every dispatch
+            **{name: 0 for name in family.counters},
         }
+        self._counters = tuple(family.counters)
         #: the engine's own after-the-fact record of what its loop did:
         #: one span per closed phase, one async span per request. Used
         #: while no process-wide timeline is installed (``_ledger``).
@@ -574,7 +614,8 @@ class ContinuousBatchingEngine:
         if self.paged:
             self._pool = _kvpool.BlockPool(
                 cfg, self._num_blocks, self.block_tokens,
-                kv_codec=kv_quant, mesh=mesh, owner=self.obs_name)
+                kv_codec=kv_quant, mesh=mesh, owner=self.obs_name,
+                lanes=self.B)
             #: sid → per-stream decode state (stream, blocks, pos, last,
             #: key, budget, deadline_t, slot); engine thread only. Every
             #: ADMITTED stream lives here whether or not it currently
@@ -643,32 +684,43 @@ class ContinuousBatchingEngine:
         self._build_dispatch = build_dispatch
         if self.paged:
             paged_decode = self._paged_decode
+            counters = self._counters
 
             def build_paged_dispatch(K):
                 def dispatch(params, token, arena, bt, pos, keys):
                     """Paged twin of the mono dispatch: same K-step scan,
                     cache replaced by (arena, block tables). bt is LOOP-
                     INVARIANT across the K steps — the loop tops up every
-                    bound stream's blocks through pos+K-1 first."""
+                    bound stream's blocks through pos+K-1 first. A family
+                    that counts (``counters``) gets a seventh result, its
+                    counts summed over the K steps in one int32 vector."""
 
                     def body(carry, _):
-                        token, arena, pos, keys = carry
-                        logits, arena = paged_decode(params, token, arena,
-                                                     bt, pos)
+                        token, arena, pos, keys, counts = carry
+                        logits, arena, *counted = paged_decode(
+                            params, token, arena, bt, pos)
                         with jax.named_scope("sample"):
                             nxt, keys, lp = sample(logits, keys)
-                        return (nxt, arena, pos + 1, keys), (nxt, lp)
+                        counts = {name: counts[name] + counted[0][name]
+                                  for name in counts}
+                        return (nxt, arena, pos + 1, keys, counts), (nxt, lp)
 
-                    (token, arena, pos, keys), (toks, lps) = jax.lax.scan(
-                        body, (token, arena, pos, keys), None, length=K)
-                    return (jnp.transpose(toks), jnp.transpose(lps),
-                            arena, keys, token, pos)
+                    zeros = {name: jnp.int32(0) for name in counters}
+                    (token, arena, pos, keys, counts), (toks, lps) = \
+                        jax.lax.scan(body, (token, arena, pos, keys, zeros),
+                                     None, length=K)
+                    out = (jnp.transpose(toks), jnp.transpose(lps), arena,
+                           keys, token, pos)
+                    if counters:
+                        out += (jnp.stack([counts[n] for n in counters]),)
+                    return out
 
                 return jax.jit(dispatch, donate_argnums=(2,))
 
             self._build_dispatch = build_paged_dispatch
-            self._paged_chunk_jitted = jax.jit(self._paged_chunk_fn,
-                                               donate_argnums=(2,))
+            if self._paged_chunk_fn is not None:
+                self._paged_chunk_jitted = jax.jit(self._paged_chunk_fn,
+                                                   donate_argnums=(2,))
         self._set_dispatch(self.K)
         self._sample_first = jax.jit(sample)
 
@@ -686,7 +738,9 @@ class ContinuousBatchingEngine:
         # one jitted prefill; XLA caches one executable per bucket shape
         self._prefill_jitted = jax.jit(self._prefill_fn)
         # chunked-prefill program: ONE executable at shape [1, chunk]
-        self._chunk_jitted = jax.jit(self._chunk_fn, donate_argnums=(2,))
+        if self._chunk_fn is not None:
+            self._chunk_jitted = jax.jit(self._chunk_fn,
+                                         donate_argnums=(2,))
         self._jnp = jnp
         self._jax = jax
 
@@ -1810,6 +1864,12 @@ class ContinuousBatchingEngine:
         blocks = self._alloc_blocks(self._blocks_for(n))
         if blocks is None:
             return None
+        # a family with lane state: the stream's lane is claimed here, and
+        # the prefill's final state goes over that lane's slot whole
+        lane = self._pool.alloc_lane() if self._lane_state else None
+        if self._lane_state and lane is None:  # every lane taken: defer
+            self._pool.release(blocks)
+            return None
         try:
             bucket = self._bucket(n)
             padded = np.zeros((1, bucket), np.int32)
@@ -1817,11 +1877,14 @@ class ContinuousBatchingEngine:
             logits, cache1 = self._prefill_jitted(
                 self.params, jnp.asarray(padded),
                 lengths=jnp.asarray([n], jnp.int32))
-            self._pool.scatter_prefill(cache1, blocks[:(n + T - 1) // T])
+            self._pool.scatter_prefill(cache1, blocks[:(n + T - 1) // T],
+                                       lane=lane)
             self._prefix_store_paged(prompt, blocks, logits)
-            return self._activate_begin_paged(req, logits, blocks)
+            return self._activate_begin_paged(req, logits, blocks, lane)
         except Exception:
             self._pool.release(blocks)
+            if lane is not None:
+                self._pool.release_lane(lane)
             raise
 
     def _activate_paged_from_cache1(self, req: _PendingRequest, logits,
@@ -1850,12 +1913,14 @@ class ContinuousBatchingEngine:
         self._begin_admission(req)
         self._partial = (req, None, self._init_cache1(), 0, 0)
 
-    def _activate_begin_paged(self, req: _PendingRequest, logits, blocks):
+    def _activate_begin_paged(self, req: _PendingRequest, logits, blocks,
+                              lane: Optional[int] = None):
         """Paged twin of _activate_begin: sample the first token,
         create the stream's decode state. No lane is claimed (EDF
-        binds lanes per dispatch) — except in speculative mode, where
-        the slot-structured draft cache pins each stream to a lane for
-        life."""
+        binds lanes per dispatch) — except where a stream is pinned to
+        a lane for life: in speculative mode (the draft cache is
+        slot-structured) and for a family with lane state, whose
+        admission claimed ``lane`` before its prefill was scattered."""
         jnp = self._jnp
         stream = req.stream
         sid = stream.stream_id
@@ -1875,7 +1940,10 @@ class ContinuousBatchingEngine:
             "slot": None,
         }
         self._sstate[sid] = state
-        if self._spec is not None:
+        if lane is not None:
+            self._lane[lane] = sid
+            state["slot"] = stream.lane = lane
+        elif self._spec is not None:
             slot = self._lane.index(None)
             self._lane[slot] = sid
             state["slot"] = slot
@@ -1916,6 +1984,8 @@ class ContinuousBatchingEngine:
             self._lane[slot] = None
             self._bt[slot, :] = self._pool.SENTINEL
             state["slot"] = None
+            if self._lane_state:
+                self._pool.release_lane(slot)
         if state["blocks"]:
             self._pool.release(state["blocks"])
             state["blocks"] = []
@@ -2018,13 +2088,17 @@ class ContinuousBatchingEngine:
             pos[st["slot"]] = st["pos"]
             keys[st["slot"]] = st["key"]
         t0 = self._phase("select")
-        toks, lps, arena, keys_d, _last_d, _pos_d = self._dispatch(
-            self.params, jnp.asarray(last), self._pool.arena,
-            jnp.asarray(self._bt), jnp.asarray(pos), jnp.asarray(keys))
+        toks, lps, arena, keys_d, _last_d, _pos_d, *counted = \
+            self._dispatch(
+                self.params, jnp.asarray(last), self._pool.arena,
+                jnp.asarray(self._bt), jnp.asarray(pos), jnp.asarray(keys))
         self._pool.arena = arena
         toks = np.asarray(toks)
         lps = np.asarray(lps)
         keys_np = np.asarray(keys_d)
+        if counted:  # ready with the tokens: the same program made them
+            for name, n in zip(self._counters, np.asarray(counted[0])):
+                self.stats[name] += int(n)
         # from the call until tokens and keys are on the host: the one
         # phase in which the device works for decoding
         self.invoke_stats.record(self._phase("dispatch") - t0)
@@ -2075,9 +2149,9 @@ class ContinuousBatchingEngine:
                     progressed = True
             admitted = []
             while self._partial is None:
-                if self._spec is not None and \
+                if (self._spec is not None or self._lane_state) and \
                         len(self._sstate) >= self.B:
-                    break  # slot-structured draft cache caps streams
+                    break  # a stream pinned to a lane: B at most
                 if self._held is not None:
                     req, self._held = self._held, None
                 else:

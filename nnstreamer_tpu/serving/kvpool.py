@@ -25,6 +25,17 @@ O(1) autoregressive-caching form, PAPERS.md):
   free list when its last owner releases it. Allocation is
   all-or-nothing: a stream that cannot get every block it asked for
   gets none, so the engine's shed ladder sees a clean failure.
+- **Lane state** — a model family whose layers keep state other than
+  keys and values (``models/family.py`` ``lane_state``: the recurrent
+  state and convolution tail of a state-space layer) gets a second arena
+  beside the blocks, ``[layers, lanes, ...]`` per leaf: one slot per
+  decode lane, so a stream keeps its lane for life. ``self.arena`` is then
+  ``{"kv": blocks, "state": {leaf: array}}``, ONE pytree donated into the
+  same programs. A slot is claimed with ``alloc_lane``, overwritten whole
+  by the prefill's hand-over (``scatter_prefill(..., lane=)``: that is
+  the reset), updated in place by the decode program under the lane's
+  own index, and given back with ``release_lane``. An empty lane's slot is
+  masked inside the program: read as zeros, never written.
 - **Accounting** — the arena registers its bytes with the PR-12 HBM
   accountant under the ``kvcache`` category at construction, so cache
   pressure shows up in ``nns_mem_used_bytes{category="kvcache"}`` and
@@ -89,6 +100,20 @@ def _scatter_prefill_impl(arena, cache1, bids):
         return jax.tree.map(leaf, arena, cache1)
 
 
+def _scatter_with_state_impl(arena, cache1, bids, lane):
+    """The hand-over of a prefill whose family keeps lane state: keys and
+    values into blocks as above, and each state leaf ``[layers, 1, ...]``
+    over the whole of slot ``lane``."""
+    import jax
+
+    kv = _scatter_prefill_impl(arena["kv"], cache1["kv"], bids)
+    with jax.named_scope("nns.state_scatter"):
+        state = jax.tree.map(
+            lambda a, c: a.at[:, lane].set(c[:, 0].astype(a.dtype)),
+            arena["state"], cache1["state"])
+    return {"kv": kv, "state": state}
+
+
 def _copy_block_impl(arena, src, dst):
     """Copy one physical block across every layer/leaf — the COW fault
     path when a stream extends a shared prefix whose tail block is only
@@ -109,7 +134,7 @@ class BlockPool:
 
     def __init__(self, cfg, num_blocks: int, block_tokens: int,
                  kv_codec: Optional[str] = None, mesh=None,
-                 owner: str = "kvpool"):
+                 owner: str = "kvpool", lanes: int = 0):
         from nnstreamer_tpu.models.transformer import _kv_codec
 
         if num_blocks <= 0:
@@ -130,13 +155,24 @@ class BlockPool:
         self._lock = threading.Lock()
         self._free: List[int] = list(range(self.num_blocks))
         self._ref = np.zeros(self.num_blocks, np.int64)
+        #: what each lane holds beside its blocks (None: nothing), and
+        #: which lanes' slots are claimed
+        self._family = cfg.family
+        self._lane_state = self._family.lane_state(cfg)
+        self.lanes = int(lanes) if self._lane_state else 0
+        if self._lane_state and self.lanes <= 0:
+            raise ValueError("BlockPool: a model with lane state needs "
+                             "lanes > 0")
+        self._lane_live: set = set()
         self.arena = self._make_arena()
 
         import jax
-        leaves = jax.tree_util.tree_leaves(self.arena)
-        self.nbytes = int(sum(l.nbytes for l in leaves))
-        self._jit_scatter = jax.jit(_scatter_prefill_impl,
-                                    donate_argnums=(0,))
+        self.nbytes = _memory.pytree_nbytes(self.arena)
+        self.state_bytes = _memory.pytree_nbytes(self.arena["state"]) \
+            if self._lane_state else 0
+        self._jit_scatter = jax.jit(
+            _scatter_with_state_impl if self._lane_state
+            else _scatter_prefill_impl, donate_argnums=(0,))
         self._jit_copy = jax.jit(_copy_block_impl, donate_argnums=(0,))
 
         acct = _memory.ACTIVE
@@ -150,13 +186,20 @@ class BlockPool:
     # -- arena construction -------------------------------------------
 
     def _make_arena(self):
-        cfg = self.cfg
-        arena = self._codec.paged_init(cfg.n_layers, self.ntot,
-                                       self.block_tokens, cfg.n_heads,
-                                       cfg.head_dim)
+        import jax.numpy as jnp
+
+        layers, heads, head_dim = self._family.kv_layout(self.cfg)
+        arena = self._codec.paged_init(layers, self.ntot, self.block_tokens,
+                                       heads, head_dim)
         if self.mesh is not None:
             arena = self._place(arena)
-        return arena
+        if not self._lane_state:
+            return arena
+        spec = dict(self._lane_state)
+        n = spec.pop("layers")
+        return {"kv": arena, "state": {
+            name: jnp.zeros((n, self.lanes) + tuple(shape), dtype)
+            for name, (shape, dtype) in spec.items()}}
 
     def _place(self, arena):
         from jax.sharding import PartitionSpec as P
@@ -221,19 +264,46 @@ class BlockPool:
         with self._lock:
             return int(np.count_nonzero(self._ref))
 
+    def alloc_lane(self) -> Optional[int]:
+        """Claim the lowest free lane's state slot, or None when every
+        lane is taken. The slot still holds its last owner's state until
+        the new owner's prefill overwrites it."""
+        with self._lock:
+            free = set(range(self.lanes)) - self._lane_live
+            if not free:
+                return None
+            self._lane_live.add(min(free))
+            return min(free)
+
+    def release_lane(self, lane: int) -> None:
+        with self._lock:
+            self._lane_live.discard(lane)
+
+    def lane_state(self, lane: int) -> dict:
+        """Host copies of what slot ``lane`` holds, leaf -> ``[layers,
+        ...]``: for checks and tests. The caller sees to it that no
+        program holds the arena meanwhile (an idle engine dispatches
+        nothing)."""
+        return {name: np.asarray(a[:, lane])
+                for name, a in self.arena["state"].items()}
+
     # -- device-side helpers ------------------------------------------
 
-    def scatter_prefill(self, cache1, block_ids: Sequence[int]) -> None:
+    def scatter_prefill(self, cache1, block_ids: Sequence[int],
+                        lane: Optional[int] = None) -> None:
         """Move a batch-1 prefill cache into ``block_ids`` (padded with
-        the sentinel up to S/T). Mutates ``self.arena`` in place (the old
-        arena buffer is donated)."""
+        the sentinel up to S/T) and, for a family with lane state, the
+        prefill's final state over slot ``lane``. Mutates ``self.arena``
+        in place (the old arena buffer is donated)."""
         import jax.numpy as jnp
 
-        mb = _leaf_slots(cache1) // self.block_tokens
+        kv = cache1["kv"] if self._lane_state else cache1
+        mb = _leaf_slots(kv) // self.block_tokens
         bids = np.full(mb, self.SENTINEL, np.int32)
         bids[:len(block_ids)] = block_ids
+        extra = (jnp.asarray(lane, jnp.int32),) if self._lane_state else ()
         self.arena = self._jit_scatter(self.arena, cache1,
-                                       jnp.asarray(bids))
+                                       jnp.asarray(bids), *extra)
 
     def copy_block(self, src: int, dst: int) -> None:
         """COW fault: duplicate physical block ``src`` into ``dst``."""
@@ -250,6 +320,7 @@ class BlockPool:
         with self._lock:
             self._free = list(range(self.num_blocks))
             self._ref[:] = 0
+            self._lane_live.clear()
         self.arena = self._make_arena()
 
     def snapshot(self) -> dict:
@@ -260,6 +331,9 @@ class BlockPool:
                 "free_blocks": len(self._free),
                 "live_blocks": int(np.count_nonzero(self._ref)),
                 "nbytes": self.nbytes,
+                "state_slots": self.lanes,
+                "state_slots_live": len(self._lane_live),
+                "state_bytes": self.state_bytes,
             }
 
 

@@ -60,13 +60,17 @@ def test_rehearsal_runs_every_leg_on_the_cpu():
     result = json.loads(proc.stdout.strip().splitlines()[-1])
     assert "ok" not in result and "rehearsal" in result
     assert result["device"] == {"platform": "cpu", "kind": "cpu", "count": 8}
-    assert set(result["legs"]) == {"kernel", "stream", "server"}
+    assert set(result["legs"]) == {"kernel", "stream", "server", "hybrid"}
     assert result["legs"]["stream"]["staged_shards"] == [[0, 8]]
     assert result["mesh"]["spec"] == "dp4"
     assert result["mesh"]["staged_shards"] == [[i, 2] for i in range(4)]
     assert result["mesh"]["reshard_bytes"] == 0
     forward = result["legs"]["server"]["first_token_vs_forward"]
     assert forward["prompts"] == 3
+    hybrid = result["legs"]["hybrid"]
+    assert hybrid["tokens_vs_forward"]["prompts"] == 2
+    assert hybrid["tokens_vs_forward"]["tokens_each"] == 9
+    assert hybrid["moe_tokens_held"] > 0 and hybrid["state_bytes"] > 0
     assert result["compile_cache"]["dir"] is None and result["claim"] is None
 
 

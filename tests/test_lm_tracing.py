@@ -29,6 +29,16 @@ LEAF_SCOPES = ("qkv", "kv_write", "kv_gather", "attend", "ffn", "logits",
                "sample")
 
 
+@pytest.fixture(autouse=True)
+def _no_installed_timeline():
+    """These tests read the engine's own ledger, which gets the spans only
+    while no process-wide timeline is installed. One that an earlier file
+    of this worker left behind (a pipeline's flight recorder not yet
+    retired) would catch them instead: start from none."""
+    timeline.deactivate()
+    yield
+
+
 def _engine(**kw):
     kw.setdefault("max_streams", 2)
     kw.setdefault("steps_per_dispatch", 4)
