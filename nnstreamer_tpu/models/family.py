@@ -23,10 +23,12 @@ class ModelFamily:
     #: prefill(params, tokens[b, s], lengths[b]) -> (logits[b, vocab],
     #: cache)``; ``cache`` is what ``BlockPool.scatter_prefill`` takes
     build_prefill: Callable
-    #: ``build_paged_decode_step(cfg, block_tokens, max_seq, kv_codec=) ->
-    #: step(params, token[b], arena, bt[b, MB], pos[b]) -> (logits, arena)``
-    #: or ``(logits, arena, counts)`` with ``counts`` a dict of int32
-    #: scalars named as ``counters``
+    #: ``build_paged_decode_step(cfg, block_tokens, max_seq, kv_codec=,
+    #: paged_attention_fn=) -> step(params, token[b], arena, bt[b, MB],
+    #: pos[b]) -> (logits, arena)`` or ``(logits, arena, counts)`` with
+    #: ``counts`` a dict of int32 scalars named as ``counters``;
+    #: ``paged_attention_fn`` is ``ops.paged_attention`` or None (the
+    #: gather form)
     build_paged_decode_step: Callable
     #: ``kv_layout(cfg) -> (layers, heads, head_dim)`` of the block arena
     kv_layout: Callable

@@ -517,7 +517,9 @@ def _prompt_layers(params, tokens, lengths, cfg: HybridConfig,
 
 def build_paged_decode_step(cfg: HybridConfig, block_tokens: int,
                             max_seq: Optional[int] = None,
-                            kv_codec: Optional[str] = None) -> Callable:
+                            kv_codec: Optional[str] = None,
+                            paged_attention_fn: Optional[Callable] = None
+                            ) -> Callable:
     """One token for every decode lane against the pool's two arenas:
     ``step(params, token[int32 b], arenas, bt[int32 b, MB], pos[int32 b])
     -> (logits[b, vocab], arenas, counts)``.
@@ -529,7 +531,8 @@ def build_paged_decode_step(cfg: HybridConfig, block_tokens: int,
     layer: lane ``i`` reads and writes slot ``i``, in place — the layer is
     a static index, the arena a carry of the K-step scan, never a scan's
     ``xs``/``ys``. A lane whose table is all sentinel is empty: it reads
-    zeros, writes nowhere and is left out of the routing."""
+    zeros, writes nowhere and is left out of the routing.
+    ``paged_attention_fn`` as in the dense block's builder."""
     dtype, r = cfg.dtype, cfg.residual_multiplier
     s_max = max_seq or cfg.max_seq
     T = int(block_tokens)
@@ -568,13 +571,18 @@ def build_paged_decode_step(cfg: HybridConfig, block_tokens: int,
                 with jax.named_scope("kv_write"):
                     pages = codec.paged_write(pages, i_attn,
                                               jnp.stack([k, v]), blk, off)
-                with jax.named_scope("kv_gather"):
-                    mask = jnp.arange(s_max)[None, None, None, :] \
-                        <= pos_c[:, None, None, None]
-                    ck, cv = codec.paged_read(pages, i_attn, bt)
+                if paged_attention_fn is not None:
+                    a = paged_attention_fn(q, pages, i_attn, bt, pos_c,
+                                           scale=cfg.attention_scale)
+                else:
+                    with jax.named_scope("kv_gather"):
+                        mask = jnp.arange(s_max)[None, None, None, :] \
+                            <= pos_c[:, None, None, None]
+                        ck, cv = codec.paged_read(pages, i_attn, bt)
+                    with jax.named_scope("attend"):
+                        a = _attend_cache(q, ck, cv, mask, cfg.head_dim,
+                                          dtype, scale=cfg.attention_scale)
                 with jax.named_scope("attend"):
-                    a = _attend_cache(q, ck, cv, mask, cfg.head_dim, dtype,
-                                      scale=cfg.attention_scale)
                     out = _attn_out(a, lp, dtype)
                 i_attn += 1
             x = x + r * out

@@ -539,7 +539,9 @@ def build_chunk_decode(cfg: TransformerConfig,
 def build_paged_decode_step(cfg: TransformerConfig,
                             block_tokens: int,
                             max_seq: Optional[int] = None,
-                            kv_codec: Optional[str] = None) -> Callable:
+                            kv_codec: Optional[str] = None,
+                            paged_attention_fn: Optional[Callable] = None
+                            ) -> Callable:
     """Single-token decode against a PAGED KV cache (serving/kvpool.py):
     ``step(params, token[int32 b], arena, bt[int32 b,MB], pos[int32 b]) ->
     (logits[b, vocab], new_arena)``.
@@ -562,6 +564,12 @@ def build_paged_decode_step(cfg: TransformerConfig,
     argument is scattered into in place; as the scan's ``xs``/``ys`` each
     layer's 1/L of the pool is sliced out and written back every step
     and the compiler plans the pool twice (PERF.md §6, PR 26).
+
+    ``paged_attention_fn(q, pages, layer, bt, pos_c)`` (``ops/
+    paged_attention.py``; for a raw arena, which is one leaf) takes the
+    place of gather + mask + ``_attend_cache``: it reads each lane's
+    live blocks where they lie, or builds the same gather form itself
+    where its kernel does not run. None keeps the gather form.
     """
     dtype = cfg.dtype
     s_max = max_seq or cfg.max_seq
@@ -590,13 +598,16 @@ def build_paged_decode_step(cfg: TransformerConfig,
             with jax.named_scope("kv_write"):
                 pages = codec.paged_write(pages, li, jnp.stack([k, v]),
                                           blk, off)
-            with jax.named_scope("kv_gather"):
-                slots = jnp.arange(s_max)
-                mask = slots[None, None, None, :] <= pos_c[:, None, None,
-                                                           None]
-                ck, cv = codec.paged_read(pages, li, bt)
-            with jax.named_scope("attend"):
-                a = _attend_cache(q, ck, cv, mask, cfg.head_dim, dtype)
+            if paged_attention_fn is not None:
+                a = paged_attention_fn(q, pages, li, bt, pos_c)
+            else:
+                with jax.named_scope("kv_gather"):
+                    slots = jnp.arange(s_max)
+                    mask = slots[None, None, None, :] <= pos_c[
+                        :, None, None, None]
+                    ck, cv = codec.paged_read(pages, li, bt)
+                with jax.named_scope("attend"):
+                    a = _attend_cache(q, ck, cv, mask, cfg.head_dim, dtype)
             with jax.named_scope("ffn"):
                 x = _block_tail(x, a, lp, cfg)
             return (x, li + 1, pages), None

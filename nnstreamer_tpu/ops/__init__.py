@@ -10,11 +10,13 @@ kernel level.
 """
 
 from nnstreamer_tpu.ops.flash_attention import flash_attention
+from nnstreamer_tpu.ops.paged_attention import paged_attention
 from nnstreamer_tpu.ops.preprocess import normalize_u8
 from nnstreamer_tpu.ops.quantize import dequantize_int8, quantize_int8
 
 __all__ = [
     "flash_attention",
+    "paged_attention",
     "normalize_u8",
     "quantize_int8",
     "dequantize_int8",

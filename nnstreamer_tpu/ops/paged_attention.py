@@ -1,0 +1,282 @@
+"""Paged decode attention — one query token a lane against the blocks its
+table names, read where they lie in the pool's arena.
+
+The gather form (``models/transformer.py`` ``_paged_gather`` +
+``_attend_cache``) copies every lane's WHOLE block table out of the arena,
+relays it out and attends over all ``MB * T`` slots under a mask, whatever
+the lanes hold. The kernel here leaves the arena in HBM and walks each
+lane's LIVE blocks only (``pos // T + 1`` of them): the grid is the lanes,
+and inside a lane a loop whose trip count is the lane's own fetches a run
+of ``chunk`` blocks at a time by async DMA, double-buffered, and folds it
+into an online softmax. The block table, the layer and the positions ride
+in by scalar prefetch.
+
+A block half is ``[T, hk, dh]``: the head axis sits INSIDE the token axis,
+so a per-head matmul would need a transpose of every block. Instead a
+block's ``T * hk`` rows go to the MXU as they lie: ``q [hq, dh]`` against
+all of them gives ``[hq, T * hk]`` scores of which a query head keeps the
+columns of its own key-value head (``col % hk == head // group``; the rest
+are masked like the slots past ``pos``). The MXU does ``hk`` times the
+arithmetic a transpose would save and has the room: decode attention is
+bound by the bytes.
+
+The numeric contract is ``_attend_cache``'s: keys and values as stored,
+float32 scores, float32 softmax, float32 probabilities x values, one
+rounding at the end; only the order of the sums differs (blockwise).
+bfloat16 x bfloat16 products are exact in float32, so the scores take one
+MXU pass; the float32 probabilities go in as three bfloat16 parts whose
+sum is the float32 value (8 + 8 + 8 mantissa bits), against values that
+ARE bfloat16: no operand is narrowed.
+
+``paged_attention`` auto-selects like ``flash_attention``: the kernel on a
+TPU for shapes it takes, the gather form elsewhere.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from nnstreamer_tpu.log import get_logger
+
+log = get_logger("paged-attention")
+
+_NEG_BIG = -1e30
+
+#: blocks fetched and attended over at a time (one DMA a block, all in
+#: flight together); what is past a lane's live blocks is neither fetched
+#: nor waited for
+CHUNK_BLOCKS = 8
+
+
+def _split3(p):
+    """float32 ``p`` as three bfloat16 parts that sum to it."""
+    hi = p.astype(jnp.bfloat16)
+    r = p - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _kernel(layer_ref, bt_ref, pos_ref, q_ref, pages_ref, o_ref,
+            buf, sem, m_scr, l_scr, acc_scr, *, scale: float,
+            block_tokens: int, kv_heads: int, chunk: int):
+    lane = pl.program_id(0)
+    layer = layer_ref[0]
+    pos = pos_ref[lane]
+    zero_block = pages_ref.shape[1] - 1
+    hq, dh = q_ref.shape[1:]
+    group = hq // kv_heads
+    rows = chunk * block_tokens * kv_heads       # key rows of a chunk
+    n_blocks = pos // block_tokens + 1           # the lane's live blocks
+    n_chunks = (n_blocks + chunk - 1) // chunk
+    exact = lax.Precision.HIGHEST if q_ref.dtype == jnp.float32 else None
+
+    @pl.when(lane == 0)
+    def _clear():
+        # rows no DMA of this call has filled are masked, and 0 x what
+        # they hold must be 0: nothing a fresh buffer may hold is allowed
+        buf[...] = jnp.zeros_like(buf)
+
+    def copies(c, slot):
+        """(live?, copy) of each block of chunk ``c`` into ``buf[slot]``."""
+        for i in range(chunk):
+            j = c * chunk + i
+            blk = jnp.minimum(bt_ref[lane, jnp.minimum(j, bt_ref.shape[1] - 1)],
+                              zero_block)
+            yield j < n_blocks, pltpu.make_async_copy(
+                pages_ref.at[layer, blk], buf.at[slot, i], sem.at[slot])
+
+    def start(c, slot):
+        for live, copy in copies(c, slot):
+            pl.when(live)(copy.start)
+
+    def wait(c, slot):
+        for live, copy in copies(c, slot):
+            pl.when(live)(copy.wait)
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    start(0, 0)
+
+    col = lax.broadcasted_iota(jnp.int32, (hq, rows), 1)
+    head = lax.broadcasted_iota(jnp.int32, (hq, rows), 0)
+    own_head = lax.rem(col, kv_heads) == lax.div(head, group)
+    slot_of = lax.div(col, kv_heads)             # slot within the chunk
+
+    def body(c, carry):
+        slot = lax.rem(c, 2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _prefetch():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        k = buf[slot, :, 0].reshape(rows, dh)
+        v = buf[slot, :, 1].reshape(rows, dh)
+        s = lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())),
+                            precision=exact,
+                            preferred_element_type=jnp.float32) * scale
+        seen = slot_of <= pos - c * (chunk * block_tokens)
+        s = jnp.where(own_head & seen, s, _NEG_BIG)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if v.dtype == jnp.bfloat16:
+            pv = sum(jnp.dot(part, v, preferred_element_type=jnp.float32)
+                     for part in _split3(p))
+        else:
+            pv = jnp.dot(p, v.astype(jnp.float32), precision=exact,
+                         preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * corr + pv
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
+
+    lax.fori_loop(0, n_chunks, body, 0)
+    o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "chunk", "interpret"))
+def _paged_decode(q, pages, layer, bt, pos_c, scale: float, chunk: int,
+                  interpret: bool):
+    """Kernel entry: ``q [b, hq, dh]``, the arena leaf whole."""
+    b, hq, dh = q.shape
+    L, ntot, two, T, hk, _ = pages.shape
+    # a block half's [T, hk] rows as one axis: the same bytes in the same
+    # order, so the blocks go to the MXU as the DMA lands them
+    flat = pages.reshape(L, ntot, two, T * hk, dh)
+    kern = functools.partial(_kernel, scale=scale, block_tokens=T,
+                             kv_heads=hk, chunk=chunk)
+    lane_block = pl.BlockSpec((1, hq, dh), lambda i, *_: (i, 0, 0))
+    return pl.pallas_call(
+        kern,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,      # layer, block table, positions
+            grid=(b,),
+            in_specs=[lane_block, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=lane_block,
+            scratch_shapes=[
+                pltpu.VMEM((2, chunk, two, T * hk, dh), pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((hq, 128), jnp.float32),   # running max
+                pltpu.VMEM((hq, 128), jnp.float32),   # running sum
+                pltpu.VMEM((hq, dh), jnp.float32),    # output accumulator
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="nns_paged_decode",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), bt.astype(jnp.int32),
+      pos_c.astype(jnp.int32), q, flat)
+
+
+def _pallas_reject(q, pages, bt) -> str | None:
+    """Why these shapes cannot go to the kernel, or None when they can.
+
+    The bounds are what Mosaic (libtpu 0.0.34, for a TPU v5e) was seen to
+    compile: bfloat16 and float32 arenas with a head dim of 128, 16 query
+    over 16 key-value heads and 32 over 8, 16 tokens a block."""
+    if not hasattr(pages, "shape") or len(pages.shape) != 6:
+        return "the arena is not one [L, NTOT, 2, T, h, dh] leaf"
+    b, one, hq, dh = q.shape
+    T, hk = pages.shape[3:5]
+    sublanes = 8 * 4 // jnp.dtype(pages.dtype).itemsize
+    if one != 1:
+        return f"{one} query tokens a lane, not 1"
+    if q.dtype != pages.dtype:
+        return f"query {q.dtype} against {pages.dtype} keys and values"
+    if jnp.dtype(q.dtype) not in (jnp.dtype(jnp.bfloat16),
+                                  jnp.dtype(jnp.float32)):
+        return f"dtype {q.dtype} is neither bfloat16 nor float32"
+    if dh % 128:
+        return f"head dim {dh} is not a multiple of 128 lanes"
+    if hq % hk:
+        return f"{hq} query heads are no multiple of {hk} key-value heads"
+    if hq % sublanes or (T * hk) % sublanes:
+        return (f"{hq} query heads or {T} x {hk} rows a block are no "
+                f"multiple of {sublanes} sublanes")
+    if bt.shape[0] != b:
+        return f"{bt.shape[0]} block tables for {b} lanes"
+    return None
+
+
+def paged_attention_form(q, pages, bt) -> str:
+    """Which form :func:`paged_attention` builds in auto mode for these
+    arguments (arrays or shapes): ``"paged_kernel"`` or ``"gather"``."""
+    if jax.default_backend() != "tpu" or _pallas_reject(q, pages, bt):
+        return "gather"
+    return "paged_kernel"
+
+
+@functools.lru_cache(maxsize=256)
+def _log_reference_choice(q_shape, pages_shape, dtype, why: str) -> None:
+    log.warning("paged_attention%s/%s %s runs the XLA gather form, not the "
+                "Pallas kernel: %s", q_shape, pages_shape, dtype, why)
+
+
+def paged_attention_reference(q, pages, layer, bt, pos_c, scale=None):
+    """The gather form: every lane's whole table copied out of the arena,
+    masked to ``slot <= pos_c`` and attended over by ``_attend_cache``."""
+    from nnstreamer_tpu.models.transformer import (
+        _attend_cache,
+        _paged_gather,
+    )
+
+    with jax.named_scope("kv_gather"):
+        slots = jnp.arange(bt.shape[1] * pages.shape[3])
+        mask = slots[None, None, None, :] <= pos_c[:, None, None, None]
+        g = _paged_gather(pages, layer, bt)
+    with jax.named_scope("attend"):
+        return _attend_cache(q, g[:, 0], g[:, 1], mask, q.shape[-1],
+                             q.dtype, scale=scale)
+
+
+def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
+                    force: str | None = None,
+                    chunk_blocks: int = CHUNK_BLOCKS):
+    """Decode attention of ``q [b, 1, hq, dh]`` over a paged cache.
+
+    ``pages`` is the arena's value leaf WHOLE, ``[L, NTOT, 2, T, hk, dh]``
+    (``serving/kvpool.py``), ``layer`` the layer to read (a Python int or
+    a traced scalar), ``bt [b, MB]`` the block tables (entries ≥ NTOT-1
+    read the zero block at NTOT-1) and ``pos_c [b]`` each lane's last
+    written slot: lane ``i`` attends over slots ``0..pos_c[i]`` of the
+    blocks ``bt[i]`` names. ``scale`` defaults to ``dh ** -0.5``; ``hk``
+    fewer than ``hq`` is grouped-query attention (query head ``i`` reads
+    key-value head ``i // (hq / hk)``). A lane whose table is all
+    sentinel reads one zero block and comes out zero.
+
+    ``force``: None (auto: the kernel on a TPU for shapes it takes, else
+    the gather form), "pallas" (always the kernel: Mosaic on a TPU, the
+    Pallas interpreter elsewhere, which is how the CPU tests run it) or
+    "reference". The kernel's instructions lie under the scope
+    ``attend``; the gather form keeps ``kv_gather`` and ``attend``.
+    """
+    on_tpu = jax.default_backend() == "tpu"
+    why_not = _pallas_reject(q, pages, bt)
+    if force == "pallas":
+        if why_not:
+            raise ValueError(
+                f"paged_attention: {q.shape} over {pages.shape}: {why_not}")
+    elif force == "reference" or not on_tpu or why_not:
+        if force is None and on_tpu:
+            _log_reference_choice(tuple(q.shape), tuple(pages.shape),
+                                  str(q.dtype), why_not)
+        return paged_attention_reference(q, pages, layer, bt, pos_c, scale)
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    with jax.named_scope("attend"):
+        out = _paged_decode(
+            q[:, 0], pages, layer, bt, pos_c, scale=float(scale),
+            chunk=min(int(chunk_blocks), bt.shape[1]),
+            interpret=not on_tpu)
+    return out[:, None]

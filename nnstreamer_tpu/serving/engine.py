@@ -327,15 +327,23 @@ class ContinuousBatchingEngine:
         tokens, so reused entries are the same arrays a cold prefill
         would produce. HBM cost ≈ N × prompt_len × per-token kv bytes
         (LRU-evicted). 0 (default) disables.
-    attention: prefill attention backend. "auto" (default) runs the
-        Pallas flash kernel (ops/flash_attention.py) for the O(s²)
-        prompt pass on TPU when the shapes tile (seq divisible by the
-        block, head_dim ≤ 256), falling back to XLA attention
-        elsewhere — long prompts stop materializing [s,s] score tiles
-        in HBM. "reference" forces XLA attention everywhere. Decode and
-        chunked ingestion keep the masked cache form (`_attend_cache`):
-        their attention is over dynamically-positioned cache slots,
-        which the causal-only kernel does not express.
+    attention: "auto" (default) or "reference". "auto" runs the Pallas
+        flash kernel (ops/flash_attention.py) for the O(s²) prompt pass
+        on a TPU when the shapes tile (seq divisible by the block,
+        head_dim ≤ 256), so long prompts stop materializing [s,s] score
+        tiles in HBM, and the paged decode step through
+        ``ops.paged_attention``: on a TPU, for a raw (not int8) arena
+        and shapes its kernel takes, each lane's LIVE blocks are read
+        where they lie in the arena (``nns_paged_decode`` in a trace),
+        work that follows the tokens held; elsewhere the XLA form that
+        gathers every lane's whole block table and attends under a mask
+        (`_attend_cache`). ``decode_attention`` says which was built:
+        "paged_kernel" or "gather". "reference" forces XLA attention
+        everywhere. A ``mesh=`` engine keeps XLA attention (a
+        ``pallas_call`` carries no partitioning rule), as do the
+        monolithic cache, chunked ingestion, prefix extension and
+        speculative verification (`build_paged_chunk`), whose attention
+        is over several query positions a lane.
     block_tokens: > 0 enables the PAGED KV cache (serving/kvpool.py):
         the cache becomes fixed-size blocks over one preallocated
         arena, per-stream block tables, admission bounded by FREE
@@ -460,6 +468,9 @@ class ContinuousBatchingEngine:
         #: the unchanged monolithic engine.
         self.paged = self.block_tokens > 0 and _kvpool.paged_enabled()
         self._pool = None
+        #: the form the paged decode program attends in: "paged_kernel"
+        #: (ops/paged_attention.py: live blocks read in place) or "gather"
+        self.decode_attention = "gather"
         if self.paged:
             if self.S % self.block_tokens:
                 raise ValueError(
@@ -467,8 +478,16 @@ class ContinuousBatchingEngine:
                     f"divide max_seq ({self.S})")
             #: block-table width: blocks per stream at full context
             self.MB = self.S // self.block_tokens
+            paged_attention_fn = None
+            if attention == "auto" and mesh is None and kv_quant is None:
+                # one chip, one raw arena leaf: as for prefill, a
+                # pallas_call carries no partitioning rule
+                from nnstreamer_tpu.ops import paged_attention
+
+                paged_attention_fn = paged_attention
             self._paged_decode = family.build_paged_decode_step(
-                cfg, self.block_tokens, self.S, kv_codec=kv_quant)
+                cfg, self.block_tokens, self.S, kv_codec=kv_quant,
+                paged_attention_fn=paged_attention_fn)
             self._paged_chunk_fn = None
             if not self._lane_state:
                 from nnstreamer_tpu.models.transformer import (
@@ -572,6 +591,9 @@ class ContinuousBatchingEngine:
             # sums of submit -> admit and admit -> first token
             "admissions": 0, "admit_wait_us": 0, "first_token_us": 0,
             "stalls": 0,
+            # blocks the paged decode steps had to read (every lane's
+            # live blocks, step by step) and blocks their tables name
+            "kv_blocks_live": 0, "kv_blocks_table": 0,
             # what the family's decode step counts of itself, summed over
             # the steps of every dispatch
             **{name: 0 for name in family.counters},
@@ -629,6 +651,17 @@ class ContinuousBatchingEngine:
             #: host mirror of the device block tables, one row per lane
             self._bt = np.full((self.B, self.MB), self._pool.SENTINEL,
                                np.int32)
+            if paged_attention_fn is not None:
+                from nnstreamer_tpu.ops.paged_attention import (
+                    paged_attention_form,
+                )
+
+                kv = self._pool.arena
+                kv = kv["kv"] if self._lane_state else kv
+                self.decode_attention = paged_attention_form(
+                    jax.ShapeDtypeStruct(
+                        (self.B, 1, cfg.n_heads, kv.shape[-1]), kv.dtype),
+                    kv, self._bt)
         self.prefix_cache = int(prefix_cache)
         if self.prefix_cache < 0:
             raise ValueError(
@@ -2087,6 +2120,10 @@ class ContinuousBatchingEngine:
             last[st["slot"]] = st["last"]
             pos[st["slot"]] = st["pos"]
             keys[st["slot"]] = st["key"]
+        steps = np.minimum(pos[:, None] + np.arange(self.K), self.S - 1)
+        self.stats["kv_blocks_live"] += int(
+            (steps // self.block_tokens + 1).sum())
+        self.stats["kv_blocks_table"] += self.B * self.MB * self.K
         t0 = self._phase("select")
         toks, lps, arena, keys_d, _last_d, _pos_d, *counted = \
             self._dispatch(

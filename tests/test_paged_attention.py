@@ -1,0 +1,271 @@
+"""Paged decode attention (ops/paged_attention.py).
+
+The kernel reads each lane's live blocks where they lie in the arena; the
+gather form copies every lane's whole table out and attends under a mask.
+Off a TPU the kernel runs through the Pallas interpreter when forced, which
+is how these tests hold it to the gather form: the same numbers
+(``_attend_cache``'s contract: only the order of the sums differs), in a
+call of its own and inside the engine's K-step decode dispatch. What
+``auto`` builds is the gather form wherever the kernel does not run: off a
+TPU, under a mesh, for an int8 arena and for the chunk builder. One test
+compiles the kernel at the two cells' widths for a described TPU v5e.
+"""
+
+import functools
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+import nnstreamer_tpu.ops as ops_pkg  # noqa: E402
+from nnstreamer_tpu.models.transformer import (  # noqa: E402
+    TransformerConfig,
+    _attend_cache,
+    _paged_gather,
+    build_paged_chunk,
+    init_params,
+)
+from nnstreamer_tpu.ops.paged_attention import (  # noqa: E402
+    _paged_decode,
+    paged_attention,
+    paged_attention_form,
+)
+from nnstreamer_tpu.serving import ContinuousBatchingEngine  # noqa: E402
+from nnstreamer_tpu.serving import engine as engine_mod  # noqa: E402
+
+pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
+
+T, MB, DH, LAYERS = 8, 12, 128, 2
+#: tokens held by each lane: the edges of a block, mid-table, one short of
+#: the table, and an EMPTY lane (its table all sentinel)
+HELD = (1, T - 1, T, T + 1, (MB * T) // 2 + 3, MB * T - 1, 0)
+
+
+def _pool(hk, dtype, seed):
+    """A scrambled pool: every lane's blocks anywhere in the arena, the
+    zero block last, unallocated table entries at the sentinel."""
+    rng = np.random.default_rng(seed)
+    lanes = len(HELD)
+    nb = lanes * MB
+    pages = rng.standard_normal((LAYERS, nb + 1, 2, T, hk, DH))
+    pages[:, nb] = 0.0
+    order = rng.permutation(nb).reshape(lanes, MB)
+    bt = np.full((lanes, MB), nb + 1, np.int32)
+    for lane, held in enumerate(HELD):
+        n = -(-held // T)
+        bt[lane, :n] = order[lane, :n]
+    pos = np.maximum(np.asarray(HELD) - 1, 0).astype(np.int32)
+    return (jnp.asarray(pages, dtype), jnp.asarray(bt), jnp.asarray(pos),
+            rng)
+
+
+def _gather_form(q, pages, layer, bt, pos, scale):
+    g = _paged_gather(pages, layer, bt)
+    mask = jnp.arange(MB * T)[None, None, None, :] <= pos[:, None, None,
+                                                          None]
+    return _attend_cache(q, g[:, 0], g[:, 1], mask, DH, q.dtype,
+                         scale=scale)
+
+
+@pytest.mark.parametrize("chunk", [2, 8], ids=["chunk2", "chunk8"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("hq,hk,scale", [(16, 16, None), (32, 8, 0.3)],
+                         ids=["16x16", "32over8"])
+def test_kernel_equals_attend_cache_over_the_gathered_table(
+        hq, hk, scale, dtype, tol, chunk):
+    pages, bt, pos, rng = _pool(hk, dtype, seed=hq + chunk)
+    q = jnp.asarray(rng.standard_normal((len(HELD), 1, hq, DH)), dtype)
+    before = np.asarray(pages, np.float32)
+    for layer in range(LAYERS):
+        want = _gather_form(q, pages, layer, bt, pos, scale)
+        got = paged_attention(q, pages, layer, bt, pos, scale=scale,
+                              force="pallas", chunk_blocks=chunk)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
+        # the empty lane read the zero block: finite, and exactly zero
+        assert not np.asarray(got, np.float32)[-1].any()
+    after = np.asarray(pages, np.float32)
+    np.testing.assert_array_equal(after, before)     # the arena untouched
+    assert not after[:, -1].any()                    # the zero block zero
+
+
+def test_layer_may_be_traced_and_reference_is_the_gather_form():
+    pages, bt, pos, rng = _pool(16, jnp.float32, seed=3)
+    q = jnp.asarray(rng.standard_normal((len(HELD), 1, 16, DH)),
+                    jnp.float32)
+
+    @jax.jit
+    def both(layer):
+        return (paged_attention(q, pages, layer, bt, pos, force="pallas"),
+                paged_attention(q, pages, layer, bt, pos,
+                                force="reference"))
+
+    for layer in range(LAYERS):
+        got, ref = both(jnp.int32(layer))
+        np.testing.assert_array_equal(
+            np.asarray(ref),
+            np.asarray(_gather_form(q, pages, layer, bt, pos, None)))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("why,kw", [
+    ("head dim", dict(dh=64)),
+    ("query tokens", dict(queries=2)),
+    ("sublanes", dict(hq=4, hk=4)),
+    ("key-value heads", dict(hq=24, hk=16)),
+    ("keys and values", dict(q_dtype=jnp.float32)),
+])
+def test_forced_kernel_refuses_shapes_it_does_not_take(why, kw):
+    hq, hk, dh = kw.get("hq", 16), kw.get("hk", 16), kw.get("dh", 128)
+    q = jnp.zeros((2, kw.get("queries", 1), hq, dh),
+                  kw.get("q_dtype", jnp.bfloat16))
+    pages = jnp.zeros((1, 5, 2, T, hk, dh), jnp.bfloat16)
+    bt = jnp.zeros((2, 2), jnp.int32)
+    pos = jnp.zeros((2,), jnp.int32)
+    with pytest.raises(ValueError, match=why):
+        paged_attention(q, pages, 0, bt, pos, force="pallas")
+    # auto gives way to the gather form instead (off a TPU it always does)
+    assert paged_attention_form(q, pages, bt) == "gather"
+
+
+# -- inside the engine's K-step dispatch --------------------------------------
+
+CFG = TransformerConfig(vocab=256, d_model=1024, n_heads=8, n_layers=2,
+                        d_ff=128, max_seq=64, dtype=jnp.float32)
+PARAMS = init_params(CFG, seed=2)
+PROMPTS = [[5, 11, 23, 42, 7, 9, 9, 1, 30], [4, 8, 15], [16] * 17]
+
+
+def _serve(**kw):
+    eng = ContinuousBatchingEngine(
+        CFG, PARAMS, max_streams=3, steps_per_dispatch=4, temperature=0.0,
+        block_tokens=T, **kw).start()
+    try:
+        streams = [eng.submit(p, max_new_tokens=11) for p in PROMPTS]
+        return eng, [(s.result(timeout=300), list(s.logprobs))
+                     for s in streams]
+    finally:
+        eng.stop()
+
+
+def test_k_step_dispatch_with_the_kernel_serves_the_gather_forms_tokens(
+        monkeypatch):
+    _, want = _serve(attention="reference")
+    # off a TPU auto builds the gather form, so hand the engine the kernel
+    # forced (the interpreter runs it inside the real K-step program)
+    monkeypatch.setattr(ops_pkg, "paged_attention", functools.partial(
+        paged_attention, force="pallas"))
+    eng, got = _serve()
+    text = engine_mod.decode_program_text(eng.obs_name)
+    assert "kv_gather/gather" not in text and "/attend/" in text
+    for (toks, lps), (ref_toks, ref_lps) in zip(got, want):
+        assert toks == ref_toks
+        np.testing.assert_allclose(lps, ref_lps, atol=1e-4)
+
+
+# -- what auto builds where the kernel does not run ---------------------------
+
+def _engine(**kw):
+    kw.setdefault("block_tokens", T)
+    return ContinuousBatchingEngine(
+        CFG, PARAMS, max_streams=2, steps_per_dispatch=2, temperature=0.0,
+        **kw)
+
+
+@pytest.mark.parametrize("case", ["cpu", "mesh", "int8", "reference"])
+def test_auto_builds_the_gather_form_where_the_kernel_does_not_run(case):
+    kw = {}
+    if case == "mesh":
+        from nnstreamer_tpu.parallel.mesh import make_mesh
+
+        kw["mesh"] = make_mesh([("dp", 2)])
+    elif case == "int8":
+        kw["kv_quant"] = "int8"
+    elif case == "reference":
+        kw["attention"] = "reference"
+    eng = _engine(**kw)
+    assert eng.decode_attention == "gather"
+    text = engine_mod.decode_program_text(eng.obs_name)
+    assert "kv_gather/gather" in text
+    assert "nns_paged_decode" not in text
+
+
+def test_chunk_builder_keeps_the_gather_form():
+    arena = jnp.zeros((CFG.n_layers, 9, 2, T, CFG.n_heads, CFG.head_dim),
+                      CFG.dtype)
+    text = jax.jit(build_paged_chunk(CFG, T)).lower(
+        PARAMS, jnp.zeros((2, 4), jnp.int32), arena,
+        jnp.zeros((2, CFG.max_seq // T), jnp.int32),
+        jnp.zeros((2,), jnp.int32), jnp.full((2,), 4, jnp.int32)
+    ).as_text(debug_info=True)
+    assert "gather" in text and "nns_paged_decode" not in text
+
+
+def test_block_counters_count_what_the_positions_say():
+    eng = _engine().start()
+    try:
+        assert eng.stats["kv_blocks_live"] == 0 == \
+            eng.stats["kv_blocks_table"]
+        eng.generate([3] * 13, max_new_tokens=5, timeout=120)
+    finally:
+        eng.stop()
+    # the first token comes from the prefill; two dispatches of K = 2 make
+    # the other four at positions 13..16 while the second lane stays empty
+    # (its positions 0, 1 are one block each)
+    assert eng.stats["dispatches"] == 2
+    live = sum(p // T + 1 for p in (13, 14, 15, 16)) + 4 * 1
+    assert eng.stats["kv_blocks_live"] == live
+    assert eng.stats["kv_blocks_table"] == 2 * 2 * eng.B * eng.MB
+    assert isinstance(eng.stats["kv_blocks_live"], int)
+
+
+# -- the kernel at the cells' widths, compiled for the chip -------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("lanes,hq,hk,mb,layers,ntot", [
+    (8, 16, 16, 128, 24, 1025),        # pythia_chat_closed
+    (64, 32, 8, 64, 1, 4097),          # granite_h_chat_closed
+], ids=["pythia_1p4b", "granite_4p0_h_small_ep2"])
+def test_mosaic_compiles_the_kernel_at_the_cells_widths(
+        one_chip, lanes, hq, hk, mb, layers, ntot):
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(functools.partial(
+            _paged_decode, scale=0.1, chunk=8, interpret=False)).lower(
+            shape((lanes, hq, 128), jnp.bfloat16),
+            shape((layers, ntot, 2, 16, hk, 128), jnp.bfloat16),
+            shape((), jnp.int32), shape((lanes, mb), jnp.int32),
+            shape((lanes,), jnp.int32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in text and "nns_paged_decode" in text
+    # the arena goes to the kernel as it lies: a bitcast, never a copy
+    arena = f"bf16[{layers},{ntot},2,"
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and arena in line.split(" copy(")[0]]
