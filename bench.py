@@ -1194,75 +1194,6 @@ def measure_spec() -> dict:
                 fps=len(out) / dt, frames=len(out))
 
 
-def measure_lm() -> dict:
-    """Paged-KV LM serving (``BENCH_LM=1``): more concurrent streams
-    than decode lanes time-share an 8-lane batch over a block pool
-    (serving/kvpool.py), so concurrency is bounded by free KV blocks,
-    not batch slots. Metric: aggregate generated tokens/s; the report
-    adds the interactive split (TTFT vs inter-token p99 from the flight
-    recorder's LMTokenStats), the concurrency high-water mark, and the
-    arena's HBM cost per token slot. ``BENCH_LM_BLOCK=0`` (or
-    ``NNSTPU_PAGED_KV=0``) reruns the same load on the monolithic cache
-    for an apples-to-apples comparison."""
-    import time as _t
-
-    import jax.numpy as jnp
-
-    from nnstreamer_tpu.models.transformer import (
-        TransformerConfig,
-        init_params,
-    )
-    from nnstreamer_tpu.serving import ContinuousBatchingEngine
-
-    cfg = TransformerConfig(vocab=32000, d_model=512, n_heads=8, n_layers=8,
-                            d_ff=2048, max_seq=512, dtype=jnp.bfloat16)
-    block = int(os.environ.get("BENCH_LM_BLOCK", "16") or 0)
-    n_streams = int(os.environ.get("BENCH_LM_STREAMS", "32"))
-    max_new = int(os.environ.get("BENCH_LM_MAX_NEW", "64"))
-    engine = ContinuousBatchingEngine(
-        cfg, init_params(cfg), max_streams=8, steps_per_dispatch=8,
-        temperature=0.0, block_tokens=block).start()
-    try:
-        rng = np.random.default_rng(0)
-        # compile warmup off the clock: the dispatch program plus one
-        # prefill per padding bucket this prompt-length range will hit
-        for warm in (8, 17, 33):
-            engine.generate(rng.integers(1, cfg.vocab, warm).tolist(),
-                            max_new_tokens=engine.K, timeout=600)
-        lens = rng.integers(8, 48, n_streams)
-        t0 = _t.monotonic()
-        streams = [engine.submit(rng.integers(1, cfg.vocab, n).tolist(),
-                                 max_new_tokens=max_new) for n in lens]
-        total = sum(len(s.result(timeout=600)) for s in streams)
-        dt = _t.monotonic() - t0
-        q = engine._lm_stats._q
-        ttft_p99 = (q["ttft"]["p99"].quantile() or 0.0) * 1e3
-        tok_p99 = (q["token"]["p99"].quantile() or 0.0) * 1e3
-        conc = int(engine.stats.get("concurrent_streams_max", 0))
-        sheds = int(engine.stats.get("kv_sheds", 0))
-        if engine.paged:
-            pool = engine._pool
-            kv_per_tok = pool.nbytes / (pool.num_blocks
-                                        * pool.block_tokens)
-        else:
-            import jax
-
-            kv_per_tok = sum(
-                leaf.nbytes for leaf in
-                jax.tree_util.tree_leaves(engine._cache)) / (
-                    engine.B * engine.S)
-    finally:
-        engine.stop()
-    return dict(metric="lm_serving_tokens_per_s_paged" if engine.paged
-                else "lm_serving_tokens_per_s_monolithic",
-                fps=total / dt, frames=total,
-                ttft_p99_ms=round(ttft_p99, 2),
-                intertoken_p99_ms=round(tok_p99, 3),
-                concurrent_streams_max=conc,
-                kv_sheds=sheds,
-                kv_hbm_bytes_per_token=round(kv_per_tok, 1))
-
-
 def measure_fleet() -> dict:
     """Replicated-fleet scaling (``BENCH_FLEET=N``): N echo replicas
     (serving/fleet.py, CPU-bound ``--spin-ms`` service so added
@@ -1348,7 +1279,6 @@ EXTRA_CONFIGS = {
     "decode": measure_decode,
     "serve": measure_serve,
     "spec": measure_spec,
-    "lm": measure_lm,
     "fleet": measure_fleet,
 }
 
@@ -1380,9 +1310,6 @@ def main():
     # MobileNetV2 pipeline, ONE JSON line.
     config = (sys.argv[1] if len(sys.argv) > 1 else
               os.environ.get("BENCH_CONFIG", "")).strip()
-    if not config and os.environ.get(
-            "BENCH_LM", "").strip().lower() in ("1", "true", "yes", "on"):
-        config = "lm"  # BENCH_LM=1 — the paged LM-serving report
     if not config and os.environ.get("BENCH_FLEET", "").strip():
         config = "fleet"  # BENCH_FLEET=N — replicated-fleet scaling
     if config and config != "mobilenet":

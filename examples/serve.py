@@ -26,11 +26,12 @@ def main():
     engine = ContinuousBatchingEngine(
         cfg, init_params(cfg, seed=0), max_streams=4,
         steps_per_dispatch=8, temperature=0.7, top_k=40, seed=42,
-        prefix_cache=4,  # multi-turn/system-prompt KV reuse
+        prefix_cache=4,  # multi-turn/system-prompt KV reuse, by blocks
     ).start()
 
     rng = np.random.default_rng(0)
-    system = rng.integers(1, cfg.vocab, 16).tolist()  # shared preamble
+    # shared preamble: one block of the arena (block_tokens defaults to 16)
+    system = rng.integers(1, cfg.vocab, 16).tolist()
     prompts = [system + rng.integers(1, cfg.vocab, n).tolist() for n in
                (5, 12, 30, 9, 21, 7)]
     t0 = time.monotonic()

@@ -4,12 +4,17 @@ The TPU-first serving design, contrasted with the reference's query server
 (one request = one pipeline invoke,
 /root/reference/gst/nnstreamer/tensor_query/tensor_query_server.c):
 
-- **One static program.** ``max_streams`` batch slots share a single KV
-  cache ``[L, 2, B, S, h, dh]`` in HBM. The hot loop is ONE jitted
-  function whose shapes never change — no recompiles as streams come and
-  go. Empty slots decode garbage that the host ignores; on a systolic
-  array the wasted lanes cost nothing extra because the batched matmul
-  runs anyway (utilization, not correctness, is what admission manages).
+- **One KV store.** Keys and values live in one preallocated arena of
+  fixed-size blocks (``serving/kvpool.py``); a stream owns a block table
+  into it. Admission is bounded by FREE BLOCKS, so more streams than
+  decode lanes can be admitted and time-share the lanes, and a shared
+  prompt prefix costs its blocks once.
+- **One static program.** ``max_streams`` decode lanes step together.
+  The hot loop is ONE jitted function over (arena, block tables) whose
+  shapes never change — no recompiles as streams come and go. Empty
+  lanes decode garbage that the host ignores; on a systolic array the
+  wasted lanes cost nothing extra because the batched matmul runs anyway
+  (utilization, not correctness, is what admission manages).
 - **Multi-step dispatch.** Each dispatch runs ``steps_per_dispatch``
   decode steps under ``lax.scan`` and returns a ``[B, K]`` token block —
   per-call overhead (Python, the host↔device round trip) amortizes
@@ -20,14 +25,14 @@ The TPU-first serving design, contrasted with the reference's query server
   come from the true last position (``build_prefill`` lengths arg), and
   pad kv entries are provably unreachable (see models/transformer.py
   build_prefill docstring).
-- **Slot-local determinism.** Each stream's PRNG key is derived from
+- **Stream-local determinism.** Each stream's PRNG key is derived from
   (engine seed, stream id), so sampled output is reproducible regardless
   of which other streams share the batch — per-stream results never
   depend on batch composition (the decode math is row-independent).
 
-Host-side state (positions, last tokens, keys) is a handful of int32s
-uploaded per dispatch; only the cache stays device-resident, donated into
-every dispatch so XLA updates it in place.
+Host-side state (positions, last tokens, keys, block tables) is a few
+hundred int32s uploaded per dispatch; only the arena stays device-resident,
+donated into every dispatch so XLA updates it in place.
 """
 
 from __future__ import annotations
@@ -37,8 +42,7 @@ import itertools
 import queue as _queue
 import threading
 import time as _time
-import weakref
-from typing import Any, Callable, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
 import numpy as np
 
@@ -136,7 +140,7 @@ class GenerationStream:
 
     def cancel(self) -> None:
         """Request cancellation (client gone, timeout, user abort): the
-        engine frees this stream's batch slot at the next block boundary
+        engine frees this stream's blocks and lane at the next dispatch
         and finishes it with reason "cancelled". Pending (not yet
         admitted) streams are dropped without prefilling. Safe from any
         thread; idempotent; a no-op once finished."""
@@ -277,20 +281,28 @@ class _PendingRequest:
 class ContinuousBatchingEngine:
     """Batched multi-stream generation over one transformer model.
 
+    Keys and values live in ONE place, the block arena
+    (``serving/kvpool.py``): fixed-size blocks over one preallocated
+    buffer, a block table per stream, admission bounded by FREE BLOCKS.
+    Every admitted stream owns blocks; at each dispatch the ``max_streams``
+    most urgent ones (per-token EDF deadlines) are bound to the decode
+    lanes, so more streams than lanes time-share them, and a shared
+    prompt prefix costs its blocks once (copy-on-write block tables).
+
     Parameters
     ----------
     cfg, params: a model config + param pytree. The config's ``family``
         (``models/family.py``) gives the prefill and paged-decode
         builders and says what a decode lane holds beside its blocks:
         ``models.transformer`` is the dense member, ``models.hybrid``
-        the one with state-space layers. A family with lane state is
-        served on the paged path only (``block_tokens > 0``), every
-        stream keeps its lane for life, and ``prefix_cache``,
+        the one with state-space layers. With a family that has lane
+        state every stream keeps its lane for life, and ``prefix_cache``,
         ``speculate``, ``prefill_chunk``, ``kv_quant`` and ``mesh=`` are
         refused at construction: each needs that state snapshotted,
         rolled back, quantized or sharded, which nothing does yet.
-    max_streams: batch slots (B). Static — sizes the cache and programs.
-    max_seq: cache length S (defaults to ``cfg.max_seq``).
+    max_streams: decode lanes (B). Static — sizes the programs and, by
+        default, the arena.
+    max_seq: a stream's longest context S (defaults to ``cfg.max_seq``).
     steps_per_dispatch: decode steps fused into one device dispatch (K),
         or "auto" — start() measures the per-dispatch sync round trip
         and per-step decode time and picks K so the fixed dispatch cost
@@ -304,7 +316,7 @@ class ContinuousBatchingEngine:
     min_bucket: smallest prefill padding bucket.
     mesh: optional ``jax.sharding.Mesh`` — multi-chip serving. Params
         shard per ``parallel.sharded.transformer_param_specs`` (heads/ffn
-        over ``tp``), the KV cache shards batch slots over ``dp`` and
+        over ``tp``), the arena shards its blocks over ``dp`` and its
         heads over ``tp``, and GSPMD propagates through the unchanged
         decode/prefill programs ("computation follows data") — batched
         decode collectives ride ICI, never the host. Requires
@@ -317,16 +329,18 @@ class ContinuousBatchingEngine:
         ``[1, chunk]``, instead of once per length bucket). Padded tail
         positions are unreachable-before-overwrite exactly like bucket
         padding. Requires ``prompt length <= max_seq - prefill_chunk``.
-    kv_quant: ``"int8"`` stores the KV cache quantized (per-vector absmax
-        scales) — ~2× batch slots or context per HBM byte, at a small,
-        bounded numeric cost (models/transformer._Int8KVCodec).
-    prefix_cache: keep the KV of the last N admitted prompts device-
-        resident and, when a new prompt extends a cached one, prefill
-        only the remainder — the multi-turn/system-prompt reuse pattern.
-        Exact by construction: causal kv depends only on the prefix
-        tokens, so reused entries are the same arrays a cold prefill
-        would produce. HBM cost ≈ N × prompt_len × per-token kv bytes
-        (LRU-evicted). 0 (default) disables.
+    kv_quant: ``"int8"`` stores the arena quantized (per-vector absmax
+        scales) — ~2× blocks per HBM byte, at a small, bounded numeric
+        cost (models/transformer._Int8KVCodec).
+    prefix_cache: keep the blocks of the last N admitted prompts
+        (a reference on each; LRU, and evicted first when the pool runs
+        out) and, when a new prompt shares a prefix with a cached one,
+        share the whole blocks of that prefix and prefill only the
+        remainder — the multi-turn/system-prompt reuse pattern. Exact by
+        construction: the reused kv is the same physical blocks. Reuse
+        is at BLOCK granularity (an exact repeat reuses the whole
+        prompt), and chunked ingestion (``prefill_chunk``) stores
+        entries but does not reuse them. 0 (default) disables.
     attention: "auto" (default) or "reference". "auto" runs the Pallas
         flash kernel (ops/flash_attention.py) for the O(s²) prompt pass
         on a TPU when the shapes tile (seq divisible by the block,
@@ -340,29 +354,22 @@ class ContinuousBatchingEngine:
         (`_attend_cache`). ``decode_attention`` says which was built:
         "paged_kernel" or "gather". "reference" forces XLA attention
         everywhere. A ``mesh=`` engine keeps XLA attention (a
-        ``pallas_call`` carries no partitioning rule), as do the
-        monolithic cache, chunked ingestion, prefix extension and
-        speculative verification (`build_paged_chunk`), whose attention
-        is over several query positions a lane.
-    block_tokens: > 0 enables the PAGED KV cache (serving/kvpool.py):
-        the cache becomes fixed-size blocks over one preallocated
-        arena, per-stream block tables, admission bounded by FREE
-        BLOCKS instead of batch slots — hundreds of streams time-share
-        the B decode lanes under per-token EDF deadlines, and a shared
-        prompt prefix costs its blocks once (copy-on-write block
-        tables). 0 (default) or ``NNSTPU_PAGED_KV=0`` keeps the
-        monolithic cache byte-identical to the unpaged engine.
-    kv_blocks: arena size in blocks (paged mode). Defaults to
-        ``max_streams * max_seq / block_tokens`` — the same HBM bytes
-        the monolithic cache would take.
+        ``pallas_call`` carries no partitioning rule), as do chunked
+        ingestion, prefix extension and speculative verification
+        (`build_paged_chunk`), whose attention is over several query
+        positions a lane.
+    block_tokens: tokens per block of the arena: a size, not a mode. A
+        positive divisor of ``max_seq`` (``ValueError`` otherwise).
+    kv_blocks: arena size in blocks. Defaults to ``max_streams * max_seq
+        / block_tokens``: every lane's full context at once.
     speculate: > 0 enables speculative decoding — a ``speculate_layers``
         -layer draft sliced from the target params
         (models/speculative.py) proposes K tokens per round inside the
         batched decode; the target verifies them in ONE chunk pass.
         Greedy only (temperature must be 0), single-chip only, and
-        concurrency is capped at ``max_streams`` (the draft cache is
-        slot-structured). Output is byte-identical to non-speculative
-        greedy decoding by construction.
+        concurrency is capped at ``max_streams`` (the draft keeps a
+        contiguous cache, one slot per lane). Output is byte-identical
+        to non-speculative greedy decoding by construction.
     """
 
     def __init__(self, cfg, params, max_streams: int = 4,
@@ -377,7 +384,7 @@ class ContinuousBatchingEngine:
                  prefix_cache: int = 0,
                  attention: str = "auto",
                  slo_budget_ms: float = 0.0,
-                 block_tokens: int = 0,
+                 block_tokens: int = 16,
                  kv_blocks: Optional[int] = None,
                  speculate: int = 0,
                  speculate_layers: Optional[int] = None):
@@ -385,29 +392,29 @@ class ContinuousBatchingEngine:
         import jax.numpy as jnp
 
         family = cfg.family
-        #: the family keeps state per decode lane: paged path only, a
-        #: stream is pinned to its lane, no option that copies that state
+        #: the family keeps state per decode lane: a stream is pinned to
+        #: its lane, no option that copies that state
         self._lane_state = family.lane_state(cfg) is not None
         if self._lane_state:
-            from nnstreamer_tpu.serving import kvpool as _kvpool
-
             refused = [name for name, on in (
                 ("prefix_cache", prefix_cache), ("speculate", speculate),
                 ("prefill_chunk", prefill_chunk), ("kv_quant", kv_quant),
                 ("mesh", mesh is not None)) if on]
-            if not (int(block_tokens or 0) > 0 and _kvpool.paged_enabled()):
-                refused.append("block_tokens=0 (the monolithic cache)")
             if refused:
                 raise ValueError(
                     f"serving: the {family.name} model family keeps "
-                    f"recurrent state per decode lane and is served on "
-                    f"the paged path alone; it does not yet support "
-                    f"{', '.join(refused)} (ROADMAP.md, \"what the system "
-                    f"cannot run yet\")")
+                    f"recurrent state per decode lane; it does not yet "
+                    f"support {', '.join(refused)} (ROADMAP.md, \"what "
+                    f"the system cannot run yet\")")
         self.cfg = cfg
         self.params = params
         self.B = int(max_streams)
         self.S = int(max_seq or cfg.max_seq)
+        self.block_tokens = int(block_tokens or 0)
+        if self.block_tokens <= 0 or self.S % self.block_tokens:
+            raise ValueError(
+                f"serving: block_tokens must be a positive divisor of "
+                f"max_seq ({self.S}), got {block_tokens}")
         #: steps_per_dispatch="auto": start() measures the per-dispatch
         #: sync round trip and the per-step decode time, then picks K so
         #: the fixed dispatch cost amortizes (see _calibrate_k) — on a
@@ -443,79 +450,35 @@ class ContinuousBatchingEngine:
             attention_fn = flash_attention  # causal=True is its default
         self._prefill_fn = family.build_prefill(
             cfg, self.S, attention_fn=attention_fn, kv_codec=kv_quant)
-        # the monolithic cache, chunked ingestion and speculation are the
-        # dense block's alone
-        self._decode = self._chunk_fn = None
+        #: block-table width: blocks per stream at full context
+        self.MB = self.S // self.block_tokens
+        paged_attention_fn = None
+        if attention == "auto" and mesh is None and kv_quant is None:
+            # one chip, one raw arena leaf: as for prefill, a
+            # pallas_call carries no partitioning rule
+            from nnstreamer_tpu.ops import paged_attention
+
+            paged_attention_fn = paged_attention
+        self._paged_decode = family.build_paged_decode_step(
+            cfg, self.block_tokens, self.S, kv_codec=kv_quant,
+            paged_attention_fn=paged_attention_fn)
+        # chunked ingestion (a batch-1 contiguous cache, scattered into
+        # blocks by its last chunk), prefix extension and speculative
+        # verification are the dense block's alone
+        self._chunk_fn = self._paged_chunk_fn = None
         if not self._lane_state:
             from nnstreamer_tpu.models.transformer import (
                 build_chunk_decode,
-                build_decode_step,
-                init_cache,
+                build_paged_chunk,
             )
 
-            self._decode = build_decode_step(cfg, self.S, kv_codec=kv_quant)
             self._chunk_fn = build_chunk_decode(cfg, self.S,
                                                 kv_codec=kv_quant)
-        #: in-progress chunked admission: (request, slot, cache1, k) with
+            self._paged_chunk_fn = build_paged_chunk(
+                cfg, self.block_tokens, self.S, kv_codec=kv_quant)
+        #: in-progress chunked admission: (request, cache1, k) with
         #: k = next chunk index; one at a time, advanced between dispatches
         self._partial = None
-
-        from nnstreamer_tpu.serving import kvpool as _kvpool
-
-        self.block_tokens = int(block_tokens or 0)
-        #: paged KV cache on: block_tokens > 0 AND the env kill switch
-        #: (NNSTPU_PAGED_KV) allows it. Off → every code path below is
-        #: the unchanged monolithic engine.
-        self.paged = self.block_tokens > 0 and _kvpool.paged_enabled()
-        self._pool = None
-        #: the form the paged decode program attends in: "paged_kernel"
-        #: (ops/paged_attention.py: live blocks read in place) or "gather"
-        self.decode_attention = "gather"
-        if self.paged:
-            if self.S % self.block_tokens:
-                raise ValueError(
-                    f"serving: block_tokens ({self.block_tokens}) must "
-                    f"divide max_seq ({self.S})")
-            #: block-table width: blocks per stream at full context
-            self.MB = self.S // self.block_tokens
-            paged_attention_fn = None
-            if attention == "auto" and mesh is None and kv_quant is None:
-                # one chip, one raw arena leaf: as for prefill, a
-                # pallas_call carries no partitioning rule
-                from nnstreamer_tpu.ops import paged_attention
-
-                paged_attention_fn = paged_attention
-            self._paged_decode = family.build_paged_decode_step(
-                cfg, self.block_tokens, self.S, kv_codec=kv_quant,
-                paged_attention_fn=paged_attention_fn)
-            self._paged_chunk_fn = None
-            if not self._lane_state:
-                from nnstreamer_tpu.models.transformer import (
-                    build_paged_chunk,
-                )
-
-                self._paged_chunk_fn = build_paged_chunk(
-                    cfg, self.block_tokens, self.S, kv_codec=kv_quant)
-            nb = int(kv_blocks) if kv_blocks else self.B * self.MB
-            if mesh is not None and "dp" in mesh.axis_names:
-                # arena block axis shards over dp: pad so NTOT divides
-                nb += (-(nb + 1)) % mesh.shape["dp"]
-            self._num_blocks = nb
-
-        # host-side per-slot state
-        self._pos = np.zeros(self.B, np.int32)
-        self._last = np.zeros(self.B, np.int32)
-        #: device-resident decode feedback (last, pos, keys) chaining
-        #: dispatch N+1 off dispatch N without a host sync; None = host
-        #: mirrors are authoritative (after admissions/recovery)
-        self._dev_state = None
-        #: issued-but-unprocessed dispatch blocks:
-        #: (t0, toks, lps, [(slot, stream), ...]) — host processing runs
-        #: one block behind so the fetch RTT overlaps the next compute
-        self._inflight: "collections.deque" = collections.deque()
-        self._keys = np.zeros((self.B, 2), np.uint32)
-        self._slots: List[Optional[GenerationStream]] = [None] * self.B
-        self._budget = np.zeros(self.B, np.int64)  # tokens still allowed
 
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
@@ -525,17 +488,12 @@ class ContinuousBatchingEngine:
                 transformer_param_specs,
             )
 
-            def axis(name, dim, total):
-                if name not in mesh.axis_names or mesh.shape[name] <= 1:
-                    return None
-                if total % mesh.shape[name]:
+            for name, dim, total in (("dp", "max_streams", self.B),
+                                     ("tp", "n_heads", cfg.n_heads)):
+                if name in mesh.axis_names and total % mesh.shape[name]:
                     raise ValueError(
                         f"serving: {dim} ({total}) must divide by mesh "
                         f"axis {name!r} ({mesh.shape[name]})")
-                return name
-
-            dp = axis("dp", "max_streams", self.B)
-            tp = axis("tp", "n_heads", cfg.n_heads)
 
             def prune(spec):
                 # drop axis names the mesh doesn't have (e.g. a dp-only
@@ -550,26 +508,6 @@ class ContinuousBatchingEngine:
             # registers with the budget accountant when one is active
             self.params = _serve.place_params(params, mesh, specs,
                                               label="engine:lm")
-
-            def shard_cache(cache):
-                # cache leaves: values [L,2,B,S,h,dh] and (int8 codec)
-                # scales [L,2,B,S,h] — same prefix, so slice the spec to
-                # each leaf's rank. Working state the engine resizes on
-                # its own schedule — placed, not budget-registered.
-                full = (None, None, dp, None, tp, None)
-                return _serve.place_tree(
-                    cache, mesh, lambda a: P(*full[:a.ndim]),
-                    label="engine:kv-cache")
-
-            self._init_cache = lambda: shard_cache(
-                init_cache(cfg, self.B, self.S, kv_codec=kv_quant))
-        else:
-            self._init_cache = lambda: init_cache(cfg, self.B, self.S,
-                                                  kv_codec=kv_quant)
-        # paged mode never materializes the monolithic [L,2,B,S,...]
-        # cache — the arena (created below, after obs_name) is the only
-        # KV storage
-        self._cache = None if self.paged else self._init_cache()
         self._pending: "_queue.Queue[_PendingRequest]" = _queue.Queue()
         self._next_id = 0
         self._lock = threading.Lock()
@@ -633,42 +571,52 @@ class ContinuousBatchingEngine:
         #: nns_lm_ttft_p50/p99_ms, nns_lm_token_p50/p99_ms
         self._lm_stats = LMTokenStats(self.obs_name)
         self._mesh = mesh
-        if self.paged:
-            self._pool = _kvpool.BlockPool(
-                cfg, self._num_blocks, self.block_tokens,
-                kv_codec=kv_quant, mesh=mesh, owner=self.obs_name,
-                lanes=self.B)
-            #: sid → per-stream decode state (stream, blocks, pos, last,
-            #: key, budget, deadline_t, slot); engine thread only. Every
-            #: ADMITTED stream lives here whether or not it currently
-            #: holds one of the B decode lanes.
-            self._sstate: Dict[int, dict] = {}
-            #: admission head deferred on block exhaustion (FIFO order
-            #: is preserved: nothing behind it admits until it fits)
-            self._held: Optional[_PendingRequest] = None
-            #: decode lane → sid occupying it (None = free lane)
-            self._lane: List[Optional[int]] = [None] * self.B
-            #: host mirror of the device block tables, one row per lane
-            self._bt = np.full((self.B, self.MB), self._pool.SENTINEL,
-                               np.int32)
-            if paged_attention_fn is not None:
-                from nnstreamer_tpu.ops.paged_attention import (
-                    paged_attention_form,
-                )
+        from nnstreamer_tpu.serving import kvpool as _kvpool
 
-                kv = self._pool.arena
-                kv = kv["kv"] if self._lane_state else kv
-                self.decode_attention = paged_attention_form(
-                    jax.ShapeDtypeStruct(
-                        (self.B, 1, cfg.n_heads, kv.shape[-1]), kv.dtype),
-                    kv, self._bt)
+        nb = int(kv_blocks) if kv_blocks else self.B * self.MB
+        if mesh is not None and "dp" in mesh.axis_names:
+            # arena block axis shards over dp: pad so NTOT divides
+            nb += (-(nb + 1)) % mesh.shape["dp"]
+        #: the one place keys and values live: the block arena
+        self._pool = _kvpool.BlockPool(
+            cfg, nb, self.block_tokens,
+            kv_codec=kv_quant, mesh=mesh, owner=self.obs_name,
+            lanes=self.B)
+        #: sid → per-stream decode state (stream, blocks, pos, last,
+        #: key, budget, deadline_t, slot); engine thread only. Every
+        #: ADMITTED stream lives here whether or not it currently
+        #: holds one of the B decode lanes.
+        self._sstate: Dict[int, dict] = {}
+        #: admission head deferred on block exhaustion (FIFO order
+        #: is preserved: nothing behind it admits until it fits)
+        self._held: Optional[_PendingRequest] = None
+        #: decode lane → sid occupying it (None = free lane)
+        self._lane: List[Optional[int]] = [None] * self.B
+        #: host mirror of the device block tables, one row per lane
+        self._bt = np.full((self.B, self.MB), self._pool.SENTINEL,
+                           np.int32)
+        #: the form the decode program attends in: "paged_kernel"
+        #: (ops/paged_attention.py: live blocks read in place) or "gather"
+        self.decode_attention = "gather"
+        if paged_attention_fn is not None:
+            from nnstreamer_tpu.ops.paged_attention import (
+                paged_attention_form,
+            )
+
+            kv = self._pool.arena
+            kv = kv["kv"] if self._lane_state else kv
+            self.decode_attention = paged_attention_form(
+                jax.ShapeDtypeStruct(
+                    (self.B, 1, cfg.n_heads, kv.shape[-1]), kv.dtype),
+                kv, self._bt)
         self.prefix_cache = int(prefix_cache)
         if self.prefix_cache < 0:
             raise ValueError(
                 f"serving: prefix_cache must be >= 0, got {prefix_cache}")
-        #: tuple(prompt ids) → (kv pytree [L,2,1,n,...], logits[1,V]) —
-        #: LRU, engine-thread only; the trie mirrors the key set for
-        #: O(prompt_len) longest-common-prefix admission lookups
+        #: tuple(prompt ids) → (block ids, logits[1,V]): the entry holds
+        #: a reference on each block (arena bytes the pool registered
+        #: once) — LRU, engine-thread only; the trie mirrors the key set
+        #: for O(prompt_len) longest-common-prefix admission lookups
         self._prefix: "collections.OrderedDict" = collections.OrderedDict()
         self._prefix_trie = _PrefixTrie()
         from nnstreamer_tpu.utils.stats import InvokeStats
@@ -682,91 +630,52 @@ class ContinuousBatchingEngine:
 
         from nnstreamer_tpu.models.transformer import make_sampler
 
-        decode = self._decode
         # the ONE sampling function (shared with the repo-loop sampled
         # step) — seeds the first token and every dispatch-loop draw with
         # identical math, per-row keys keeping streams batch-independent
         sample = make_sampler(cfg.vocab, self.temperature, self.top_k,
                               self.min_p, with_logprobs=True)
+        paged_decode = self._paged_decode
+        counters = self._counters
 
-        def build_dispatch(K):
-            def dispatch(params, token, cache, pos, keys):
-                """K decode steps in one program: ([B],cache,[B],[B,2]) →
-                ([B,K] tokens, [B,K] logprobs, cache, keys, last, pos').
-
-                The final carry (last token, advanced pos) comes back as
-                DEVICE arrays so the next dispatch can chain off them
-                without waiting for the token fetch — the loop pipelines
-                the host materialization one block behind the device
-                (engine _loop)."""
+        def build_paged_dispatch(K):
+            def dispatch(params, token, arena, bt, pos, keys):
+                """K decode steps in one program: ([B], arena, [B,MB],
+                [B], [B,2]) → ([B,K] tokens, [B,K] logprobs, arena, keys,
+                last, pos'). bt is LOOP-INVARIANT across the K steps —
+                the loop tops up every bound stream's blocks through
+                pos+K-1 first. A family that counts (``counters``) gets
+                a seventh result, its counts summed over the K steps in
+                one int32 vector."""
 
                 def body(carry, _):
-                    token, cache, pos, keys = carry
-                    logits, cache = decode(params, token, cache, pos)
+                    token, arena, pos, keys, counts = carry
+                    logits, arena, *counted = paged_decode(
+                        params, token, arena, bt, pos)
                     with jax.named_scope("sample"):
                         nxt, keys, lp = sample(logits, keys)
-                    return (nxt, cache, pos + 1, keys), (nxt, lp)
+                    counts = {name: counts[name] + counted[0][name]
+                              for name in counts}
+                    return (nxt, arena, pos + 1, keys, counts), (nxt, lp)
 
-                (token, cache, pos, keys), (toks, lps) = jax.lax.scan(
-                    body, (token, cache, pos, keys), None, length=K)
-                return (jnp.transpose(toks), jnp.transpose(lps), cache,
-                        keys, token, pos)
+                zeros = {name: jnp.int32(0) for name in counters}
+                (token, arena, pos, keys, counts), (toks, lps) = \
+                    jax.lax.scan(body, (token, arena, pos, keys, zeros),
+                                 None, length=K)
+                out = (jnp.transpose(toks), jnp.transpose(lps), arena,
+                       keys, token, pos)
+                if counters:
+                    out += (jnp.stack([counts[n] for n in counters]),)
+                return out
 
             return jax.jit(dispatch, donate_argnums=(2,))
 
-        self._build_dispatch = build_dispatch
-        if self.paged:
-            paged_decode = self._paged_decode
-            counters = self._counters
-
-            def build_paged_dispatch(K):
-                def dispatch(params, token, arena, bt, pos, keys):
-                    """Paged twin of the mono dispatch: same K-step scan,
-                    cache replaced by (arena, block tables). bt is LOOP-
-                    INVARIANT across the K steps — the loop tops up every
-                    bound stream's blocks through pos+K-1 first. A family
-                    that counts (``counters``) gets a seventh result, its
-                    counts summed over the K steps in one int32 vector."""
-
-                    def body(carry, _):
-                        token, arena, pos, keys, counts = carry
-                        logits, arena, *counted = paged_decode(
-                            params, token, arena, bt, pos)
-                        with jax.named_scope("sample"):
-                            nxt, keys, lp = sample(logits, keys)
-                        counts = {name: counts[name] + counted[0][name]
-                                  for name in counts}
-                        return (nxt, arena, pos + 1, keys, counts), (nxt, lp)
-
-                    zeros = {name: jnp.int32(0) for name in counters}
-                    (token, arena, pos, keys, counts), (toks, lps) = \
-                        jax.lax.scan(body, (token, arena, pos, keys, zeros),
-                                     None, length=K)
-                    out = (jnp.transpose(toks), jnp.transpose(lps), arena,
-                           keys, token, pos)
-                    if counters:
-                        out += (jnp.stack([counts[n] for n in counters]),)
-                    return out
-
-                return jax.jit(dispatch, donate_argnums=(2,))
-
-            self._build_dispatch = build_paged_dispatch
-            if self._paged_chunk_fn is not None:
-                self._paged_chunk_jitted = jax.jit(self._paged_chunk_fn,
-                                                   donate_argnums=(2,))
+        self._build_dispatch = build_paged_dispatch
+        if self._paged_chunk_fn is not None:
+            self._paged_chunk_jitted = jax.jit(self._paged_chunk_fn,
+                                               donate_argnums=(2,))
         self._set_dispatch(self.K)
         self._sample_first = jax.jit(sample)
-
-        def insert(cache, cache1, slot):
-            # tree-aware: raw caches are one [L,2,B,S,h,dh] array; the
-            # int8 codec adds a rank-5 scales leaf — slot is batch axis 2
-            # in every leaf
-            return jax.tree.map(
-                lambda c, u: jax.lax.dynamic_update_slice(
-                    c, u.astype(c.dtype),
-                    (0, 0, slot) + (0,) * (c.ndim - 3)), cache, cache1)
-
-        self._insert = jax.jit(insert, donate_argnums=(0,))
 
         # one jitted prefill; XLA caches one executable per bucket shape
         self._prefill_jitted = jax.jit(self._prefill_fn)
@@ -776,17 +685,6 @@ class ContinuousBatchingEngine:
                                          donate_argnums=(2,))
         self._jnp = jnp
         self._jax = jax
-
-        #: monolithic prefix-cache HBM accounting (tensors/memory.py
-        #: "kvcache" category): tuple key → (acct_key, nbytes). Paged
-        #: entries skip this — their blocks are arena bytes the pool
-        #: already registered.
-        self._prefix_acct: Dict[tuple, tuple] = {}
-        self._prefix_seq = itertools.count()
-        #: prefix keys the accountant dropped under pressure (on_drop
-        #: fires on an arbitrary thread; the engine thread reaps)
-        self._condemned: set = set()
-        self._condemned_lock = threading.Lock()
 
         self.speculate = 0
         self._speculate_layers: Optional[int] = None
@@ -811,13 +709,11 @@ class ContinuousBatchingEngine:
         def host(*dims, dtype=jnp.int32):
             return jax.ShapeDtypeStruct(dims, dtype)
 
-        kv = self._pool.arena if self.paged else self._cache
-        tables = (host(self.B, self.MB),) if self.paged else ()
         _DECODE_PROGRAMS.pop(self.obs_name, None)
         _DECODE_PROGRAMS[self.obs_name] = (self._build_dispatch, k, (
             jax.tree.map(shape, self.params), host(self.B),
-            jax.tree.map(shape, kv), *tables, host(self.B),
-            host(self.B, 2, dtype=jnp.uint32)))
+            jax.tree.map(shape, self._pool.arena), host(self.B, self.MB),
+            host(self.B), host(self.B, 2, dtype=jnp.uint32)))
         while len(_DECODE_PROGRAMS) > _DECODE_PROGRAMS_KEPT:
             del _DECODE_PROGRAMS[next(iter(_DECODE_PROGRAMS))]
 
@@ -832,8 +728,7 @@ class ContinuousBatchingEngine:
         initial K. K is then chosen so the fixed cost is ≤ ~20% of the
         block (K ≥ 4·rtt/s), clamped to [8, 128] and rounded down to a
         power of two (bucketed executables). Runs once, before the
-        engine loop starts, on the LIVE cache (safe because _insert
-        fully overwrites a slot's KV at admission — see below)."""
+        engine loop starts, on the LIVE arena (see below)."""
         import numpy as _np
         import time as _time
 
@@ -844,33 +739,24 @@ class ContinuousBatchingEngine:
         rtt = min(
             (lambda t0: (_np.asarray(tiny(x)), _time.monotonic() - t0)[1])(
                 _time.monotonic()) for _ in range(3))
-        # calibrate on the LIVE cache (no streams are active before
-        # start(), and every slot is fully overwritten at admission by
-        # _insert) — a throwaway cache would transiently double KV HBM
-        # and OOM exactly the memory-tight configs auto-K serves
+        # calibrate on the LIVE arena: a throwaway one would transiently
+        # double KV HBM and OOM exactly the memory-tight configs auto-K
+        # serves. All-sentinel block tables: writes drop, reads hit the
+        # zero block — a pure timing run that cannot corrupt the arena
         token = jnp.zeros((self.B,), jnp.int32)
         pos = jnp.zeros((self.B,), jnp.int32)
         keys = jnp.zeros((self.B, 2), jnp.uint32)
-        # dispatch DONATES the cache/arena: reassign immediately after
-        # each call so a failure mid-calibration never leaves it
-        # pointing at deleted buffers (start() also reinits on error)
-        if self.paged:
-            # all-sentinel block tables: writes drop, reads hit the zero
-            # block — a pure timing run that cannot corrupt the arena
-            bt = jnp.full((self.B, self.MB), self._pool.SENTINEL,
-                          jnp.int32)
+        bt = jnp.full((self.B, self.MB), self._pool.SENTINEL, jnp.int32)
 
-            def run():
-                out = self._dispatch(self.params, token,
-                                     self._pool.arena, bt, pos, keys)
-                self._pool.arena = out[2]
-                return out
-        else:
-            def run():
-                out = self._dispatch(self.params, token, self._cache,
-                                     pos, keys)
-                self._cache = out[2]
-                return out
+        def run():
+            # dispatch DONATES the arena: reassign immediately after
+            # each call so a failure mid-calibration never leaves it
+            # pointing at deleted buffers (start() also resets on error)
+            out = self._dispatch(self.params, token, self._pool.arena, bt,
+                                 pos, keys)
+            self._pool.arena = out[2]
+            return out
+
         out = run()
         _np.asarray(out[0])  # compile + warm
         t0 = _time.monotonic()
@@ -907,14 +793,8 @@ class ContinuousBatchingEngine:
                 log.warning("serving: K auto-calibration failed (%s); "
                             "keeping K=%d", e, self.K)
                 # the failed dispatch may have donated (deleted) the
-                # live cache's buffers or left error arrays in it;
-                # release the old reference BEFORE reallocating so the
-                # two caches never coexist (HBM headroom)
-                if self.paged:
-                    self._pool.reset()
-                else:
-                    self._cache = None
-                    self._cache = self._init_cache()
+                # live arena's buffers or left error arrays in it
+                self._pool.reset()
         self._stop_evt.clear()
         self._thread = threading.Thread(target=self._loop,
                                         name="cb-engine", daemon=True)
@@ -941,18 +821,11 @@ class ContinuousBatchingEngine:
             if self._partial is not None:
                 self._finish_stream(self._partial[0].stream, "engine-stopped")
                 self._partial = None
-            for i, st in enumerate(self._slots):
-                if st is self._RESERVED:
-                    self._slots[i] = None
-                elif st is not None and not st.finished:
-                    self._finish_stream(st, "engine-stopped")
-                    self._slots[i] = None
-            if self.paged:
-                for state in list(self._sstate.values()):
-                    self._finish_paged(state, "engine-stopped")
-                if self._held is not None:
-                    self._finish_stream(self._held.stream, "engine-stopped")
-                    self._held = None
+            for state in list(self._sstate.values()):
+                self._finish_paged(state, "engine-stopped")
+            if self._held is not None:
+                self._finish_stream(self._held.stream, "engine-stopped")
+                self._held = None
             while True:
                 try:
                     req = self._pending.get_nowait()
@@ -993,15 +866,12 @@ class ContinuousBatchingEngine:
                     "serving: engine is not running — call start() first "
                     "(a submit with no loop thread would never complete)")
             if self._slo is not None:
-                # backlog ahead of this request: queued + active streams
-                # (raises SloRejected before any slot/queue capacity is
-                # consumed — overload is turned away at the door, not
-                # discovered as a latency outlier)
-                backlog = self._pending.qsize() + (
-                    len(self._sstate) + (1 if self._held is not None
-                                         else 0)
-                    if self.paged else
-                    sum(1 for s in self._slots if s is not None))
+                # backlog ahead of this request: queued + admitted
+                # streams (raises SloRejected before any block/queue
+                # capacity is consumed — overload is turned away at the
+                # door, not discovered as a latency outlier)
+                backlog = self._pending.qsize() + len(self._sstate) + (
+                    1 if self._held is not None else 0)
                 self._slo.admit_request(_time.monotonic(), backlog)
             sid = self._next_id
             self._next_id += 1
@@ -1019,10 +889,7 @@ class ContinuousBatchingEngine:
 
     @property
     def active_streams(self) -> int:
-        if self.paged:
-            return len(self._sstate)
-        return sum(1 for s in self._slots
-                   if s is not None and s is not self._RESERVED)
+        return len(self._sstate)
 
     # -- the serving path measures itself ---------------------------------------
     def _ledger(self) -> "_timeline.Timeline":
@@ -1109,163 +976,6 @@ class ContinuousBatchingEngine:
             b *= 2
         return min(b, self.S)
 
-    # -- prefix cache (engine thread only) ------------------------------------
-    def _prefix_lookup(self, prompt: np.ndarray):
-        """Longest COMMON prefix between ``prompt`` and any cached entry
-        (two different user prompts sharing a system preamble still
-        reuse the shared part); returns (p, kv sliced to p, logits) —
-        logits only when the whole prompt equals a whole stored key."""
-        best_key, best_lcp = self._prefix_trie.lookup(prompt)
-        if best_key is None or best_lcp <= 0:
-            return 0, None, None
-        self._prefix.move_to_end(best_key)
-        kv, logits = self._prefix[best_key]
-        if not (best_lcp == prompt.size == len(best_key)):
-            logits = None
-        if logits is None and best_lcp == prompt.size:
-            # whole prompt covered by a LONGER stored key: we have its kv
-            # but not its last-position logits — recompute one position
-            best_lcp -= 1
-        if best_lcp < len(best_key):
-            kv = self._jax.tree.map(lambda a: a[:, :, :, :best_lcp], kv)
-        if best_lcp <= 0:
-            return 0, None, None
-        return best_lcp, kv, logits
-
-    def _prefix_store(self, prompt: np.ndarray, cache1, logits):
-        if not self.prefix_cache:
-            return
-        key = tuple(int(t) for t in prompt)
-        n = prompt.size
-        # slice slot-S down to the prompt's n positions (axis 3 = S in
-        # every cache leaf, values and int8 scales alike)
-        kv = self._jax.tree.map(lambda a: a[:, :, :, :n], cache1)
-        if key not in self._prefix:
-            self._prefix_trie.insert(key)
-        else:
-            self._prefix_unaccount(key)  # re-stored: bytes change
-        self._prefix[key] = (kv, logits)
-        self._prefix.move_to_end(key)
-        self._prefix_account(key, kv)
-        while len(self._prefix) > self.prefix_cache:
-            evicted, _ = self._prefix.popitem(last=False)
-            self._prefix_trie.remove(evicted)
-            self._prefix_unaccount(evicted)
-
-    # -- prefix-cache HBM accounting (tensors/memory.py, "kvcache") ----------
-    def _prefix_account(self, key: tuple, kv) -> None:
-        """Register one monolithic prefix entry's device bytes with the
-        HBM accountant as a DROPPABLE unit: under pressure the
-        accountant revokes it (on_drop condemns the key; the engine
-        thread reaps), so cached prefixes ride the evict rung of the
-        pressure ladder instead of being invisible HBM."""
-        from nnstreamer_tpu.tensors import memory as _memory
-
-        acct = _memory.ACTIVE
-        if acct is None:
-            return
-        nbytes = _memory.pytree_nbytes(kv)
-        acct_key = f"{self.obs_name}:prefix:{next(self._prefix_seq)}"
-        ref = weakref.ref(self)
-
-        def on_drop(_k, key=key):
-            eng = ref()
-            if eng is not None:
-                with eng._condemned_lock:
-                    eng._condemned.add(key)
-
-        acct.residency.register_droppable(
-            acct_key, nbytes, on_drop, label=f"{self.obs_name}:prefix")
-        self._prefix_acct[key] = (acct_key, nbytes)
-
-    def _prefix_unaccount(self, key: tuple) -> None:
-        rec = self._prefix_acct.pop(key, None)
-        if rec is None:
-            return
-        from nnstreamer_tpu.tensors import memory as _memory
-
-        acct = _memory.ACTIVE
-        if acct is not None:
-            acct.residency.unregister(rec[0])
-
-    def _reap_condemned(self) -> None:
-        """Engine-thread half of droppable prefix eviction: drop the
-        entries whose accounting units the pressure ladder revoked.
-        (Their bytes are already un-registered — only the engine's
-        references remain to release.)"""
-        if not self._condemned:
-            return
-        with self._condemned_lock:
-            keys = list(self._condemned)
-            self._condemned.clear()
-        for key in keys:
-            self._prefix_acct.pop(key, None)
-            if key in self._prefix:
-                del self._prefix[key]
-                self._prefix_trie.remove(key)
-
-    def _place_prefix_kv(self, cache1, kv):
-        """Write a cached kv slice into slots [0, n) of a fresh cache."""
-        jax = self._jax
-        return jax.tree.map(
-            lambda c, u: jax.lax.dynamic_update_slice(
-                c, u.astype(c.dtype), (0,) * c.ndim), cache1, kv)
-
-    def _admit(self, req: _PendingRequest, slot: int):
-        """Device phase of one admission: prefill (or prefix reuse) and
-        first-token sampling DISPATCH. Returns the activation record for
-        :meth:`_activate_commit` — the loop commits a whole admission
-        wave with one host sync instead of one round trip per prompt."""
-        self._begin_admission(req)
-        jnp = self._jnp
-        prompt = req.prompt
-        n = prompt.size
-        p, kv, cached_logits = (self._prefix_lookup(prompt)
-                                if self.prefix_cache else (0, None, None))
-        if p == n:  # whole prompt cached: zero prefill compute
-            self.stats["prefix_hits"] += 1
-            self.stats["prefix_tokens_reused"] += p
-            cache1 = self._place_prefix_kv(self._init_cache1(), kv)
-            return self._activate_begin(req, slot, cached_logits, cache1)
-        if (p >= self.PREFIX_MIN_REUSE
-                and p + self._bucket(n - p) <= self.S):
-            # prefill only the remainder through the chunk program. The
-            # first bound skips near-useless hits (a 1-token overlap
-            # costs a cache copy to save one token of an already-compiled
-            # prefill — the chunked path's sub-chunk-is-a-miss rule,
-            # bucketed flavor); the second keeps the padded chunk's
-            # writes inside the cache (a near-capacity prompt just takes
-            # the cold path)
-            self.stats["prefix_hits"] += 1
-            self.stats["prefix_tokens_reused"] += p
-            cache1 = self._place_prefix_kv(self._init_cache1(), kv)
-            rem = n - p
-            bucket = self._bucket(rem)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :rem] = prompt[p:]
-            logits, cache1 = self._chunk_jitted(
-                self.params, jnp.asarray(padded), cache1,
-                jnp.asarray(p, jnp.int32))
-            logits = logits[:, rem - 1]
-            self._prefix_store(prompt, cache1, logits)
-            return self._activate_begin(req, slot, logits, cache1)
-        bucket = self._bucket(n)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = prompt
-        logits, cache1 = self._prefill_jitted(
-            self.params, jnp.asarray(padded),
-            lengths=jnp.asarray([n], jnp.int32))
-        self._prefix_store(prompt, cache1, logits)
-        return self._activate_begin(req, slot, logits, cache1)
-
-    def _init_cache1(self):
-        from nnstreamer_tpu.models.transformer import init_cache
-
-        return init_cache(self.cfg, 1, self.S, kv_codec=self.kv_quant)
-
-    #: reserves a batch slot while its chunked prefill is in flight
-    _RESERVED = object()
-
     #: process-wide sequence behind ``obs_name`` (engine0, engine1, ...)
     _OBS_SEQ = itertools.count()
 
@@ -1273,37 +983,14 @@ class ContinuousBatchingEngine:
     #: admission; exact whole-prompt hits are never thresholded
     PREFIX_MIN_REUSE = 4
 
-    def _begin_partial(self, req: _PendingRequest, slot: int):
-        self._begin_admission(req)
-        base = 0
-        cache1 = self._init_cache1()
-        if self.prefix_cache:
-            p, kv, cached_logits = self._prefix_lookup(req.prompt)
-            if p == req.prompt.size:  # whole prompt cached: no chunks
-                self.stats["prefix_hits"] += 1
-                self.stats["prefix_tokens_reused"] += p
-                cache1 = self._place_prefix_kv(cache1, kv)
-                self._activate(req, slot, cached_logits, cache1)
-                return
-            elif (p // self.prefill_chunk) > 0:
-                # resume at the last chunk boundary <= p: chunk starts
-                # stay multiples of C (the submit-time bound assumes it),
-                # recomputing at most C-1 cached positions. A hit below
-                # one chunk (base would be 0) is a miss — nothing reusable
-                self.stats["prefix_hits"] += 1
-                base = (p // self.prefill_chunk) * self.prefill_chunk
-                self.stats["prefix_tokens_reused"] += base
-                cache1 = self._place_prefix_kv(cache1, kv)
-        self._slots[slot] = self._RESERVED
-        self._partial = (req, slot, cache1, 0, base)
-
     def _advance_partial(self):
-        """Run ONE prefill chunk; on the last chunk, activate the slot."""
+        """Run ONE prefill chunk; the last one scatters the finished
+        batch-1 cache into fresh blocks and activates the stream."""
         jnp = self._jnp
-        req, slot, cache1, k, base = self._partial
+        req, cache1, k = self._partial
         C = self.prefill_chunk
         prompt, n = req.prompt, req.prompt.size
-        start = base + k * C
+        start = k * C
         end = min(start + C, n)
         chunk = np.zeros((1, C), np.int32)
         chunk[0, :end - start] = prompt[start:end]
@@ -1314,196 +1001,48 @@ class ContinuousBatchingEngine:
                 jnp.asarray(start, jnp.int32))
             self.stats["prefill_chunks"] += 1
             if end < n:
-                self._partial = (req, slot, cache1, k + 1, base)
+                self._partial = (req, cache1, k + 1)
                 self._phase("admit", **who)
                 return
             # final chunk: logits at the prompt's true last position
             self._partial = None
             logits_last = logits[:, (n - 1) - start]
-            if self.paged:
-                rec = self._activate_paged_from_cache1(req, logits_last,
-                                                       cache1)
-                if rec is None:  # pool exhausted: re-ingest when it isn't
-                    self.stats["kv_defers"] += 1
-                    self._held = req
-                else:
-                    self._phase("admit", **who)
-                    self._activate_commit_paged(rec)
-                    self._phase("first_token", **who)
-                return
-            self._prefix_store(prompt, cache1, logits_last)
-            self._activate(req, slot, logits_last, cache1)
-        except Exception as e:  # noqa: BLE001 — a failed chunk must free
-            # the reserved slot and fail only this request
+            rec = self._activate_paged_from_cache1(req, logits_last, cache1)
+            if rec is None:  # pool exhausted: re-ingest when it isn't
+                self.stats["kv_defers"] += 1
+                self._held = req
+            else:
+                self._phase("admit", **who)
+                self._activate_commit_paged(rec)
+                self._phase("first_token", **who)
+        except Exception as e:  # noqa: BLE001 — a failed chunk must fail
+            # only this request
             log.warning("serving: chunked prefill failed: %s", e)
             self._partial = None
-            if slot is not None:
-                self._slots[slot] = None
             self._finish_stream(req.stream, f"error: {e}")
 
-    def _activate_begin(self, req: _PendingRequest, slot: int, logits,
-                        cache1):
-        """Device half of an activation: dispatch the first-token sample
-        and the cache insert, CLAIM the slot, and return the record
-        ``(req, slot, first_d, key_d, lp_d)`` whose device handles
-        :meth:`_activate_commit` materializes. Splitting lets an
-        admission wave share one host sync (grouped fetch) instead of
-        paying a full link round trip per prompt."""
-        jnp = self._jnp
-        key = np.asarray(
-            [self.seed & 0xFFFFFFFF, req.stream.stream_id & 0xFFFFFFFF],
-            np.uint32)[None]
-        first_d, key_d, lp_d = self._sample_first(logits,
-                                                  jnp.asarray(key))
-        # dtype alignment happens inside the tree-aware _insert
-        self._cache = self._insert(self._cache, cache1, slot)
-        if self._spec is not None:
-            # the shallow draft re-reads the whole prompt (cheap: half
-            # the layers, one bucketed prefill) so its cache is
-            # canonical from position 0
-            self._draft_prefill(req, slot)
-        self._slots[slot] = req.stream  # claimed; mirrors land at commit
-        return (req, slot, first_d, key_d, lp_d)
-
-    def _activate_commit(self, rec) -> None:
-        """Host half: materialize the sampled first token and install
-        the per-slot host mirrors. Callers must run
-        :meth:`_sync_host_state` after the begins and before the first
-        commit — this is the one place per-slot host state is written,
-        and syncing at commit time (not at a check-then-act distance
-        from the pending queue) closes the race where a submit() lands
-        after the loop's emptiness check; the dispatch that follows any
-        activation always rebuilds its device state from the mirrors."""
-        req, slot, first_d, key_d, lp_d = rec
-        n = req.prompt.size
-        self.stats["prefills"] += 1
-        first = int(np.asarray(first_d)[0])
-        first_lp = float(np.asarray(lp_d)[0])
-        self._pos[slot] = n
-        self._last[slot] = first
-        self._keys[slot] = np.asarray(key_d)[0]
-        # cap generation so cache writes stay inside the slot's S window
-        # (a speculative verify chunk writes through pos+K, hence the
-        # extra margin; zero when speculation is off)
-        self._budget[slot] = min(req.max_new, self.S - n - self.speculate)
-        self._emit_first(req.stream, first, first_lp)
-        self._post_emit(slot, first)
-
-    def _activate(self, req: _PendingRequest, slot: int, logits, cache1):
-        """Single-admission tail (chunked-prefill path): begin + one
-        host sync + commit."""
-        who = req.who()
-        rec = self._activate_begin(req, slot, logits, cache1)
-        self._phase("admit", **who)
-        self._sync_host_state()
-        self._activate_commit(rec)
-        self._phase("first_token", **who)
-
-    def _post_emit(self, slot: int, tok: int):
-        """Budget/EOS bookkeeping after a token reaches its stream. The
-        slot is freed BEFORE _finish wakes the client, so a caller that
-        observes its stream done also observes the slot released."""
-        st = self._slots[slot]
-        self._budget[slot] -= 1
-        done = (self.eos_id is not None and tok == self.eos_id) or \
-            self._budget[slot] <= 0
-        if done and self._slo is not None:
-            # whole-request service time feeds the admission EWMA (and
-            # the controller's p99 window) — per-REQUEST, since the
-            # engine's admission unit is a request, not a frame
-            now = _time.monotonic()
-            self._slo.observe_completion(now - st.submit_t, now, frames=1)
-            self._slo.observe_service(now - st.submit_t, frames=1)
-        if self.eos_id is not None and tok == self.eos_id:
-            self._slots[slot] = None
-            self._finish_stream(st, "eos")
-        elif self._budget[slot] <= 0:
-            self._slots[slot] = None
-            self._finish_stream(st, "length")
-
-    # -- pipelined block processing -------------------------------------------
-    def _process_block(self, t0, toks_dev, lps_dev, snapshot):
-        """Materialize one dispatched block and emit its tokens to the
-        streams that were active when it was ISSUED (a slot freed or
-        re-admitted since then skips emission — its tokens were garbage
-        or belong to a stream that already finished)."""
-        toks = np.asarray(toks_dev)  # the D2H sync; timed below
-        lps = np.asarray(lps_dev)
-        # issue of the next block and the wait for this one: the device
-        # decodes throughout
-        self.invoke_stats.record(self._phase("dispatch") - t0)
-        self.stats["slot_steps"] += self.B * self.K
-        for slot, st in snapshot:
-            if self._slots[slot] is not st:
-                continue  # freed/replaced while the block was in flight
-            self._pos[slot] += self.K
-            self._last[slot] = toks[slot, -1]
-            for j in range(self.K):
-                tok = int(toks[slot, j])
-                self.stats["tokens_generated"] += 1
-                self.stats["active_slot_steps"] += 1
-                st._emit(tok, float(lps[slot, j]))
-                self._post_emit(slot, tok)
-                if self._slots[slot] is None:
-                    break  # EOS/length mid-block: drop the tail
-        self._phase("emit")
-        self.stats["dispatches"] += 1
-
-    def _drain_inflight(self):
-        while self._inflight:
-            self._process_block(*self._inflight.popleft())
-
-    def _sync_host_state(self):
-        """Drain the pipeline and pull the device decode state back into
-        the host mirrors so admissions (which write per-slot host state)
-        operate on current values."""
-        self._drain_inflight()
-        if self._dev_state is not None:
-            _last_d, _pos_d, keys_d = self._dev_state
-            # last/pos mirrors were advanced per processed block; only
-            # keys (folded on-device every step) need the fetch
-            self._keys = np.array(keys_d)
-            self._dev_state = None
-
     def _recover(self, e) -> None:
-        """Device failure: salvage what the chip already computed (a
-        best-effort drain — those tokens were generated), then fail every
-        in-flight stream and any half-ingested prompt, rebuild the
-        (possibly donated-away) cache, and keep serving."""
+        """Device failure: fail every admitted stream, the held request
+        and any half-ingested prompt, rebuild the (possibly donated-away)
+        arena, and keep serving."""
         log.error("serving: dispatch failed: %s", e)
-        try:
-            self._drain_inflight()
-        except Exception:  # noqa: BLE001 — wedged device: drop the rest
-            self._inflight.clear()
-        self._dev_state = None
         if self._partial is not None:
             self._finish_stream(self._partial[0].stream, f"error: {e}")
             self._partial = None
-        for slot in range(self.B):
-            st = self._slots[slot]
-            if st is self._RESERVED:
-                self._slots[slot] = None
-            elif st is not None:
-                self._finish_stream(st, f"error: {e}")
-                self._slots[slot] = None
-        if self.paged:
-            for state in list(self._sstate.values()):
-                self._finish_stream(state["stream"], f"error: {e}")
-            self._sstate.clear()
-            if self._held is not None:
-                self._finish_stream(self._held.stream, f"error: {e}")
-                self._held = None
-            self._lane = [None] * self.B
-            # the arena may hold donated-away/error buffers; a fresh one
-            # is the same bytes, so accounting is unchanged. Paged prefix
-            # entries hold block ids into the dead allocation map — drop
-            # them with it.
-            self._pool.reset()
-            self._bt[:] = self._pool.SENTINEL
-            self._prefix.clear()
-            self._prefix_trie = _PrefixTrie()
-        else:
-            self._cache = self._init_cache()
+        for state in list(self._sstate.values()):
+            self._finish_stream(state["stream"], f"error: {e}")
+        self._sstate.clear()
+        if self._held is not None:
+            self._finish_stream(self._held.stream, f"error: {e}")
+            self._held = None
+        self._lane = [None] * self.B
+        # the arena may hold donated-away/error buffers; a fresh one is
+        # the same bytes, so accounting is unchanged. Prefix entries hold
+        # block ids into the dead allocation map — drop them with it.
+        self._pool.reset()
+        self._bt[:] = self._pool.SENTINEL
+        self._prefix.clear()
+        self._prefix_trie = _PrefixTrie()
         if self._spec is not None:
             self._spec["dcache"] = None
             self._spec["dcache"] = self._spec["init_dcache"]()
@@ -1542,10 +1081,11 @@ class ContinuousBatchingEngine:
         non-speculative greedy decoding would emit (the target argmax
         is ground truth; drafts only decide how many positions one
         round advances). A rejected draft costs nothing to undo: the
-        host simply advances pos by n_emit, and the stale cache slots
-        above it are overwritten before they are ever attended (the
-        next round's chunk covers them). In paged mode the roll-back
-        is the block-table tail pointer — no block copies."""
+        host simply advances pos by n_emit, and the stale kv above it
+        is overwritten before it is ever attended (the next round's
+        chunk covers it) — the roll-back is the block-table tail
+        pointer, no block copies. The draft keeps a contiguous cache of
+        its own, one slot per decode lane."""
         if self.temperature > 0:
             raise ValueError(
                 "serving: speculate requires greedy decoding "
@@ -1609,48 +1149,37 @@ class ContinuousBatchingEngine:
                                        pos + n_emit - 1)
             return tgt, lps, n_emit, dcache
 
-        if self.paged:
-            pchunk = self._paged_chunk_fn
+        pchunk = self._paged_chunk_fn
 
-            def spec_round(params, dparams, token, arena, bt, dcache,
-                           pos):
-                out_box = []  # closure cell for the updated arena tree
+        def spec_round(params, dparams, token, arena, bt, dcache, pos):
+            out_box = []  # closure cell for the updated arena tree
 
-                def verify(chunk_toks):
-                    b = chunk_toks.shape[0]
-                    logits, new_arena = pchunk(
-                        params, chunk_toks, arena, bt, pos,
-                        jnp.full((b,), g + 1, jnp.int32))
-                    out_box.append(new_arena)
-                    return logits
+            def verify(chunk_toks):
+                b = chunk_toks.shape[0]
+                logits, new_arena = pchunk(
+                    params, chunk_toks, arena, bt, pos,
+                    jnp.full((b,), g + 1, jnp.int32))
+                out_box.append(new_arena)
+                return logits
 
-                tgt, lps, n_emit, dcache = draft_and_verify(
-                    params, dparams, token, dcache, pos, verify)
-                return tgt, lps, n_emit, out_box[0], dcache
+            tgt, lps, n_emit, dcache = draft_and_verify(
+                params, dparams, token, dcache, pos, verify)
+            return tgt, lps, n_emit, out_box[0], dcache
 
-            dispatch = jax.jit(spec_round, donate_argnums=(3, 5))
-        else:
-            chunk = self._chunk_fn
+        def insert(dcache, dcache1, slot):
+            # a prompt's batch-1 draft cache over lane ``slot``'s (batch
+            # axis 2) of the draft's [L,2,B,S,h,dh] cache
+            return jax.tree.map(
+                lambda c, u: jax.lax.dynamic_update_slice(
+                    c, u.astype(c.dtype),
+                    (0, 0, slot) + (0,) * (c.ndim - 3)), dcache, dcache1)
 
-            def spec_round(params, dparams, token, cache, dcache, pos):
-                out_cache = []
-
-                def verify(chunk_toks):
-                    logits, new_cache = chunk(params, chunk_toks, cache,
-                                              pos)
-                    out_cache.append(new_cache)
-                    return logits
-
-                tgt, lps, n_emit, dcache = draft_and_verify(
-                    params, dparams, token, dcache, pos, verify)
-                return tgt, lps, n_emit, out_cache[0], dcache
-
-            dispatch = jax.jit(spec_round, donate_argnums=(3, 4))
         self._spec = {
             "dparams": dparams, "dcfg": dcfg,
             "dcache": init_dcache(), "init_dcache": init_dcache,
-            "prefill": self._jax.jit(build_prefill(dcfg, self.S)),
-            "dispatch": dispatch,
+            "prefill": jax.jit(build_prefill(dcfg, self.S)),
+            "insert": jax.jit(insert, donate_argnums=(0,)),
+            "dispatch": jax.jit(spec_round, donate_argnums=(3, 5)),
         }
 
     def _draft_prefill(self, req: _PendingRequest, slot: int) -> None:
@@ -1662,45 +1191,7 @@ class ContinuousBatchingEngine:
         padded[0, :n] = req.prompt
         _lg, dcache1 = sp["prefill"](sp["dparams"], jnp.asarray(padded),
                                      lengths=jnp.asarray([n], jnp.int32))
-        sp["dcache"] = self._insert(sp["dcache"], dcache1, slot)
-
-    def _spec_step_mono(self) -> None:
-        jnp = self._jnp
-        sp = self._spec
-        g = self.speculate
-        snapshot = [(slot, st) for slot, st in enumerate(self._slots)
-                    if st is not None and st is not self._RESERVED]
-        if not snapshot:
-            return
-        t0 = self._phase("select")
-        tgt, lps, n_emit, cache, dcache = sp["dispatch"](
-            self.params, sp["dparams"], jnp.asarray(self._last),
-            self._cache, sp["dcache"], jnp.asarray(self._pos))
-        self._cache = cache
-        sp["dcache"] = dcache
-        tgt = np.asarray(tgt)
-        lps = np.asarray(lps)
-        n_emit = np.asarray(n_emit)
-        self.invoke_stats.record(self._phase("dispatch") - t0)
-        self.stats["slot_steps"] += self.B * (g + 1)
-        for slot, st in snapshot:
-            if self._slots[slot] is not st:
-                continue
-            m = int(n_emit[slot])
-            self.stats["spec_drafted"] += g
-            self.stats["spec_accepted"] += m - 1
-            self._pos[slot] += m
-            self._last[slot] = int(tgt[slot, m - 1])
-            for j in range(m):
-                tok = int(tgt[slot, j])
-                self.stats["tokens_generated"] += 1
-                self.stats["active_slot_steps"] += 1
-                st._emit(tok, float(lps[slot, j]))
-                self._post_emit(slot, tok)
-                if self._slots[slot] is None:
-                    break
-        self._phase("emit")
-        self.stats["dispatches"] += 1
+        sp["dcache"] = sp["insert"](sp["dcache"], dcache1, slot)
 
     def _spec_step_paged(self) -> None:
         jnp = self._jnp
@@ -1758,7 +1249,7 @@ class ContinuousBatchingEngine:
         self._phase("emit")
         self.stats["dispatches"] += 1
 
-    # -- paged mode (block_tokens > 0) ----------------------------------------
+    # -- blocks, prefix cache, admission, decode --------------------------------
     def _blocks_for(self, n: int) -> int:
         """Blocks a fresh n-token-prompt stream needs up front: the
         prompt's positions plus the first decode write (always
@@ -1830,7 +1321,7 @@ class ContinuousBatchingEngine:
             self._pool.release(list(eids))
 
     def _admit_paged(self, req: _PendingRequest):
-        """Paged admission: allocate the stream's block table, prefill
+        """Admission: allocate the stream's block table, prefill
         cold / block-aligned warm / exact-hit, and return the
         activation record — or None to DEFER when the pool cannot
         cover the prompt (admission is bounded by FREE BLOCKS, not
@@ -1938,17 +1429,19 @@ class ContinuousBatchingEngine:
             raise
 
     def _begin_partial_paged(self, req: _PendingRequest) -> None:
-        """Chunked prompt ingestion, paged flavor: chunks build a
-        batch-1 monolithic cache that the FINAL chunk scatters into
-        fresh blocks — no slot is reserved, blocks allocate at
-        activation. (Prefix reuse is not wired on this path; chunked
-        paged prompts ingest from 0.)"""
+        """Chunked prompt ingestion: chunks build a batch-1 contiguous
+        cache that the FINAL chunk scatters into fresh blocks — no lane
+        is reserved, blocks allocate at activation. (Prefix reuse is
+        not wired on this path; chunked prompts ingest from 0.)"""
+        from nnstreamer_tpu.models.transformer import init_cache
+
         self._begin_admission(req)
-        self._partial = (req, None, self._init_cache1(), 0, 0)
+        self._partial = (req, init_cache(self.cfg, 1, self.S,
+                                         kv_codec=self.kv_quant), 0)
 
     def _activate_begin_paged(self, req: _PendingRequest, logits, blocks,
                               lane: Optional[int] = None):
-        """Paged twin of _activate_begin: sample the first token,
+        """Device half of an activation: sample the first token,
         create the stream's decode state. No lane is claimed (EDF
         binds lanes per dispatch) — except where a stream is pinned to
         a lane for life: in speculative mode (the draft cache is
@@ -2007,10 +1500,9 @@ class ContinuousBatchingEngine:
             self._finish_paged(state, "length")
 
     def _finish_paged(self, state, reason: str) -> None:
-        """Paged stream teardown: blocks return to the pool BEFORE the
-        client wakes (mirroring the mono engine's slot-free-before-
-        finish contract, so a caller that observes its stream done also
-        observes the capacity released)."""
+        """Stream teardown: blocks return to the pool BEFORE the client
+        wakes, so a caller that observes its stream done also observes
+        the capacity released."""
         self._sstate.pop(state["sid"], None)
         slot = state["slot"]
         if slot is not None:
@@ -2061,7 +1553,10 @@ class ContinuousBatchingEngine:
         walking the evict → shed ladder on exhaustion. False = the
         stream itself was shed."""
         steps = (self.speculate + 1) if self._spec is not None else self.K
-        hi = (state["pos"] + steps - 1) // self.block_tokens
+        # a dispatch whose last steps pass max_seq writes them at S-1 (the
+        # programs clamp; the budget ends the stream before they are read)
+        hi = min((state["pos"] + steps - 1) // self.block_tokens,
+                 self.MB - 1)
         while len(state["blocks"]) <= hi:
             ids = self._alloc_blocks(hi + 1 - len(state["blocks"]))
             if ids is None:
@@ -2158,16 +1653,17 @@ class ContinuousBatchingEngine:
         self._phase("emit")
         self.stats["dispatches"] += 1
 
-    def _loop_paged(self):
-        """Paged engine loop. Dispatch → emit runs synchronously (the
+    def _loop(self):
+        """The engine loop. Dispatch → emit runs synchronously (the
         host state it re-uploads per block is a few hundred int32s —
         noise next to the gather the decode already pays), which keeps
         lane parking/rebinding and EDF preemption a plain host-side
         concern instead of a device-state pipeline hazard."""
+        self._phase_t = _time.monotonic()
+        self._iter_us = {}
         while not self._stop_evt.is_set():
             self._end_iteration()
             busy = bool(self._sstate)
-            self._reap_condemned()
             for state in list(self._sstate.values()):
                 if state["stream"].cancelled:
                     self._finish_paged(state, "cancelled")
@@ -2255,170 +1751,3 @@ class ContinuousBatchingEngine:
             except Exception as e:  # noqa: BLE001 — a device failure
                 # must not strand clients blocked on their streams
                 self._recover(e)
-
-    def _loop(self):
-        self._phase_t = _time.monotonic()
-        self._iter_us = {}
-        if self.paged:
-            return self._loop_paged()
-        return self._loop_mono()
-
-    def _loop_mono(self):
-        jnp = self._jnp
-        while not self._stop_evt.is_set():
-            self._end_iteration()
-            busy = self.active_streams > 0
-            self._reap_condemned()
-            # honor cancellations first: active slots free at this block
-            # boundary; a half-ingested prompt stops mid-prefill
-            for slot in range(self.B):
-                st = self._slots[slot]
-                if (st is not None and st is not self._RESERVED
-                        and st.cancelled):
-                    self._slots[slot] = None
-                    self._finish_stream(st, "cancelled")
-            if self._partial is not None and self._partial[0].stream.cancelled:
-                _, slot, _, _, _ = self._partial
-                self._slots[slot] = None
-                self._finish_stream(self._partial[0].stream, "cancelled")
-                self._partial = None
-            if busy:  # else these microseconds go to what comes next
-                self._phase("other")
-            # in-flight chunked prefill: ONE chunk per iteration, so the
-            # decode dispatch below keeps running streams moving while a
-            # long prompt ingests.
-            # (A dispatch-FIRST reordering — decode block issued before
-            # admissions so its compute "overlaps" the admission's host
-            # work — was tried and reverted: the chip executes queued
-            # programs serially, so it bought no measured throughput and
-            # cost new streams up to a full K-step block of
-            # time-to-first-token, since the wave commit then had to
-            # drain a block issued microseconds earlier instead of one
-            # nearly done from the previous iteration.)
-            progressed = False
-            if self._partial is not None:
-                self._advance_partial()
-                progressed = True
-            # admission: fill free slots from the pending queue. The
-            # device work (prefill + first-token sample) dispatches per
-            # request; the host fetches commit as ONE grouped wave below,
-            # so a burst of N prompts costs ~1 link round trip, not N.
-            queue_dry = False
-            admitted = []
-            for slot in range(self.B):
-                if queue_dry or self._slots[slot] is not None \
-                        or self._partial is not None:
-                    continue
-                # retry THIS slot past cancelled/failed queue heads — a
-                # cancelled request must not cost a slot its admission
-                while True:
-                    try:
-                        req = self._pending.get_nowait()
-                    except _queue.Empty:
-                        queue_dry = True
-                        break
-                    if req.stream.cancelled:
-                        self._finish_stream(req.stream, "cancelled")
-                        continue
-                    try:
-                        if self.prefill_chunk is not None:
-                            self._begin_partial(req, slot)
-                        else:
-                            admitted.append(self._admit(req, slot))
-                        progressed = True
-                        self._phase("admit", **req.who())
-                        break  # slot filled
-                    except Exception as e:  # noqa: BLE001 — a bad request
-                        # (or a prefill/cache-alloc failure) must not kill
-                        # the engine loop
-                        log.warning("serving: admit failed: %s", e)
-                        if self._slots[slot] is self._RESERVED:
-                            self._slots[slot] = None
-                        self._partial = None
-                        self._finish_stream(req.stream, f"error: {e}")
-            if admitted:
-                try:
-                    self._sync_host_state()
-                except Exception as e:  # noqa: BLE001 — deferred device
-                    # errors surface at the drain. _recover already
-                    # failed every admitted stream and freed the slots:
-                    # committing the wave now would write mirrors into
-                    # freed slots and emit ghost tokens
-                    self._recover(e)
-                    admitted = []
-                for rec in admitted:  # start all fetches before blocking
-                    for d in (rec[2], rec[3], rec[4]):
-                        start_async = getattr(d, "copy_to_host_async",
-                                              None)
-                        if start_async is not None:
-                            start_async()
-                for rec in admitted:
-                    try:
-                        self._activate_commit(rec)
-                    except Exception as e:  # noqa: BLE001 — fail only
-                        # this stream; the slot frees for the next prompt
-                        log.warning("serving: activate failed: %s", e)
-                        self._slots[rec[1]] = None
-                        self._finish_stream(rec[0].stream, f"error: {e}")
-                    self._phase("first_token", **rec[0].who())
-            if self.active_streams == 0:
-                try:
-                    self._sync_host_state()  # late EOS frees the last slot
-                except Exception as e:  # noqa: BLE001 — deferred device
-                    # errors surface at materialization; must not kill the
-                    # engine thread
-                    self._recover(e)
-                    continue
-                if self.active_streams == 0:
-                    if not progressed:
-                        self._wake.wait(timeout=0.05)
-                        self._wake.clear()
-                        self._phase("idle")
-                    continue
-            if self._spec is not None:
-                # speculative rounds replace the K-step dispatch; they
-                # run synchronously off the host mirrors (variable
-                # per-stream emit counts don't pipeline)
-                try:
-                    self._sync_host_state()
-                    self._spec_step_mono()
-                except Exception as e:  # noqa: BLE001
-                    self._recover(e)
-                continue
-            try:
-                t0 = self._phase("select")
-                if self._dev_state is None:
-                    last_d = jnp.asarray(self._last)
-                    pos_d = jnp.asarray(self._pos)
-                    keys_d = jnp.asarray(self._keys)
-                else:
-                    last_d, pos_d, keys_d = self._dev_state
-                toks, lps, self._cache, keys_d, last_d, pos_d = \
-                    self._dispatch(self.params, last_d, self._cache,
-                                   pos_d, keys_d)
-                self._dev_state = (last_d, pos_d, keys_d)
-                # start the transfers NOW; the blocking materialization
-                # runs one block behind, so the link round trip overlaps
-                # the next dispatch's compute instead of serializing it
-                for t in (toks, lps):
-                    start_async = getattr(t, "copy_to_host_async", None)
-                    if start_async is not None:
-                        start_async()
-                self._inflight.append((t0, toks, lps, [
-                    (slot, st) for slot, st in enumerate(self._slots)
-                    if st is not None and st is not self._RESERVED]))
-                if len(self._inflight) > 1:
-                    self._process_block(*self._inflight.popleft())
-                else:  # the pipeline fills: the issue alone
-                    self._phase("dispatch")
-            except Exception as e:  # noqa: BLE001 — a device failure must
-                # not strand clients blocked on their streams
-                self._recover(e)
-                continue
-        # stop requested: flush the pipelined blocks so streams whose
-        # tokens were already computed still receive them
-        try:
-            self._drain_inflight()
-        except Exception as e:  # noqa: BLE001 — draining on shutdown is
-            # best-effort; a dead device must not block stop()
-            log.warning("serving: drain at stop failed: %s", e)

@@ -1,10 +1,11 @@
 """Paged KV-cache allocator: one preallocated device arena, block tables.
 
-The monolithic serving cache gives every one of ``max_streams`` batch
-slots the full ``max_seq`` window — HBM cost B×S whether streams use it
-or not, concurrency hard-capped at B. This module carves the same bytes
-into fixed ``block_tokens``-sized blocks instead (the compiler-first
-O(1) autoregressive-caching form, PAPERS.md):
+A contiguous cache would give every one of ``max_streams`` decode lanes
+the full ``max_seq`` window — HBM cost B×S whether streams use it or
+not, concurrency hard-capped at B. This module carves the bytes into
+fixed ``block_tokens``-sized blocks instead (the compiler-first O(1)
+autoregressive-caching form, PAPERS.md). It is the serving engine's one
+KV store:
 
 - **Arena** — one device pytree per codec, leaves ``[L, NTOT, 2, T, h,
   dh]`` (int8 adds a ``[L, NTOT, 2, T, h]`` scale leaf). ONE buffer per
@@ -50,15 +51,10 @@ subscripts the arena outside this file (lint rule NNS118): every
 host-side mutation (prefill scatter, COW block copy) must go through
 the pool so refcounts, donation, and the zero block's invariants stay
 in one place.
-
-Kill switch: ``NNSTPU_PAGED_KV=0`` (or ``block_tokens=0`` on the
-engine) disables paging entirely — the engine then never imports an
-arena and runs the monolithic PR-18 path byte-identically.
 """
 
 from __future__ import annotations
 
-import os
 import threading
 import weakref
 from typing import List, Optional, Sequence
@@ -67,23 +63,14 @@ import numpy as np
 
 from nnstreamer_tpu.tensors import memory as _memory
 
-_FALSY = ("0", "false", "no", "off")
-
-
-def paged_enabled() -> bool:
-    """Environment kill switch (default ON; the engine additionally
-    requires ``block_tokens > 0``, which defaults off)."""
-    return os.environ.get("NNSTPU_PAGED_KV", "1").strip().lower() \
-        not in _FALSY
-
 
 def _scatter_prefill_impl(arena, cache1, bids):
-    """Scatter a batch-1 monolithic cache ([L, 2, 1, S, ...] leaves) into
-    arena blocks ``bids`` ([S/T] int32, sentinel entries drop). Block i
-    receives slots [i*T, (i+1)*T) — including any trailing bucket-pad
-    garbage in the last data block, which stays masked until the owning
-    stream overwrites it (the same padded-prefill contract as the
-    monolithic cache)."""
+    """Scatter a prefill's batch-1 contiguous cache ([L, 2, 1, S, ...]
+    leaves) into arena blocks ``bids`` ([S/T] int32, sentinel entries
+    drop). Block i receives slots [i*T, (i+1)*T) — including any trailing
+    bucket-pad garbage in the last data block, which stays masked until
+    the owning stream overwrites it (the padded-prefill contract of
+    ``build_prefill``)."""
     import jax
     import jax.numpy as jnp
 
@@ -315,8 +302,7 @@ class BlockPool:
 
     def reset(self) -> None:
         """Drop every allocation and rebuild a zeroed arena — the engine
-        recovery path (mirrors re-running ``_init_cache`` on the
-        monolithic engine). Accounting is unchanged: same bytes."""
+        recovery path. Accounting is unchanged: same bytes."""
         with self._lock:
             self._free = list(range(self.num_blocks))
             self._ref[:] = 0
@@ -338,7 +324,7 @@ class BlockPool:
 
 
 def _leaf_slots(cache1) -> int:
-    """Sequence length S of a batch-1 monolithic cache pytree."""
+    """Sequence length S of a batch-1 contiguous cache pytree."""
     import jax
 
     return jax.tree_util.tree_leaves(cache1)[0].shape[3]

@@ -125,27 +125,16 @@ class ResidencyUnit:
       the OWNER holds (training params, a serving engine). Counted in
       ``nns_mem_used_bytes`` but never an eviction victim — evicting
       would free nothing while the owner's references live.
-    - ``on_drop``: a DROPPABLE unit — owner-held bytes (like ``pinned``)
-      that the owner can surrender on demand (a prefix-cache entry, a
-      regenerable scratch buffer). Eviction calls ``on_drop(key)`` so
-      the owner releases its reference, un-registers the bytes, and
-      removes the unit — there is no host staging and no reload.
 
-    ``category`` names the budget bucket the unit's bytes count under
-    (``weights`` by default; the serving prefix/KV caches use
-    ``kvcache``), so ``nns_mem_used_bytes`` splits honestly by owner
-    kind instead of lumping every residency unit into weights.
+    A unit's bytes count under the budget's ``weights`` category.
     """
 
     __slots__ = ("key", "label", "nbytes", "_host", "_loader", "_device",
-                 "loads", "evictions", "group", "pinned", "category",
-                 "on_drop")
+                 "loads", "evictions", "group", "pinned")
 
     def __init__(self, key: str, host_value: Any, nbytes: int,
                  loader: Optional[Callable[[Any], Any]], label: str = "",
-                 group: Optional[str] = None, pinned: bool = False,
-                 category: str = "weights",
-                 on_drop: Optional[Callable[[str], None]] = None):
+                 group: Optional[str] = None, pinned: bool = False):
         self.key = key
         self.label = label or key
         self.nbytes = int(nbytes)
@@ -156,8 +145,6 @@ class ResidencyUnit:
         self.evictions = 0
         self.group = group
         self.pinned = bool(pinned)
-        self.category = category
-        self.on_drop = on_drop
 
     @property
     def resident(self) -> bool:
@@ -230,29 +217,6 @@ class ResidencyManager:
         self._budget.register(unit.nbytes, "weights")
         return unit
 
-    def register_droppable(self, key: str, nbytes: int,
-                           on_drop: Callable[[str], None],
-                           label: str = "", category: str = "kvcache"
-                           ) -> ResidencyUnit:
-        """Account owner-held bytes the owner can SURRENDER on demand (a
-        prefix-cache entry, a regenerable scratch buffer). Unlike
-        :meth:`adopt` the unit IS an eviction victim: under pressure the
-        manager calls ``on_drop(key)`` (outside no locks the owner
-        needs), un-registers the bytes and forgets the unit — there is
-        no host staging and no reload. Registers under ``category`` so
-        cache bytes show up as ``kvcache``, not ``weights``."""
-        unit = ResidencyUnit(key, None, int(nbytes), None, label,
-                             category=category, on_drop=on_drop)
-        unit._device = _PINNED      # resident from creation, owner-held
-        with self._lock:
-            old = self._units.pop(key, None)
-            if old is not None:
-                self._evict_locked(old)
-                self._drop_from_group(old)
-            self._units[key] = unit
-        self._budget.register(unit.nbytes, category)
-        return unit
-
     def unregister(self, key: str) -> None:
         """Drop a unit (owner closed): its device bytes un-register and
         the host staging reference is released."""
@@ -262,7 +226,7 @@ class ResidencyManager:
                 return
             if unit.resident:
                 unit._device = None
-                self._budget.unregister(unit.nbytes, unit.category)
+                self._budget.unregister(unit.nbytes, "weights")
             unit._host = None
             self._drop_from_group(unit)
 
@@ -305,7 +269,7 @@ class ResidencyManager:
                 if p is not unit:
                     p.loads += 1
                 self._units.move_to_end(p.key)
-                self._budget.register(p.nbytes, p.category, reclaim=False)
+                self._budget.register(p.nbytes, "weights", reclaim=False)
             self._units.move_to_end(unit.key)
             return dev
 
@@ -314,23 +278,6 @@ class ResidencyManager:
         group) to host staging. Returns bytes freed."""
         if not unit.resident or unit.pinned:
             return 0
-        if unit.on_drop is not None:
-            # Droppable unit: no host staging — surrender the owner's
-            # allocation entirely and forget the unit.
-            unit._device = None
-            unit.evictions += 1
-            self._budget.unregister(unit.nbytes, unit.category)
-            self._budget._m["evictions"].inc()
-            self._units.pop(unit.key, None)
-            try:
-                unit.on_drop(unit.key)
-            except Exception:  # noqa: BLE001 — owner callback, best-effort
-                log.warning("on_drop callback for %s raised", unit.label,
-                            exc_info=True)
-            _mark("mem_evict", unit=unit.label, nbytes=unit.nbytes)
-            log.info("dropped cache unit %s (%d bytes)", unit.label,
-                     unit.nbytes)
-            return unit.nbytes
         freed = 0
         for p in self._peers_locked(unit):
             if not p.resident:
@@ -338,7 +285,7 @@ class ResidencyManager:
             p._device = None
             p.evictions += 1
             freed += p.nbytes
-            self._budget.unregister(p.nbytes, p.category)
+            self._budget.unregister(p.nbytes, "weights")
             self._budget._m["evictions"].inc()
         _mark("mem_evict", unit=unit.label, nbytes=freed)
         log.info("evicted residency unit %s (%d bytes) to host staging",
@@ -369,9 +316,7 @@ class ResidencyManager:
         nothing."""
         freed = 0
         with self._lock:
-            # list(): droppable units delete themselves from _units
-            # mid-eviction, which would break a live dict iterator.
-            for unit in list(self._units.values()):
+            for unit in self._units.values():
                 if unit.resident and not unit.pinned:
                     freed += self._evict_locked(unit)
         return freed
@@ -385,7 +330,7 @@ class ResidencyManager:
             units = [{"key": u.key, "label": u.label, "nbytes": u.nbytes,
                       "resident": u.resident, "loads": u.loads,
                       "evictions": u.evictions, "group": u.group,
-                      "pinned": u.pinned, "category": u.category}
+                      "pinned": u.pinned}
                      for u in self._units.values()]
         return {"units": units,
                 "resident": sum(1 for u in units if u["resident"])}

@@ -326,7 +326,7 @@ def test_eight_requests_together_equal_the_same_eight_alone():
 
 @pytest.mark.parametrize("option", [
     {"prefix_cache": 2}, {"speculate": 2}, {"prefill_chunk": 8},
-    {"kv_quant": "int8"}, {"block_tokens": 0}, {"mesh": "dp2"}],
+    {"kv_quant": "int8"}, {"mesh": "dp2"}],
     ids=lambda o: next(iter(o)))
 def test_refused_options_raise_at_construction(option):
     if "mesh" in option:
@@ -337,10 +337,13 @@ def test_refused_options_raise_at_construction(option):
         _engine(**option)
 
 
-def test_paged_kill_switch_refuses_the_family(monkeypatch):
-    monkeypatch.setenv("NNSTPU_PAGED_KV", "0")
-    with pytest.raises(ValueError, match="monolithic"):
-        _engine()
+@pytest.mark.parametrize("block_tokens", [0, -1, 24],
+                         ids=["zero", "negative", "non-divisor"])
+def test_block_tokens_must_be_a_positive_divisor_of_max_seq(block_tokens):
+    """One message for every family (the dense one:
+    ``tests/test_paged_serving.py``)."""
+    with pytest.raises(ValueError, match="positive divisor of max_seq"):
+        _engine(block_tokens=block_tokens)
 
 
 # -- (g) names, counters, snapshot -------------------------------------------

@@ -1,27 +1,23 @@
-"""Prefix-cache entries as accounted, droppable HBM (satellite fix).
+"""Prefix-cache entries are arena bytes: what the accountant and the pool
+see of them.
 
-Before this PR the trie-backed prefix cache held device arrays that
-never registered with the HBM accountant — invisible bytes the pressure
-ladder could neither see nor reclaim. Now every monolithic prefix entry
-registers under the ``kvcache`` category as a DROPPABLE residency unit:
-eviction surrenders the bytes (on_drop condemns the key; the engine
-thread reaps), and LRU turnover un-registers as entries rotate out.
-(The paged engine needs none of this per-entry machinery — its entries
-are refcounts on pool blocks, and the arena itself is one registered
-``kvcache`` unit, covered in test_kvpool.py.)"""
+The engine keeps keys and values in one place, the block arena, which the
+pool registers ONCE with the HBM accountant under ``kvcache``
+(test_kvpool.py). A prefix entry is a reference on blocks of that arena:
+it adds no bytes, LRU turnover gives its blocks back to the free list,
+and under block exhaustion entries are the first thing to go (the evict
+rung of the pressure ladder, ``_evict_prefix_paged``), before a request
+is deferred or a stream shed."""
 
-import gc
-import time
-
-import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-import jax.numpy as jnp  # noqa: E402
 
 from nnstreamer_tpu.serving import ContinuousBatchingEngine  # noqa: E402
 from nnstreamer_tpu.tensors import memory  # noqa: E402
 from tests.test_serving import CFG, PARAMS, reference_greedy  # noqa: E402
+
+T = 8
 
 
 @pytest.fixture(autouse=True)
@@ -44,107 +40,96 @@ def _kv_bytes(budget):
     return budget.snapshot()["used_by_category"].get("kvcache", 0)
 
 
-def _prefix_units(budget):
-    return [u for u in budget.residency.snapshot()["units"]
-            if ":prefix" in u["label"]]
+def _evict_rung(budget):
+    return budget._m["pressure"]["evict"].value
 
 
-# -- the residency primitive ----------------------------------------------
-
-
-def test_droppable_unit_accounting(_budget):
-    dropped = []
-    _budget.residency.register_droppable(
-        "t:prefix:0", 1000, dropped.append, label="t:prefix")
-    assert _kv_bytes(_budget) == 1000
-    assert _budget.residency.evict_all() == 1000
-    assert dropped == ["t:prefix:0"]      # owner told to surrender
-    assert _kv_bytes(_budget) == 0
-    # unregister (owner closed) releases bytes WITHOUT the callback
-    _budget.residency.register_droppable(
-        "t:prefix:1", 500, dropped.append, label="t:prefix")
-    _budget.residency.unregister("t:prefix:1")
-    assert _kv_bytes(_budget) == 0
-    assert dropped == ["t:prefix:0"]
-
-
-# -- the engine's prefix cache rides it -----------------------------------
-
-
-PROMPT_A = [7, 3, 9, 1, 4, 6, 2, 8, 5, 11]
-PROMPT_B = [13, 17, 19, 23, 29, 31, 37, 41]
+# 17 tokens: two whole blocks of 8 and a tail, three blocks an entry
+PROMPT_A = [7, 3, 9, 1, 4, 6, 2, 8, 5, 11, 13, 17, 19, 23, 29, 27, 25]
+PROMPT_B = [13, 17, 19, 23, 29, 31, 37, 41, 2, 4, 6, 8, 10, 12, 14, 16, 18]
 PROMPT_C = [2, 4, 6, 8, 10, 12, 14, 16, 18]
 
 
-def mono_engine(**kw):
+def engine(**kw):
     kw.setdefault("max_streams", 2)
     kw.setdefault("steps_per_dispatch", 4)
     kw.setdefault("temperature", 0.0)
+    kw.setdefault("block_tokens", T)
     kw.setdefault("prefix_cache", 2)
     return ContinuousBatchingEngine(CFG, PARAMS, **kw).start()
 
 
-def test_prefix_entries_register_kvcache_bytes(_budget):
-    eng = mono_engine()
+def test_prefix_entry_adds_no_bytes_to_the_kvcache_category(_budget):
+    eng = engine()
     try:
-        assert not eng.paged
+        arena = _kv_bytes(_budget)
+        assert arena == eng._pool.nbytes > 0
         eng.generate(PROMPT_A, max_new_tokens=4, timeout=120)
-        used = _kv_bytes(_budget)
-        assert used > 0, "prefix entry bytes invisible to the accountant"
-        units = _prefix_units(_budget)
-        assert len(units) == 1
-        assert units[0]["category"] == "kvcache"
-        assert sum(u["nbytes"] for u in units) == used
+        assert len(eng._prefix) == 1
+        assert eng._pool.live_blocks() == 3    # the entry holds its blocks
+        assert _kv_bytes(_budget) == arena     # ... of the same arena
+        assert not [u for u in _budget.residency.snapshot()["units"]
+                    if "prefix" in u["label"]]
     finally:
         eng.stop()
-    # engine teardown releases the entries' accounting
-    del eng
-    gc.collect()
 
 
-def test_lru_turnover_unregisters_bytes(_budget):
-    eng = mono_engine(prefix_cache=2)
+def test_lru_turnover_returns_the_evicted_entrys_blocks(_budget):
+    eng = engine(prefix_cache=1)
+    try:
+        eng.generate(PROMPT_A, max_new_tokens=4, timeout=120)
+        baseline = eng._pool.snapshot()        # one entry, no stream
+        assert baseline["live_blocks"] == 3
+        for p in (PROMPT_B, PROMPT_A, PROMPT_B):
+            eng.generate(p, max_new_tokens=4, timeout=120)
+            assert len(eng._prefix) == 1
+            assert eng._pool.snapshot() == baseline
+        assert eng.stats["prefix_hits"] == 0   # each turned the other out
+    finally:
+        eng.stop()
+
+
+def test_block_exhaustion_evicts_prefix_entries_before_it_defers(_budget):
+    eng = engine(kv_blocks=6, prefix_cache=4)
     try:
         for p in (PROMPT_A, PROMPT_B):
             eng.generate(p, max_new_tokens=4, timeout=120)
-        two = _kv_bytes(_budget)
-        assert len(_prefix_units(_budget)) == 2
-        # third distinct prompt: capacity 2 evicts the LRU entry and its
-        # bytes leave the ledger with it
-        eng.generate(PROMPT_C, max_new_tokens=4, timeout=120)
-        assert len(_prefix_units(_budget)) == 2
-        assert len(eng._prefix) == 2
-        assert _kv_bytes(_budget) <= two + max(
-            u["nbytes"] for u in _prefix_units(_budget))
-        # the ledger tracks exactly the live entries
-        assert _kv_bytes(_budget) == sum(
-            u["nbytes"] for u in _prefix_units(_budget))
+        assert eng._pool.snapshot()["free_blocks"] == 0  # two entries hold all
+        before = _evict_rung(_budget)
+        got = eng.generate(PROMPT_C, max_new_tokens=6, timeout=120)
+        assert _evict_rung(_budget) == before + 1
+        assert tuple(PROMPT_A) not in eng._prefix       # the LRU one went
+        assert tuple(PROMPT_B) in eng._prefix
+        assert eng.stats["kv_defers"] == 0 and eng.stats["kv_sheds"] == 0
     finally:
         eng.stop()
+    assert got == reference_greedy(PROMPT_C, 6)
 
 
-def test_pressure_eviction_drops_entries_and_serving_continues(_budget):
-    eng = mono_engine()
+def test_recovery_drops_the_entries_with_the_arena_and_keeps_the_bytes(
+        _budget):
+    """A failed dispatch rebuilds the arena: entries point into the dead
+    allocation map and go with it; the accountant sees the same bytes."""
+    eng = engine()
     try:
-        want = reference_greedy(PROMPT_A, 6)
-        assert eng.generate(PROMPT_A, max_new_tokens=6,
-                            timeout=120) == want
-        assert _kv_bytes(_budget) > 0
-        # pressure-ladder rung 1: the accountant revokes droppable units
-        freed = _budget.residency.evict_all()
-        assert freed > 0
-        assert _kv_bytes(_budget) == 0    # bytes surrendered immediately
-        assert eng._condemned               # reap pending, engine-side
-        # serving continues — the next request both reaps the condemned
-        # entry and re-decodes exactly (the cache is an optimization,
-        # never a correctness dependency)
-        assert eng.generate(PROMPT_A, max_new_tokens=6,
-                            timeout=120) == want
-        deadline = time.monotonic() + 10
-        while eng._condemned and time.monotonic() < deadline:
-            time.sleep(0.05)
-        assert not eng._condemned
-        # the re-decode re-stored the prefix: accounted again
-        assert _kv_bytes(_budget) > 0
+        arena = _kv_bytes(_budget)
+        eng.generate(PROMPT_A, max_new_tokens=4, timeout=120)
+        real = eng._dispatch
+
+        def failing(*args):
+            eng._dispatch = real
+            raise RuntimeError("injected device failure")
+
+        eng._dispatch = failing
+        s = eng.submit(PROMPT_B, max_new_tokens=6)
+        s.result(timeout=120)
+        assert s.finish_reason == "error: injected device failure"
+        # the repeat is served by the recovered loop: a miss, and exact
+        got = eng.generate(PROMPT_A, max_new_tokens=6, timeout=120)
+        assert eng.stats["prefix_hits"] == 0
+        assert list(eng._prefix) == [tuple(PROMPT_A)]
+        assert eng._pool.live_blocks() == 3    # the new entry's, no other
+        assert _kv_bytes(_budget) == arena
     finally:
         eng.stop()
+    assert got == reference_greedy(PROMPT_A, 6)

@@ -29,17 +29,6 @@ def _no_budget():
     memory.deactivate()
 
 
-def test_env_kill_switch(monkeypatch):
-    for off in ("0", "false", "no", "off", " OFF "):
-        monkeypatch.setenv("NNSTPU_PAGED_KV", off)
-        assert not kvpool.paged_enabled(), off
-    for on in ("1", "true", "yes", ""):
-        monkeypatch.setenv("NNSTPU_PAGED_KV", on)
-        assert kvpool.paged_enabled() or on == "", on
-    monkeypatch.delenv("NNSTPU_PAGED_KV")
-    assert kvpool.paged_enabled()  # default ON (engine gates on knob)
-
-
 def test_alloc_is_all_or_nothing_and_lifo():
     pool = kvpool.BlockPool(CFG, 4, T)
     ids = pool.alloc(3)

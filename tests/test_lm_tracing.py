@@ -70,13 +70,14 @@ def _idle_waits(eng, n=3):
         seen, last = seen + (now != last), now
 
 
+# with the default block (16 tokens) and with blocks of 8
 ADMISSIONS = {
-    "paged": {"block_tokens": 8},
-    "monolithic": {},
+    "blocks8": {"block_tokens": 8},
+    "default": {},
     "chunked": {"prefill_chunk": 8},
-    "chunked_paged": {"block_tokens": 8, "prefill_chunk": 8},
+    "chunked_blocks8": {"block_tokens": 8, "prefill_chunk": 8},
     "prefix_hit": {"prefix_cache": 4},
-    "prefix_hit_paged": {"block_tokens": 8, "prefix_cache": 4},
+    "prefix_hit_blocks8": {"block_tokens": 8, "prefix_cache": 4},
 }
 
 
@@ -121,9 +122,13 @@ def test_unadmitted_stream_gets_submit_and_finish_only():
         assert dropped.first_t is None
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "monolithic"])
-def test_phase_counters_are_ints_from_the_start_and_tile_the_loop(paged):
-    eng = _engine(block_tokens=8 if paged else 0)
+BLOCKS = pytest.mark.parametrize("blocks", [{"block_tokens": 8}, {}],
+                                 ids=["blocks8", "default"])
+
+
+@BLOCKS
+def test_phase_counters_are_ints_from_the_start_and_tile_the_loop(blocks):
+    eng = _engine(**blocks)
     keys = {"loop_us", "admissions", "admit_wait_us", "first_token_us",
             "stalls"} | {f"phase_{p}_us" for p in engine_mod.PHASES}
     assert keys <= set(eng.stats)
@@ -245,9 +250,9 @@ def test_stall_note_fires_once_with_the_phases(monkeypatch):
     assert notes[0].startswith(f"serving: {eng.obs_name} iteration")
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "monolithic"])
-def test_token_stats_see_one_observation_per_finished_request(paged):
-    eng = _engine(block_tokens=8 if paged else 0).start()
+@BLOCKS
+def test_token_stats_see_one_observation_per_finished_request(blocks):
+    eng = _engine(**blocks).start()
     try:
         streams = _serve(eng, [_prompt(5), _prompt(20), _prompt(12)])
         one = _serve(eng, [_prompt(7)], max_new=1)  # no second token
@@ -374,9 +379,9 @@ def _has_scope(text, scope):
     return f'"{scope}/' in text or f"/{scope}/" in text
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "monolithic"])
-def test_decode_program_is_jit_dispatch_and_holds_every_scope(paged):
-    eng = _engine(block_tokens=8 if paged else 0)
+@BLOCKS
+def test_decode_program_is_jit_dispatch_and_holds_every_scope(blocks):
+    eng = _engine(**blocks)
     build, k, shapes = engine_mod._DECODE_PROGRAMS[eng.obs_name]
     assert build is eng._build_dispatch and k == eng.K
     text = _lowered_text(eng._dispatch, *shapes)
@@ -418,8 +423,10 @@ def test_prefill_program_is_jit_prefill_and_holds_every_scope():
 
 
 def test_scatter_program_holds_its_scope():
+    from nnstreamer_tpu.models.transformer import init_cache
+
     eng = _engine(block_tokens=8)
-    cache1 = jax.eval_shape(eng._init_cache1)
+    cache1 = jax.eval_shape(lambda: init_cache(CFG, 1, eng.S))
     arena = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), eng._pool.arena)
     text = _lowered_text(eng._pool._jit_scatter, arena, cache1,

@@ -1,14 +1,14 @@
 """Paged-KV continuous batching (serving/engine.py + serving/kvpool.py).
 
-The correctness bar, per ISSUE 19's acceptance criteria:
+The correctness bar:
 
-- ``NNSTPU_PAGED_KV=0`` (or ``block_tokens=0``) keeps the monolithic
-  cache — the engine never builds a pool and outputs are byte-identical
-  to the unpaged engine (pinned here);
-- with paging ON, greedy outputs are byte-identical to the monolithic
-  cache for the same prompts — single stream, concurrent streams,
-  ``kv_quant=int8``, chunked prefill, and oversubscription (more
-  streams than decode lanes) alike;
+- the block arena is the engine's one KV store: ``block_tokens`` is a
+  size, never a mode, and no environment variable switches it off;
+- greedy outputs are byte-identical to the model-level reference over a
+  contiguous cache (``build_prefill`` + ``build_decode_step``) for the
+  same prompts — single stream, concurrent streams, ``kv_quant=int8``,
+  chunked prefill, and oversubscription (more streams than decode
+  lanes) alike;
 - the decode loop stays ONE jitted program (retrace count pinned);
 - under a starved pool the evict -> shed ladder fires, shed streams'
   blocks return to the free list, and surviving streams stay exact;
@@ -53,39 +53,55 @@ PROMPTS = [[5, 11, 23, 42, 7], [4, 8, 15], [16, 23], [42, 7, 9, 1],
            [2, 2, 2, 2, 2], [31, 59, 26, 53], [9] * 17, [13, 2]]
 
 
-# -- kill switch ----------------------------------------------------------
+# -- one KV store: a size, not a mode ---------------------------------------
 
 
-def test_env_kill_switch_keeps_monolithic_path(monkeypatch):
-    monkeypatch.setenv("NNSTPU_PAGED_KV", "0")
-    eng = paged_engine()  # block_tokens set, env wins
+def test_default_engine_serves_from_a_block_pool():
+    """An engine built as its signature suggests, with no
+    ``block_tokens``, has the arena and one of the two decode forms."""
+    from nnstreamer_tpu.serving.kvpool import BlockPool
+
+    eng = ContinuousBatchingEngine(
+        CFG, PARAMS, max_streams=2, steps_per_dispatch=4,
+        temperature=0.0).start()
     try:
-        assert not eng.paged
-        assert eng._cache is not None          # monolithic cache built
-        assert not hasattr(eng, "_pool") or eng._pool is None
+        assert isinstance(eng._pool, BlockPool)
+        assert eng.block_tokens == 16 and eng.MB == CFG.max_seq // 16
+        assert eng.decode_attention in ("gather", "paged_kernel")
         got = eng.generate(PROMPTS[0], max_new_tokens=9, timeout=120)
+        assert eng._pool.live_blocks() == 0
     finally:
         eng.stop()
     assert got == reference_greedy(PROMPTS[0], 9)
 
 
-def test_block_tokens_zero_is_monolithic():
-    eng = ContinuousBatchingEngine(
-        CFG, PARAMS, max_streams=2, steps_per_dispatch=4,
-        temperature=0.0).start()
+def test_removed_env_switch_changes_nothing(monkeypatch):
+    """``NNSTPU_PAGED_KV`` was the monolithic cache's kill switch; it is
+    read by nothing now, and must not come back unnoticed."""
+    monkeypatch.setenv("NNSTPU_PAGED_KV", "0")
+    eng = paged_engine()
     try:
-        assert not eng.paged and eng._cache is not None
+        assert eng._pool is not None and eng.block_tokens == T
+        got = eng.generate(PROMPTS[0], max_new_tokens=9, timeout=120)
+        assert eng.stats["kv_blocks_table"] > 0   # decoded from the arena
     finally:
         eng.stop()
+    assert got == reference_greedy(PROMPTS[0], 9)
 
 
-# -- greedy byte-parity vs the monolithic cache ---------------------------
+@pytest.mark.parametrize("block_tokens", [0, -1, 24],
+                         ids=["zero", "negative", "non-divisor"])
+def test_block_tokens_must_be_a_positive_divisor_of_max_seq(block_tokens):
+    with pytest.raises(ValueError, match="positive divisor of max_seq"):
+        ContinuousBatchingEngine(CFG, PARAMS, block_tokens=block_tokens)
+
+
+# -- greedy byte-parity vs the contiguous-cache reference -----------------
 
 
 def test_single_stream_matches_reference():
     eng = paged_engine()
     try:
-        assert eng.paged
         for p in PROMPTS[:4]:
             assert eng.generate(p, max_new_tokens=9, timeout=120) == \
                 reference_greedy(p, 9), f"prompt={p}"
@@ -106,16 +122,11 @@ def test_concurrent_streams_match_isolated_runs():
 
 
 def test_int8_paged_matches_int8_monolithic():
-    """The per-block int8 codec must equal the monolithic int8 cache
-    bit for bit — same quantization grid, different storage layout."""
-    mono = ContinuousBatchingEngine(
-        CFG, PARAMS, max_streams=2, steps_per_dispatch=4,
-        temperature=0.0, kv_quant="int8").start()
-    try:
-        want = [mono.generate(p, max_new_tokens=9, timeout=120)
-                for p in PROMPTS[:3]]
-    finally:
-        mono.stop()
+    """The per-block int8 codec must equal the model-level int8
+    contiguous cache (``build_prefill`` / ``build_decode_step`` with
+    ``kv_codec="int8"``) bit for bit — same quantization grid, different
+    storage layout."""
+    want = [reference_greedy(p, 9, kv_codec="int8") for p in PROMPTS[:3]]
     eng = paged_engine(kv_quant="int8")
     try:
         got = [eng.generate(p, max_new_tokens=9, timeout=120)
@@ -255,7 +266,6 @@ def test_paged_int8_dp2_mesh_matches_single_device():
         temperature=0.0, kv_quant="int8", block_tokens=T,
         mesh=mesh).start()
     try:
-        assert eng.paged
         # the arena (incl. zero block) divides over dp ranks
         assert eng._pool.ntot % 2 == 0
         got = [eng.generate(p, max_new_tokens=8, timeout=240)

@@ -26,11 +26,13 @@ CFG = TransformerConfig(vocab=97, d_model=64, n_heads=4, n_layers=2,
 PARAMS = init_params(CFG, seed=3)
 
 
-def reference_greedy(prompt, n_tokens, cfg=CFG, params=PARAMS):
+def reference_greedy(prompt, n_tokens, cfg=CFG, params=PARAMS,
+                     kv_codec=None):
     """Exact-length prefill + one-at-a-time greedy decode (no padding,
-    no batching) — the ground truth the engine must match."""
-    prefill = jax.jit(build_prefill(cfg))
-    decode = jax.jit(build_decode_step(cfg))
+    no batching, a contiguous cache) — the ground truth the engine must
+    match."""
+    prefill = jax.jit(build_prefill(cfg, kv_codec=kv_codec))
+    decode = jax.jit(build_decode_step(cfg, kv_codec=kv_codec))
     tokens = jnp.asarray(np.asarray(prompt, np.int32)[None])
     logits, cache1 = prefill(params, tokens)
     out = [int(jnp.argmax(logits[0]))]
@@ -104,9 +106,12 @@ def test_eos_truncates_stream(engine):
     assert s.finish_reason == "eos"
 
 
-def test_length_budget_respects_cache_window():
+@pytest.mark.parametrize("steps", [4, 8])
+def test_length_budget_respects_cache_window(steps):
+    """With 8 steps a dispatch the last one runs past ``max_seq``: the
+    stream's block table must not grow past its width for them."""
     eng = ContinuousBatchingEngine(
-        CFG, PARAMS, max_streams=1, steps_per_dispatch=4,
+        CFG, PARAMS, max_streams=1, steps_per_dispatch=steps,
         temperature=0.0).start()
     try:
         prompt = list(range(1, 60))  # 59 tokens, S=64 → at most 5 new
@@ -114,7 +119,7 @@ def test_length_budget_respects_cache_window():
         got = s.result(timeout=120)
     finally:
         eng.stop()
-    assert len(got) == CFG.max_seq - len(prompt)
+    assert got == reference_greedy(prompt, CFG.max_seq - len(prompt))
     assert s.finish_reason == "length"
 
 
@@ -283,9 +288,10 @@ def test_prefix_cache_extension_is_exact():
     base = [7, 3, 11, 30, 2, 9]
     full = base + [14, 27, 5]
     cold = reference_greedy(full, 9)
+    # reuse is by whole blocks: blocks of 2 tokens divide the base
     eng = ContinuousBatchingEngine(
         CFG, PARAMS, max_streams=2, steps_per_dispatch=4,
-        temperature=0.0, prefix_cache=4).start()
+        temperature=0.0, prefix_cache=4, block_tokens=2).start()
     try:
         eng.generate(base, max_new_tokens=3, timeout=240)
         got = eng.generate(full, max_new_tokens=9, timeout=240)
@@ -297,6 +303,9 @@ def test_prefix_cache_extension_is_exact():
 
 
 def test_prefix_cache_with_chunked_prefill():
+    """Chunked ingestion stores its prompts as entries but ingests every
+    prompt from 0 (ROADMAP: chunked ingestion straight into blocks): the
+    output is exact and nothing is counted as reused."""
     base = [(i * 13 + 5) % CFG.vocab for i in range(17)]
     full = base + [(i * 7 + 1) % CFG.vocab for i in range(9)]
     cold = reference_greedy(full, 6)
@@ -311,10 +320,9 @@ def test_prefix_cache_with_chunked_prefill():
     finally:
         eng.stop()
     assert got == cold
-    # resume at chunk boundary 16 (p=17 → base 16): 26 tokens need
-    # chunks [16,24) and [24,32) — two, not ceil(26/8)=4
-    assert chunks_used == 2
-    assert eng.stats["prefix_tokens_reused"] == 16
+    assert chunks_used == 4  # ceil(26/8): every chunk of the prompt
+    assert eng.stats["prefix_tokens_reused"] == 0
+    assert len(eng._prefix) == 2
 
 
 def test_prefix_cache_shared_system_prompt():
@@ -325,9 +333,10 @@ def test_prefix_cache_shared_system_prompt():
     u1 = system + [50, 51]
     u2 = system + [60, 61, 62]
     cold_u2 = reference_greedy(u2, 8)
+    # reuse is by whole blocks: the preamble is one block of 8 tokens
     eng = ContinuousBatchingEngine(
         CFG, PARAMS, max_streams=2, steps_per_dispatch=4,
-        temperature=0.0, prefix_cache=4).start()
+        temperature=0.0, prefix_cache=4, block_tokens=8).start()
     try:
         eng.generate(u1, max_new_tokens=3, timeout=240)
         got = eng.generate(u2, max_new_tokens=8, timeout=240)
@@ -340,13 +349,16 @@ def test_prefix_cache_shared_system_prompt():
 
 def test_prefix_cache_prompt_inside_longer_entry():
     """The new prompt is a strict PREFIX of a stored key: kv is reused
-    for n-1 positions and the last position recomputes for its logits."""
+    for the whole blocks below its last position, which recomputes for
+    its logits."""
     long_p = [5, 11, 23, 42, 7, 9, 14]
-    short_p = long_p[:6]  # n-1 = 5 reusable, above PREFIX_MIN_REUSE
+    short_p = long_p[:6]
     ref = reference_greedy(short_p, 6)
+    # blocks of 2 tokens: positions 0..3 are shared (two whole blocks
+    # below position n-1 = 5, and PREFIX_MIN_REUSE = 4), 4..5 recompute
     eng = ContinuousBatchingEngine(
         CFG, PARAMS, max_streams=2, steps_per_dispatch=4,
-        temperature=0.0, prefix_cache=4).start()
+        temperature=0.0, prefix_cache=4, block_tokens=2).start()
     try:
         eng.generate(long_p, max_new_tokens=3, timeout=240)
         got = eng.generate(short_p, max_new_tokens=6, timeout=240)
@@ -354,7 +366,7 @@ def test_prefix_cache_prompt_inside_longer_entry():
         eng.stop()
     assert got == ref
     assert eng.stats["prefix_hits"] == 1
-    assert eng.stats["prefix_tokens_reused"] == len(short_p) - 1
+    assert eng.stats["prefix_tokens_reused"] == 4
 
 
 def test_prefix_cache_exact_repeat_wins_over_longer_tie():

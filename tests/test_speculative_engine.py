@@ -5,7 +5,7 @@ Drafts come from a shallow prefix slice of the target
 one chunk pass, so emitted tokens are exactly greedy-decode tokens —
 speculation only changes how many positions a round advances, never the
 values. That makes byte-parity with ``reference_greedy`` the whole
-correctness story, in BOTH cache modes (monolithic and paged)."""
+correctness story, whatever the arena's block size."""
 
 import numpy as np
 import pytest
@@ -32,12 +32,11 @@ def spec_engine(**kw):
     return ContinuousBatchingEngine(CFG, PARAMS, **kw)
 
 
-@pytest.mark.parametrize("block_tokens", [0, 8],
-                         ids=["monolithic", "paged"])
+@pytest.mark.parametrize("block_tokens", [8, 16])
 def test_speculative_greedy_parity(block_tokens):
     eng = spec_engine(block_tokens=block_tokens).start()
     try:
-        assert eng.paged == (block_tokens > 0)
+        assert eng._pool.block_tokens == block_tokens
         for p in PROMPTS:
             assert eng.generate(p, max_new_tokens=9, timeout=240) == \
                 reference_greedy(p, 9), f"prompt={p}"
