@@ -565,6 +565,8 @@ def server_leg(sizes: dict, on_chip: bool) -> dict:
         if on_chip:
             # the prefill bucket >= 256 must be the program with the
             # Pallas flash kernel in it, not the XLA reference
+            # engine.params is the tree the engine HOLDS: the float32
+            # matrices it was given, narrowed to cfg.dtype at construction
             lowered = engine._prefill_jitted.lower(
                 engine.params, jnp.zeros((1, 256), jnp.int32),
                 lengths=jnp.asarray([200], jnp.int32)).as_text()

@@ -23,11 +23,17 @@ from nnstreamer_tpu.serving import ContinuousBatchingEngine
 def main():
     cfg = TransformerConfig(vocab=4096, d_model=256, n_heads=8, n_layers=4,
                             d_ff=1024, max_seq=256, dtype=jnp.bfloat16)
+    params = init_params(cfg, seed=0)      # float32, as the model stores
     engine = ContinuousBatchingEngine(
-        cfg, init_params(cfg, seed=0), max_streams=4,
+        cfg, params, max_streams=4,
         steps_per_dispatch=8, temperature=0.7, top_k=40, seed=42,
         prefix_cache=4,  # multi-turn/system-prompt KV reuse, by blocks
     ).start()
+    # the engine holds its own tree, the matrices narrowed to cfg.dtype
+    # (engine.params, engine.weights), and not the one it was given:
+    # dropping ours gives the float32 bytes back
+    del params
+    print(f"weights: {engine.weights}")
 
     rng = np.random.default_rng(0)
     # shared preamble: one block of the arena (block_tokens defaults to 16)

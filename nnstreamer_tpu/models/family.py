@@ -6,12 +6,16 @@ other than keys and values, brings its own: its configuration's ``family``
 property returns a :class:`ModelFamily`, and the engine, the pool and the
 benchmark's drivers take parameter init, the prefill and paged-decode
 builders and the description of a lane's state from there.
+
+The record also says which parameter leaves the family's programs read
+through ``.astype(cfg.dtype)``; :func:`serving_params` makes, once, the
+tree a server's programs will read from the tree it was given.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,3 +44,50 @@ class ModelFamily:
     lane_state: Callable = lambda cfg: None
     #: names of the per-step counts the decode step returns
     counters: Tuple[str, ...] = ()
+    #: names (a leaf's own key in the parameter tree) of the leaves every
+    #: program of the family reads as ``leaf.astype(cfg.dtype)`` and in no
+    #: other width; what a program reads as stored (norm scales, a head's
+    #: float32 table) is not named
+    read_in_dtype: Tuple[str, ...] = ()
+
+
+def serving_params(cfg, params) -> Tuple[Any, Dict[str, int]]:
+    """The tree a server's programs read, made once from the tree it was
+    given: ``(held, record)``.
+
+    One rule for every family, decided by what the input shows: a leaf
+    the family names in ``read_in_dtype`` that is WIDER than ``cfg.dtype``
+    is rounded to it, all such leaves in one jitted call; every other leaf
+    (one already in ``cfg.dtype``, one narrower, one a program reads as
+    stored) is passed through as the same array. The builders keep their
+    ``.astype(cfg.dtype)``: on a leaf of that dtype it is the identity and
+    the program has no ``convert``, so the rounding that a program given
+    the wide tree repeats at every call happens here, once, to the same
+    values bit for bit.
+
+    ``record``: ``weight_bytes_given``, ``weight_bytes_held`` and
+    ``weight_leaves_narrowed``."""
+    import jax
+    import jax.numpy as jnp
+
+    dtype = jnp.dtype(cfg.dtype)
+    names = cfg.family.read_in_dtype
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    held = [leaf for _, leaf in flat]
+
+    def nbytes():
+        return sum(int(a.size) * jnp.dtype(a.dtype).itemsize for a in held)
+
+    record = {"weight_bytes_given": nbytes()}
+    wide = [i for i, (path, leaf) in enumerate(flat)
+            if getattr(path[-1], "key", None) in names
+            and jnp.issubdtype(leaf.dtype, jnp.floating)
+            and jnp.dtype(leaf.dtype).itemsize > dtype.itemsize]
+    if wide:
+        narrow = jax.jit(lambda leaves: [a.astype(dtype) for a in leaves])(
+            [held[i] for i in wide])
+        for i, a in zip(wide, narrow):
+            held[i] = a
+    record.update(weight_bytes_held=nbytes(),
+                  weight_leaves_narrowed=len(wide))
+    return jax.tree_util.tree_unflatten(treedef, held), record

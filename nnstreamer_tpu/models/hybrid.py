@@ -613,4 +613,9 @@ HYBRID = ModelFamily(
     name="hybrid", init_params=init_params, build_prefill=build_prefill,
     build_paged_decode_step=build_paged_decode_step,
     kv_layout=lambda cfg: (cfg.attn_layers, cfg.n_kv_heads, cfg.head_dim),
-    lane_state=lane_state, counters=COUNTERS)
+    lane_state=lane_state, counters=COUNTERS,
+    # every matrix but the embedding, whose lookup (``_embed``) widens the
+    # STORED rows to float32; ``init_params`` stores all of them in
+    # ``param_dtype``, which is ``dtype`` unless a caller says otherwise
+    read_in_dtype=("ssm_in", "ssm_out", "wq", "wk", "wv", "wo", "router",
+                   "w_in", "w_out", "shared_in", "shared_out"))

@@ -228,7 +228,7 @@ def build_forward(cfg: TransformerConfig,
         positions = position_offset + jnp.arange(s)[None, :].astype(
             jnp.int32
         ) * jnp.ones((b, 1), jnp.int32)
-        x = params["embed"].astype(dtype)[tokens]
+        x = params["embed"][tokens].astype(dtype)
         layer_params = {k: v for k, v in params.items()
                         if k not in ("embed", "ln_f")}
         (x, _), _ = lax.scan(layer_body, (x, positions), layer_params)
@@ -438,7 +438,7 @@ def build_decode_step(cfg: TransformerConfig,
         pos = jnp.asarray(pos, jnp.int32)
         per_stream = pos.ndim == 1
         pos_c = jnp.minimum(pos, s_max - 1)  # see cache-length contract
-        x = params["embed"].astype(dtype)[token][:, None]       # [b,1,d]
+        x = params["embed"][token].astype(dtype)[:, None]       # [b,1,d]
         positions = pos[:, None] if per_stream \
             else jnp.full((b, 1), pos, jnp.int32)
         layer_params = {k: v for k, v in params.items()
@@ -513,7 +513,7 @@ def build_chunk_decode(cfg: TransformerConfig,
                 (b, 1), jnp.int32)                               # [b,c]
             # query i (global position pos0+i) sees slots <= pos0+i
             qpos = (pos0 + jnp.arange(c))[None, None, :, None]
-        x = params["embed"].astype(dtype)[tokens]
+        x = params["embed"][tokens].astype(dtype)
         layer_params = {k: v for k, v in params.items()
                         if k not in ("embed", "ln_f")}
 
@@ -584,7 +584,7 @@ def build_paged_decode_step(cfg: TransformerConfig,
     def step(params, token, arena, bt, pos):
         pos = jnp.asarray(pos, jnp.int32)
         pos_c = jnp.minimum(pos, s_max - 1)  # cache-length contract
-        x = params["embed"].astype(dtype)[token][:, None]       # [b,1,d]
+        x = params["embed"][token].astype(dtype)[:, None]       # [b,1,d]
         positions = pos[:, None]
         blk = jnp.take_along_axis(bt, (pos_c // T)[:, None], axis=1)
         off = (pos_c % T)[:, None]                               # [b,1]
@@ -657,7 +657,7 @@ def build_paged_chunk(cfg: TransformerConfig,
         blk = jnp.take_along_axis(bt, positions // T, axis=1)    # [b,c]
         blk = jnp.where(valid, blk, jnp.int32(ntot))   # pad writes drop
         off = positions % T
-        x = params["embed"].astype(dtype)[tokens]
+        x = params["embed"][tokens].astype(dtype)
         layer_params = {k: v for k, v in params.items()
                         if k not in ("embed", "ln_f")}
 
@@ -711,7 +711,7 @@ def build_prefill(cfg: TransformerConfig,
         b, s = tokens.shape
         positions = jnp.arange(s)[None, :].astype(jnp.int32) * jnp.ones(
             (b, 1), jnp.int32)
-        x = params["embed"].astype(dtype)[tokens]
+        x = params["embed"][tokens].astype(dtype)
         layer_params = {k: v for k, v in params.items()
                         if k not in ("embed", "ln_f")}
         (x, _), kv = lax.scan(layer_body, (x, positions), layer_params)
@@ -867,7 +867,11 @@ from nnstreamer_tpu.models.family import ModelFamily  # noqa: E402
 DENSE = ModelFamily(
     name="dense", init_params=init_params, build_prefill=build_prefill,
     build_paged_decode_step=build_paged_decode_step,
-    kv_layout=lambda cfg: (cfg.n_layers, cfg.n_heads, cfg.head_dim))
+    kv_layout=lambda cfg: (cfg.n_layers, cfg.n_heads, cfg.head_dim),
+    # the router is read in float32 and the embedding has two readers: the
+    # lookup gathers rows and casts those, the head multiplies the stored
+    # table in float32 (``_final_logits``)
+    read_in_dtype=("qkv", "proj", "w_in", "w_out"))
 
 
 def transformer_lm(vocab: int = 32000, d_model: int = 512, n_heads: int = 8,

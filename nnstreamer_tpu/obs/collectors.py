@@ -62,6 +62,11 @@ def register_engine_collector(engine, registry: MetricsRegistry = None
                   **labels).set(eng.active_streams)
         reg.gauge("nns_serving_batch_slots", "Configured batch slots (B)",
                   **labels).set(eng.B)
+        for key, nbytes in eng.weights.items():
+            reg.gauge(f"nns_serving_{key}",
+                      "Weights as given to the engine, as it holds them "
+                      "(serving_params), and the leaves it narrowed",
+                      **labels).set(nbytes)
         slot_steps = eng.stats["slot_steps"]
         occupancy = (eng.stats["active_slot_steps"] / slot_steps
                      if slot_steps else 0.0)

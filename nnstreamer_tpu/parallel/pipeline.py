@@ -154,7 +154,7 @@ def build_pipelined_forward(cfg: TransformerConfig, mesh: Mesh,
                 f"{num_microbatches} the step was built for")
         positions = jnp.broadcast_to(
             jnp.arange(s, dtype=jnp.int32)[None, None, :], tokens.shape)
-        x = params["embed"].astype(dtype)[tokens]   # [num_mb, mb, s, d]
+        x = params["embed"][tokens].astype(dtype)   # [num_mb, mb, s, d]
         stage_params = {k: v for k, v in params.items()
                         if k not in ("embed", "ln_f")}
         x = blocks(stage_params, x, positions)

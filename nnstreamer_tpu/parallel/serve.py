@@ -314,14 +314,17 @@ def _per_device_nbytes(leaves) -> Dict[Any, int]:
     return per
 
 
-def account_placement(placed: Any, label: str) -> None:
+def account_placement(placed: Any, label: str, owner: Any = None) -> None:
     """Register an externally-held sharded placement's per-device bytes
     with the active HBM accountant as PINNED per-shard residency units
     (satellite of NNS113: the bytes show in ``nns_mem_used_bytes``
     instead of hiding behind a pragma). The units un-register when the
     placed pytree dies — they are accounting, not an eviction target,
     because the caller (a train step, the serving engine) holds the
-    arrays and an eviction here could not actually free them."""
+    arrays and an eviction here could not actually free them. ``owner``
+    names whose death retires them instead, where the tree may share
+    arrays with someone who outlives it (the serving engine passes
+    through what it is given in the dtype it reads)."""
     acct = _memory.ACTIVE
     if acct is None:
         return
@@ -342,7 +345,8 @@ def account_placement(placed: Any, label: str) -> None:
         acct.residency.adopt(key, nbytes, label=f"{label}#shard{k}")
         keys.append(key)
     try:
-        weakref.finalize(leaves[0], _release_placement,
+        weakref.finalize(leaves[0] if owner is None else owner,
+                         _release_placement,
                          weakref.ref(acct), tuple(keys))
     except TypeError:
         # not weakref-able (unexpected for jax arrays): count the

@@ -300,6 +300,22 @@ class ContinuousBatchingEngine:
         ``speculate``, ``prefill_chunk``, ``kv_quant`` and ``mesh=`` are
         refused at construction: each needs that state snapshotted,
         rolled back, quantized or sharded, which nothing does yet.
+        The engine does not keep the tree it is given. At construction
+        it makes, once, the tree its programs read
+        (``models.family.serving_params``): a leaf the family's programs
+        read as ``leaf.astype(cfg.dtype)`` and that arrives wider
+        (float32 weights under a bfloat16 config) is rounded to
+        ``cfg.dtype`` in one jitted call, every other leaf is held as the
+        same array; ``mesh=`` placement and a speculative draft take the
+        held tree. The rounding is the one every program would otherwise
+        repeat at every call, so outputs are bit-equal; a caller that
+        needs the float32 bytes back drops its own reference after
+        construction. ``engine.params`` is the held tree;
+        ``engine.weights`` records ``weight_bytes_given``,
+        ``weight_bytes_held`` and ``weight_leaves_narrowed`` (logged at
+        construction, exported as ``nns_serving_weight_*`` gauges, the
+        held bytes registered with the HBM accountant when one is
+        active).
     max_streams: decode lanes (B). Static — sizes the programs and, by
         default, the arena.
     max_seq: a stream's longest context S (defaults to ``cfg.max_seq``).
@@ -407,7 +423,12 @@ class ContinuousBatchingEngine:
                     f"support {', '.join(refused)} (ROADMAP.md, \"what "
                     f"the system cannot run yet\")")
         self.cfg = cfg
-        self.params = params
+        from nnstreamer_tpu.models.family import serving_params
+
+        #: the tree every program reads, and what making it did
+        #: (``serving_params``; the class docstring, "cfg, params")
+        self.params, self.weights = serving_params(cfg, params)
+        del params  # not kept: nothing below may read the given tree
         self.B = int(max_streams)
         self.S = int(max_seq or cfg.max_seq)
         self.block_tokens = int(block_tokens or 0)
@@ -480,10 +501,15 @@ class ContinuousBatchingEngine:
         #: k = next chunk index; one at a time, advanced between dispatches
         self._partial = None
 
-        if mesh is not None:
+        from nnstreamer_tpu.parallel import serve as _serve
+
+        if mesh is None:
+            # the held bytes, with the budget accountant when one is
+            # active, for as long as the engine lives
+            _serve.account_placement(self.params, "engine:lm", owner=self)
+        else:
             from jax.sharding import PartitionSpec as P
 
-            from nnstreamer_tpu.parallel import serve as _serve
             from nnstreamer_tpu.parallel.sharded import (
                 transformer_param_specs,
             )
@@ -506,7 +532,7 @@ class ContinuousBatchingEngine:
                      for k, s in transformer_param_specs(cfg).items()}
             # serving-plane placement (parallel/serve.py): per-shard HBM
             # registers with the budget accountant when one is active
-            self.params = _serve.place_params(params, mesh, specs,
+            self.params = _serve.place_params(self.params, mesh, specs,
                                               label="engine:lm")
         self._pending: "_queue.Queue[_PendingRequest]" = _queue.Queue()
         self._next_id = 0
@@ -556,6 +582,12 @@ class ContinuousBatchingEngine:
             "submit() to batch-slot admission wait",
             engine=self.obs_name)
         register_engine_collector(self)
+        log.info("serving: %s holds %d B of weights (given %d B, %d leaves "
+                 "narrowed to %s)", self.obs_name,
+                 self.weights["weight_bytes_held"],
+                 self.weights["weight_bytes_given"],
+                 self.weights["weight_leaves_narrowed"],
+                 jnp.dtype(cfg.dtype).name)
         #: request-path SLO admission (serving/scheduler.py): submit()
         #: rejects prompts whose deadline is unmeetable under the EWMA
         #: per-request service estimate; 0 = admit everything (default)
