@@ -25,7 +25,10 @@ weights from a seed, nothing downloaded):
   size through the same engine's paged path: each prompt's first token
   and eight decoded tokens must agree with the family's full forward
   over prompt + served tokens, and the pool must end with no block and
-  no state slot live;
+  no state slot live; then the same checks for the family's other
+  recurrence (**hybrid_delta**: gated delta rule mixers and gated
+  attention with 16 query over 2 key-value heads of 256, partial rotary,
+  a gated shared expert, an untied head);
 - **mesh leg** — on a host with four or more chips, the stream leg again
   with ``mesh=dp4``: the batches the pipeline staged lie two rows each on
   four distinct devices, no byte is resharded, labels equal the
@@ -586,13 +589,33 @@ def server_leg(sizes: dict, on_chip: bool) -> dict:
         unregister_engine(ENGINE_NAME)
 
 
-def hybrid_leg(sizes: dict, on_chip: bool) -> dict:
-    """The hybrid LM family (state-space + attention mixers, a share of
-    the routed experts) at a small size through the engine's paged path:
-    the first token and eight decoded tokens of each prompt against the
+#: the hybrid family's two recurrences at a small size: Mamba-2 beside
+#: plain grouped attention, and the gated delta rule beside gated attention
+#: at the head shape the paged and flash kernels are built for
+HYBRID_CONFIGS = {
+    "hybrid": dict(
+        layer_types=("mamba", "mamba", "attention", "mamba"),
+        n_heads=4, n_kv_heads=2, head_dim=64, attention_scale=0.125,
+        ssm_heads=8, ssm_head_dim=64, ssm_state=128, ssm_chunk=256),
+    "hybrid_delta": dict(
+        layer_types=("linear_attention",) * 3 + ("attention",),
+        n_heads=16, n_kv_heads=2, head_dim=256, attention_scale=0.0625,
+        rotary_dim=64, rope_theta=1e7, qk_norm=True, attn_gate=True,
+        la_key_heads=4, la_value_heads=8, la_key_dim=128, la_value_dim=128,
+        la_chunk=64, shared_gate=True, tie_embeddings=False,
+        embedding_multiplier=1.0, residual_multiplier=1.0,
+        logits_scaling=1.0, rms_eps=1e-6),
+}
+
+
+def hybrid_leg(sizes: dict, on_chip: bool, which: str = "hybrid") -> dict:
+    """The hybrid LM family (recurrent + attention mixers, a share of the
+    routed experts) at a small size through the engine's paged path: the
+    first token and eight decoded tokens of each prompt against the
     family's own full forward over prompt + served tokens — prefill, the
     hand-over of the recurrent state at the prompt's last token, and the
-    decode steps through both arenas."""
+    decode steps through both arenas. ``which``: a key of
+    :data:`HYBRID_CONFIGS`."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -601,12 +624,9 @@ def hybrid_leg(sizes: dict, on_chip: bool) -> dict:
     from nnstreamer_tpu.serving import ContinuousBatchingEngine
 
     cfg = hybrid.HybridConfig(
-        vocab=1024, d_model=256,
-        layer_types=("mamba", "mamba", "attention", "mamba"),
-        n_heads=4, n_kv_heads=2, head_dim=64, attention_scale=0.125,
-        ssm_heads=8, ssm_head_dim=64, ssm_state=128, ssm_chunk=256,
-        num_experts=8, experts_per_token=3, expert_width=128,
-        shared_width=256, experts_held=(0, 4), max_seq=512)
+        vocab=1024, d_model=256, num_experts=8, experts_per_token=3,
+        expert_width=128, shared_width=256, experts_held=(0, 4),
+        max_seq=512, **HYBRID_CONFIGS[which])
     params = hybrid.init_params(cfg, seed=0)
     new = 9
     rng = np.random.default_rng(2)
@@ -621,7 +641,7 @@ def hybrid_leg(sizes: dict, on_chip: bool) -> dict:
         worst_lp = worst_gap = 0.0
         for p, st in zip(prompts, streams):
             toks = np.asarray(st.result(timeout=600))
-            who = f"hybrid prompt of {p.size}"
+            who = f"{which} prompt of {p.size}"
             check(toks.size == new and (toks >= 0).all()
                   and (toks < cfg.vocab).all() and st.finish_reason == "length",
                   f"{who}: tokens {toks.tolist()} ({st.finish_reason})")
@@ -708,6 +728,7 @@ def run(rehearse: bool) -> dict:
     leg("stream", stream_leg, sizes, device.platform, frames_u8, reference)
     leg("server", server_leg, sizes, on_chip)
     leg("hybrid", hybrid_leg, sizes, on_chip)
+    leg("hybrid_delta", hybrid_leg, sizes, on_chip, "hybrid_delta")
     mesh = None
     if n_devices >= 4:
         # same reference as the single-device leg, so equal labels

@@ -1,8 +1,8 @@
-"""Hybrid decoder LM: state-space (Mamba-2) and attention mixers under one
-layer pattern, every layer ending in a routed expert layer plus one shared
-gated MLP — the second member of the serving engine's model family
-(``models/family.py``; the dense block of ``models/transformer.py`` is the
-first).
+"""Hybrid decoder LM: recurrent mixers (Mamba-2, or the gated delta rule of
+``models/gated_delta.py``) and attention mixers under one layer pattern,
+every layer ending in a routed expert layer plus one shared gated MLP — the
+second member of the serving engine's model family (``models/family.py``;
+the dense block of ``models/transformer.py`` is the first).
 
 What is different from the dense block, and why it is here and not a flag
 on it:
@@ -14,14 +14,20 @@ on it:
   arena in place.
 - **Two kinds of state.** An attention layer keeps keys and values in the
   paged block arena (``serving/kvpool.py``), as the dense block does, with
-  fewer key-value heads than query heads, no positional encoding and a
-  stated score scale. A state-space layer keeps, per decode lane, one
-  recurrent state ``[heads, head_dim, state]`` (float32 unless the
+  fewer key-value heads than query heads and a stated score scale; what
+  else it does is the configuration's (``rotary_dim``: rotary positions on
+  the first dims of each head, none at 0; ``qk_norm``: a norm over each
+  query and key head; ``attn_gate``: the query projection yields an output
+  gate beside the query). A recurrent layer keeps, per decode lane, one
+  recurrent state (``ssm``: ``[heads, head_dim, state]`` for Mamba-2,
+  ``[value heads, key, value]`` for the delta rule; float32 unless the
   configuration says otherwise) and the last ``conv - 1`` rows of its
-  convolution's input. Prefill computes the recurrence in chunks (the
-  state-space-duality form, PAPERS.md) and hands over the state after the
-  prompt's last real token, whatever the bucket's padding; decode is the
-  recurrence for one token, written into the arena in place.
+  convolution's input (``conv``). Prefill computes the recurrence in
+  chunks (the state-space-duality form, or the delta rule's triangular
+  solve; PAPERS.md) and hands over the state after the prompt's last real
+  token, whatever the bucket's padding; decode is the recurrence for one
+  token, written into the arena in place. A pattern holds attention and
+  ONE recurrent kind: the lanes' state arena has one shape.
 - **The expert layer holds a share** (``experts_held``): the router keeps
   its published width and its experts per token, gates are the softmax
   over the chosen experts and are not renormalised over the held ones,
@@ -30,9 +36,10 @@ on it:
   tiles, and one loop multiplies tile by tile with that tile's expert
   (``moe_ffn``): no expert computes a token that did not choose it.
 
-Parameters are a plain pytree: ``embed``, ``ln_f`` and ``layers``, a list
-with one dict per layer. Large matrices are stored in ``param_dtype``;
-norm scales, the convolution and the per-head ``A_log`` / ``dt_bias`` /
+Parameters are a plain pytree: ``embed``, ``ln_f``, ``layers`` (a list
+with one dict per layer) and, where the head is not the embedding
+(``tie_embeddings`` false), ``lm_head``. Large matrices are stored in
+``param_dtype``; norm scales, the convolution and the per-head ``A_log`` / ``dt_bias`` /
 ``D`` in float32.
 """
 
@@ -49,9 +56,14 @@ import numpy as np
 from jax import lax
 
 from nnstreamer_tpu.models.family import ModelFamily
+from nnstreamer_tpu.models.gated_delta import (
+    gated_delta_chunked,
+    gated_delta_step,
+    l2norm,
+)
 from nnstreamer_tpu.models.transformer import _attend_cache, _kv_codec
 
-MAMBA, ATTENTION = "mamba", "attention"
+MAMBA, ATTENTION, DELTA = "mamba", "attention", "linear_attention"
 #: the published pattern's period: five state-space layers, one attention
 #: layer, four state-space layers
 PERIOD = (MAMBA,) * 5 + (ATTENTION,) + (MAMBA,) * 4
@@ -67,24 +79,45 @@ class HybridConfig:
     vocab: int = 100352
     d_model: int = 4096
     layer_types: Tuple[str, ...] = PERIOD
-    # attention mixer: grouped queries, no positional encoding
+    # attention mixer: grouped queries; no positions, norms or gate
+    # unless said
     n_heads: int = 32
     n_kv_heads: int = 8
     head_dim: int = 128
     attention_scale: float = 0.0078125
+    #: rotary positions (half-split) on the first ``rotary_dim`` dims of
+    #: every query and key head; 0: none
+    rotary_dim: int = 0
+    rope_theta: float = 10000.0
+    #: an RMSNorm over each query and each key head, before the positions
+    qk_norm: bool = False
+    #: the query projection yields ``[q | gate]``; the attention's output
+    #: is multiplied by ``sigmoid(gate)`` before the output projection
+    attn_gate: bool = False
     # state-space mixer (Mamba-2, one group of B and C shared by all heads)
     ssm_heads: int = 128
     ssm_head_dim: int = 64
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # gated delta rule mixer: key heads are repeated over the value heads
+    la_key_heads: int = 16
+    la_value_heads: int = 32
+    la_key_dim: int = 128
+    la_value_dim: int = 128
+    la_conv: int = 4
+    la_chunk: int = 64
     # expert layer
     num_experts: int = 72
     experts_per_token: int = 10
     expert_width: int = 768
     shared_width: int = 1536
+    #: the shared MLP's output is multiplied by ``sigmoid(h . shared_gate)``
+    shared_gate: bool = False
     #: ``[lo, hi)``: the expert ids whose weights are held here
     experts_held: Tuple[int, int] = (0, 72)
+    #: the output head is the embedding (else a leaf of its own, ``lm_head``)
+    tie_embeddings: bool = True
     embedding_multiplier: float = 12.0
     residual_multiplier: float = 0.22
     logits_scaling: float = 16.0
@@ -96,10 +129,20 @@ class HybridConfig:
 
     def __post_init__(self):
         lo, hi = self.experts_held
-        if set(self.layer_types) != {MAMBA, ATTENTION}:
-            raise ValueError(f"HybridConfig: layer_types must hold both "
-                             f"{MAMBA!r} and {ATTENTION!r} and nothing "
-                             f"else, got {self.layer_types!r}")
+        if set(self.layer_types) not in ({MAMBA, ATTENTION},
+                                         {DELTA, ATTENTION}):
+            raise ValueError(f"HybridConfig: layer_types must hold "
+                             f"{ATTENTION!r} and one of {MAMBA!r} and "
+                             f"{DELTA!r}, and nothing else, got "
+                             f"{self.layer_types!r}")
+        if self.la_value_heads % self.la_key_heads:
+            raise ValueError(
+                f"HybridConfig: la_value_heads ({self.la_value_heads}) must "
+                f"be a multiple of la_key_heads ({self.la_key_heads})")
+        if self.rotary_dim % 2 or self.rotary_dim > self.head_dim:
+            raise ValueError(
+                f"HybridConfig: rotary_dim ({self.rotary_dim}) must be even "
+                f"and at most head_dim ({self.head_dim})")
         if self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"HybridConfig: n_heads ({self.n_heads}) must be a multiple "
@@ -118,6 +161,10 @@ class HybridConfig:
         return self.layer_types.count(MAMBA)
 
     @property
+    def la_layers(self) -> int:
+        return self.layer_types.count(DELTA)
+
+    @property
     def attn_layers(self) -> int:
         return self.layer_types.count(ATTENTION)
 
@@ -128,6 +175,19 @@ class HybridConfig:
     @property
     def conv_dim(self) -> int:
         return self.d_inner + 2 * self.ssm_state
+
+    @property
+    def la_key_width(self) -> int:
+        return self.la_key_heads * self.la_key_dim
+
+    @property
+    def la_value_width(self) -> int:
+        return self.la_value_heads * self.la_value_dim
+
+    @property
+    def la_conv_dim(self) -> int:
+        """q, k and v side by side: the channels the convolution runs over."""
+        return 2 * self.la_key_width + self.la_value_width
 
     @property
     def n_held(self) -> int:
@@ -147,11 +207,12 @@ def init_params(cfg: HybridConfig, seed: int = 0) -> Dict[str, Any]:
     """Seeded weights, each leaf made on the default device by one small
     program (nothing the size of the model passes through the host):
     normal x 0.02 for every matrix, the convolution and its bias; ones for
-    the norm scales; per head ``A_log = log(1..heads)``, ``D = 1`` and
-    ``dt_bias`` the inverse softplus of a step drawn log-uniformly from
-    [0.001, 0.1] (the family's usual initialisation)."""
+    the norm scales (the EFFECTIVE scale, where a published checkpoint
+    stores ``scale - 1``); per head of a recurrent mixer ``A_log =
+    log(1..heads)``, ``D = 1`` and ``dt_bias`` the inverse softplus of a
+    step drawn log-uniformly from [0.001, 0.1] (the families' usual
+    initialisation: heads from slow to fast decay)."""
     D, F, Fs = cfg.d_model, cfg.expert_width, cfg.shared_width
-    H = cfg.ssm_heads
     keys = map(functools.partial(jax.random.fold_in,
                                  jax.random.PRNGKey(seed % (2 ** 31 - 1))),
                itertools.count())
@@ -162,31 +223,52 @@ def init_params(cfg: HybridConfig, seed: int = 0) -> Dict[str, Any]:
     def ones(*shape):
         return jnp.ones(shape, jnp.float32)
 
+    def per_head(heads):
+        dt = jnp.exp(jax.random.uniform(
+            next(keys), (heads,), jnp.float32, np.log(1e-3), np.log(0.1)))
+        return {"dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "A_log": jnp.log(jnp.arange(1, heads + 1, dtype=jnp.float32))}
+
     layers = []
     for kind in cfg.layer_types:
         if kind == MAMBA:
-            dt = jnp.exp(jax.random.uniform(
-                next(keys), (H,), jnp.float32, np.log(1e-3), np.log(0.1)))
-            p = {"ssm_in": mat(D, 2 * cfg.d_inner + 2 * cfg.ssm_state + H),
+            H = cfg.ssm_heads
+            p = {**per_head(H),
+                 "ssm_in": mat(D, 2 * cfg.d_inner + 2 * cfg.ssm_state + H),
                  "conv_w": mat(cfg.ssm_conv, cfg.conv_dim,
                                dtype=jnp.float32),
                  "conv_b": mat(cfg.conv_dim, dtype=jnp.float32),
-                 "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-                 "A_log": jnp.log(jnp.arange(1, H + 1, dtype=jnp.float32)),
                  "D": ones(H), "norm": ones(cfg.d_inner),
                  "ssm_out": mat(cfg.d_inner, D)}
+        elif kind == DELTA:
+            p = {**per_head(cfg.la_value_heads),
+                 "la_in": mat(D, cfg.la_conv_dim + cfg.la_value_width),
+                 "la_ba": mat(D, 2 * cfg.la_value_heads),
+                 "conv_w": mat(cfg.la_conv, cfg.la_conv_dim,
+                               dtype=jnp.float32),
+                 "norm": ones(cfg.la_value_dim),
+                 "la_out": mat(cfg.la_value_width, D)}
         else:
             p = {"wq": mat(D, cfg.n_heads, cfg.head_dim),
                  "wk": mat(D, cfg.n_kv_heads, cfg.head_dim),
                  "wv": mat(D, cfg.n_kv_heads, cfg.head_dim),
                  "wo": mat(cfg.n_heads, cfg.head_dim, D)}
+            if cfg.attn_gate:
+                p["wg"] = mat(D, cfg.n_heads, cfg.head_dim)
+            if cfg.qk_norm:
+                p.update(q_norm=ones(cfg.head_dim), k_norm=ones(cfg.head_dim))
         p.update(ln1=ones(D), ln2=ones(D),
                  router=mat(D, cfg.num_experts),
                  w_in=mat(cfg.n_held, D, 2 * F),
                  w_out=mat(cfg.n_held, F, D),
                  shared_in=mat(D, 2 * Fs), shared_out=mat(Fs, D))
+        if cfg.shared_gate:
+            p["shared_gate"] = mat(D)
         layers.append(p)
-    return {"embed": mat(cfg.vocab, D), "ln_f": ones(D), "layers": layers}
+    params = {"embed": mat(cfg.vocab, D), "ln_f": ones(D), "layers": layers}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = mat(cfg.vocab, D)
+    return params
 
 
 def _rmsnorm(x, scale, eps):
@@ -287,6 +369,10 @@ def _expert_layer(x, lp, cfg: HybridConfig, live=None):
     routed, counts = moe_ffn(h, lp, cfg, live)
     with jax.named_scope("shared_ffn"):
         shared = _gated(h, lp["shared_in"], lp["shared_out"], cfg.dtype)
+        if cfg.shared_gate:
+            shared = shared * jax.nn.sigmoid(jnp.dot(
+                h, lp["shared_gate"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32))[:, None]
     y = (routed + shared).astype(cfg.dtype).reshape(b, s, d)
     return x + cfg.residual_multiplier * y, counts
 
@@ -368,6 +454,30 @@ def ssd_chunked(x, dt, a, bm, cm, chunk: int):
     return y.reshape(b, s, h, p)[:, :s_in], state
 
 
+def _conv_prefill(x, conv_w, lengths, w: int):
+    """The causal depthwise convolution of a recurrent mixer over
+    right-padded rows ``x [b, s, c]`` (``conv_w [w, c]``, no bias): ``(conv
+    [b, s, c] float32, tail [b, w-1, c])``, the tail being the last ``w -
+    1`` REAL rows of the input, zeros where the row is shorter."""
+    s = x.shape[1]
+    shifted = jnp.pad(x, ((0, 0), (w - 1, 0), (0, 0)))
+    conv = sum(shifted[:, i:i + s].astype(jnp.float32) * conv_w[i]
+               for i in range(w))
+    idx = lengths[:, None] - (w - 1) + jnp.arange(w - 1)[None, :]
+    tail = jnp.take_along_axis(x, jnp.maximum(idx, 0)[..., None], axis=1)
+    return conv, jnp.where((idx >= 0)[..., None], tail, 0)
+
+
+def _conv_decode(x, conv_w, tail, live):
+    """The same convolution for one new row a lane, over the lane's stored
+    tail: ``(conv [b, c] float32, tail)``. An empty lane reads zeros and
+    keeps what its slot holds."""
+    rows = jnp.concatenate([jnp.where(live[:, None, None], tail, 0),
+                            x[:, None]], axis=1)                # [b,w,c]
+    conv = jnp.einsum("bwc,wc->bc", rows.astype(jnp.float32), conv_w)
+    return conv, jnp.where(live[:, None, None], rows[:, 1:], tail)
+
+
 def _ssm_prefill(h, lp, lengths, cfg: HybridConfig):
     """The mixer over a whole right-padded prompt ``h [b, s, d]``:
     ``(out [b, s, d], state [b, heads, head_dim, n], tail [b, conv-1,
@@ -378,14 +488,9 @@ def _ssm_prefill(h, lp, lengths, cfg: HybridConfig):
     with jax.named_scope("ssm_in"):
         z, xbc, dt = _ssm_project(h, lp, cfg)
     with jax.named_scope("ssm_conv"):
-        shifted = jnp.pad(xbc, ((0, 0), (w - 1, 0), (0, 0)))
-        conv = sum(shifted[:, i:i + s].astype(jnp.float32) * lp["conv_w"][i]
-                   for i in range(w)) + lp["conv_b"]
-        idx = lengths[:, None] - (w - 1) + jnp.arange(w - 1)[None, :]
-        tail = jnp.take_along_axis(xbc, jnp.maximum(idx, 0)[..., None],
-                                   axis=1)
-        tail = jnp.where((idx >= 0)[..., None], tail, 0)
-        x, bm, cm, step, a = _ssm_split(jax.nn.silu(conv), dt, lp, cfg)
+        conv, tail = _conv_prefill(xbc, lp["conv_w"], lengths, w)
+        x, bm, cm, step, a = _ssm_split(jax.nn.silu(conv + lp["conv_b"]),
+                                        dt, lp, cfg)
     with jax.named_scope("ssm_scan"):
         real = jnp.arange(s)[None, :] < lengths[:, None]
         y, state = ssd_chunked(x, jnp.where(real[..., None], step, 0.0), a,
@@ -400,16 +505,12 @@ def _ssm_decode(h, lp, state, tail, live, cfg: HybridConfig):
     head_dim, n]``, ``tail [b, conv-1, conv_dim]`` → ``(out [b, d], state,
     tail)``. An empty lane (``live`` false) reads zeros and keeps what its
     slot holds."""
-    w = cfg.ssm_conv
     with jax.named_scope("ssm_in"):
         z, xbc, dt = _ssm_project(h, lp, cfg)
     with jax.named_scope("ssm_conv"):
-        rows = jnp.concatenate([jnp.where(live[:, None, None], tail, 0),
-                                xbc[:, None]], axis=1)          # [b,w,c]
-        conv = jnp.einsum("bwc,wc->bc", rows.astype(jnp.float32),
-                          lp["conv_w"]) + lp["conv_b"]
-        new_tail = jnp.where(live[:, None, None], rows[:, 1:], tail)
-        x, bm, cm, step, a = _ssm_split(jax.nn.silu(conv), dt, lp, cfg)
+        conv, new_tail = _conv_decode(xbc, lp["conv_w"], tail, live)
+        x, bm, cm, step, a = _ssm_split(jax.nn.silu(conv + lp["conv_b"]),
+                                        dt, lp, cfg)
     with jax.named_scope("ssm_update"):
         lane = live[:, None, None, None]
         old = jnp.where(lane, state.astype(jnp.float32), 0.0)
@@ -422,22 +523,131 @@ def _ssm_decode(h, lp, state, tail, live, cfg: HybridConfig):
     return out, new_state, new_tail
 
 
+# -- the gated delta rule mixer ---------------------------------------------
+
+def _la_project(h, lp, cfg: HybridConfig):
+    """``[q | k | v | z] = h . la_in`` and ``[b | a] = h . la_ba``: the
+    convolution's input (q, k and v side by side), the output gate, and
+    per value head the write strength ``sigmoid(b)`` and the log decay
+    ``-exp(A_log) softplus(a + dt_bias)``."""
+    qkvz = jnp.dot(h, lp["la_in"].astype(cfg.dtype),
+                   preferred_element_type=jnp.float32)
+    qkv, z = jnp.split(qkvz, [cfg.la_conv_dim], axis=-1)
+    b, a = jnp.split(jnp.dot(h, lp["la_ba"].astype(cfg.dtype),
+                             preferred_element_type=jnp.float32), 2, axis=-1)
+    g = -jnp.exp(lp["A_log"]) * jax.nn.softplus(a + lp["dt_bias"])
+    return qkv.astype(cfg.dtype), z, g, jax.nn.sigmoid(b)
+
+
+def _la_split(qkv, cfg: HybridConfig):
+    """The activated convolution's output as float32 ``q``, ``k [.., value
+    heads, key]`` (each key head repeated over its value heads, both of
+    length one, q scaled by ``key ** -0.5``) and ``v [.., value heads,
+    value]``."""
+    q, k, v = jnp.split(qkv.astype(jnp.float32),
+                        [cfg.la_key_width, 2 * cfg.la_key_width], axis=-1)
+    heads = q.shape[:-1] + (cfg.la_key_heads, cfg.la_key_dim)
+    group = cfg.la_value_heads // cfg.la_key_heads
+    q, k = (jnp.repeat(l2norm(x.reshape(heads)), group, axis=-2)
+            for x in (q, k))
+    return (q * cfg.la_key_dim ** -0.5, k,
+            v.reshape(v.shape[:-1] + (cfg.la_value_heads, cfg.la_value_dim)))
+
+
+def _la_finish(o, z, lp, cfg: HybridConfig):
+    """The norm over each head's values (one scale for all heads), the
+    gate, then the output projection."""
+    z = z.reshape(o.shape)
+    y = _rmsnorm(o, lp["norm"], cfg.rms_eps) * jax.nn.silu(z)
+    y = y.reshape(y.shape[:-2] + (cfg.la_value_width,)).astype(cfg.dtype)
+    return jnp.dot(y, lp["la_out"].astype(cfg.dtype),
+                   preferred_element_type=jnp.float32).astype(cfg.dtype)
+
+
+def _la_prefill(h, lp, lengths, cfg: HybridConfig):
+    """The mixer over a whole right-padded prompt ``h [b, s, d]``: ``(out
+    [b, s, d], state [b, value heads, key, value], tail [b, conv-1,
+    channels])``, as :func:`_ssm_prefill` hands them over."""
+    with jax.named_scope("la_in"):
+        qkv, z, g, beta = _la_project(h, lp, cfg)
+    with jax.named_scope("la_conv"):
+        conv, tail = _conv_prefill(qkv, lp["conv_w"], lengths, cfg.la_conv)
+        q, k, v = _la_split(jax.nn.silu(conv), cfg)
+    with jax.named_scope("la_scan"):
+        real = (jnp.arange(h.shape[1])[None, :] < lengths[:, None])[..., None]
+        o, state = gated_delta_chunked(
+            q, k, v, jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0),
+            cfg.la_chunk)
+    with jax.named_scope("la_out"):
+        out = _la_finish(o, z, lp, cfg)
+    return out, state.astype(cfg.ssm_state_dtype), tail
+
+
+def _la_decode(h, lp, state, tail, live, cfg: HybridConfig):
+    """One token for every lane, as :func:`_ssm_decode`: ``h [b, d]``,
+    ``state [b, value heads, key, value]``, ``tail [b, conv-1, channels]``
+    → ``(out [b, d], state, tail)``."""
+    with jax.named_scope("la_in"):
+        qkv, z, g, beta = _la_project(h, lp, cfg)
+    with jax.named_scope("la_conv"):
+        conv, new_tail = _conv_decode(qkv, lp["conv_w"], tail, live)
+        q, k, v = _la_split(jax.nn.silu(conv), cfg)
+    with jax.named_scope("la_update"):
+        lane = live[:, None, None, None]
+        o, new = gated_delta_step(
+            jnp.where(lane, state.astype(jnp.float32), 0.0), q, k, v, g, beta)
+        new_state = jnp.where(lane, new.astype(state.dtype), state)
+    with jax.named_scope("la_out"):
+        out = _la_finish(o, z, lp, cfg)
+    return out, new_state, new_tail
+
+
 # -- the attention mixer -----------------------------------------------------
 
-def _qkv(h, lp, dtype):
+def _rope(x, positions, rotary_dim: int, theta: float):
+    """Rotary positions on the first ``rotary_dim`` dims of each head,
+    half-split (dim ``i`` turns with dim ``i + rotary_dim / 2``); the rest
+    pass. ``x [b, s, h, c]``, ``positions [b, s]``."""
+    half = rotary_dim // 2
+    freqs = jnp.exp(-np.log(theta) * jnp.arange(half) / half)
+    angles = positions[..., None].astype(jnp.float32) * freqs
+    cos, sin = jnp.cos(angles)[:, :, None], jnp.sin(angles)[:, :, None]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:rotary_dim]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos,
+                            x32[..., rotary_dim:]], axis=-1).astype(x.dtype)
+
+
+def _qkv(h, lp, positions, cfg: HybridConfig):
+    """``(q, k, v, gate)`` of ``h [b, s, d]`` at ``positions [b, s]``:
+    the projections, then what the configuration asks of queries and keys
+    (a norm over each head, rotary positions); ``gate`` is None without
+    ``attn_gate``."""
+    dtype = cfg.dtype
     q = jnp.einsum("bsd,dhc->bshc", h, lp["wq"].astype(dtype))
     k = jnp.einsum("bsd,dhc->bshc", h, lp["wk"].astype(dtype))
     v = jnp.einsum("bsd,dhc->bshc", h, lp["wv"].astype(dtype))
-    return q, k, v
+    gate = jnp.einsum("bsd,dhc->bshc", h, lp["wg"].astype(dtype)) \
+        if cfg.attn_gate else None
+    if cfg.qk_norm:
+        q = _rmsnorm(q, lp["q_norm"], cfg.rms_eps)
+        k = _rmsnorm(k, lp["k_norm"], cfg.rms_eps)
+    if cfg.rotary_dim:
+        q = _rope(q, positions, cfg.rotary_dim, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rotary_dim, cfg.rope_theta)
+    return q, k, v, gate
 
 
-def _attn_out(a, lp, dtype):
+def _attn_out(a, gate, lp, dtype):
+    if gate is not None:
+        a = (a * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dtype)
     return jnp.einsum("bshc,hcd->bsd", a, lp["wo"].astype(dtype))
 
 
 def _logits(x, params, cfg: HybridConfig):
     x = _rmsnorm(x, params["ln_f"], cfg.rms_eps)
-    return jnp.einsum("bd,vd->bv", x, params["embed"].astype(cfg.dtype),
+    head = params["embed" if cfg.tie_embeddings else "lm_head"]
+    return jnp.einsum("bd,vd->bv", x, head.astype(cfg.dtype),
                       preferred_element_type=jnp.float32) \
         / cfg.logits_scaling
 
@@ -449,13 +659,17 @@ def _embed(params, tokens, cfg: HybridConfig):
 
 def lane_state(cfg: HybridConfig) -> Dict[str, tuple]:
     """What a decode lane holds beside its blocks: leaf -> (shape after
-    the ``[layers, lanes]`` axes, dtype), and the number of layers."""
-    return {
-        "layers": cfg.ssm_layers,
-        "ssm": ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
-                cfg.ssm_state_dtype),
-        "conv": ((cfg.ssm_conv - 1, cfg.conv_dim), cfg.dtype),
-    }
+    the ``[layers, lanes]`` axes, dtype), and the number of layers. The
+    recurrent state is ``ssm`` and the convolution's tail ``conv``,
+    whichever recurrence the pattern holds."""
+    if cfg.la_layers:
+        layers, w, channels = cfg.la_layers, cfg.la_conv, cfg.la_conv_dim
+        state = (cfg.la_value_heads, cfg.la_key_dim, cfg.la_value_dim)
+    else:
+        layers, w, channels = cfg.ssm_layers, cfg.ssm_conv, cfg.conv_dim
+        state = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
+    return {"layers": layers, "ssm": (state, cfg.ssm_state_dtype),
+            "conv": ((w - 1, channels), cfg.dtype)}
 
 
 def build_prefill(cfg: HybridConfig, max_seq: Optional[int] = None,
@@ -495,19 +709,21 @@ def _prompt_layers(params, tokens, lengths, cfg: HybridConfig,
     attn = attention_fn or attention_reference
     dtype, r = cfg.dtype, cfg.residual_multiplier
     x = _embed(params, tokens, cfg)
+    positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
     kv, ssm, conv = [], [], []
     for kind, lp in zip(cfg.layer_types, params["layers"]):
         h = _rmsnorm(x, lp["ln1"], cfg.rms_eps)
-        if kind == MAMBA:
-            out, state, tail = _ssm_prefill(h, lp, lengths, cfg)
+        if kind != ATTENTION:
+            mixer = _ssm_prefill if kind == MAMBA else _la_prefill
+            out, state, tail = mixer(h, lp, lengths, cfg)
             ssm.append(state)
             conv.append(tail)
         else:
             with jax.named_scope("qkv"):
-                q, k, v = _qkv(h, lp, dtype)
+                q, k, v, gate = _qkv(h, lp, positions, cfg)
             with jax.named_scope("attend"):
                 a = attn(q, k, v, scale=cfg.attention_scale)
-                out = _attn_out(a, lp, dtype)
+                out = _attn_out(a, gate, lp, dtype)
             kv.append(jnp.stack([k, v]))
         x = x + r * out
         x, _ = _expert_layer(x, lp, cfg)
@@ -555,19 +771,21 @@ def build_paged_decode_step(cfg: HybridConfig, block_tokens: int,
         i_ssm = i_attn = 0
         for kind, lp in zip(cfg.layer_types, params["layers"]):
             h = _rmsnorm(x, lp["ln1"], cfg.rms_eps)
-            if kind == MAMBA:
-                out, new, tail = _ssm_decode(
+            if kind != ATTENTION:
+                mixer, scope = (_ssm_decode, "ssm") if kind == MAMBA \
+                    else (_la_decode, "la")
+                out, new, tail = mixer(
                     h[:, 0], lp, state["ssm"][i_ssm], state["conv"][i_ssm],
                     live, cfg)
-                with jax.named_scope("ssm_update"):
+                with jax.named_scope(scope + "_update"):
                     state["ssm"] = state["ssm"].at[i_ssm].set(new)
-                with jax.named_scope("ssm_conv"):
+                with jax.named_scope(scope + "_conv"):
                     state["conv"] = state["conv"].at[i_ssm].set(tail)
                 out = out[:, None]
                 i_ssm += 1
             else:
                 with jax.named_scope("qkv"):
-                    q, k, v = _qkv(h, lp, dtype)                # [b,1,h,c]
+                    q, k, v, gate = _qkv(h, lp, pos_c[:, None], cfg)
                 with jax.named_scope("kv_write"):
                     pages = codec.paged_write(pages, i_attn,
                                               jnp.stack([k, v]), blk, off)
@@ -583,7 +801,7 @@ def build_paged_decode_step(cfg: HybridConfig, block_tokens: int,
                         a = _attend_cache(q, ck, cv, mask, cfg.head_dim,
                                           dtype, scale=cfg.attention_scale)
                 with jax.named_scope("attend"):
-                    out = _attn_out(a, lp, dtype)
+                    out = _attn_out(a, gate, lp, dtype)
                 i_attn += 1
             x = x + r * out
             x, c = _expert_layer(x, lp, cfg, live)
@@ -617,5 +835,6 @@ HYBRID = ModelFamily(
     # every matrix but the embedding, whose lookup (``_embed``) widens the
     # STORED rows to float32; ``init_params`` stores all of them in
     # ``param_dtype``, which is ``dtype`` unless a caller says otherwise
-    read_in_dtype=("ssm_in", "ssm_out", "wq", "wk", "wv", "wo", "router",
-                   "w_in", "w_out", "shared_in", "shared_out"))
+    read_in_dtype=("ssm_in", "ssm_out", "la_in", "la_ba", "la_out", "wq",
+                   "wk", "wv", "wo", "wg", "router", "w_in", "w_out",
+                   "shared_in", "shared_out", "shared_gate", "lm_head"))

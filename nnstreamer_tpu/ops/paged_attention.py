@@ -183,9 +183,14 @@ def _paged_decode(q, pages, layer, bt, pos_c, scale: float, chunk: int,
 def _pallas_reject(q, pages, bt) -> str | None:
     """Why these shapes cannot go to the kernel, or None when they can.
 
-    The bounds are what Mosaic (libtpu 0.0.34, for a TPU v5e) was seen to
-    compile: bfloat16 and float32 arenas with a head dim of 128, 16 query
-    over 16 key-value heads and 32 over 8, 16 tokens a block."""
+    What was proved: Mosaic (libtpu 0.0.34, for a TPU v5e) compiles the
+    kernel for bfloat16 arenas of 16 tokens a block at head dims 128 and
+    256 with 16 query over 16 key-value heads, 32 over 8 and 16 over 2
+    (groups of 1, 4 and 8; ``tests/test_paged_attention.py``), and the
+    chip served all three from it; the interpreter holds the same shapes,
+    float32 too, to the gather form. The checks below are what the layout
+    needs (whole lanes, whole sublanes), which is wider than what was
+    proved: a head dim of 384 or a group of 16 would pass them untried."""
     if not hasattr(pages, "shape") or len(pages.shape) != 6:
         return "the arena is not one [L, NTOT, 2, T, h, dh] leaf"
     b, one, hq, dh = q.shape

@@ -60,17 +60,22 @@ def test_rehearsal_runs_every_leg_on_the_cpu():
     result = json.loads(proc.stdout.strip().splitlines()[-1])
     assert "ok" not in result and "rehearsal" in result
     assert result["device"] == {"platform": "cpu", "kind": "cpu", "count": 8}
-    assert set(result["legs"]) == {"kernel", "stream", "server", "hybrid"}
+    assert set(result["legs"]) == {"kernel", "stream", "server", "hybrid",
+                                   "hybrid_delta"}
     assert result["legs"]["stream"]["staged_shards"] == [[0, 8]]
     assert result["mesh"]["spec"] == "dp4"
     assert result["mesh"]["staged_shards"] == [[i, 2] for i in range(4)]
     assert result["mesh"]["reshard_bytes"] == 0
     forward = result["legs"]["server"]["first_token_vs_forward"]
     assert forward["prompts"] == 3
-    hybrid = result["legs"]["hybrid"]
-    assert hybrid["tokens_vs_forward"]["prompts"] == 2
-    assert hybrid["tokens_vs_forward"]["tokens_each"] == 9
-    assert hybrid["moe_tokens_held"] > 0 and hybrid["state_bytes"] > 0
+    for name, state_bytes in (("hybrid", 3 * (8 * 64 * 128 * 4 + 3 * 768 * 2)),
+                              ("hybrid_delta",
+                               3 * (8 * 128 * 128 * 4 + 3 * 2048 * 2))):
+        hybrid = result["legs"][name]
+        assert hybrid["tokens_vs_forward"]["prompts"] == 2
+        assert hybrid["tokens_vs_forward"]["tokens_each"] == 9
+        assert hybrid["moe_tokens_held"] > 0
+        assert hybrid["state_bytes"] == 4 * state_bytes  # four lanes
     assert result["compile_cache"]["dir"] is None and result["claim"] is None
 
 

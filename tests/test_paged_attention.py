@@ -8,7 +8,8 @@ is how these tests hold it to the gather form: the same numbers
 call of its own and inside the engine's K-step decode dispatch. What
 ``auto`` builds is the gather form wherever the kernel does not run: off a
 TPU, under a mesh, for an int8 arena and for the chunk builder. One test
-compiles the kernel at the two cells' widths for a described TPU v5e.
+compiles the kernel at the three cells' widths for a described TPU v5e:
+head dims 128 and 256, groups of 1, 4 and 8 query heads a key-value head.
 """
 
 import functools
@@ -43,13 +44,13 @@ T, MB, DH, LAYERS = 8, 12, 128, 2
 HELD = (1, T - 1, T, T + 1, (MB * T) // 2 + 3, MB * T - 1, 0)
 
 
-def _pool(hk, dtype, seed):
+def _pool(hk, dtype, seed, dh=DH):
     """A scrambled pool: every lane's blocks anywhere in the arena, the
     zero block last, unallocated table entries at the sentinel."""
     rng = np.random.default_rng(seed)
     lanes = len(HELD)
     nb = lanes * MB
-    pages = rng.standard_normal((LAYERS, nb + 1, 2, T, hk, DH))
+    pages = rng.standard_normal((LAYERS, nb + 1, 2, T, hk, dh))
     pages[:, nb] = 0.0
     order = rng.permutation(nb).reshape(lanes, MB)
     bt = np.full((lanes, MB), nb + 1, np.int32)
@@ -65,7 +66,7 @@ def _gather_form(q, pages, layer, bt, pos, scale):
     g = _paged_gather(pages, layer, bt)
     mask = jnp.arange(MB * T)[None, None, None, :] <= pos[:, None, None,
                                                           None]
-    return _attend_cache(q, g[:, 0], g[:, 1], mask, DH, q.dtype,
+    return _attend_cache(q, g[:, 0], g[:, 1], mask, q.shape[-1], q.dtype,
                          scale=scale)
 
 
@@ -73,12 +74,13 @@ def _gather_form(q, pages, layer, bt, pos, scale):
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
                                        (jnp.bfloat16, 2e-2)],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("hq,hk,scale", [(16, 16, None), (32, 8, 0.3)],
-                         ids=["16x16", "32over8"])
+@pytest.mark.parametrize("hq,hk,scale,dh", [
+    (16, 16, None, 128), (32, 8, 0.3, 128), (16, 2, 0.0625, 256)],
+    ids=["16x16", "32over8", "16over2_dh256"])
 def test_kernel_equals_attend_cache_over_the_gathered_table(
-        hq, hk, scale, dtype, tol, chunk):
-    pages, bt, pos, rng = _pool(hk, dtype, seed=hq + chunk)
-    q = jnp.asarray(rng.standard_normal((len(HELD), 1, hq, DH)), dtype)
+        hq, hk, scale, dh, dtype, tol, chunk):
+    pages, bt, pos, rng = _pool(hk, dtype, seed=hq + chunk, dh=dh)
+    q = jnp.asarray(rng.standard_normal((len(HELD), 1, hq, dh)), dtype)
     before = np.asarray(pages, np.float32)
     for layer in range(LAYERS):
         want = _gather_form(q, pages, layer, bt, pos, scale)
@@ -241,12 +243,13 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("lanes,hq,hk,mb,layers,ntot", [
-    (8, 16, 16, 128, 24, 1025),        # pythia_chat_closed
-    (64, 32, 8, 64, 1, 4097),          # granite_h_chat_closed
-], ids=["pythia_1p4b", "granite_4p0_h_small_ep2"])
+@pytest.mark.parametrize("lanes,hq,hk,dh,mb,layers,ntot", [
+    (8, 16, 16, 128, 128, 24, 1025),    # pythia_chat_closed
+    (64, 32, 8, 128, 64, 1, 4097),      # granite_h_chat_closed
+    (128, 16, 2, 256, 128, 1, 16385),   # qwen3next_chat_closed
+], ids=["pythia_1p4b", "granite_4p0_h_small_ep2", "qwen3_next_80b_a3b_ep2"])
 def test_mosaic_compiles_the_kernel_at_the_cells_widths(
-        one_chip, lanes, hq, hk, mb, layers, ntot):
+        one_chip, lanes, hq, hk, dh, mb, layers, ntot):
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
@@ -257,8 +260,8 @@ def test_mosaic_compiles_the_kernel_at_the_cells_widths(
     try:
         text = jax.jit(functools.partial(
             _paged_decode, scale=0.1, chunk=8, interpret=False)).lower(
-            shape((lanes, hq, 128), jnp.bfloat16),
-            shape((layers, ntot, 2, 16, hk, 128), jnp.bfloat16),
+            shape((lanes, hq, dh), jnp.bfloat16),
+            shape((layers, ntot, 2, 16, hk, dh), jnp.bfloat16),
             shape((), jnp.int32), shape((lanes, mb), jnp.int32),
             shape((lanes,), jnp.int32)).compile().as_text()
     finally:
