@@ -9,7 +9,10 @@ weights from a seed, nothing downloaded):
 
 - **kernel leg** — the three Pallas kernels compiled by Mosaic (never
   interpreted) at the shapes the other legs and ``bench.py attn`` use,
-  compared on the chip with their ``force="reference"`` results;
+  compared on the chip with their ``force="reference"`` results; and the
+  routed experts' grouped matmul (``nns_expert_tiles``) against the tile
+  loop at both benchmark configurations' real expert shapes, the decode
+  tile and the largest prefill tile of each;
 - **stream leg** — MobileNetV2 (width 1.0, 224², bf16, batch 8) through
   ``parse_launch`` with the flagship topology; every label must equal the
   argmax of a plain ``jax.jit`` of the same model on the same frames, and
@@ -112,12 +115,20 @@ FULL = dict(
     # per client; buckets hit: 16, 64, 256, 512 (min_bucket 16, x2 steps)
     prompts=((12, 300), (40, 200), (9, 260)),
     hybrid_prompts=(12, 200, 300),
-    flash_shapes=((1, 256, 8, 64), (4, 4096, 8, 64)))
+    flash_shapes=((1, 256, 8, 64), (4, 4096, 8, 64)),
+    # (experts per token, experts, held, d, f), then tokens a call: 128
+    # lanes and a 512-token prompt of qwen3_next_80b_a3b_ep2 (tiles of 8
+    # and 32 rows), 64 lanes and a 512-token prompt of
+    # granite_4p0_h_small_ep2 (32 and 128)
+    expert_shapes=(((10, 512, 256, 2048, 512), (128, 512)),
+                   ((10, 72, 36, 4096, 768), (64, 512))))
 REHEARSAL = dict(
     frames=16, lm_layers=2, max_new=4,
     prompts=((12, 260), (40,)),
     hybrid_prompts=(12, 40),
-    flash_shapes=((1, 256, 8, 64),))
+    flash_shapes=((1, 256, 8, 64),),
+    expert_shapes=(((4, 64, 32, 256, 128), (16,)),
+                   ((4, 16, 8, 256, 256), (64,))))
 
 
 class SmokeFailure(AssertionError):
@@ -245,7 +256,81 @@ def kernel_leg(sizes: dict, on_chip: bool) -> dict:
                   ) / float(scale[0])
     check(steps <= 1.001, f"quantize_int8: {steps} steps from reference")
     out["quantize_max_steps_from_reference"] = round(steps, 6)
+    out["expert_tiles"] = [
+        case for layer, calls in sizes["expert_shapes"]
+        for case in _expert_tiles_cases(layer, calls, on_chip)]
     return out
+
+
+def _expert_tiles_cases(layer: tuple, calls: tuple, on_chip: bool):
+    """The grouped matmul against the tile loop, one routed batch an entry
+    of ``calls``: ``tokens`` tokens choose ``k`` of ``experts`` at random,
+    the pairs on the ``held`` first experts are laid out as ``moe_ffn``
+    lays them out, and both forms run the same buffer through the same
+    bfloat16 weights (one draw for all of ``calls``). The two differ in
+    the order of float32 sums alone, so a row may move by one rounding of
+    ``silu(a) * b`` to bfloat16: 2**-7 of the largest output."""
+    import jax
+    import jax.numpy as jnp
+
+    k, experts, held, d, f = layer
+    key = jax.random.PRNGKey(held)
+    w_in = jax.random.normal(key, (held, d, 2 * f), jnp.bfloat16) * 0.02
+    w_out = jax.random.normal(jax.random.fold_in(key, 1), (held, f, d),
+                              jnp.bfloat16) * 0.02
+    for tokens in calls:
+        yield _expert_tiles_case(tokens, k, experts, held, w_in, w_out,
+                                 on_chip)
+
+
+def _expert_tiles_case(tokens, k, experts, held, w_in, w_out, on_chip):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from nnstreamer_tpu.models.hybrid import HybridConfig, expert_tile
+    from nnstreamer_tpu.ops.grouped_matmul import expert_tiles
+
+    d = w_in.shape[1]
+    tile, rows = expert_tile(HybridConfig(
+        num_experts=experts, experts_per_token=k, experts_held=(0, held)),
+        tokens)
+    rng = np.random.default_rng(tokens)
+    choice = np.stack([rng.permutation(experts)[:k] for _ in range(tokens)])
+    counts = np.bincount(choice[choice < held], minlength=held)
+    padded = -(-counts // tile) * tile
+    ends = np.cumsum(padded)
+    real = np.zeros(rows, bool)
+    for start, n in zip(ends - padded, counts):
+        real[start:start + n] = True
+    tile_expert = jnp.asarray(np.minimum(
+        (ends[None, :] <= (np.arange(rows // tile) * tile)[:, None]).sum(1),
+        held - 1), jnp.int32)
+    n_live = jnp.int32(ends[-1] // tile)
+    x = jnp.where(jnp.asarray(real)[:, None], jax.random.normal(
+        jax.random.PRNGKey(tokens), (rows, d), jnp.bfloat16), 0)
+    what = f"expert_tiles {tokens} tokens through {tuple(w_in.shape)}"
+    if on_chip:
+        check(_mosaic_compiled(
+            lambda *a: expert_tiles(*a, tile, force="pallas"),
+            x, tile_expert, n_live, w_in, w_out),
+            f"{what}: no Mosaic call in the program")
+    got = expert_tiles(x, tile_expert, n_live, w_in, w_out, tile,
+                       force="pallas")
+    ref = expert_tiles(x, tile_expert, n_live, w_in, w_out, tile,
+                       force="reference")
+    err = float(jnp.max(jnp.abs(got - ref)))
+    size = float(jnp.max(jnp.abs(ref)))
+    check(got.shape == (rows, d) and got.dtype == jnp.float32,
+          f"{what}: got {got.shape} {got.dtype}")
+    check(size > 0 and err <= 2.0 ** -7 * size,
+          f"{what}: max |kernel - tile loop| = {err} against outputs of "
+          f"{size}")
+    check(not bool(jnp.any(got[int(n_live) * tile:])),
+          f"{what}: rows past the live tiles are not zero")
+    return {"tokens": tokens, "w_in": list(w_in.shape), "tile": tile,
+            "tiles_grid": rows // tile, "tiles_live": int(n_live),
+            "max_abs_delta": err, "max_abs_out": size}
 
 
 # --------------------------------------------------------------------------
@@ -667,6 +752,7 @@ def hybrid_leg(sizes: dict, on_chip: bool, which: str = "hybrid") -> dict:
             "max_logprob_diff": round(worst_lp, 5),
             "max_gap_to_argmax": round(worst_gap, 5)},
             "state_bytes": snap["state_bytes"],
+            "expert_matmul": engine.expert_matmul,
             "moe_tokens_held": int(engine.stats["moe_tokens_held"]),
             "moe_tokens_absent": int(engine.stats["moe_tokens_absent"])}
     finally:

@@ -15,7 +15,7 @@ tree a server's programs will read from the tree it was given.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,6 +44,10 @@ class ModelFamily:
     lane_state: Callable = lambda cfg: None
     #: names of the per-step counts the decode step returns
     counters: Tuple[str, ...] = ()
+    #: ``expert_matmul(cfg, tokens) -> form``: the form the family's
+    #: routed experts run in for that many tokens a call
+    #: (``ops/grouped_matmul.py``); None for a family with none
+    expert_matmul: Optional[Callable] = None
     #: names (a leaf's own key in the parameter tree) of the leaves every
     #: program of the family reads as ``leaf.astype(cfg.dtype)`` and in no
     #: other width; what a program reads as stored (norm scales, a head's

@@ -33,8 +33,10 @@ on it:
   over the chosen experts and are not renormalised over the held ones,
   and what the absent experts would add is left out. The tokens routed to
   held experts are sorted by expert, each expert's rows padded to whole
-  tiles, and one loop multiplies tile by tile with that tile's expert
-  (``moe_ffn``): no expert computes a token that did not choose it.
+  tiles, and the tiles go through their experts as one grouped matmul
+  (``moe_ffn``, ``ops/grouped_matmul.py``: a Pallas grid over the sorted
+  tiles on a TPU, a loop tile by tile elsewhere): no expert computes a
+  token that did not choose it.
 
 Parameters are a plain pytree: ``embed``, ``ln_f``, ``layers`` (a list
 with one dict per layer) and, where the head is not the embedding
@@ -62,15 +64,20 @@ from nnstreamer_tpu.models.gated_delta import (
     l2norm,
 )
 from nnstreamer_tpu.models.transformer import _attend_cache, _kv_codec
+from nnstreamer_tpu.ops import grouped_matmul
 
 MAMBA, ATTENTION, DELTA = "mamba", "attention", "linear_attention"
 #: the published pattern's period: five state-space layers, one attention
 #: layer, four state-space layers
 PERIOD = (MAMBA,) * 5 + (ATTENTION,) + (MAMBA,) * 4
 #: what the decode step counts of its expert layers, summed over layers
-#: and steps (``engine.stats``; live lanes only)
+#: and steps (``engine.stats``; live lanes only). The last two: the tiles
+#: of the sorted buffer that hold a row, and the tiles the buffer has (the
+#: grouped matmul's grid: their quotient is the share of its steps that
+#: move weights)
 COUNTERS = ("moe_tokens_held", "moe_tokens_absent", "moe_expert_load_max",
-            "moe_experts_hit", "moe_layer_steps")
+            "moe_experts_hit", "moe_layer_steps", "moe_tiles_live",
+            "moe_tiles_grid")
 _HI = lax.Precision.HIGHEST
 
 
@@ -277,12 +284,30 @@ def _rmsnorm(x, scale, eps):
     return (x32 * lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
-def _gated(h, w_in, w_out, dtype):
-    """``(silu(a) * b) . w_out`` with ``[a | b] = h . w_in``."""
-    ab = jnp.dot(h, w_in.astype(dtype), preferred_element_type=jnp.float32)
-    a, b = jnp.split(ab, 2, axis=-1)
-    return jnp.dot((jax.nn.silu(a) * b).astype(dtype), w_out.astype(dtype),
-                   preferred_element_type=jnp.float32)
+_gated = grouped_matmul.gated_mlp
+
+
+def expert_tile(cfg: HybridConfig, t: int) -> Tuple[int, int]:
+    """``(tile, rows)`` of the expert-sorted buffer for ``t`` tokens. An
+    expert gets ``t * k / num_experts`` rows on average and the fullest
+    about twice that: a tile of the next power of two (8 to 128 rows)
+    takes an expert in one pass, so its weights are read once; ``rows``
+    holds every pair with each held expert padded to whole tiles."""
+    pairs = t * cfg.experts_per_token
+    most = -(-2 * pairs // cfg.num_experts)
+    tile = min(128, max(8, 1 << (most - 1).bit_length()))
+    return tile, -(-(pairs + cfg.n_held * (tile - 1)) // tile) * tile
+
+
+def expert_matmul(cfg: HybridConfig, t: int) -> str:
+    """The form :func:`moe_ffn` runs ``t`` tokens' tiles in here:
+    ``"grouped_kernel"`` or ``"tile_loop"`` (``ops/grouped_matmul.py``)."""
+    tile, rows = expert_tile(cfg, t)
+    d, f = cfg.d_model, cfg.expert_width
+    return grouped_matmul.expert_matmul_form(
+        jax.ShapeDtypeStruct((rows, d), cfg.dtype),
+        jax.ShapeDtypeStruct((cfg.n_held, d, 2 * f), cfg.dtype),
+        jax.ShapeDtypeStruct((cfg.n_held, f, d), cfg.dtype), tile)
 
 
 def moe_ffn(h, lp, cfg: HybridConfig, live=None):
@@ -292,11 +317,11 @@ def moe_ffn(h, lp, cfg: HybridConfig, live=None):
     Routing is over all ``num_experts`` router outputs; a token's gates
     are the softmax over its ``experts_per_token`` largest logits. The
     (token, choice) pairs that fell on a held expert are sorted by expert,
-    each expert's rows start on a tile boundary of a padded buffer, and a
-    loop with a data-dependent trip count runs one tile at a time through
-    that tile's expert: the work follows the routing, and an expert nobody
-    chose is never read. ``live [t]`` masks tokens out of the routing
-    (empty decode lanes). ``counts``: int32 scalars named as
+    each expert's rows start on a tile boundary of a padded buffer, and
+    the tiles that hold a row go one by one through their tile's expert
+    (``grouped_matmul.expert_tiles``): the work follows the routing, and
+    an expert nobody chose is never read. ``live [t]`` masks tokens out of
+    the routing (empty decode lanes). ``counts``: int32 scalars named as
     :data:`COUNTERS`."""
     t, d = h.shape
     k, (lo, hi), n_held = cfg.experts_per_token, cfg.experts_held, cfg.n_held
@@ -311,13 +336,8 @@ def moe_ffn(h, lp, cfg: HybridConfig, live=None):
         if live is not None:
             held, absent = held & live[:, None], absent & live[:, None]
     with jax.named_scope("experts"):
-        # an expert gets t * k / num_experts rows on average and the
-        # fullest about twice that: a tile of the next power of two (8 to
-        # 128 rows) takes an expert in one pass, so its weights are read once
         pairs = t * k
-        most = -(-2 * pairs // cfg.num_experts)
-        tile = min(128, max(8, 1 << (most - 1).bit_length()))
-        rows = -(-(pairs + n_held * (tile - 1)) // tile) * tile
+        tile, rows = expert_tile(cfg, t)
         # sort the pairs by held expert; n_held stands for "not held here"
         expert = jnp.where(held, choice - lo, n_held).reshape(pairs)
         order = jnp.argsort(expert, stable=True)
@@ -337,15 +357,9 @@ def moe_ffn(h, lp, cfg: HybridConfig, live=None):
         tile_expert = jnp.minimum(jnp.sum(
             ends[None, :] <= (jnp.arange(rows // tile) * tile)[:, None],
             axis=1), n_held - 1)
-
-        def one_tile(i, out):
-            w = tile_expert[i]
-            y = _gated(lax.dynamic_slice_in_dim(x, i * tile, tile),
-                       lp["w_in"][w], lp["w_out"][w], dtype)
-            return lax.dynamic_update_slice_in_dim(out, y, i * tile, 0)
-
-        out = lax.fori_loop(0, ends[-1] // tile, one_tile,
-                            jnp.zeros((rows, d), jnp.float32))
+        tiles_live = ends[-1] // tile
+        out = grouped_matmul.expert_tiles(x, tile_expert, tiles_live,
+                                          lp["w_in"], lp["w_out"], tile)
         # back to the tokens: each pair's row, weighted by its gate
         dest = jnp.zeros(pairs, jnp.int32).at[order].set(dest_s)
         picked = out[jnp.minimum(dest, rows - 1)].reshape(t, k, d)
@@ -356,6 +370,8 @@ def moe_ffn(h, lp, cfg: HybridConfig, live=None):
         "moe_expert_load_max": jnp.max(counts),
         "moe_experts_hit": jnp.sum(counts > 0, dtype=jnp.int32),
         "moe_layer_steps": jnp.int32(1),
+        "moe_tiles_live": tiles_live.astype(jnp.int32),
+        "moe_tiles_grid": jnp.int32(rows // tile),
     }
 
 
@@ -831,7 +847,7 @@ HYBRID = ModelFamily(
     name="hybrid", init_params=init_params, build_prefill=build_prefill,
     build_paged_decode_step=build_paged_decode_step,
     kv_layout=lambda cfg: (cfg.attn_layers, cfg.n_kv_heads, cfg.head_dim),
-    lane_state=lane_state, counters=COUNTERS,
+    lane_state=lane_state, counters=COUNTERS, expert_matmul=expert_matmul,
     # every matrix but the embedding, whose lookup (``_embed``) widens the
     # STORED rows to float32; ``init_params`` stores all of them in
     # ``param_dtype``, which is ``dtype`` unless a caller says otherwise

@@ -10,6 +10,7 @@ kernel level.
 """
 
 from nnstreamer_tpu.ops.flash_attention import flash_attention
+from nnstreamer_tpu.ops.grouped_matmul import expert_tiles
 from nnstreamer_tpu.ops.paged_attention import paged_attention
 from nnstreamer_tpu.ops.preprocess import normalize_u8
 from nnstreamer_tpu.ops.quantize import dequantize_int8, quantize_int8
@@ -17,6 +18,7 @@ from nnstreamer_tpu.ops.quantize import dequantize_int8, quantize_int8
 __all__ = [
     "flash_attention",
     "paged_attention",
+    "expert_tiles",
     "normalize_u8",
     "quantize_int8",
     "dequantize_int8",

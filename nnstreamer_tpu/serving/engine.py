@@ -641,6 +641,15 @@ class ContinuousBatchingEngine:
                 jax.ShapeDtypeStruct(
                     (self.B, 1, cfg.n_heads, kv.shape[-1]), kv.dtype),
                 kv, self._bt)
+        #: the form the decode program runs its routed experts in:
+        #: "grouped_kernel" (ops/grouped_matmul.py: a Pallas grid over the
+        #: sorted tiles) or "tile_loop"; None for a family without experts
+        self.expert_matmul = None
+        if family.expert_matmul is not None:
+            self.expert_matmul = family.expert_matmul(cfg, self.B)
+            self.stats["expert_matmul"] = self.expert_matmul
+            log.info("serving: %s runs its routed experts as %s",
+                     self.obs_name, self.expert_matmul)
         self.prefix_cache = int(prefix_cache)
         if self.prefix_cache < 0:
             raise ValueError(

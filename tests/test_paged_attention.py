@@ -9,7 +9,8 @@ call of its own and inside the engine's K-step decode dispatch. What
 ``auto`` builds is the gather form wherever the kernel does not run: off a
 TPU, under a mesh, for an int8 arena and for the chunk builder. One test
 compiles the kernel at the three cells' widths for a described TPU v5e:
-head dims 128 and 256, groups of 1, 4 and 8 query heads a key-value head.
+head dims 128 and 256, groups of 1, 4 and 8 query heads a key-value head;
+another the routed experts' kernel at both expert cells' shapes.
 """
 
 import functools
@@ -272,3 +273,41 @@ def test_mosaic_compiles_the_kernel_at_the_cells_widths(
     arena = f"bf16[{layers},{ntot},2,"
     assert not [line for line in text.splitlines()
                 if " copy(" in line and arena in line.split(" copy(")[0]]
+
+
+@pytest.mark.parametrize("n_held,d,f,tile,rows", [
+    (256, 2048, 512, 8, 3072),      # qwen3next_chat_closed, decode
+    (256, 2048, 512, 32, 13056),    # ... its 512-token prefill
+    (36, 4096, 768, 32, 1760),      # granite_h_chat_closed, decode
+    (36, 4096, 768, 128, 9728),     # ... its 512-token prefill
+], ids=["qwen3_next_decode", "qwen3_next_prefill", "granite_decode",
+        "granite_prefill"])
+def test_mosaic_compiles_the_expert_kernel_at_the_cells_widths(
+        one_chip, n_held, d, f, tile, rows):
+    """The routed experts' grouped matmul (``ops/grouped_matmul.py``; here
+    because a process describes the chip once, and this file does) with
+    the blocks its own rule gives for a v5e: whole experts, no copy of a
+    weight stack on the way in."""
+    from nnstreamer_tpu.ops import grouped_matmul as gm
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    f_chunk, limit = gm.expert_blocks(d, f, tile, jnp.bfloat16)
+    assert f_chunk == f
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = gm._expert_tiles.lower(
+            shape((rows, d), jnp.bfloat16), shape((rows // tile,), jnp.int32),
+            shape((), jnp.int32), shape((n_held, d, 2 * f), jnp.bfloat16),
+            shape((n_held, f, d), jnp.bfloat16), tile=tile, f_chunk=f_chunk,
+            vmem_limit_bytes=limit, interpret=False).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in text and "nns_expert_tiles" in text
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and f"bf16[{n_held}," in line]
