@@ -34,8 +34,16 @@ class ModelFamily:
     #: ``paged_attention_fn`` is ``ops.paged_attention`` or None (the
     #: gather form)
     build_paged_decode_step: Callable
-    #: ``kv_layout(cfg) -> (layers, heads, head_dim)`` of the block arena
-    kv_layout: Callable
+    #: ``kv_entry(cfg) -> (layers, parts, shape)``: what the block arena
+    #: holds for one token of one layer, ``parts`` arrays of ``shape``:
+    #: keys and values per head are ``(layers, 2, (heads, head_dim))``, one
+    #: latent row shared by every head ``(layers, 1, (width,))``. The
+    #: arena leaf is ``[layers, blocks, parts, T, *shape]``
+    kv_entry: Callable
+    #: ``latent_value_width(cfg)``: for a one-part entry, how many of the
+    #: row's first columns are the value (``ops.paged_attention``
+    #: ``v_width``); None for keys and values per head
+    latent_value_width: Callable = lambda cfg: None
     #: ``lane_state(cfg) -> None``, or what each decode lane holds beside
     #: its blocks: ``{"layers": n, leaf: (shape, dtype), ...}``. A family
     #: with lane state is served on the paged path only, a stream keeps
@@ -48,6 +56,19 @@ class ModelFamily:
     #: routed experts run in for that many tokens a call
     #: (``ops/grouped_matmul.py``); None for a family with none
     expert_matmul: Optional[Callable] = None
+    #: the engine options the family's programs bring, of ``prefix_cache``,
+    #: ``speculate``, ``prefill_chunk``, ``kv_quant`` and ``mesh``; any
+    #: other is refused at construction with ``refusal``, which says what
+    #: the family lacks for them and where ``ROADMAP.md`` queues it
+    brings: Tuple[str, ...] = ()
+    refusal: str = ""
+    #: ``build_chunk_decode(cfg, max_seq, kv_codec=)`` and
+    #: ``build_paged_chunk(cfg, block_tokens, max_seq, kv_codec=)``:
+    #: several query positions a lane (chunked ingestion, prefix
+    #: extension, speculation's verification); None where ``brings`` names
+    #: none of them
+    build_chunk_decode: Optional[Callable] = None
+    build_paged_chunk: Optional[Callable] = None
     #: names (a leaf's own key in the parameter tree) of the leaves every
     #: program of the family reads as ``leaf.astype(cfg.dtype)`` and in no
     #: other width; what a program reads as stored (norm scales, a head's

@@ -123,6 +123,11 @@ class HybridConfig:
     shared_gate: bool = False
     #: ``[lo, hi)``: the expert ids whose weights are held here
     experts_held: Tuple[int, int] = (0, 72)
+    #: gates are the softmax over the chosen experts' logits; false: the
+    #: softmax over ALL router outputs, taken at the chosen and not
+    #: renormalised, times ``routed_scaling_factor``
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
     #: the output head is the embedding (else a leaf of its own, ``lm_head``)
     tie_embeddings: bool = True
     embedding_multiplier: float = 12.0
@@ -315,7 +320,9 @@ def moe_ffn(h, lp, cfg: HybridConfig, live=None):
     ``h [t, d]`` (already normed): ``(out [t, d] float32, counts)``.
 
     Routing is over all ``num_experts`` router outputs; a token's gates
-    are the softmax over its ``experts_per_token`` largest logits. The
+    are the softmax over its ``experts_per_token`` largest logits (or,
+    where the configuration says ``norm_topk_prob`` false, the softmax
+    over all of them at the chosen, times ``routed_scaling_factor``). The
     (token, choice) pairs that fell on a held expert are sorted by expert,
     each expert's rows start on a tile boundary of a padded buffer, and
     the tiles that hold a row go one by one through their tile's expert
@@ -330,7 +337,12 @@ def moe_ffn(h, lp, cfg: HybridConfig, live=None):
         logits = jnp.dot(h, lp["router"].astype(dtype),
                          preferred_element_type=jnp.float32)
         top, choice = lax.top_k(logits, k)                       # [t, k]
-        gates = jax.nn.softmax(top, axis=-1)
+        if cfg.norm_topk_prob:
+            gates = jax.nn.softmax(top, axis=-1)
+        else:
+            gates = jnp.take_along_axis(jax.nn.softmax(logits, axis=-1),
+                                        choice, axis=-1) \
+                * cfg.routed_scaling_factor
         held = (choice >= lo) & (choice < hi)
         absent = ~held
         if live is not None:
@@ -377,11 +389,12 @@ def moe_ffn(h, lp, cfg: HybridConfig, live=None):
 
 def _expert_layer(x, lp, cfg: HybridConfig, live=None):
     """``x + r . (moe(h) + shared(h))`` with one norm for both branches;
-    ``x [b, s, d]``."""
+    ``x [b, s, d]``. ``live``: the rows that are routed, ``[b]`` (a lane's
+    every token) or ``[b, s]`` (token by token: a prompt's padding)."""
     b, s, d = x.shape
     h = _rmsnorm(x, lp["ln2"], cfg.rms_eps).reshape(b * s, d)
     if live is not None:
-        live = jnp.repeat(live, s)
+        live = jnp.repeat(live, s) if live.ndim == 1 else live.reshape(b * s)
     routed, counts = moe_ffn(h, lp, cfg, live)
     with jax.named_scope("shared_ffn"):
         shared = _gated(h, lp["shared_in"], lp["shared_out"], cfg.dtype)
@@ -620,12 +633,15 @@ def _la_decode(h, lp, state, tail, live, cfg: HybridConfig):
 
 # -- the attention mixer -----------------------------------------------------
 
-def _rope(x, positions, rotary_dim: int, theta: float):
+def _rope(x, positions, rotary_dim: int, theta: float, freqs=None):
     """Rotary positions on the first ``rotary_dim`` dims of each head,
     half-split (dim ``i`` turns with dim ``i + rotary_dim / 2``); the rest
-    pass. ``x [b, s, h, c]``, ``positions [b, s]``."""
+    pass. ``x [b, s, h, c]``, ``positions [b, s]``. ``freqs
+    [rotary_dim / 2]``: the angle a position turns each pair by, where it
+    is not ``theta ** (-2 i / rotary_dim)`` (scaled frequencies)."""
     half = rotary_dim // 2
-    freqs = jnp.exp(-np.log(theta) * jnp.arange(half) / half)
+    if freqs is None:
+        freqs = jnp.exp(-np.log(theta) * jnp.arange(half) / half)
     angles = positions[..., None].astype(jnp.float32) * freqs
     cos, sin = jnp.cos(angles)[:, :, None], jnp.sin(angles)[:, :, None]
     x32 = x.astype(jnp.float32)
@@ -846,8 +862,11 @@ def build_forward(cfg: HybridConfig) -> Callable:
 HYBRID = ModelFamily(
     name="hybrid", init_params=init_params, build_prefill=build_prefill,
     build_paged_decode_step=build_paged_decode_step,
-    kv_layout=lambda cfg: (cfg.attn_layers, cfg.n_kv_heads, cfg.head_dim),
+    kv_entry=lambda cfg: (cfg.attn_layers, 2,
+                          (cfg.n_kv_heads, cfg.head_dim)),
     lane_state=lane_state, counters=COUNTERS, expert_matmul=expert_matmul,
+    refusal="keeps recurrent state per decode lane, which nothing can copy, "
+            "share, shard or narrow yet (ROADMAP.md R3; mesh: R2)",
     # every matrix but the embedding, whose lookup (``_embed``) widens the
     # STORED rows to float32; ``init_params`` stores all of them in
     # ``param_dtype``, which is ``dtype`` unless a caller says otherwise

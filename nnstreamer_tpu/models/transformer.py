@@ -136,7 +136,8 @@ def _attend_cache(q, ck, cv, mask, head_dim, dtype, scale=None):
 
     ``scale`` defaults to ``head_dim ** -0.5``. A cache with fewer heads
     than ``q`` is grouped-query attention: query head ``i`` reads
-    key-value head ``i // (query heads / key-value heads)``."""
+    key-value head ``i // (query heads / key-value heads)``; values may
+    be narrower or wider than keys."""
     scale = head_dim ** -0.5 if scale is None else scale
     if q.shape[2] != ck.shape[2]:
         b, nq, hq, c = q.shape
@@ -149,7 +150,7 @@ def _attend_cache(q, ck, cv, mask, head_dim, dtype, scale=None):
             jnp.where(mask[:, :, None], scores, -1e30), axis=-1)
         return jnp.einsum("bkgqs,bskc->bqkgc", probs,
                           cv.astype(jnp.float32)).reshape(
-                              b, nq, hq, c).astype(dtype)
+                              b, nq, hq, cv.shape[-1]).astype(dtype)
     scores = jnp.einsum("bqhc,bshc->bhqs", q.astype(jnp.float32),
                         ck.astype(jnp.float32))
     scores = scores * scale
@@ -298,12 +299,13 @@ class _RawKVCodec:
         return jax.lax.dynamic_update_slice(
             cache, kv.astype(self.dtype), (0, 0, 0, 0, 0, 0))
 
-    def paged_init(self, L, ntot, T, h, dh):
-        """Paged arena [L, NTOT, 2, T, h, dh]: ONE buffer that the paged
+    def paged_init(self, L, ntot, T, *entry, parts=2):
+        """Paged arena [L, NTOT, parts, T, *entry] (keys and values per
+        head: parts 2, entry ``h, dh``): ONE buffer that the paged
         builders address whole, the layer one more index beside the block
         (serving/kvpool.py owns allocation; index NTOT-1 of every layer
         is the permanent zero block)."""
-        return jnp.zeros((L, ntot, 2, T, h, dh), self.dtype)
+        return jnp.zeros((L, ntot, parts, T) + entry, self.dtype)
 
     def paged_write(self, pages, layer, kv, blk, off):
         """kv [2, b, c, h, dh] → pages[layer, blk[b,c], :, off[b,c]]."""
@@ -353,9 +355,10 @@ class _Int8KVCodec:
                 cache["scale"], s, (0, 0, 0, 0, 0)),
         }
 
-    def paged_init(self, L, ntot, T, h, dh):
-        return {"q": jnp.zeros((L, ntot, 2, T, h, dh), jnp.int8),
-                "scale": jnp.zeros((L, ntot, 2, T, h), jnp.float32)}
+    def paged_init(self, L, ntot, T, *entry, parts=2):
+        return {"q": jnp.zeros((L, ntot, parts, T) + entry, jnp.int8),
+                "scale": jnp.zeros((L, ntot, parts, T) + entry[:-1],
+                                   jnp.float32)}
 
     def paged_write(self, pages, layer, kv, blk, off):
         """Codec applied per block: each written vector quantizes with the
@@ -867,7 +870,11 @@ from nnstreamer_tpu.models.family import ModelFamily  # noqa: E402
 DENSE = ModelFamily(
     name="dense", init_params=init_params, build_prefill=build_prefill,
     build_paged_decode_step=build_paged_decode_step,
-    kv_layout=lambda cfg: (cfg.n_layers, cfg.n_heads, cfg.head_dim),
+    kv_entry=lambda cfg: (cfg.n_layers, 2, (cfg.n_heads, cfg.head_dim)),
+    brings=("prefix_cache", "speculate", "prefill_chunk", "kv_quant",
+            "mesh"),
+    build_chunk_decode=build_chunk_decode,
+    build_paged_chunk=build_paged_chunk,
     # the router is read in float32 and the embedding has two readers: the
     # lookup gathers rows and casts those, the head multiplies the stored
     # table in float32 (``_final_logits``)

@@ -111,9 +111,10 @@ def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
                 interpret: bool, scale=None):
     """Kernel entry on [batch, heads, seq, dim] layout. ``k``/``v`` with
     fewer heads than ``q``: the grid walks the query heads and head ``i``
-    streams the tiles of key-value head ``i // group``."""
+    streams the tiles of key-value head ``i // group``. ``v`` may be
+    narrower or wider than ``q`` and ``k``: the output has its width."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     group = h // k.shape[1]
     scale = d ** -0.5 if scale is None else scale
     kv_head = (lambda ih: ih) if group == 1 else (lambda ih: ih // group)
@@ -127,7 +128,7 @@ def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
     # sequential ("arbitrary")
     return pl.pallas_call(
         kern,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
         grid=grid,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -137,38 +138,42 @@ def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
                          lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda ib, ih, iq, ik: (ib, kv_head(ih), ik, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, dv),
                          lambda ib, ih, iq, ik: (ib, kv_head(ih), ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d),
+        out_specs=pl.BlockSpec((1, 1, block_q, dv),
                                lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max
             pltpu.VMEM((block_q, 128), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
+            pltpu.VMEM((block_q, dv), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
         name="nns_flash_prefill",
     )(q, k, v)
 
 
-def _pallas_reject(q, k, block_q: int, block_k: int) -> str | None:
+def _pallas_reject(q, k, block_q: int, block_k: int, v=None) -> str | None:
     """Why these shapes cannot go to the kernel, or None when they can.
 
     The bounds are what Mosaic (libtpu 0.0.34, TPU v5e) was seen to
     compile, bf16 and f32: q/k blocks of 8, 16, 24, 40, 128 and 256 rows
     — bf16 included, although its native tile is 16 rows — and head dims
     8 to 256 (the head dim is the blocks' lane dimension, legal at any
-    size because it spans the whole array dimension)."""
+    size because it spans the whole array dimension). Values of another
+    width than the keys (128 beside 192: PERF.md, PR 33) are held to the
+    same bounds."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    dv = d if v is None else v.shape[-1]
     if sq % block_q or sk % block_k:
         return (f"seq ({sq}, {sk}) is not a multiple of the blocks "
                 f"({block_q}, {block_k})")
     if block_q % 8 or block_k % 8:
         return f"blocks ({block_q}, {block_k}) are not multiples of 8 rows"
-    if d % 8 or d > 256:
-        return f"head dim {d} is not a multiple of 8 in [8, 256]"
+    if d % 8 or d > 256 or dv % 8 or dv > 256:
+        return (f"head dim {d} (values {dv}) is not a multiple of 8 in "
+                f"[8, 256]")
     if h % k.shape[2]:
         return (f"{h} query heads are no multiple of {k.shape[2]} "
                 f"key-value heads")
@@ -187,7 +192,8 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
                     block_k: int = 256, force: str | None = None,
                     scale: float | None = None):
     """Attention on [batch, seq, heads, dim] tensors; ``scale`` and fewer
-    key-value heads as in :func:`attention_reference`.
+    key-value heads as in :func:`attention_reference`; the values may
+    have another width than queries and keys, which the output takes.
 
     ``force``: None (auto: the Pallas kernel on a TPU for tileable
     shapes, else the XLA reference), "pallas" (always the kernel — Mosaic
@@ -199,7 +205,7 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
     block_q = min(block_q, q.shape[1])
     block_k = min(block_k, k.shape[1])
     on_tpu = jax.default_backend() == "tpu"
-    why_not = _pallas_reject(q, k, block_q, block_k)
+    why_not = _pallas_reject(q, k, block_q, block_k, v)
     if force == "pallas":
         if why_not:
             raise ValueError(

@@ -28,6 +28,15 @@ MXU pass; the float32 probabilities go in as three bfloat16 parts whose
 sum is the float32 value (8 + 8 + 8 mantissa bits), against values that
 ARE bfloat16: no operand is narrowed.
 
+A LATENT arena (``v_width``; ``serving/kvpool.py``, one part of ``[T,
+width]`` a block) holds one row a token that every query head shares: the
+key is the row at all its columns, the value the first ``v_width`` columns
+OF THE SAME ROW, so one DMA a block serves both, every head keeps every
+column (``hk`` = 1: the mask above has nothing to mask), and the output is
+``[hq, v_width]`` a lane. The same kernel, built with ``v_width`` and under
+the name ``nns_mla_paged_decode``; its chunk is ``LATENT_CHUNK_BLOCKS``
+(a block is a fifth of a per-head block's bytes at 16 heads).
+
 ``paged_attention`` auto-selects like ``flash_attention``: the kernel on a
 TPU for shapes it takes, the gather form elsewhere.
 """
@@ -52,6 +61,9 @@ _NEG_BIG = -1e30
 #: flight together); what is past a lane's live blocks is neither fetched
 #: nor waited for
 CHUNK_BLOCKS = 8
+#: the same for a latent arena: 32 blocks of 16 rows are 512 rows a matmul
+#: and 1.3 MB of VMEM for both buffers at 576 columns
+LATENT_CHUNK_BLOCKS = 32
 
 
 def _split3(p):
@@ -65,7 +77,8 @@ def _split3(p):
 
 def _kernel(layer_ref, bt_ref, pos_ref, q_ref, pages_ref, o_ref,
             buf, sem, m_scr, l_scr, acc_scr, *, scale: float,
-            block_tokens: int, kv_heads: int, chunk: int):
+            block_tokens: int, kv_heads: int, chunk: int,
+            v_width: int | None = None):
     lane = pl.program_id(0)
     layer = layer_ref[0]
     pos = pos_ref[lane]
@@ -118,11 +131,23 @@ def _kernel(layer_ref, bt_ref, pos_ref, q_ref, pages_ref, o_ref,
             start(c + 1, 1 - slot)
 
         wait(c, slot)
-        k = buf[slot, :, 0].reshape(rows, dh)
-        v = buf[slot, :, 1].reshape(rows, dh)
-        s = lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())),
-                            precision=exact,
-                            preferred_element_type=jnp.float32) * scale
+
+        def scores(q, k):
+            return lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                   precision=exact,
+                                   preferred_element_type=jnp.float32)
+
+        if v_width is None:
+            v = buf[slot, :, 1].reshape(rows, dh)
+            s = scores(q_ref[0], buf[slot, :, 0].reshape(rows, dh)) * scale
+        else:
+            # the row's first columns are the value AND the greater part
+            # of the key: two products over whole lane tiles, not one
+            # over a width that is no multiple of 128
+            v = buf[slot, :, 0, :, :v_width].reshape(rows, v_width)
+            rest = buf[slot, :, 0, :, v_width:].reshape(rows, dh - v_width)
+            s = (scores(q_ref[0, :, :v_width], v)
+                 + scores(q_ref[0, :, v_width:], rest)) * scale
         seen = slot_of <= pos - c * (chunk * block_tokens)
         s = jnp.where(own_head & seen, s, _NEG_BIG)
         m_prev = m_scr[:, :1]
@@ -145,42 +170,48 @@ def _kernel(layer_ref, bt_ref, pos_ref, q_ref, pages_ref, o_ref,
     o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "chunk", "interpret",
+                                             "v_width"))
 def _paged_decode(q, pages, layer, bt, pos_c, scale: float, chunk: int,
-                  interpret: bool):
+                  interpret: bool, v_width: int | None = None):
     """Kernel entry: ``q [b, hq, dh]``, the arena leaf whole."""
     b, hq, dh = q.shape
-    L, ntot, two, T, hk, _ = pages.shape
-    # a block half's [T, hk] rows as one axis: the same bytes in the same
-    # order, so the blocks go to the MXU as the DMA lands them
-    flat = pages.reshape(L, ntot, two, T * hk, dh)
+    if v_width is None:
+        L, ntot, two, T, hk, _ = pages.shape
+        # a block half's [T, hk] rows as one axis: the same bytes in the
+        # same order, so the blocks go to the MXU as the DMA lands them
+        flat = pages.reshape(L, ntot, two, T * hk, dh)
+    else:
+        (L, ntot, two, T, _), hk, flat = pages.shape, 1, pages
+    dv = dh if v_width is None else v_width
     kern = functools.partial(_kernel, scale=scale, block_tokens=T,
-                             kv_heads=hk, chunk=chunk)
-    lane_block = pl.BlockSpec((1, hq, dh), lambda i, *_: (i, 0, 0))
+                             kv_heads=hk, chunk=chunk, v_width=v_width)
     return pl.pallas_call(
         kern,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,      # layer, block table, positions
             grid=(b,),
-            in_specs=[lane_block, pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=lane_block,
+            in_specs=[pl.BlockSpec((1, hq, dh), lambda i, *_: (i, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, hq, dv), lambda i, *_: (i, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, chunk, two, T * hk, dh), pages.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((hq, 128), jnp.float32),   # running max
                 pltpu.VMEM((hq, 128), jnp.float32),   # running sum
-                pltpu.VMEM((hq, dh), jnp.float32),    # output accumulator
+                pltpu.VMEM((hq, dv), jnp.float32),    # output accumulator
             ]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="nns_paged_decode",
+        name="nns_paged_decode" if v_width is None
+        else "nns_mla_paged_decode",
     )(jnp.asarray(layer, jnp.int32).reshape(1), bt.astype(jnp.int32),
       pos_c.astype(jnp.int32), q, flat)
 
 
-def _pallas_reject(q, pages, bt) -> str | None:
+def _pallas_reject(q, pages, bt, v_width: int | None = None) -> str | None:
     """Why these shapes cannot go to the kernel, or None when they can.
 
     What was proved: Mosaic (libtpu 0.0.34, for a TPU v5e) compiles the
@@ -190,12 +221,22 @@ def _pallas_reject(q, pages, bt) -> str | None:
     chip served all three from it; the interpreter holds the same shapes,
     float32 too, to the gather form. The checks below are what the layout
     needs (whole lanes, whole sublanes), which is wider than what was
-    proved: a head dim of 384 or a group of 16 would pass them untried."""
-    if not hasattr(pages, "shape") or len(pages.shape) != 6:
-        return "the arena is not one [L, NTOT, 2, T, h, dh] leaf"
+    proved: a head dim of 384 or a group of 16 would pass them untried.
+    A latent arena (``v_width``) was compiled and served from at 16 heads
+    over rows of 576 columns, the value the first 512
+    (``tests/test_paged_attention.py``; PERF.md, PR 33)."""
+    if not hasattr(pages, "shape") or \
+            len(pages.shape) != (6 if v_width is None else 5):
+        return "the arena is not one [L, NTOT, 2, T, h, dh] leaf, nor " \
+               "with v_width one [L, NTOT, 1, T, width]"
     b, one, hq, dh = q.shape
-    T, hk = pages.shape[3:5]
+    T, hk = pages.shape[3], 1 if v_width is not None else pages.shape[4]
     sublanes = 8 * 4 // jnp.dtype(pages.dtype).itemsize
+    if v_width is not None and (dh != pages.shape[-1] or v_width % 128
+                                or not 0 < v_width < dh):
+        return (f"queries of {dh} against rows of {pages.shape[-1]} "
+                f"columns, or a value width ({v_width}) that is no "
+                f"multiple of 128 lanes inside the row")
     if one != 1:
         return f"{one} query tokens a lane, not 1"
     if q.dtype != pages.dtype:
@@ -203,7 +244,7 @@ def _pallas_reject(q, pages, bt) -> str | None:
     if jnp.dtype(q.dtype) not in (jnp.dtype(jnp.bfloat16),
                                   jnp.dtype(jnp.float32)):
         return f"dtype {q.dtype} is neither bfloat16 nor float32"
-    if dh % 128:
+    if dh % 128 and v_width is None:
         return f"head dim {dh} is not a multiple of 128 lanes"
     if hq % hk:
         return f"{hq} query heads are no multiple of {hk} key-value heads"
@@ -215,10 +256,11 @@ def _pallas_reject(q, pages, bt) -> str | None:
     return None
 
 
-def paged_attention_form(q, pages, bt) -> str:
+def paged_attention_form(q, pages, bt, v_width: int | None = None) -> str:
     """Which form :func:`paged_attention` builds in auto mode for these
     arguments (arrays or shapes): ``"paged_kernel"`` or ``"gather"``."""
-    if jax.default_backend() != "tpu" or _pallas_reject(q, pages, bt):
+    if jax.default_backend() != "tpu" or \
+            _pallas_reject(q, pages, bt, v_width):
         return "gather"
     return "paged_kernel"
 
@@ -229,9 +271,12 @@ def _log_reference_choice(q_shape, pages_shape, dtype, why: str) -> None:
                 "Pallas kernel: %s", q_shape, pages_shape, dtype, why)
 
 
-def paged_attention_reference(q, pages, layer, bt, pos_c, scale=None):
+def paged_attention_reference(q, pages, layer, bt, pos_c, scale=None,
+                              v_width: int | None = None):
     """The gather form: every lane's whole table copied out of the arena,
-    masked to ``slot <= pos_c`` and attended over by ``_attend_cache``."""
+    masked to ``slot <= pos_c`` and attended over by ``_attend_cache``
+    (a latent arena's rows as one key-value head whose value is the
+    row's first ``v_width`` columns)."""
     from nnstreamer_tpu.models.transformer import (
         _attend_cache,
         _paged_gather,
@@ -241,14 +286,20 @@ def paged_attention_reference(q, pages, layer, bt, pos_c, scale=None):
         slots = jnp.arange(bt.shape[1] * pages.shape[3])
         mask = slots[None, None, None, :] <= pos_c[:, None, None, None]
         g = _paged_gather(pages, layer, bt)
+        if v_width is None:
+            ck, cv = g[:, 0], g[:, 1]
+        else:
+            ck = g[:, 0, :, None]
+            cv = ck[..., :v_width]
     with jax.named_scope("attend"):
-        return _attend_cache(q, g[:, 0], g[:, 1], mask, q.shape[-1],
-                             q.dtype, scale=scale)
+        return _attend_cache(q, ck, cv, mask, q.shape[-1], q.dtype,
+                             scale=scale)
 
 
 def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
                     force: str | None = None,
-                    chunk_blocks: int = CHUNK_BLOCKS):
+                    chunk_blocks: int | None = None,
+                    v_width: int | None = None):
     """Decode attention of ``q [b, 1, hq, dh]`` over a paged cache.
 
     ``pages`` is the arena's value leaf WHOLE, ``[L, NTOT, 2, T, hk, dh]``
@@ -261,6 +312,12 @@ def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
     key-value head ``i // (hq / hk)``). A lane whose table is all
     sentinel reads one zero block and comes out zero.
 
+    ``v_width``: ``pages`` is a latent arena ``[L, NTOT, 1, T, width]``,
+    ``q [b, 1, hq, width]``; every query head attends over the lane's rows
+    at all their columns and the value is the rows' first ``v_width``
+    columns: ``[b, 1, hq, v_width]`` comes back. ``chunk_blocks`` defaults
+    to ``CHUNK_BLOCKS``, for a latent arena ``LATENT_CHUNK_BLOCKS``.
+
     ``force``: None (auto: the kernel on a TPU for shapes it takes, else
     the gather form), "pallas" (always the kernel: Mosaic on a TPU, the
     Pallas interpreter elsewhere, which is how the CPU tests run it) or
@@ -268,7 +325,7 @@ def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
     ``attend``; the gather form keeps ``kv_gather`` and ``attend``.
     """
     on_tpu = jax.default_backend() == "tpu"
-    why_not = _pallas_reject(q, pages, bt)
+    why_not = _pallas_reject(q, pages, bt, v_width)
     if force == "pallas":
         if why_not:
             raise ValueError(
@@ -277,11 +334,15 @@ def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
         if force is None and on_tpu:
             _log_reference_choice(tuple(q.shape), tuple(pages.shape),
                                   str(q.dtype), why_not)
-        return paged_attention_reference(q, pages, layer, bt, pos_c, scale)
+        return paged_attention_reference(q, pages, layer, bt, pos_c, scale,
+                                         v_width)
     scale = q.shape[-1] ** -0.5 if scale is None else scale
+    if chunk_blocks is None:
+        chunk_blocks = CHUNK_BLOCKS if v_width is None \
+            else LATENT_CHUNK_BLOCKS
     with jax.named_scope("attend"):
         out = _paged_decode(
             q[:, 0], pages, layer, bt, pos_c, scale=float(scale),
             chunk=min(int(chunk_blocks), bt.shape[1]),
-            interpret=not on_tpu)
+            interpret=not on_tpu, v_width=v_width)
     return out[:, None]

@@ -136,6 +136,10 @@ class GenerationStream:
         #: the decode lane the stream is pinned to for life, where the
         #: model family keeps state per lane (``BlockPool.lane_state``)
         self.lane: Optional[int] = None
+        #: the blocks the stream held when it finished, in table order:
+        #: ids only, released with the finish (``BlockPool.stream_rows``
+        #: reads what they still hold, for a check on an idle engine)
+        self.blocks: tuple = ()
         self._q: _queue.Queue = _queue.Queue()
 
     def cancel(self) -> None:
@@ -293,13 +297,16 @@ class ContinuousBatchingEngine:
     ----------
     cfg, params: a model config + param pytree. The config's ``family``
         (``models/family.py``) gives the prefill and paged-decode
-        builders and says what a decode lane holds beside its blocks:
-        ``models.transformer`` is the dense member, ``models.hybrid``
-        the one with state-space layers. With a family that has lane
-        state every stream keeps its lane for life, and ``prefix_cache``,
-        ``speculate``, ``prefill_chunk``, ``kv_quant`` and ``mesh=`` are
-        refused at construction: each needs that state snapshotted,
-        rolled back, quantized or sharded, which nothing does yet.
+        builders, and says what the arena holds for a token
+        (``kv_entry``), what a decode lane holds beside its blocks and
+        which options its programs bring: ``models.transformer`` is the
+        dense member, ``models.hybrid`` the one with recurrent layers,
+        ``models.mla`` the one whose cache is one latent row a token.
+        With a family that has lane state every stream keeps its lane
+        for life. Of ``prefix_cache``, ``speculate``, ``prefill_chunk``,
+        ``kv_quant`` and ``mesh=`` the dense family brings all; what a
+        family does not bring (``ModelFamily.brings``) is refused at
+        construction by name, with the family's own reason.
         The engine does not keep the tree it is given. At construction
         it makes, once, the tree its programs read
         (``models.family.serving_params``): a leaf the family's programs
@@ -409,19 +416,19 @@ class ContinuousBatchingEngine:
 
         family = cfg.family
         #: the family keeps state per decode lane: a stream is pinned to
-        #: its lane, no option that copies that state
+        #: its lane
         self._lane_state = family.lane_state(cfg) is not None
-        if self._lane_state:
-            refused = [name for name, on in (
-                ("prefix_cache", prefix_cache), ("speculate", speculate),
-                ("prefill_chunk", prefill_chunk), ("kv_quant", kv_quant),
-                ("mesh", mesh is not None)) if on]
-            if refused:
-                raise ValueError(
-                    f"serving: the {family.name} model family keeps "
-                    f"recurrent state per decode lane; it does not yet "
-                    f"support {', '.join(refused)} (ROADMAP.md, \"what "
-                    f"the system cannot run yet\")")
+        # what a family can do comes from its record: an option its
+        # programs do not bring is refused by name
+        refused = [name for name, on in (
+            ("prefix_cache", prefix_cache), ("speculate", speculate),
+            ("prefill_chunk", prefill_chunk), ("kv_quant", kv_quant),
+            ("mesh", mesh is not None)) if on and name not in family.brings]
+        if refused:
+            raise ValueError(
+                f"serving: the {family.name} model family "
+                f"{family.refusal}; it does not yet support "
+                f"{', '.join(refused)}")
         self.cfg = cfg
         from nnstreamer_tpu.models.family import serving_params
 
@@ -485,17 +492,12 @@ class ContinuousBatchingEngine:
             paged_attention_fn=paged_attention_fn)
         # chunked ingestion (a batch-1 contiguous cache, scattered into
         # blocks by its last chunk), prefix extension and speculative
-        # verification are the dense block's alone
+        # verification: a family's own builders, where it brings them
         self._chunk_fn = self._paged_chunk_fn = None
-        if not self._lane_state:
-            from nnstreamer_tpu.models.transformer import (
-                build_chunk_decode,
-                build_paged_chunk,
-            )
-
-            self._chunk_fn = build_chunk_decode(cfg, self.S,
-                                                kv_codec=kv_quant)
-            self._paged_chunk_fn = build_paged_chunk(
+        if family.build_chunk_decode is not None:
+            self._chunk_fn = family.build_chunk_decode(
+                cfg, self.S, kv_codec=kv_quant)
+            self._paged_chunk_fn = family.build_paged_chunk(
                 cfg, self.block_tokens, self.S, kv_codec=kv_quant)
         #: in-progress chunked admission: (request, cache1, k) with
         #: k = next chunk index; one at a time, advanced between dispatches
@@ -640,7 +642,15 @@ class ContinuousBatchingEngine:
             self.decode_attention = paged_attention_form(
                 jax.ShapeDtypeStruct(
                     (self.B, 1, cfg.n_heads, kv.shape[-1]), kv.dtype),
-                kv, self._bt)
+                kv, self._bt, v_width=family.latent_value_width(cfg))
+        #: bytes of the arena's entry for one token, all layers, as held;
+        #: for a latent arena the form beside it (a dense engine's stats
+        #: stay integers: ``tests/test_lm_tracing.py``)
+        self.stats["kv_bytes_per_token"] = (
+            self._pool.nbytes - self._pool.state_bytes) \
+            // (self._pool.ntot * self.block_tokens)
+        if family.latent_value_width(cfg) is not None:
+            self.stats["decode_attention"] = self.decode_attention
         #: the form the decode program runs its routed experts in:
         #: "grouped_kernel" (ops/grouped_matmul.py: a Pallas grid over the
         #: sorted tiles) or "tile_loop"; None for a family without experts
@@ -1553,6 +1563,7 @@ class ContinuousBatchingEngine:
             if self._lane_state:
                 self._pool.release_lane(slot)
         if state["blocks"]:
+            state["stream"].blocks = tuple(state["blocks"])
             self._pool.release(state["blocks"])
             state["blocks"] = []
         self._finish_stream(state["stream"], reason)

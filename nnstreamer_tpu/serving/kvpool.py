@@ -7,8 +7,13 @@ fixed ``block_tokens``-sized blocks instead (the compiler-first O(1)
 autoregressive-caching form, PAPERS.md). It is the serving engine's one
 KV store:
 
-- **Arena** — one device pytree per codec, leaves ``[L, NTOT, 2, T, h,
-  dh]`` (int8 adds a ``[L, NTOT, 2, T, h]`` scale leaf). ONE buffer per
+- **Arena** — one device pytree per codec, leaves ``[L, NTOT, parts, T,
+  *entry]``: what a token's entry is the model family states
+  (``models/family.py`` ``kv_entry``) — keys and values per head are two
+  parts of ``[h, dh]`` (int8 adds a ``[L, NTOT, 2, T, h]`` scale leaf),
+  a latent cache ONE part of ``[width]``, a row every head shares — and
+  allocation, refcounts, sentinel, scatter and block copy are the same
+  code for either. ONE buffer per
   leaf that the decode program updates in place: it is a carry of the
   K-step scan and of the layer scan, never a scan's ``xs``/``ys``, and
   the layer is one more index beside the block (``[layer, block, :,
@@ -65,7 +70,7 @@ from nnstreamer_tpu.tensors import memory as _memory
 
 
 def _scatter_prefill_impl(arena, cache1, bids):
-    """Scatter a prefill's batch-1 contiguous cache ([L, 2, 1, S, ...]
+    """Scatter a prefill's batch-1 contiguous cache ([L, parts, 1, S, ...]
     leaves) into arena blocks ``bids`` ([S/T] int32, sentinel entries
     drop). Block i receives slots [i*T, (i+1)*T) — including any trailing
     bucket-pad garbage in the last data block, which stays masked until
@@ -75,12 +80,12 @@ def _scatter_prefill_impl(arena, cache1, bids):
     import jax.numpy as jnp
 
     def leaf(a, c):
-        L = c.shape[0]
+        L, parts = c.shape[:2]
         S = c.shape[3]
         T = a.shape[3]
-        u = c[:, :, 0]                                   # [L,2,S,...]
-        u = u.reshape((L, 2, S // T, T) + u.shape[3:])
-        u = jnp.moveaxis(u, 2, 1)                        # [L,MB,2,T,...]
+        u = c[:, :, 0]                                   # [L,parts,S,...]
+        u = u.reshape((L, parts, S // T, T) + u.shape[3:])
+        u = jnp.moveaxis(u, 2, 1)                        # [L,MB,parts,T,...]
         return a.at[:, bids].set(u.astype(a.dtype), mode="drop")
 
     with jax.named_scope("nns.kv_scatter"):
@@ -175,9 +180,9 @@ class BlockPool:
     def _make_arena(self):
         import jax.numpy as jnp
 
-        layers, heads, head_dim = self._family.kv_layout(self.cfg)
+        layers, parts, entry = self._family.kv_entry(self.cfg)
         arena = self._codec.paged_init(layers, self.ntot, self.block_tokens,
-                                       heads, head_dim)
+                                       *entry, parts=parts)
         if self.mesh is not None:
             arena = self._place(arena)
         if not self._lane_state:
@@ -203,7 +208,7 @@ class BlockPool:
                 f"pad num_blocks")
 
         def spec_of(leaf):
-            # [L, NTOT, 2, T, h(, dh)] — blocks over dp, heads over tp
+            # [L, NTOT, parts, T, h(, dh)] — blocks over dp, heads over tp
             head = (None, dp, None, None, tp)
             return P(*(head + (None,) * (leaf.ndim - 5)))
 
@@ -271,8 +276,29 @@ class BlockPool:
         ...]``: for checks and tests. The caller sees to it that no
         program holds the arena meanwhile (an idle engine dispatches
         nothing)."""
+        if not self._lane_state:
+            return {}
         return {name: np.asarray(a[:, lane])
                 for name, a in self.arena["state"].items()}
+
+    def stream_rows(self, block_ids: Sequence[int], tokens: int):
+        """Host copies of the first ``tokens`` entries that the blocks
+        ``block_ids`` hold, in table order: per arena leaf ``[layers,
+        parts, tokens, ...]``. For checks and tests, as ``lane_state``:
+        the caller sees to it that no program holds the arena meanwhile,
+        and that no other stream was given the blocks since (a released
+        block keeps its rows until its next owner writes them)."""
+        import jax
+
+        ids = np.asarray(block_ids, np.int32)
+        kv = self.arena["kv"] if self._lane_state else self.arena
+
+        def leaf(a):
+            rows = np.moveaxis(np.asarray(a[:, ids]), 2, 1)
+            rows = rows.reshape(rows.shape[:2] + (-1,) + rows.shape[4:])
+            return rows[:, :, :tokens]
+
+        return jax.tree.map(leaf, kv)
 
     # -- device-side helpers ------------------------------------------
 

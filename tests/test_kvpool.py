@@ -116,3 +116,76 @@ def test_bad_sizes_rejected():
         kvpool.BlockPool(CFG, 0, T)
     with pytest.raises(ValueError):
         kvpool.BlockPool(CFG, 4, 0)
+
+
+# -- one code path for both token entries ------------------------------------
+
+def _latent_case():
+    from nnstreamer_tpu.models.mla import MLAConfig, build_prefill as mla_pre
+
+    cfg = MLAConfig(
+        vocab=97, d_model=32, n_layers=2, n_heads=2, qk_nope_dim=8,
+        qk_rope_dim=4, v_head_dim=8, kv_lora_rank=12, dense_width=16,
+        num_experts=4, experts_per_token=2, expert_width=8, shared_width=8,
+        experts_held=(0, 4), max_seq=64, dtype=jnp.float32,
+        param_dtype=jnp.float32)
+    # 12 + 4 columns, held at the next multiple of 128 lanes
+    return cfg, cfg.family.init_params(cfg, 3), mla_pre, (2, 7, 1, T, 128)
+
+
+def _dense_case():
+    return CFG, PARAMS, build_prefill, (2, 7, 2, T, 4, 16)
+
+
+@pytest.mark.parametrize("case", [_dense_case, _latent_case],
+                         ids=["two_parts_of_heads", "one_latent_row"])
+def test_one_pool_serves_both_token_entries(case):
+    """Alloc, retain, release, the prefill's scatter, the copy-on-write
+    block copy, the sentinel and the read of a stream's rows: the same
+    ``BlockPool`` code whether a token's entry is keys and values per head
+    or one latent row (``ModelFamily.kv_entry``)."""
+    cfg, params, prefill_of, shape = case()
+    pool = kvpool.BlockPool(cfg, 6, T)
+    leaf = jax.tree_util.tree_leaves(pool.arena)[0]
+    assert leaf.shape == shape and pool.nbytes == leaf.nbytes
+    layers, parts, entry = cfg.family.kv_entry(cfg)
+    assert shape == (layers, 7, parts, T) + entry
+
+    ids = pool.alloc(3)
+    pool.retain(ids[:1])
+    pool.release(ids)
+    assert pool.live_blocks() == 1 and pool.free_blocks == 5
+    pool.release(ids[:1])
+    assert pool.live_blocks() == 0
+    assert pool.alloc(7) is None                    # all or nothing
+
+    toks = jnp.asarray(
+        np.random.default_rng(0).integers(1, cfg.vocab, (1, 16)), jnp.int32)
+    _, cache1 = jax.jit(prefill_of(cfg, cfg.max_seq))(params, toks)
+    want = np.asarray(jax.tree_util.tree_leaves(cache1)[0])
+    assert want.shape[:3] == (layers, parts, 1)     # [L, parts, 1, S, ...]
+    ids = pool.alloc(3)
+    pool.scatter_prefill(cache1, ids[:2])           # the table's third
+    got = np.asarray(jax.tree_util.tree_leaves(pool.arena)[0])
+    for i, b in enumerate(ids[:2]):                 # entry: the sentinel
+        np.testing.assert_array_equal(
+            got[:, b], want[:, :, 0, i * T:(i + 1) * T])
+    assert not got[:, ids[2]].any()                 # never written
+    assert not got[:, pool.num_blocks].any()        # the zero block: zeros
+    # a stream's rows, in table order, are the prefill's
+    rows = pool.stream_rows(ids[:2], 13)
+    np.testing.assert_array_equal(rows, want[:, :, 0, :13])
+    # copy-on-write: one block duplicated across every layer
+    pool.copy_block(ids[1], ids[2])
+    got = np.asarray(jax.tree_util.tree_leaves(pool.arena)[0])
+    np.testing.assert_array_equal(got[:, ids[2]], got[:, ids[1]])
+    # a table entry at the sentinel reads the zero block: exact zeros
+    from nnstreamer_tpu.models.transformer import _paged_gather
+
+    bt = jnp.asarray([[ids[0], pool.SENTINEL]], jnp.int32)
+    g = np.asarray(_paged_gather(pool.arena, 0, bt))
+    assert g.shape[:3] == (1, parts, 2 * T) and not g[:, :, T:].any()
+    assert g[:, :, :T].any()
+    pool.reset()
+    assert pool.free_blocks == 6 and not np.asarray(pool.arena).any()
+    assert pool.lane_state(0) == {}

@@ -138,6 +138,77 @@ def test_forced_kernel_refuses_shapes_it_does_not_take(why, kw):
     assert paged_attention_form(q, pages, bt) == "gather"
 
 
+# -- a latent arena: one row a token, shared by every head --------------------
+
+def _latent_pool(dtype, seed, width=192, t=T):
+    rng = np.random.default_rng(seed)
+    lanes = len(HELD)
+    nb = lanes * MB
+    pages = rng.standard_normal((LAYERS, nb + 1, 1, t, width))
+    pages[:, nb] = 0.0
+    order = rng.permutation(nb).reshape(lanes, MB)
+    bt = np.full((lanes, MB), nb + 1, np.int32)
+    held = [h * t // T for h in HELD]
+    for lane, n in enumerate(held):
+        bt[lane, :-(-n // t)] = order[lane, :-(-n // t)]
+    pos = np.maximum(np.asarray(held) - 1, 0).astype(np.int32)
+    return (jnp.asarray(pages, dtype), jnp.asarray(bt), jnp.asarray(pos),
+            rng)
+
+
+@pytest.mark.parametrize("chunk", [2, 5, None], ids=["chunk2", "chunk5",
+                                                     "default"])
+@pytest.mark.parametrize("dtype,tol,t", [(jnp.float32, 1e-5, 8),
+                                         (jnp.bfloat16, 2e-2, 16)],
+                         ids=["f32", "bf16"])
+def test_latent_kernel_equals_attend_cache_over_the_gathered_rows(
+        dtype, tol, t, chunk):
+    """K is a block's rows at all their columns, V the first 128 columns
+    of the same rows, 16 query heads against the one shared row: the
+    kernel against the gather form, and the gather form against
+    ``_attend_cache`` by hand."""
+    width, vw, hq = 192, 128, 16
+    pages, bt, pos, rng = _latent_pool(dtype, seed=7, width=width, t=t)
+    q = jnp.asarray(rng.standard_normal((len(HELD), 1, hq, width)), dtype)
+    for layer in range(LAYERS):
+        g = _paged_gather(pages, layer, bt)[:, 0]             # [b, S, w]
+        mask = jnp.arange(MB * t)[None, None, None, :] \
+            <= pos[:, None, None, None]
+        want = _attend_cache(q, g[:, :, None], g[:, :, None, :vw], mask,
+                             width, dtype, scale=0.2)
+        ref = paged_attention(q, pages, layer, bt, pos, scale=0.2,
+                              force="reference", v_width=vw)
+        np.testing.assert_array_equal(np.asarray(ref, np.float32),
+                                      np.asarray(want, np.float32))
+        got = paged_attention(q, pages, layer, bt, pos, scale=0.2,
+                              force="pallas", chunk_blocks=chunk, v_width=vw)
+        assert got.shape == (len(HELD), 1, hq, vw) and got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
+        assert not np.asarray(got, np.float32)[-1].any()   # the empty lane
+
+
+@pytest.mark.parametrize("why,kw", [
+    ("not one", dict(pages=(1, 5, 2, 16, 1, 192))),
+    ("value width", dict(v_width=96)),
+    ("value width", dict(v_width=192)),
+    ("against rows of", dict(q_width=160)),
+    ("sublanes", dict(hq=4)),
+    ("sublanes", dict(pages=(1, 5, 1, 8, 192))),
+])
+def test_forced_latent_kernel_refuses_shapes_it_does_not_take(why, kw):
+    q = jnp.zeros((2, 1, kw.get("hq", 16), kw.get("q_width", 192)),
+                  jnp.bfloat16)
+    pages = jnp.zeros(kw.get("pages", (1, 5, 1, 16, 192)), jnp.bfloat16)
+    bt = jnp.zeros((2, 2), jnp.int32)
+    vw = kw.get("v_width", 128)
+    with pytest.raises(ValueError, match=why):
+        paged_attention(q, pages, 0, bt, jnp.zeros((2,), jnp.int32),
+                        force="pallas", v_width=vw)
+    assert paged_attention_form(q, pages, bt, v_width=vw) == "gather"
+
+
 # -- inside the engine's K-step dispatch --------------------------------------
 
 CFG = TransformerConfig(vocab=256, d_model=1024, n_heads=8, n_layers=2,
@@ -273,6 +344,60 @@ def test_mosaic_compiles_the_kernel_at_the_cells_widths(
     arena = f"bf16[{layers},{ntot},2,"
     assert not [line for line in text.splitlines()
                 if " copy(" in line and arena in line.split(" copy(")[0]]
+
+
+def _compile_for_the_chip(fn, *shapes, **kw):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return fn.lower(*shapes, **kw).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+def test_mosaic_compiles_the_latent_kernel_at_the_cells_widths(one_chip):
+    """``dsv2lite_longctx_closed``: 64 lanes, 16 heads against rows of 576
+    columns held at 640 (Mosaic moves whole 128-lane tiles: a slice of
+    576 it refuses) of which the first 512 are the value, 256 blocks a
+    table, 14 layers: one DMA a block, and the arena goes in as it lies."""
+    from nnstreamer_tpu.ops.paged_attention import LATENT_CHUNK_BLOCKS
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    text = _compile_for_the_chip(
+        jax.jit(functools.partial(
+            _paged_decode, scale=0.1, chunk=LATENT_CHUNK_BLOCKS,
+            interpret=False, v_width=512)),
+        shape((64, 16, 640), jnp.bfloat16),
+        shape((14, 16385, 1, 16, 640), jnp.bfloat16),
+        shape((), jnp.int32), shape((64, 256), jnp.int32),
+        shape((64,), jnp.int32))
+    assert "tpu_custom_call" in text and "nns_mla_paged_decode" in text
+    assert "bf16[64,16,512]" in text
+    assert not [line for line in text.splitlines()
+                if " copy(" in line
+                and "bf16[14,16385,1," in line.split(" copy(")[0]]
+
+
+@pytest.mark.parametrize("s", [1536, 3072])
+def test_mosaic_compiles_flash_prefill_with_narrower_values(one_chip, s):
+    """Queries and keys 192 wide against values of 128, 16 heads, at the
+    two prefill buckets the latent cell's traffic runs."""
+    from nnstreamer_tpu.ops.flash_attention import _flash_bhsd
+
+    def shape(dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    text = _compile_for_the_chip(
+        _flash_bhsd, shape((1, 16, s, 192)), shape((1, 16, s, 192)),
+        shape((1, 16, s, 128)), causal=True, block_q=256, block_k=256,
+        interpret=False, scale=0.1147)
+    assert "tpu_custom_call" in text and "nns_flash_prefill" in text
+    assert f"bf16[1,16,{s},128]" in text
 
 
 @pytest.mark.parametrize("n_held,d,f,tile,rows", [
