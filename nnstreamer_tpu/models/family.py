@@ -38,7 +38,11 @@ class ModelFamily:
     #: holds for one token of one layer, ``parts`` arrays of ``shape``:
     #: keys and values per head are ``(layers, 2, (heads, head_dim))``, one
     #: latent row shared by every head ``(layers, 1, (width,))``. The
-    #: arena leaf is ``[layers, blocks, parts, T, *shape]``
+    #: arena leaf is ``[layers, blocks, parts, T, *shape]``, or, for a
+    #: shape ``(heads, head_dim)`` of fewer than 8 heads, heads-major
+    #: ``[layers, blocks, parts, heads, T, head_dim]``: this shape is ALL
+    #: that decides the order (``models/transformer.py``
+    #: ``kv_heads_major``, applied by the codec that makes the arena)
     kv_entry: Callable
     #: ``latent_value_width(cfg)``: for a one-part entry, how many of the
     #: row's first columns are the value (``ops.paged_attention``

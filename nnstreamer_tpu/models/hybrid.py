@@ -823,7 +823,8 @@ def build_paged_decode_step(cfg: HybridConfig, block_tokens: int,
                                               jnp.stack([k, v]), blk, off)
                 if paged_attention_fn is not None:
                     a = paged_attention_fn(q, pages, i_attn, bt, pos_c,
-                                           scale=cfg.attention_scale)
+                                           scale=cfg.attention_scale,
+                                           heads_major=codec.heads_major)
                 else:
                     with jax.named_scope("kv_gather"):
                         mask = jnp.arange(s_max)[None, None, None, :] \

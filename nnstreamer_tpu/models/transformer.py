@@ -253,34 +253,85 @@ def _slot_write(layer_cache, upd, pos, per_stream):
         layer_cache, upd, (0, 0, pos) + (0,) * (layer_cache.ndim - 3))
 
 
-def _paged_gather(pages, layer, bt):
+#: rows of one ``(8, 128)`` tile of the chip's memory
+_TILE_ROWS = 8
+
+
+def kv_heads_major(entry) -> bool:
+    """THE rule for the order of the rows inside a block, from the shape of
+    a token's entry (``ModelFamily.kv_entry``) and nothing else: an entry
+    ``(heads, head_dim)`` with fewer heads than one tile has rows is stored
+    HEADS-MAJOR, ``[.., heads, T, head_dim]``, so that a head's ``T`` tokens
+    are whole tiles; 8 heads or more, and a latent row ``(width,)``, stay
+    token-major, ``[.., T, *entry]``, where a token's entry is whole tiles
+    already and the decode write is one contiguous entry. With 2 heads
+    token-major the chip tiles the arena two rows to a tile and every flat
+    view or scatter of it is a copy of the WHOLE arena (PERF.md, PR 31 and
+    PR 34). The order cannot be read back off a shape (``[2, 16, dh]`` is 2
+    heads of 16 tokens and 2 tokens of 16 heads): the codec that made the
+    arena states it (``heads_major``) and hands it on."""
+    return len(entry) == 2 and entry[0] < _TILE_ROWS
+
+
+def _paged_gather(pages, layer, bt, heads_major: bool = False):
     """Block gather out of the whole arena leaf: ``pages [L, NTOT, 2, T,
-    ...]`` + layer number + block table ``bt [b, MB]`` → that layer's
-    contiguous ``[b, 2, MB*T, ...]`` k/v in global-slot order. Table
+    ...]`` (``heads_major``: ``[L, NTOT, 2, h, T, ...]``) + layer number +
+    block table ``bt [b, MB]`` → that layer's contiguous ``[b, 2, MB*T,
+    ...]`` k/v in global-slot order, heads inside tokens in either order
+    of the arena. Table
     entries ≥ NTOT-1 (the pool's unallocated sentinel) clamp onto the
     pool's permanent ZERO block at index NTOT-1, so unallocated slots read
     exact zeros — finite, and masked out anyway."""
     ntot = pages.shape[1]
     g = pages[layer, jnp.minimum(bt, ntot - 1)]          # [b,MB,2,T,...]
-    g = jnp.moveaxis(g, 2, 1)                            # [b,2,MB,T,...]
+    if heads_major:                             # from [b,MB,2,h,T,...]
+        g = jnp.transpose(g, (0, 2, 1, 4, 3) + tuple(range(5, g.ndim)))
+    else:
+        g = jnp.moveaxis(g, 2, 1)                        # [b,2,MB,T,...]
     b, two, mb, t = g.shape[:4]
     return g.reshape((b, two, mb * t) + g.shape[4:])
 
 
-def _paged_scatter(pages, layer, upd, blk, off):
+def _paged_scatter(pages, layer, upd, blk, off, heads_major: bool = False):
     """Block scatter into the whole arena leaf, in place when the leaf is
     a donated loop carry: ``upd [b, c, 2, ...]`` into ``pages[layer, blk,
-    :, off]`` (``blk``/``off`` are ``[b, c]``). Out-of-range block ids
-    (the sentinel) DROP — a masked write, not a clamped one, so the zero
-    block is never corrupted."""
+    :, off]`` (``blk``/``off`` are ``[b, c]``; ``heads_major``: into
+    ``pages[layer, blk, :, :, off]``, the same ``upd``). Out-of-range
+    block ids (the sentinel) DROP — a masked write, not a clamped one, so
+    the zero block is never corrupted.
+
+    Heads-major the part and the head are INDICES too, so that an update's
+    window is one row ``[dh]`` (one number of a scale leaf): a window ``[2,
+    h, 1, dh]`` astride the token axis makes XLA:TPU relay the whole arena
+    out token-major for the scatter and back, every step (compiled for a
+    described v5e, PR 34); rows it scatters in place."""
+    if heads_major:
+        part = jnp.arange(pages.shape[2])[:, None]
+        head = jnp.arange(pages.shape[3])[None, :]
+        return pages.at[layer, blk[..., None, None], part, head,
+                        off[..., None, None]].set(upd, mode="drop")
     return pages.at[layer, blk, :, off].set(upd, mode="drop")
 
 
-class _RawKVCodec:
-    """Cache = one array [L, 2, b, S, h, dh] in the model dtype."""
+def _paged_shape(L, ntot, T, entry, parts, heads_major):
+    if heads_major:
+        return (L, ntot, parts, entry[0], T) + tuple(entry[1:])
+    return (L, ntot, parts, T) + tuple(entry)
 
-    def __init__(self, dtype):
+
+class _RawKVCodec:
+    """Cache = one array [L, 2, b, S, h, dh] in the model dtype.
+
+    ``heads_major`` is the order of the rows inside a block of the PAGED
+    arena this codec makes (:func:`kv_heads_major`; the contiguous cache
+    is token-major always): ``paged_init`` shapes the arena by it,
+    ``paged_write`` and ``paged_read`` address it by it, and whoever reads
+    the arena otherwise (``serving/kvpool.py``, ``ops/paged_attention.py``)
+    is told it, never infers it."""
+
+    def __init__(self, dtype, heads_major: bool = False):
         self.dtype = dtype
+        self.heads_major = bool(heads_major)
 
     def init(self, L, b, S, h, dh):
         return jnp.zeros((L, 2, b, S, h, dh), self.dtype)
@@ -301,19 +352,23 @@ class _RawKVCodec:
 
     def paged_init(self, L, ntot, T, *entry, parts=2):
         """Paged arena [L, NTOT, parts, T, *entry] (keys and values per
-        head: parts 2, entry ``h, dh``): ONE buffer that the paged
+        head: parts 2, entry ``h, dh``), or ``heads_major`` [L, NTOT,
+        parts, h, T, dh]: ONE buffer that the paged
         builders address whole, the layer one more index beside the block
         (serving/kvpool.py owns allocation; index NTOT-1 of every layer
         is the permanent zero block)."""
-        return jnp.zeros((L, ntot, parts, T) + entry, self.dtype)
+        return jnp.zeros(_paged_shape(L, ntot, T, entry, parts,
+                                      self.heads_major), self.dtype)
 
     def paged_write(self, pages, layer, kv, blk, off):
-        """kv [2, b, c, h, dh] → pages[layer, blk[b,c], :, off[b,c]]."""
+        """kv [2, b, c, h, dh] → pages[layer, blk[b,c], :, off[b,c]]
+        (``heads_major``: pages[layer, blk[b,c], :, :, off[b,c]], row by
+        row: ``_paged_scatter``)."""
         upd = jnp.transpose(kv.astype(self.dtype), (1, 2, 0, 3, 4))
-        return _paged_scatter(pages, layer, upd, blk, off)
+        return _paged_scatter(pages, layer, upd, blk, off, self.heads_major)
 
     def paged_read(self, pages, layer, bt):
-        g = _paged_gather(pages, layer, bt)
+        g = _paged_gather(pages, layer, bt, self.heads_major)
         return g[:, 0], g[:, 1]
 
 
@@ -322,7 +377,12 @@ class _Int8KVCodec:
     scales [L, 2, b, S, h] fp32 — ~2× context (or batch slots) per HBM
     byte vs bf16, and the attend path reads half the bytes. Dequantize
     happens in fp32 right before the score/pv einsums, so the attention
-    numeric core (_attend_cache) is unchanged."""
+    numeric core (_attend_cache) is unchanged. ``heads_major`` as
+    ``_RawKVCodec``'s: both leaves of the paged arena in the same order
+    (the scale leaf ``[L, NTOT, 2, h, T]``)."""
+
+    def __init__(self, heads_major: bool = False):
+        self.heads_major = bool(heads_major)
 
     def _q(self, kv):
         kf = kv.astype(jnp.float32)
@@ -356,37 +416,45 @@ class _Int8KVCodec:
         }
 
     def paged_init(self, L, ntot, T, *entry, parts=2):
-        return {"q": jnp.zeros((L, ntot, parts, T) + entry, jnp.int8),
-                "scale": jnp.zeros((L, ntot, parts, T) + entry[:-1],
-                                   jnp.float32)}
+        hm = self.heads_major
+        return {"q": jnp.zeros(_paged_shape(L, ntot, T, entry, parts, hm),
+                               jnp.int8),
+                "scale": jnp.zeros(_paged_shape(L, ntot, T, entry[:-1],
+                                                parts, hm), jnp.float32)}
 
     def paged_write(self, pages, layer, kv, blk, off):
         """Codec applied per block: each written vector quantizes with the
         same per-vector absmax math as the monolithic write, so paged int8
         caches are bit-identical to monolithic int8 ones. Both leaves
-        take the same ``[layer, blk, :, off]`` index."""
+        take the same ``[layer, blk, :, off]`` index (``heads_major``:
+        ``[layer, blk, :, :, off]``)."""
         q, s = self._q(kv)                 # [2,b,c,h,dh], [2,b,c,h]
         return {
             "q": _paged_scatter(pages["q"], layer,
                                 jnp.transpose(q, (1, 2, 0, 3, 4)),
-                                blk, off),
+                                blk, off, self.heads_major),
             "scale": _paged_scatter(pages["scale"], layer,
                                     jnp.transpose(s, (1, 2, 0, 3)),
-                                    blk, off),
+                                    blk, off, self.heads_major),
         }
 
     def paged_read(self, pages, layer, bt):
-        gq = _paged_gather(pages["q"], layer, bt)
-        gs = _paged_gather(pages["scale"], layer, bt)
+        gq = _paged_gather(pages["q"], layer, bt, self.heads_major)
+        gs = _paged_gather(pages["scale"], layer, bt, self.heads_major)
         deq = gq.astype(jnp.float32) * gs[..., None]
         return deq[:, 0], deq[:, 1]
 
 
 def _kv_codec(cfg: TransformerConfig, kv_codec: Optional[str]):
+    """The codec of ``cfg``'s caches, the order of its paged arena's rows
+    decided here, once, by :func:`kv_heads_major` from the family's
+    ``kv_entry``: the pool and every paged builder make their codec
+    through this function, so they agree."""
+    heads_major = kv_heads_major(cfg.family.kv_entry(cfg)[2])
     if kv_codec in (None, "raw"):
-        return _RawKVCodec(cfg.dtype)
+        return _RawKVCodec(cfg.dtype, heads_major)
     if kv_codec == "int8":
-        return _Int8KVCodec()
+        return _Int8KVCodec(heads_major)
     raise ValueError(
         f"kv_codec must be None/'raw'/'int8', got {kv_codec!r}")
 
@@ -549,7 +617,9 @@ def build_paged_decode_step(cfg: TransformerConfig,
     ``step(params, token[int32 b], arena, bt[int32 b,MB], pos[int32 b]) ->
     (logits[b, vocab], new_arena)``.
 
-    The arena is the pool's ``[L, NTOT, 2, T, h, dh]`` pytree; ``bt`` maps
+    The arena is the pool's ``[L, NTOT, 2, T, h, dh]`` pytree (heads-major
+    ``[L, NTOT, 2, h, T, dh]`` where :func:`kv_heads_major` says so: the
+    codec addresses either); ``bt`` maps
     each row's logical blocks ``0..MB-1`` (MB = S/T) to physical pool
     blocks, with unallocated entries holding the pool sentinel (≥ NTOT).
     Each layer scatters k/v into physical slot ``(layer, bt[pos//T],
@@ -568,8 +638,9 @@ def build_paged_decode_step(cfg: TransformerConfig,
     layer's 1/L of the pool is sliced out and written back every step
     and the compiler plans the pool twice (PERF.md §6, PR 26).
 
-    ``paged_attention_fn(q, pages, layer, bt, pos_c)`` (``ops/
-    paged_attention.py``; for a raw arena, which is one leaf) takes the
+    ``paged_attention_fn(q, pages, layer, bt, pos_c, heads_major=)``
+    (``ops/paged_attention.py``; for a raw arena, which is one leaf; the
+    order is the codec's) takes the
     place of gather + mask + ``_attend_cache``: it reads each lane's
     live blocks where they lie, or builds the same gather form itself
     where its kernel does not run. None keeps the gather form.
@@ -602,7 +673,8 @@ def build_paged_decode_step(cfg: TransformerConfig,
                 pages = codec.paged_write(pages, li, jnp.stack([k, v]),
                                           blk, off)
             if paged_attention_fn is not None:
-                a = paged_attention_fn(q, pages, li, bt, pos_c)
+                a = paged_attention_fn(q, pages, li, bt, pos_c,
+                                       heads_major=codec.heads_major)
             else:
                 with jax.named_scope("kv_gather"):
                     slots = jnp.arange(s_max)

@@ -11,14 +11,24 @@ of ``chunk`` blocks at a time by async DMA, double-buffered, and folds it
 into an online softmax. The block table, the layer and the positions ride
 in by scalar prefetch.
 
-A block half is ``[T, hk, dh]``: the head axis sits INSIDE the token axis,
-so a per-head matmul would need a transpose of every block. Instead a
-block's ``T * hk`` rows go to the MXU as they lie: ``q [hq, dh]`` against
-all of them gives ``[hq, T * hk]`` scores of which a query head keeps the
-columns of its own key-value head (``col % hk == head // group``; the rest
-are masked like the slots past ``pos``). The MXU does ``hk`` times the
-arithmetic a transpose would save and has the room: decode attention is
-bound by the bytes.
+A block half is ``[T, hk, dh]``, the head axis INSIDE the token axis, or
+HEADS-MAJOR ``[hk, T, dh]`` where the codec that made the arena says so
+(``heads_major``: fewer key-value heads than a tile's 8 rows;
+``models/transformer.py`` ``kv_heads_major``). Which of the two is an
+ARGUMENT, never read off the shape: ``[2, 16, dh]`` is both. Either way a
+block's ``T * hk`` rows go to the MXU as they lie, with no transpose of
+any block: ``q [hq, dh]`` against all of them gives ``[hq, T * hk]``
+scores of which a query head keeps the columns of its own key-value head
+(token-major ``col % hk == head // group``, heads-major ``(col % (T * hk))
+// T == head // group``; the rest are masked like the slots past ``pos``,
+and a column's slot is ``col // hk``, heads-major ``(col // (T * hk)) * T +
+col % T``). The MXU does ``hk`` times the arithmetic a transpose would
+save and has the room: decode attention is bound by the bytes. The flat
+view ``[T * hk, dh]`` of a block half has to be a bitcast of the arena as
+the chip tiles it, or XLA relays out the WHOLE arena every step: with
+``(hk, dh)`` minor and ``hk`` = 2 it was no bitcast (two rows to a tile;
+2.3 ms a step at 537 MB: PERF.md, PR 31), with ``(T, dh)`` minor it is,
+as with 8 or 16 heads token-major.
 
 The numeric contract is ``_attend_cache``'s: keys and values as stored,
 float32 scores, float32 softmax, float32 probabilities x values, one
@@ -78,7 +88,7 @@ def _split3(p):
 def _kernel(layer_ref, bt_ref, pos_ref, q_ref, pages_ref, o_ref,
             buf, sem, m_scr, l_scr, acc_scr, *, scale: float,
             block_tokens: int, kv_heads: int, chunk: int,
-            v_width: int | None = None):
+            v_width: int | None = None, heads_major: bool = False):
     lane = pl.program_id(0)
     layer = layer_ref[0]
     pos = pos_ref[lane]
@@ -120,8 +130,15 @@ def _kernel(layer_ref, bt_ref, pos_ref, q_ref, pages_ref, o_ref,
 
     col = lax.broadcasted_iota(jnp.int32, (hq, rows), 1)
     head = lax.broadcasted_iota(jnp.int32, (hq, rows), 0)
-    own_head = lax.rem(col, kv_heads) == lax.div(head, group)
-    slot_of = lax.div(col, kv_heads)             # slot within the chunk
+    if heads_major:                              # a block: [hk, T] rows
+        blk_rows = block_tokens * kv_heads
+        own_head = lax.div(lax.rem(col, blk_rows), block_tokens) \
+            == lax.div(head, group)
+        slot_of = lax.div(col, blk_rows) * block_tokens \
+            + lax.rem(col, block_tokens)
+    else:                                        # a block: [T, hk] rows
+        own_head = lax.rem(col, kv_heads) == lax.div(head, group)
+        slot_of = lax.div(col, kv_heads)         # slot within the chunk
 
     def body(c, carry):
         slot = lax.rem(c, 2)
@@ -171,21 +188,26 @@ def _kernel(layer_ref, bt_ref, pos_ref, q_ref, pages_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "chunk", "interpret",
-                                             "v_width"))
+                                             "v_width", "heads_major"))
 def _paged_decode(q, pages, layer, bt, pos_c, scale: float, chunk: int,
-                  interpret: bool, v_width: int | None = None):
+                  interpret: bool, v_width: int | None = None,
+                  heads_major: bool = False):
     """Kernel entry: ``q [b, hq, dh]``, the arena leaf whole."""
     b, hq, dh = q.shape
     if v_width is None:
         L, ntot, two, T, hk, _ = pages.shape
-        # a block half's [T, hk] rows as one axis: the same bytes in the
-        # same order, so the blocks go to the MXU as the DMA lands them
+        if heads_major:
+            T, hk = hk, T
+        # a block half's [T, hk] (heads-major [hk, T]) rows as one axis:
+        # the same bytes in the same order, so the blocks go to the MXU
+        # as the DMA lands them
         flat = pages.reshape(L, ntot, two, T * hk, dh)
     else:
         (L, ntot, two, T, _), hk, flat = pages.shape, 1, pages
     dv = dh if v_width is None else v_width
     kern = functools.partial(_kernel, scale=scale, block_tokens=T,
-                             kv_heads=hk, chunk=chunk, v_width=v_width)
+                             kv_heads=hk, chunk=chunk, v_width=v_width,
+                             heads_major=heads_major)
     return pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct((b, hq, dv), q.dtype),
@@ -211,15 +233,22 @@ def _paged_decode(q, pages, layer, bt, pos_c, scale: float, chunk: int,
       pos_c.astype(jnp.int32), q, flat)
 
 
-def _pallas_reject(q, pages, bt, v_width: int | None = None) -> str | None:
+def _pallas_reject(q, pages, bt, v_width: int | None = None,
+                   heads_major: bool = False) -> str | None:
     """Why these shapes cannot go to the kernel, or None when they can.
+    ``heads_major`` says which of a six-axis arena's axes 3 and 4 is the
+    block's tokens and which its heads; it is never guessed.
 
     What was proved: Mosaic (libtpu 0.0.34, for a TPU v5e) compiles the
-    kernel for bfloat16 arenas of 16 tokens a block at head dims 128 and
-    256 with 16 query over 16 key-value heads, 32 over 8 and 16 over 2
-    (groups of 1, 4 and 8; ``tests/test_paged_attention.py``), and the
-    chip served all three from it; the interpreter holds the same shapes,
-    float32 too, to the gather form. The checks below are what the layout
+    kernel for bfloat16 arenas of 16 tokens a block, TOKEN-MAJOR at head
+    dim 128 with 16 query over 16 key-value heads and 32 over 8 (groups
+    of 1 and 4), HEADS-MAJOR at head dim 256 with 16 over 2 (a group of
+    8; ``tests/test_paged_attention.py``), and the chip served all three
+    from it (16 over 2 token-major too, until PR 34: it compiled and
+    served, behind a relayout of the whole arena a step); the interpreter
+    holds the same shapes and heads-major 1, 2 and 4 key-value heads at
+    head dims 128 and 256, float32 too, to the gather form. The checks
+    below are what the layout
     needs (whole lanes, whole sublanes), which is wider than what was
     proved: a head dim of 384 or a group of 16 would pass them untried.
     A latent arena (``v_width``) was compiled and served from at 16 heads
@@ -227,10 +256,15 @@ def _pallas_reject(q, pages, bt, v_width: int | None = None) -> str | None:
     (``tests/test_paged_attention.py``; PERF.md, PR 33)."""
     if not hasattr(pages, "shape") or \
             len(pages.shape) != (6 if v_width is None else 5):
-        return "the arena is not one [L, NTOT, 2, T, h, dh] leaf, nor " \
-               "with v_width one [L, NTOT, 1, T, width]"
+        return "the arena is not one [L, NTOT, 2, T, h, dh] leaf (or " \
+               "heads-major [L, NTOT, 2, h, T, dh]), nor with v_width " \
+               "one [L, NTOT, 1, T, width]"
+    if heads_major and v_width is not None:
+        return "a latent arena has no heads to put first"
     b, one, hq, dh = q.shape
     T, hk = pages.shape[3], 1 if v_width is not None else pages.shape[4]
+    if heads_major:
+        T, hk = hk, T
     sublanes = 8 * 4 // jnp.dtype(pages.dtype).itemsize
     if v_width is not None and (dh != pages.shape[-1] or v_width % 128
                                 or not 0 < v_width < dh):
@@ -256,11 +290,12 @@ def _pallas_reject(q, pages, bt, v_width: int | None = None) -> str | None:
     return None
 
 
-def paged_attention_form(q, pages, bt, v_width: int | None = None) -> str:
+def paged_attention_form(q, pages, bt, v_width: int | None = None,
+                         heads_major: bool = False) -> str:
     """Which form :func:`paged_attention` builds in auto mode for these
     arguments (arrays or shapes): ``"paged_kernel"`` or ``"gather"``."""
     if jax.default_backend() != "tpu" or \
-            _pallas_reject(q, pages, bt, v_width):
+            _pallas_reject(q, pages, bt, v_width, heads_major):
         return "gather"
     return "paged_kernel"
 
@@ -272,8 +307,10 @@ def _log_reference_choice(q_shape, pages_shape, dtype, why: str) -> None:
 
 
 def paged_attention_reference(q, pages, layer, bt, pos_c, scale=None,
-                              v_width: int | None = None):
-    """The gather form: every lane's whole table copied out of the arena,
+                              v_width: int | None = None,
+                              heads_major: bool = False):
+    """The gather form: every lane's whole table copied out of the arena
+    (into ``[b, MB * T, hk, dh]`` whichever the arena's order),
     masked to ``slot <= pos_c`` and attended over by ``_attend_cache``
     (a latent arena's rows as one key-value head whose value is the
     row's first ``v_width`` columns)."""
@@ -283,9 +320,9 @@ def paged_attention_reference(q, pages, layer, bt, pos_c, scale=None,
     )
 
     with jax.named_scope("kv_gather"):
-        slots = jnp.arange(bt.shape[1] * pages.shape[3])
+        g = _paged_gather(pages, layer, bt, heads_major)
+        slots = jnp.arange(g.shape[2])
         mask = slots[None, None, None, :] <= pos_c[:, None, None, None]
-        g = _paged_gather(pages, layer, bt)
         if v_width is None:
             ck, cv = g[:, 0], g[:, 1]
         else:
@@ -299,10 +336,13 @@ def paged_attention_reference(q, pages, layer, bt, pos_c, scale=None,
 def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
                     force: str | None = None,
                     chunk_blocks: int | None = None,
-                    v_width: int | None = None):
+                    v_width: int | None = None,
+                    heads_major: bool = False):
     """Decode attention of ``q [b, 1, hq, dh]`` over a paged cache.
 
     ``pages`` is the arena's value leaf WHOLE, ``[L, NTOT, 2, T, hk, dh]``
+    or, with ``heads_major`` (the arena's codec says which: its
+    ``heads_major``), ``[L, NTOT, 2, hk, T, dh]``
     (``serving/kvpool.py``), ``layer`` the layer to read (a Python int or
     a traced scalar), ``bt [b, MB]`` the block tables (entries ≥ NTOT-1
     read the zero block at NTOT-1) and ``pos_c [b]`` each lane's last
@@ -325,7 +365,7 @@ def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
     ``attend``; the gather form keeps ``kv_gather`` and ``attend``.
     """
     on_tpu = jax.default_backend() == "tpu"
-    why_not = _pallas_reject(q, pages, bt, v_width)
+    why_not = _pallas_reject(q, pages, bt, v_width, heads_major)
     if force == "pallas":
         if why_not:
             raise ValueError(
@@ -335,7 +375,7 @@ def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
             _log_reference_choice(tuple(q.shape), tuple(pages.shape),
                                   str(q.dtype), why_not)
         return paged_attention_reference(q, pages, layer, bt, pos_c, scale,
-                                         v_width)
+                                         v_width, heads_major)
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     if chunk_blocks is None:
         chunk_blocks = CHUNK_BLOCKS if v_width is None \
@@ -344,5 +384,5 @@ def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
         out = _paged_decode(
             q[:, 0], pages, layer, bt, pos_c, scale=float(scale),
             chunk=min(int(chunk_blocks), bt.shape[1]),
-            interpret=not on_tpu, v_width=v_width)
+            interpret=not on_tpu, v_width=v_width, heads_major=heads_major)
     return out[:, None]

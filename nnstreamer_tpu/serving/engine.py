@@ -298,7 +298,9 @@ class ContinuousBatchingEngine:
     cfg, params: a model config + param pytree. The config's ``family``
         (``models/family.py``) gives the prefill and paged-decode
         builders, and says what the arena holds for a token
-        (``kv_entry``), what a decode lane holds beside its blocks and
+        (``kv_entry``: its shape also decides the order of the rows
+        inside a block, ``serving/kvpool.py``), what a decode lane
+        holds beside its blocks and
         which options its programs bring: ``models.transformer`` is the
         dense member, ``models.hybrid`` the one with recurrent layers,
         ``models.mla`` the one whose cache is one latent row a token.
@@ -642,7 +644,8 @@ class ContinuousBatchingEngine:
             self.decode_attention = paged_attention_form(
                 jax.ShapeDtypeStruct(
                     (self.B, 1, cfg.n_heads, kv.shape[-1]), kv.dtype),
-                kv, self._bt, v_width=family.latent_value_width(cfg))
+                kv, self._bt, v_width=family.latent_value_width(cfg),
+                heads_major=self._pool.heads_major)
         #: bytes of the arena's entry for one token, all layers, as held;
         #: for a latent arena the form beside it (a dense engine's stats
         #: stay integers: ``tests/test_lm_tracing.py``)

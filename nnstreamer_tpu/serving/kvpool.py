@@ -13,7 +13,22 @@ KV store:
   parts of ``[h, dh]`` (int8 adds a ``[L, NTOT, 2, T, h]`` scale leaf),
   a latent cache ONE part of ``[width]``, a row every head shares — and
   allocation, refcounts, sentinel, scatter and block copy are the same
-  code for either. ONE buffer per
+  code for either. OR HEADS-MAJOR, ``[L, NTOT, parts, h, T, dh]`` (scale
+  leaf ``[L, NTOT, 2, h, T]``), where the entry has fewer heads than a
+  tile has rows (``h < 8``): a head's ``T`` tokens are then whole tiles,
+  where two rows of ``[T, 2, dh]`` to a tile made every flat view and
+  every scatter a copy of the whole arena. Who decides: the codec that
+  makes the arena (``models/transformer.py`` ``_kv_codec`` by
+  ``kv_heads_major``, from ``kv_entry``'s shape alone); it states the
+  order as ``codec.heads_major``, the pool repeats it as
+  ``pool.heads_major`` and ``snapshot()["heads_major"]``, and nothing
+  reads it off a shape (``[2, 16, dh]`` is both orders). The order is
+  seen by the prefill's scatter (which transposes the PROMPT's rows),
+  ``stream_rows`` (which hands out ``[.., tokens, h, dh]`` whatever the
+  order) and the ``tp`` spec's head axis; the block copy, allocation and
+  accounting do not care. The heads keep an axis of their own in either
+  order (not flat ``[T * h, dh]`` rows) because ``tp`` shards it. ONE
+  buffer per
   leaf that the decode program updates in place: it is a carry of the
   K-step scan and of the layer scan, never a scan's ``xs``/``ys``, and
   the layer is one more index beside the block (``[layer, block, :,
@@ -69,36 +84,40 @@ import numpy as np
 from nnstreamer_tpu.tensors import memory as _memory
 
 
-def _scatter_prefill_impl(arena, cache1, bids):
+def _scatter_prefill_impl(arena, cache1, bids, heads_major=False):
     """Scatter a prefill's batch-1 contiguous cache ([L, parts, 1, S, ...]
     leaves) into arena blocks ``bids`` ([S/T] int32, sentinel entries
     drop). Block i receives slots [i*T, (i+1)*T) — including any trailing
     bucket-pad garbage in the last data block, which stays masked until
     the owning stream overwrites it (the padded-prefill contract of
-    ``build_prefill``)."""
+    ``build_prefill``). For a ``heads_major`` arena the prompt's rows are
+    transposed on the way (the prompt's, never the arena's)."""
     import jax
     import jax.numpy as jnp
 
     def leaf(a, c):
         L, parts = c.shape[:2]
         S = c.shape[3]
-        T = a.shape[3]
+        T = a.shape[4 if heads_major else 3]
         u = c[:, :, 0]                                   # [L,parts,S,...]
         u = u.reshape((L, parts, S // T, T) + u.shape[3:])
-        u = jnp.moveaxis(u, 2, 1)                        # [L,MB,parts,T,...]
+        if heads_major:                              # [L,MB,parts,h,T,.]
+            u = jnp.transpose(u, (0, 2, 1, 4, 3) + tuple(range(5, u.ndim)))
+        else:
+            u = jnp.moveaxis(u, 2, 1)                    # [L,MB,parts,T,...]
         return a.at[:, bids].set(u.astype(a.dtype), mode="drop")
 
     with jax.named_scope("nns.kv_scatter"):
         return jax.tree.map(leaf, arena, cache1)
 
 
-def _scatter_with_state_impl(arena, cache1, bids, lane):
+def _scatter_with_state_impl(arena, cache1, bids, lane, heads_major=False):
     """The hand-over of a prefill whose family keeps lane state: keys and
     values into blocks as above, and each state leaf ``[layers, 1, ...]``
     over the whole of slot ``lane``."""
     import jax
 
-    kv = _scatter_prefill_impl(arena["kv"], cache1["kv"], bids)
+    kv = _scatter_prefill_impl(arena["kv"], cache1["kv"], bids, heads_major)
     with jax.named_scope("nns.state_scatter"):
         state = jax.tree.map(
             lambda a, c: a.at[:, lane].set(c[:, 0].astype(a.dtype)),
@@ -144,6 +163,10 @@ class BlockPool:
         self.mesh = mesh
         self.owner = owner
         self._codec = _kv_codec(cfg, kv_codec)
+        #: the order of the rows inside a block, as the codec that makes
+        #: the arena states it: ``[.., h, T, dh]`` (True) or ``[.., T,
+        #: *entry]``
+        self.heads_major = self._codec.heads_major
         self._lock = threading.Lock()
         self._free: List[int] = list(range(self.num_blocks))
         self._ref = np.zeros(self.num_blocks, np.int64)
@@ -164,7 +187,8 @@ class BlockPool:
             if self._lane_state else 0
         self._jit_scatter = jax.jit(
             _scatter_with_state_impl if self._lane_state
-            else _scatter_prefill_impl, donate_argnums=(0,))
+            else _scatter_prefill_impl, donate_argnums=(0,),
+            static_argnames=("heads_major",))
         self._jit_copy = jax.jit(_copy_block_impl, donate_argnums=(0,))
 
         acct = _memory.ACTIVE
@@ -208,8 +232,10 @@ class BlockPool:
                 f"pad num_blocks")
 
         def spec_of(leaf):
-            # [L, NTOT, parts, T, h(, dh)] — blocks over dp, heads over tp
-            head = (None, dp, None, None, tp)
+            # [L, NTOT, parts, T, h(, dh)], heads-major [L, NTOT, parts,
+            # h, T(, dh)] — blocks over dp, heads over tp
+            head = (None, dp, None, tp, None) if self.heads_major \
+                else (None, dp, None, None, tp)
             return P(*(head + (None,) * (leaf.ndim - 5)))
 
         return _serve.place_tree(arena, self.mesh, spec_of,
@@ -284,7 +310,9 @@ class BlockPool:
     def stream_rows(self, block_ids: Sequence[int], tokens: int):
         """Host copies of the first ``tokens`` entries that the blocks
         ``block_ids`` hold, in table order: per arena leaf ``[layers,
-        parts, tokens, ...]``. For checks and tests, as ``lane_state``:
+        parts, tokens, ...]``, a token's entry as the family states it
+        (``[h, dh]``) whichever order the arena keeps a block's rows in.
+        For checks and tests, as ``lane_state``:
         the caller sees to it that no program holds the arena meanwhile,
         and that no other stream was given the blocks since (a released
         block keeps its rows until its next owner writes them)."""
@@ -294,7 +322,10 @@ class BlockPool:
         kv = self.arena["kv"] if self._lane_state else self.arena
 
         def leaf(a):
-            rows = np.moveaxis(np.asarray(a[:, ids]), 2, 1)
+            rows = np.asarray(a[:, ids])                 # [L,n,parts,T,...]
+            if self.heads_major:
+                rows = np.swapaxes(rows, 3, 4)           # from [..,h,T,...]
+            rows = np.moveaxis(rows, 2, 1)
             rows = rows.reshape(rows.shape[:2] + (-1,) + rows.shape[4:])
             return rows[:, :, :tokens]
 
@@ -316,7 +347,8 @@ class BlockPool:
         bids[:len(block_ids)] = block_ids
         extra = (jnp.asarray(lane, jnp.int32),) if self._lane_state else ()
         self.arena = self._jit_scatter(self.arena, cache1,
-                                       jnp.asarray(bids), *extra)
+                                       jnp.asarray(bids), *extra,
+                                       heads_major=self.heads_major)
 
     def copy_block(self, src: int, dst: int) -> None:
         """COW fault: duplicate physical block ``src`` into ``dst``."""
@@ -346,6 +378,7 @@ class BlockPool:
                 "state_slots": self.lanes,
                 "state_slots_live": len(self._lane_live),
                 "state_bytes": self.state_bytes,
+                "heads_major": int(self.heads_major),
             }
 
 

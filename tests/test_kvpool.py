@@ -2,6 +2,8 @@
 arena invariants, and HBM accounting — the pool in isolation, before the
 engine builds continuous batching on top of it."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -66,11 +68,11 @@ def test_scatter_prefill_and_zero_block_stay_exact():
     ids = pool.alloc(2)
     pool.scatter_prefill(cache1, ids)
     got = np.asarray(jax.tree_util.tree_leaves(pool.arena)[0])
-    # block i holds prompt slots [i*T, (i+1)*T)
+    # block i holds prompt slots [i*T, (i+1)*T): 4 heads, so heads-major
+    assert pool.heads_major and got.shape[3:5] == (CFG.n_heads, T)
     for i, b in enumerate(ids):
         np.testing.assert_array_equal(
-            got[:, b], np.moveaxis(
-                want[:, :, 0, i * T:(i + 1) * T], 1, 1).reshape(got[:, b].shape))
+            got[:, b], np.swapaxes(want[:, :, 0, i * T:(i + 1) * T], 2, 3))
     # the permanent zero block is untouched (sentinel writes dropped)
     assert not np.any(got[:, pool.num_blocks])
 
@@ -133,23 +135,43 @@ def _latent_case():
     return cfg, cfg.family.init_params(cfg, 3), mla_pre, (2, 7, 1, T, 128)
 
 
-def _dense_case():
-    return CFG, PARAMS, build_prefill, (2, 7, 2, T, 4, 16)
+def _dense_case(heads=4):
+    """``heads`` key-value heads of ``64 / heads``: under 8 the arena is
+    heads-major ``[.., heads, T, dh]``, from 8 on token-major."""
+    def case():
+        cfg = dataclasses.replace(CFG, n_heads=heads)
+        dh = cfg.head_dim
+        return cfg, init_params(cfg, seed=3), build_prefill, \
+            ((2, 7, 2, heads, T, dh) if heads < 8 else (2, 7, 2, T, heads, dh))
+    return case
 
 
-@pytest.mark.parametrize("case", [_dense_case, _latent_case],
-                         ids=["two_parts_of_heads", "one_latent_row"])
+def _blocks_as_tokens(pool, blocks):
+    """``[.., T, heads, dh]`` of arena blocks in either order."""
+    return np.swapaxes(blocks, -3, -2) if pool.heads_major else blocks
+
+
+@pytest.mark.parametrize("case", [
+    _dense_case(4), _latent_case, _dense_case(1), _dense_case(2),
+    _dense_case(8), _dense_case(16)],
+    ids=["two_parts_of_heads", "one_latent_row", "one_head_heads_major",
+         "two_heads_heads_major", "eight_heads_token_major",
+         "sixteen_heads_token_major"])
 def test_one_pool_serves_both_token_entries(case):
     """Alloc, retain, release, the prefill's scatter, the copy-on-write
     block copy, the sentinel and the read of a stream's rows: the same
     ``BlockPool`` code whether a token's entry is keys and values per head
-    or one latent row (``ModelFamily.kv_entry``)."""
+    or one latent row (``ModelFamily.kv_entry``), and whether a block's
+    rows lie heads-major (fewer than 8 heads) or token-major."""
     cfg, params, prefill_of, shape = case()
     pool = kvpool.BlockPool(cfg, 6, T)
     leaf = jax.tree_util.tree_leaves(pool.arena)[0]
     assert leaf.shape == shape and pool.nbytes == leaf.nbytes
     layers, parts, entry = cfg.family.kv_entry(cfg)
-    assert shape == (layers, 7, parts, T) + entry
+    assert pool.heads_major == (len(entry) == 2 and entry[0] < 8)
+    assert pool.snapshot()["heads_major"] == int(pool.heads_major)
+    assert _blocks_as_tokens(pool, np.asarray(leaf)).shape \
+        == (layers, 7, parts, T) + entry
 
     ids = pool.alloc(3)
     pool.retain(ids[:1])
@@ -169,7 +191,8 @@ def test_one_pool_serves_both_token_entries(case):
     got = np.asarray(jax.tree_util.tree_leaves(pool.arena)[0])
     for i, b in enumerate(ids[:2]):                 # entry: the sentinel
         np.testing.assert_array_equal(
-            got[:, b], want[:, :, 0, i * T:(i + 1) * T])
+            _blocks_as_tokens(pool, got[:, b]),
+            want[:, :, 0, i * T:(i + 1) * T])
     assert not got[:, ids[2]].any()                 # never written
     assert not got[:, pool.num_blocks].any()        # the zero block: zeros
     # a stream's rows, in table order, are the prefill's
@@ -183,9 +206,94 @@ def test_one_pool_serves_both_token_entries(case):
     from nnstreamer_tpu.models.transformer import _paged_gather
 
     bt = jnp.asarray([[ids[0], pool.SENTINEL]], jnp.int32)
-    g = np.asarray(_paged_gather(pool.arena, 0, bt))
-    assert g.shape[:3] == (1, parts, 2 * T) and not g[:, :, T:].any()
-    assert g[:, :, :T].any()
+    g = np.asarray(_paged_gather(pool.arena, 0, bt, pool.heads_major))
+    assert g.shape == (1, parts, 2 * T) + entry and not g[:, :, T:].any()
+    np.testing.assert_array_equal(g[0, :, :T], want[0, :, 0, :T])
     pool.reset()
     assert pool.free_blocks == 6 and not np.asarray(pool.arena).any()
     assert pool.lane_state(0) == {}
+
+
+# -- the order of the rows inside a block: one rule, stated, never inferred ---
+
+@pytest.mark.parametrize("entry,heads_major,tail", [
+    ((1, 128), True, (1, 16, 128)),
+    ((2, 256), True, (2, 16, 256)),
+    ((4, 128), True, (4, 16, 128)),
+    ((8, 128), False, (16, 8, 128)),
+    ((16, 128), False, (16, 16, 128)),
+    ((640,), False, (16, 640)),
+], ids=["1_head", "2_heads", "4_heads", "8_heads", "16_heads", "latent_row"])
+@pytest.mark.parametrize("codec", ["raw", "int8"])
+def test_the_rule_fewer_heads_than_a_tiles_rows_are_heads_major(
+        entry, heads_major, tail, codec):
+    """``kv_heads_major`` reads the entry's shape and nothing else; the
+    codec it is handed to shapes both leaves by it and SAYS which order
+    it made (``[2, 16, dh]`` is 2 heads of 16 tokens and 2 tokens of 16
+    heads: nobody can read it back)."""
+    from nnstreamer_tpu.models import transformer as tr
+
+    assert tr.kv_heads_major(entry) is heads_major
+    made = (tr._RawKVCodec(jnp.bfloat16, heads_major) if codec == "raw"
+            else tr._Int8KVCodec(heads_major))
+    assert made.heads_major is heads_major
+    arena = jax.eval_shape(lambda: made.paged_init(
+        3, 9, 16, *entry, parts=len(entry)))
+    if codec == "raw":
+        assert arena.shape == (3, 9, len(entry)) + tail
+    else:
+        assert arena["q"].shape == (3, 9, len(entry)) + tail
+        assert arena["scale"].shape == (3, 9, len(entry)) + tail[:-1]
+    # the default is today's order: a codec made without the word
+    assert not tr._RawKVCodec(jnp.bfloat16).heads_major
+    assert not tr._Int8KVCodec().heads_major
+
+
+@pytest.mark.parametrize("name,driver,builder,shape,heads_major", [
+    ("pythia_1p4b", "lm", "transformer_config",
+     (24, 1025, 2, 16, 16, 128), False),
+    ("granite_4p0_h_small_ep2", "lm_hybrid", "hybrid_config",
+     (1, 4097, 2, 16, 8, 128), False),
+    ("qwen3_next_80b_a3b_ep2", "lm_qwen3_next", "qwen3_next_config",
+     (1, 16385, 2, 2, 16, 256), True),
+    ("deepseek_v2_lite_ep2", "lm_deepseek_v2", "deepseek_v2_config",
+     (14, 16385, 1, 16, 640), False),
+])
+def test_the_registered_configurations_arenas(name, driver, builder, shape,
+                                              heads_major):
+    """The arena each registered cell's pool makes, as the exact shape
+    (shapes only: nothing that size is allocated here): three stay what
+    they were before PR 34, Qwen3-Next's two key-value heads go first."""
+    import importlib
+    import json
+    import os
+
+    from nnstreamer_tpu.models.transformer import _kv_codec
+
+    root = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+    with open(os.path.join(root, "configs", name + ".json")) as f:
+        config = json.load(f)
+    cfg = getattr(importlib.import_module("benchmark.drivers." + driver),
+                  builder)(config)
+    layers, parts, entry = cfg.family.kv_entry(cfg)
+    codec = _kv_codec(cfg, None)
+    assert codec.heads_major is heads_major
+    T = config["block_tokens"]
+    ntot = config["max_streams"] * (cfg.max_seq // T) + 1
+    arena = jax.eval_shape(lambda: codec.paged_init(
+        layers, ntot, T, *entry, parts=parts))
+    assert arena.shape == shape and arena.dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("heads,spec", [
+    (4, (None, "dp", None, "tp", None, None)),
+    (8, (None, "dp", None, None, "tp", None))],
+    ids=["heads_major", "token_major"])
+def test_tp_shards_the_head_axis_wherever_the_order_puts_it(heads, spec):
+    from nnstreamer_tpu.parallel.mesh import make_mesh
+
+    cfg = dataclasses.replace(CFG, n_heads=heads)
+    pool = kvpool.BlockPool(cfg, 7, T, mesh=make_mesh([("dp", 2),
+                                                       ("tp", 2)]))
+    assert tuple(pool.arena.sharding.spec) == spec
+    assert pool.arena.shape[spec.index("tp")] == heads

@@ -369,8 +369,8 @@ def test_device_trace_writes_the_ledger_on_the_traces_clock(tmp_path):
 
 # -- names inside the programs -----------------------------------------------
 
-def _lowered_text(jitted, *shapes):
-    return jitted.lower(*shapes).as_text(debug_info=True)
+def _lowered_text(jitted, *shapes, **static):
+    return jitted.lower(*shapes, **static).as_text(debug_info=True)
 
 
 def _has_scope(text, scope):
@@ -430,7 +430,8 @@ def test_scatter_program_holds_its_scope():
     arena = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), eng._pool.arena)
     text = _lowered_text(eng._pool._jit_scatter, arena, cache1,
-                         jax.ShapeDtypeStruct((CFG.max_seq // 8,), jnp.int32))
+                         jax.ShapeDtypeStruct((CFG.max_seq // 8,), jnp.int32),
+                         heads_major=eng._pool.heads_major)
     assert "module @jit__scatter_prefill_impl" in text
     assert "nns.kv_scatter" in text
 

@@ -9,8 +9,11 @@ call of its own and inside the engine's K-step decode dispatch. What
 ``auto`` builds is the gather form wherever the kernel does not run: off a
 TPU, under a mesh, for an int8 arena and for the chunk builder. One test
 compiles the kernel at the three cells' widths for a described TPU v5e:
-head dims 128 and 256, groups of 1, 4 and 8 query heads a key-value head;
-another the routed experts' kernel at both expert cells' shapes.
+head dims 128 and 256, groups of 1, 4 and 8 query heads a key-value head,
+each arena in the order its codec makes it (heads-major under 8 key-value
+heads); another the three other programs that hold a two-head arena, none
+of which may move it; another the routed experts' kernel at both expert
+cells' shapes.
 """
 
 import functools
@@ -45,20 +48,24 @@ T, MB, DH, LAYERS = 8, 12, 128, 2
 HELD = (1, T - 1, T, T + 1, (MB * T) // 2 + 3, MB * T - 1, 0)
 
 
-def _pool(hk, dtype, seed, dh=DH):
+def _pool(hk, dtype, seed, dh=DH, t=T, heads_major=False):
     """A scrambled pool: every lane's blocks anywhere in the arena, the
-    zero block last, unallocated table entries at the sentinel."""
+    zero block last, unallocated table entries at the sentinel. Blocks of
+    ``t`` tokens (``HELD`` scaled with it); ``heads_major`` hands the SAME
+    numbers out as ``[.., hk, t, dh]`` blocks."""
     rng = np.random.default_rng(seed)
     lanes = len(HELD)
     nb = lanes * MB
-    pages = rng.standard_normal((LAYERS, nb + 1, 2, T, hk, dh))
+    pages = rng.standard_normal((LAYERS, nb + 1, 2, t, hk, dh))
     pages[:, nb] = 0.0
+    if heads_major:
+        pages = np.swapaxes(pages, 3, 4)
     order = rng.permutation(nb).reshape(lanes, MB)
     bt = np.full((lanes, MB), nb + 1, np.int32)
-    for lane, held in enumerate(HELD):
-        n = -(-held // T)
-        bt[lane, :n] = order[lane, :n]
-    pos = np.maximum(np.asarray(HELD) - 1, 0).astype(np.int32)
+    held = [h * t // T for h in HELD]
+    for lane, n in enumerate(held):
+        bt[lane, :-(-n // t)] = order[lane, :-(-n // t)]
+    pos = np.maximum(np.asarray(held) - 1, 0).astype(np.int32)
     return (jnp.asarray(pages, dtype), jnp.asarray(bt), jnp.asarray(pos),
             rng)
 
@@ -98,6 +105,75 @@ def test_kernel_equals_attend_cache_over_the_gathered_table(
     assert not after[:, -1].any()                    # the zero block zero
 
 
+@pytest.mark.parametrize("dh", [128, 256])
+@pytest.mark.parametrize("hq,hk,dtype,tol", [
+    (16, 1, jnp.float32, 1e-5), (16, 1, jnp.bfloat16, 2e-2),
+    (16, 2, jnp.float32, 1e-5), (16, 2, jnp.bfloat16, 2e-2),
+    (16, 4, jnp.float32, 1e-5), (16, 4, jnp.bfloat16, 2e-2),
+    (8, 2, jnp.float32, 1e-5)],    # 8 rows: a float32 tile, half a bf16 one
+    ids=["16over1-f32", "16over1-bf16", "16over2-f32", "16over2-bf16",
+         "16over4-f32", "16over4-bf16", "8over2-f32"])
+def test_heads_major_kernel_equals_the_reference_and_the_gather_form(
+        hq, hk, dtype, tol, dh):
+    """Blocks of ``[hk, T, dh]`` rows (fewer key-value heads than a tile
+    has rows): the kernel's two masks find a column's head and slot in
+    that order; ragged positions, a block edge, a full table, an empty
+    lane whose table is all sentinel. Held to ``paged_attention_reference``
+    told the same order, which is ``_attend_cache`` over what
+    ``_paged_gather`` copies out, which equals, to the bit, the gather form
+    over the token-major arena of the same numbers."""
+    from nnstreamer_tpu.ops.paged_attention import paged_attention_reference
+
+    seed = hq + hk + dh
+    t = 16
+    pages, bt, pos, rng = _pool(hk, dtype, seed, dh=dh, t=t,
+                                heads_major=True)
+    twin = _pool(hk, dtype, seed, dh=dh, t=t)[0]
+    assert pages.shape[3:5] == (hk, t) and twin.shape[3:5] == (t, hk)
+    q = jnp.asarray(rng.standard_normal((len(HELD), 1, hq, dh)), dtype)
+    before = np.asarray(pages, np.float32)
+    for layer in range(LAYERS):
+        g = _paged_gather(pages, layer, bt, True)
+        np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(
+            _paged_gather(twin, layer, bt), np.float32))
+        mask = jnp.arange(MB * t)[None, None, None, :] \
+            <= pos[:, None, None, None]
+        want = _attend_cache(q, g[:, 0], g[:, 1], mask, dh, dtype, scale=0.1)
+        ref = paged_attention_reference(q, pages, layer, bt, pos, 0.1,
+                                        heads_major=True)
+        np.testing.assert_array_equal(np.asarray(ref, np.float32),
+                                      np.asarray(want, np.float32))
+        for chunk in (2, 8):
+            got = paged_attention(q, pages, layer, bt, pos, scale=0.1,
+                                  force="pallas", chunk_blocks=chunk,
+                                  heads_major=True)
+            assert got.shape == want.shape and got.dtype == want.dtype
+            np.testing.assert_allclose(np.asarray(got, np.float32),
+                                       np.asarray(want, np.float32),
+                                       rtol=tol, atol=tol)
+            assert not np.asarray(got, np.float32)[-1].any()  # empty lane
+    np.testing.assert_array_equal(np.asarray(pages, np.float32), before)
+
+
+def test_the_order_is_told_never_read_off_the_shape():
+    """16 tokens of 16 heads and 16 heads of 16 tokens are one shape: the
+    same arena read in the two orders gives different numbers, each the
+    gather form's for the order it was told."""
+    pages, bt, pos, rng = _pool(16, jnp.float32, seed=5, t=16)
+    q = jnp.asarray(rng.standard_normal((len(HELD), 1, 16, DH)), jnp.float32)
+    out = {}
+    for heads_major in (False, True):
+        g = _paged_gather(pages, 0, bt, heads_major)
+        mask = jnp.arange(MB * 16)[None, None, None, :] \
+            <= pos[:, None, None, None]
+        want = _attend_cache(q, g[:, 0], g[:, 1], mask, DH, jnp.float32)
+        out[heads_major] = got = paged_attention(
+            q, pages, 0, bt, pos, force="pallas", heads_major=heads_major)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+    assert np.abs(np.asarray(out[True]) - np.asarray(out[False])).max() > 0.1
+
+
 def test_layer_may_be_traced_and_reference_is_the_gather_form():
     pages, bt, pos, rng = _pool(16, jnp.float32, seed=3)
     q = jnp.asarray(rng.standard_normal((len(HELD), 1, 16, DH)),
@@ -124,18 +200,24 @@ def test_layer_may_be_traced_and_reference_is_the_gather_form():
     ("sublanes", dict(hq=4, hk=4)),
     ("key-value heads", dict(hq=24, hk=16)),
     ("keys and values", dict(q_dtype=jnp.float32)),
+    # heads-major: 8 tokens of one head are half a bfloat16 tile; 3 heads
+    # do not divide 16 (read token-major the same arena is 8 heads: taken)
+    ("sublanes", dict(hk=1, heads_major=True)),
+    ("key-value heads", dict(pages=(1, 5, 2, 3, 8, 128), heads_major=True)),
 ])
 def test_forced_kernel_refuses_shapes_it_does_not_take(why, kw):
     hq, hk, dh = kw.get("hq", 16), kw.get("hk", 16), kw.get("dh", 128)
+    hm = kw.get("heads_major", False)
     q = jnp.zeros((2, kw.get("queries", 1), hq, dh),
                   kw.get("q_dtype", jnp.bfloat16))
-    pages = jnp.zeros((1, 5, 2, T, hk, dh), jnp.bfloat16)
+    pages = jnp.zeros(kw.get("pages", (1, 5, 2, hk, T, dh) if hm
+                             else (1, 5, 2, T, hk, dh)), jnp.bfloat16)
     bt = jnp.zeros((2, 2), jnp.int32)
     pos = jnp.zeros((2,), jnp.int32)
     with pytest.raises(ValueError, match=why):
-        paged_attention(q, pages, 0, bt, pos, force="pallas")
+        paged_attention(q, pages, 0, bt, pos, force="pallas", heads_major=hm)
     # auto gives way to the gather form instead (off a TPU it always does)
-    assert paged_attention_form(q, pages, bt) == "gather"
+    assert paged_attention_form(q, pages, bt, heads_major=hm) == "gather"
 
 
 # -- a latent arena: one row a token, shared by every head --------------------
@@ -196,17 +278,19 @@ def test_latent_kernel_equals_attend_cache_over_the_gathered_rows(
     ("against rows of", dict(q_width=160)),
     ("sublanes", dict(hq=4)),
     ("sublanes", dict(pages=(1, 5, 1, 8, 192))),
+    ("no heads", dict(heads_major=True)),
 ])
 def test_forced_latent_kernel_refuses_shapes_it_does_not_take(why, kw):
     q = jnp.zeros((2, 1, kw.get("hq", 16), kw.get("q_width", 192)),
                   jnp.bfloat16)
     pages = jnp.zeros(kw.get("pages", (1, 5, 1, 16, 192)), jnp.bfloat16)
     bt = jnp.zeros((2, 2), jnp.int32)
-    vw = kw.get("v_width", 128)
+    vw, hm = kw.get("v_width", 128), kw.get("heads_major", False)
     with pytest.raises(ValueError, match=why):
         paged_attention(q, pages, 0, bt, jnp.zeros((2,), jnp.int32),
-                        force="pallas", v_width=vw)
-    assert paged_attention_form(q, pages, bt, v_width=vw) == "gather"
+                        force="pallas", v_width=vw, heads_major=hm)
+    assert paged_attention_form(q, pages, bt, v_width=vw,
+                                heads_major=hm) == "gather"
 
 
 # -- inside the engine's K-step dispatch --------------------------------------
@@ -231,12 +315,17 @@ def _serve(**kw):
 
 def test_k_step_dispatch_with_the_kernel_serves_the_gather_forms_tokens(
         monkeypatch):
+    """Eight heads, so a token-major arena (a dense block's query heads
+    ARE its key-value heads, and the kernel wants eight: the heads-major
+    twin of this test is ``tests/test_qwen3_next_lm.py``'s, 8 query over
+    2 key-value heads)."""
     _, want = _serve(attention="reference")
     # off a TPU auto builds the gather form, so hand the engine the kernel
     # forced (the interpreter runs it inside the real K-step program)
     monkeypatch.setattr(ops_pkg, "paged_attention", functools.partial(
         paged_attention, force="pallas"))
     eng, got = _serve()
+    assert not eng._pool.heads_major
     text = engine_mod.decode_program_text(eng.obs_name)
     assert "kv_gather/gather" not in text and "/attend/" in text
     for (toks, lps), (ref_toks, ref_lps) in zip(got, want):
@@ -315,6 +404,26 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _moves_of(text, elements):
+    """The instructions of a compiled program that MOVE ``elements`` or
+    more: a copy, a reshape that is no bitcast, a transpose (alone or as
+    the root a fusion is named after)."""
+    import re
+
+    moves = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]", line)
+        if not m or not m.group(2):
+            continue
+        size = int(np.prod([int(d) for d in m.group(2).split(",")]))
+        if size >= elements and (
+                re.search(r"[\]}] (copy|reshape|transpose)\(", line)
+                or (" fusion(" in line and re.search(
+                    r"copy|transpose|reshape", m.group(1)))):
+            moves.append(line.strip()[:160])
+    return moves
+
+
 @pytest.mark.parametrize("lanes,hq,hk,dh,mb,layers,ntot", [
     (8, 16, 16, 128, 128, 24, 1025),    # pythia_chat_closed
     (64, 32, 8, 128, 64, 1, 4097),      # granite_h_chat_closed
@@ -322,40 +431,100 @@ def one_chip():
 ], ids=["pythia_1p4b", "granite_4p0_h_small_ep2", "qwen3_next_80b_a3b_ep2"])
 def test_mosaic_compiles_the_kernel_at_the_cells_widths(
         one_chip, lanes, hq, hk, dh, mb, layers, ntot):
+    """Each cell's arena in the order its codec makes it (2 key-value
+    heads: heads-major): Mosaic takes the kernel, and the flat view the
+    kernel reads is a bitcast: nothing arena-sized is copied, reshaped or
+    transposed (token-major at 2 heads XLA relaid all 537 MB out a step,
+    ``%reshape.2236 bf16[1,16385,2,32,256]``: PERF.md, PR 31 and PR 34)."""
+    from nnstreamer_tpu.models.transformer import kv_heads_major
+
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
+    heads_major = kv_heads_major((hk, dh))
+    assert heads_major == (hk == 2)
+    block = (hk, 16) if heads_major else (16, hk)
+    text = _compile_for_the_chip(
+        jax.jit(functools.partial(
+            _paged_decode, scale=0.1, chunk=8, interpret=False,
+            heads_major=heads_major)),
+        shape((lanes, hq, dh), jnp.bfloat16),
+        shape((layers, ntot, 2) + block + (dh,), jnp.bfloat16),
+        shape((), jnp.int32), shape((lanes, mb), jnp.int32),
+        shape((lanes,), jnp.int32))
+    assert "tpu_custom_call" in text and "nns_paged_decode" in text
+    # the arena goes to the kernel as it lies: a bitcast, never a copy
+    assert not _moves_of(text, layers * ntot * 2 * 16 * hk * dh)
+
+
+@pytest.mark.parametrize("program", ["prefill_scatter", "decode_write",
+                                     "prefix_extension"])
+def test_no_program_moves_the_arena_at_two_key_value_heads(one_chip,
+                                                           program):
+    """The other programs that hold ``qwen3next_chat_closed``'s arena
+    (``[1, 16385, 2, 2, 16, 256]``, 537 MB), compiled for the chip: the
+    prefill's hand-over, eight steps of the decode write with the arena a
+    carry, and the prefix-extension program of a dense block with the same
+    entry. None copies, reshapes or transposes anything arena-sized, and
+    none plans a temporary near it (token-major the hand-over copied the
+    arena into ``{5,3,4,2,1,0}`` and back, 537 MB of temporaries; a
+    heads-major write with the window ``[2, h, 1, dh]`` did the same
+    every step)."""
+    from nnstreamer_tpu.models import transformer as tr
+    from nnstreamer_tpu.serving import kvpool
+
+    def shape(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    cfg = TransformerConfig(vocab=1024, d_model=512, n_heads=2, n_layers=1,
+                            d_ff=1024, max_seq=2048, dtype=jnp.bfloat16)
+    codec = tr._kv_codec(cfg, None)
+    assert codec.heads_major
+    arena = jax.eval_shape(lambda: codec.paged_init(1, 16385, 16, 2, 256))
+    assert arena.shape == (1, 16385, 2, 2, 16, 256)
+    arena = shape(arena.shape, arena.dtype)
+    if program == "prefill_scatter":
+        fn = jax.jit(functools.partial(kvpool._scatter_prefill_impl,
+                                       heads_major=True),
+                     donate_argnums=(0,))
+        args = (arena, shape((1, 2, 1, 512, 2, 256), jnp.bfloat16),
+                shape((32,)))
+    elif program == "decode_write":
+        def steps(pages, kv, blk, off):
+            def body(carry, _):
+                pages, off = carry
+                pages = codec.paged_write(pages, jnp.int32(0), kv, blk, off)
+                return (pages, (off + 1) % 16), pages[0, 0, 0, 0, 0, 0]
+            return jax.lax.scan(body, (pages, off), None, length=8)
+
+        fn = jax.jit(steps, donate_argnums=(0,))
+        args = (arena, shape((2, 128, 1, 2, 256), jnp.bfloat16),
+                shape((128, 1)), shape((128, 1)))
+    else:
+        params = jax.eval_shape(lambda: tr.init_params(cfg, 0))
+        fn = jax.jit(tr.build_paged_chunk(cfg, 16), donate_argnums=(2,))
+        args = (jax.tree.map(lambda a: shape(a.shape, a.dtype), params),
+                shape((1, 64)), arena, shape((1, 128)), shape((1,)),
+                shape((1,)))
+    compiled = _compiled_for_the_chip(fn, *args)
+    assert not _moves_of(compiled.as_text(), 16385 * 2 * 2 * 16 * 256)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
+
+
+def _compiled_for_the_chip(fn, *shapes, **kw):
     from jax.experimental.compilation_cache import compilation_cache
 
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        text = jax.jit(functools.partial(
-            _paged_decode, scale=0.1, chunk=8, interpret=False)).lower(
-            shape((lanes, hq, dh), jnp.bfloat16),
-            shape((layers, ntot, 2, 16, hk, dh), jnp.bfloat16),
-            shape((), jnp.int32), shape((lanes, mb), jnp.int32),
-            shape((lanes,), jnp.int32)).compile().as_text()
+        return fn.lower(*shapes, **kw).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
-    assert "tpu_custom_call" in text and "nns_paged_decode" in text
-    # the arena goes to the kernel as it lies: a bitcast, never a copy
-    arena = f"bf16[{layers},{ntot},2,"
-    assert not [line for line in text.splitlines()
-                if " copy(" in line and arena in line.split(" copy(")[0]]
 
 
 def _compile_for_the_chip(fn, *shapes, **kw):
-    from jax.experimental.compilation_cache import compilation_cache
-
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        return fn.lower(*shapes, **kw).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-        compilation_cache.reset_cache()
+    return _compiled_for_the_chip(fn, *shapes, **kw).as_text()
 
 
 def test_mosaic_compiles_the_latent_kernel_at_the_cells_widths(one_chip):

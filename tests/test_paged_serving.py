@@ -245,6 +245,72 @@ def test_prefix_entry_blocks_survive_donor_stream_exit():
     assert got == reference_greedy(base, 9)
 
 
+# -- either order of the rows inside a block (PR 34) ----------------------
+
+
+def _reference_with_logprobs(prompt, n_tokens, cfg, params, kv_codec=None):
+    """``reference_greedy`` with each token's log-probability."""
+    from nnstreamer_tpu.models.transformer import (
+        build_decode_step,
+        build_prefill,
+    )
+
+    prefill = jax.jit(build_prefill(cfg, kv_codec=kv_codec))
+    decode = jax.jit(build_decode_step(cfg, kv_codec=kv_codec))
+    logits, cache = prefill(
+        params, jnp.asarray(np.asarray(prompt, np.int32)[None]))
+    toks, lps = [], []
+    for i in range(n_tokens):
+        lp = jax.nn.log_softmax(logits[0].astype(jnp.float32))
+        toks.append(int(jnp.argmax(lp)))
+        lps.append(float(lp[toks[-1]]))
+        logits, cache = decode(
+            params, jnp.asarray(toks[-1:], jnp.int32), cache,
+            jnp.asarray(len(prompt) + i, jnp.int32))
+    return toks, lps
+
+
+@pytest.mark.parametrize("codec", [None, "int8"], ids=["raw", "int8"])
+@pytest.mark.parametrize("heads", [1, 2, 4, 8],
+                         ids=["heads1_major", "heads2_major", "heads4_major",
+                              "heads8_token_major"])
+def test_engine_serves_the_contiguous_reference_in_either_order(heads,
+                                                                codec):
+    """Prefill scatter, decode writes across block edges, the shared
+    prefix's blocks, its copy-on-write tail and the prefix-extension
+    program: tokens AND log-probabilities of the contiguous-cache
+    reference, whichever way the arena orders a block's rows."""
+    from nnstreamer_tpu.models.transformer import init_params
+
+    cfg = dataclasses.replace(CFG, n_heads=heads)
+    params = init_params(cfg, seed=3)
+    base = [7, 3, 9, 1, 4, 6, 2, 8, 5, 11, 13, 17, 19, 23, 29, 27, 25]
+    prompts = [base, base + [31, 37], PROMPTS[0], PROMPTS[6]]
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_streams=3, steps_per_dispatch=4, temperature=0.0,
+        block_tokens=T, prefix_cache=4, kv_blocks=64,
+        kv_quant=codec).start()
+    try:
+        assert eng._pool.heads_major == (heads < 8)
+        assert eng._pool.snapshot()["heads_major"] == int(heads < 8)
+        got = []
+        for p in prompts:
+            stream = eng.submit(p, max_new_tokens=11)
+            got.append((stream.result(timeout=240), list(stream.logprobs)))
+        assert eng.stats["prefix_hits"] >= 1
+    finally:
+        eng.stop()
+    for p, (toks, lps) in zip(prompts, got):
+        want_toks, want_lps = _reference_with_logprobs(p, 11, cfg, params,
+                                                       kv_codec=codec)
+        assert toks == want_toks, f"prompt={p}"
+        # a prefix hit attends over STORED keys and values where the
+        # reference's prefill attends over fresh ones: int8 rounds them
+        np.testing.assert_allclose(lps, want_lps,
+                                   atol=2e-5 if codec is None else 2e-3)
+    assert_zero_block_is_zero(eng)
+
+
 # -- satellite 4: paging x int8 x mesh=dp2 --------------------------------
 
 
@@ -283,12 +349,13 @@ def test_paged_int8_dp2_mesh_matches_single_device():
 CODECS = pytest.mark.parametrize("codec", [None, "int8"], ids=["raw", "int8"])
 
 
-def _toy():
+def _toy(heads=CFG.n_heads):
     """3 layers (so an index off by one lands on a real layer or off the
-    end), 3 lanes, the last one EMPTY: its table is all sentinel."""
+    end), 3 lanes, the last one EMPTY: its table is all sentinel.
+    ``heads`` under 8 make the arena heads-major."""
     from nnstreamer_tpu.models.transformer import init_params
 
-    cfg = dataclasses.replace(CFG, n_layers=3, max_seq=32)
+    cfg = dataclasses.replace(CFG, n_layers=3, max_seq=32, n_heads=heads)
     mb = cfg.max_seq // T
     nb = 3 * mb                                   # ntot 13, sentinel 13
     bt = np.full((3, mb), nb + 1, np.int32)
@@ -296,7 +363,8 @@ def _toy():
     return cfg, init_params(cfg, seed=5), bt, nb
 
 
-def _assert_pool_is_the_monolithic_cache(arena, cache, bt, nb):
+def _assert_pool_is_the_monolithic_cache(arena, cache, bt, nb,
+                                         heads_major=False):
     """Every layer of the pool, the LAST included, holds what the
     monolithic cache holds for the live lanes, slot for slot; every block
     no live lane owns — the zero block first — is still zero."""
@@ -305,6 +373,8 @@ def _assert_pool_is_the_monolithic_cache(arena, cache, bt, nb):
     assert nb in rest
     for leaf, mono in zip(jax.tree.leaves(arena), jax.tree.leaves(cache)):
         leaf, mono = np.asarray(leaf), np.asarray(mono)
+        if heads_major:                  # [L,NTOT,2,h,T,..] as [..,T,h,..]
+            leaf = np.swapaxes(leaf, 3, 4)
         assert mono[-1].any(), "the last layer wrote nothing to compare"
         for layer in range(leaf.shape[0]):
             for lane in (0, 1):
@@ -318,8 +388,11 @@ def _assert_pool_is_the_monolithic_cache(arena, cache, bt, nb):
 
 @CODECS
 @pytest.mark.parametrize("builder", ["step", "chunk"])
+@pytest.mark.parametrize("heads", [4, 1, 2, 8],
+                         ids=["", "heads1_major", "heads2_major",
+                              "heads8_token_major"])
 def test_paged_builders_are_bit_identical_to_the_monolithic_ones(
-        builder, codec):
+        builder, codec, heads):
     from nnstreamer_tpu.models.transformer import (
         _kv_codec,
         build_chunk_decode,
@@ -329,9 +402,13 @@ def test_paged_builders_are_bit_identical_to_the_monolithic_ones(
         init_cache,
     )
 
-    cfg, params, bt, nb = _toy()
-    arena = _kv_codec(cfg, codec).paged_init(
+    cfg, params, bt, nb = _toy(heads)
+    made = _kv_codec(cfg, codec)
+    assert made.heads_major == (heads < 8)
+    arena = made.paged_init(
         cfg.n_layers, nb + 1, T, cfg.n_heads, cfg.head_dim)
+    for leaf in jax.tree.leaves(arena):
+        assert leaf.shape[3:5] == ((heads, T) if heads < 8 else (T, heads))
     cache = init_cache(cfg, 3, kv_codec=codec)
     tables = jnp.asarray(bt)
     rng = np.random.default_rng(11)
@@ -355,7 +432,8 @@ def test_paged_builders_are_bit_identical_to_the_monolithic_ones(
                                jnp.full((3,), 5, jnp.int32))
             want, cache = mono(params, toks, cache, pos0)
             np.testing.assert_array_equal(got[:2], want[:2], f"chunk {i}")
-    _assert_pool_is_the_monolithic_cache(arena, cache, bt, nb)
+    _assert_pool_is_the_monolithic_cache(arena, cache, bt, nb,
+                                         made.heads_major)
 
 
 def _scans(jaxpr):

@@ -18,6 +18,7 @@ so that the layers, not the embedding, decide the logits.
 """
 
 import dataclasses
+import functools
 import time
 
 import numpy as np
@@ -66,18 +67,18 @@ def _prompt(n, seed=0):
         1, CFG.vocab, n, dtype=np.int32)
 
 
-def _engine(**kw):
+def _engine(cfg=CFG, params=PARAMS, **kw):
     kw.setdefault("max_streams", 4)
     kw.setdefault("steps_per_dispatch", 4)
     kw.setdefault("block_tokens", 16)
-    return ContinuousBatchingEngine(CFG, PARAMS, **kw)
+    return ContinuousBatchingEngine(cfg, params, **kw)
 
 
-def _reference(tokens, first, count, **wrong):
+def _reference(tokens, first, count, cfg=CFG, params=PARAMS, **wrong):
     return np.asarray(jax.jit(
-        lambda p, t: ref.qwen3_next_logprobs(p, t, first, count, CFG,
+        lambda p, t: ref.qwen3_next_logprobs(p, t, first, count, cfg,
                                              **wrong))(
-            PARAMS, jnp.asarray(tokens)))
+            params, jnp.asarray(tokens)))
 
 
 # -- (a) prefill -------------------------------------------------------------
@@ -123,23 +124,47 @@ def test_the_tolerance_tells_each_wrong_model_from_the_right_one(wrong):
 
 # -- (b) prefill, then decode through the engine's paged path ----------------
 
-@pytest.mark.parametrize("n", [7, 21, 40])
-def test_engine_serves_what_the_reference_computes_at_every_step(n):
+@pytest.mark.parametrize("n,form", [
+    (7, "gather"), (21, "gather"), (40, "gather"), (21, "paged_kernel"),
+    (40, "paged_kernel")], ids=["7", "21", "40", "21-kernel", "40-kernel"])
+def test_engine_serves_what_the_reference_computes_at_every_step(
+        n, form, monkeypatch):
     """Prefill (padded to its bucket), the hand-over of state and blocks,
     and 25 decode steps in dispatches of 4, rotary positions from the
     lane's own: each served token's reported log-probability is the
     reference's, from ONE forward over prompt + served tokens, and each
-    token is the reference's best."""
+    token is the reference's best. Two key-value heads: the arena is
+    heads-major (``[.., 2, 16, dh]`` a block half), in the gather form
+    and, at a head dim the kernel takes (8 query heads of 128 over the
+    2), through ``nns_paged_decode`` interpreted inside the real K-step
+    program."""
+    cfg, params = CFG, PARAMS
+    if form == "paged_kernel":
+        import nnstreamer_tpu.ops as ops_pkg
+        from nnstreamer_tpu.ops.paged_attention import paged_attention
+
+        cfg = dataclasses.replace(CFG, n_heads=8, head_dim=128,
+                                  attention_scale=128 ** -0.5)
+        params = _weights(cfg, 5)
+        monkeypatch.setattr(ops_pkg, "paged_attention", functools.partial(
+            paged_attention, force="pallas"))
     new = 26
-    eng = _engine().start()
+    eng = _engine(cfg, params).start()
     try:
+        assert eng._pool.heads_major and eng._pool.arena["kv"].shape[3:5] \
+            == (2, 16)
         prompt = _prompt(n, seed=1)
         stream = eng.submit(prompt, max_new_tokens=new)
         toks = np.asarray(stream.result(timeout=300))
+        text = engine_mod.decode_program_text(eng.obs_name) \
+            if form == "paged_kernel" else ""
     finally:
         eng.stop()
+    assert ("kv_gather/gather" not in text and "/attend/" in text) \
+        == (form == "paged_kernel")
     assert len(toks) == new and stream.finish_reason == "length"
-    lp = _reference(np.concatenate([prompt, toks[:-1]]), n - 1, new)
+    lp = _reference(np.concatenate([prompt, toks[:-1]]), n - 1, new, cfg,
+                    params)
     at = lp[np.arange(new), toks]
     assert np.abs(at - np.asarray(stream.logprobs)).max() < TOL
     assert (lp.max(axis=1) - at).max() < TOL

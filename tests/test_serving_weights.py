@@ -86,8 +86,9 @@ def direct(cfg, params, n_new, temperature, prompt=PROMPT):
                              lengths=jnp.asarray([n], jnp.int32))
     tok, keys, lp = jax.jit(sample)(
         logits, jnp.asarray([[SEED, 0]], jnp.uint32))
+    # 4 heads: the arena's blocks are heads-major, [.., 2, h, T, dh]
     blocks = cache1[:, :, 0].reshape(
-        L, 2, MB, T, cfg.n_heads, cfg.head_dim).transpose(0, 2, 1, 3, 4, 5)
+        L, 2, MB, T, cfg.n_heads, cfg.head_dim).transpose(0, 2, 1, 4, 3, 5)
     arena = jnp.zeros((L, MB + 1) + blocks.shape[2:], cfg.dtype
                       ).at[:, :MB].set(blocks)
     bt = jnp.arange(MB, dtype=jnp.int32)[None]
@@ -145,7 +146,7 @@ def test_builders_on_the_held_tree_equal_the_float32_tree_bit_for_bit(name):
     step = jax.jit(build_paged_decode_step(cfg, T, cfg.max_seq))
     arena = jax.random.normal(
         jax.random.PRNGKey(1),
-        (cfg.n_layers, 9, 2, T, cfg.n_heads, cfg.head_dim)).astype(cfg.dtype)
+        (cfg.n_layers, 9, 2, cfg.n_heads, T, cfg.head_dim)).astype(cfg.dtype)
     bt = jnp.arange(8, dtype=jnp.int32)[None]
     args = (jnp.asarray([7], jnp.int32), arena, bt,
             jnp.asarray([19], jnp.int32))
