@@ -85,6 +85,14 @@ def register_engine_collector(engine, registry: MetricsRegistry = None
                     "Engine-loop thread time by phase; the phases tile "
                     "the loop", phase=key[len("phase_"):-len("_us")],
                     **labels).set_total(us / 1e6)
+            elif key.startswith("starved_") and key != "starved_us":
+                reg.counter(
+                    "nns_serving_loop_starved_seconds_total",
+                    "The part of a phase in which the loop had nothing "
+                    "queued on the device; phase less starved is host "
+                    "work hidden behind device work",
+                    phase=key[len("starved_"):-len("_us")],
+                    **labels).set_total(us / 1e6)
         return True
 
     reg.register_collector(collect)

@@ -1,14 +1,13 @@
 """Frame-ledger timeline: per-frame lifecycle spans across the async
 substrate (lanes, queues, scheduler, dispatch window, transfers).
 
-The PR-1 ``utils/trace.py`` Tracer wraps synchronous ``_chain_entry``
-calls — one COMPLETE slice per element invoke — which was the whole
-story when the pipeline WAS its chain calls. Everything built since is
-asynchronous: DispatchWindow keeps K device batches in flight, lane
-workers process frames out of order behind a reorder buffer, the SLO
-scheduler holds frames in an EDF heap and sheds them, DeviceBuffers
-defer their D2H to the sink. None of that shows up in a chain-wrapped
-trace. This module records where a FRAME's time actually goes.
+A slice per element invoke was the whole story when the pipeline WAS its
+chain calls. Everything built since is asynchronous: DispatchWindow keeps
+K device batches in flight, lane workers process frames out of order
+behind a reorder buffer, the SLO scheduler holds frames in an EDF heap
+and sheds them, DeviceBuffers defer their D2H to the sink. None of that
+shows up in a chain-wrapped trace. This module records where a FRAME's
+time actually goes.
 
 Recording model
 ---------------
@@ -73,7 +72,8 @@ notes one ``(time.monotonic(), time.time_ns())`` pair when it is made,
 :func:`device_trace` runs the profiler (device tracer only) over a
 window and writes the ledger's spans on the trace's clock beside the
 device's program executions, so a gap on the device line lies over the
-host span that caused it.
+host span that caused it; :func:`idle_by_span` is that reading as
+numbers, the seconds of gap under each span kind.
 """
 
 from __future__ import annotations
@@ -249,12 +249,67 @@ def clock_differences(host: List[Tuple[int, int]],
     return best
 
 
+def idle_by_span(programs: List[Tuple[str, str, int, int]],
+                 spans: List[Tuple[str, int, int]],
+                 window: Tuple[int, int]) -> Dict[str, float]:
+    """The device's idle gaps put down to the host spans over them.
+
+    ``programs`` are ``(device, program, start_ns, end_ns)`` as
+    :func:`program_events` gives them, ``spans`` are ``(kind, start_ns,
+    end_ns)`` on the same clock, ``window`` is ``(start_ns, end_ns)``. A
+    gap is the time between two program executions of one device inside
+    the window (the union of its executions, as the benchmark's
+    ``trace_reduce.reduce_events`` takes them; several devices add up).
+    Returns seconds: for every kind among ``spans`` the part of the gaps
+    that lies under a span of that kind, ``gap_s`` (all of it) and
+    ``unattributed_s`` (under no span). Spans that tile a thread, as the
+    engine loop's ``lm_*`` do, leave unattributed only what the clocks'
+    mismatch and the window's edges cut off."""
+    w0, w1 = window
+    by_device: Dict[str, List[Tuple[int, int]]] = {}
+    for dev, _, a, b in programs:
+        a, b = max(a, w0), min(b, w1)
+        if a < b:
+            by_device.setdefault(dev, []).append((a, b))
+    spans = sorted((s for s in spans if s[1] < s[2]), key=lambda s: s[1])
+    out = {kind: 0 for kind, _, _ in spans}
+    total = unattributed = 0
+    for busy in by_device.values():
+        busy.sort()
+        end = busy[0][1]
+        gaps = []
+        for a, b in busy[1:]:
+            if a > end:
+                gaps.append((end, a))
+            end = max(end, b)
+        lo = 0  # spans before it ended before the gap at hand began
+        for g0, g1 in gaps:
+            while lo < len(spans) and spans[lo][2] <= g0:
+                lo += 1
+            covered, reach = 0, g0
+            for i in range(lo, len(spans)):
+                kind, a, b = spans[i]
+                if a >= g1:
+                    break
+                a, b = max(a, g0), min(b, g1)
+                if a < b:
+                    out[kind] += b - a
+                    covered += max(b - max(a, reach), 0)
+                    reach = max(reach, b)
+            total += g1 - g0
+            unattributed += g1 - g0 - covered
+    return {**{k: v / 1e9 for k, v in out.items()}, "gap_s": total / 1e9,
+            "unattributed_s": unattributed / 1e9}
+
+
 class DeviceTrace:
     """What :func:`device_trace` yields; filled in when the block ends."""
 
-    def __init__(self, logdir: str, ledger: Optional["Timeline"]):
+    def __init__(self, logdir: str, ledger: Optional["Timeline"],
+                 track: Optional[str] = None):
         self.logdir = logdir
         self.ledger = ledger
+        self.track = track
         self.window: Tuple[float, float] = (0.0, 0.0)  # time.monotonic()
         self.xplane: Optional[str] = None       # the profiler's file
         self.ledger_path: Optional[str] = None  # the ledger, trace clock
@@ -267,9 +322,22 @@ class DeviceTrace:
         #: largest) and ``lag_min_ns``/``lag_median_ns``/``lag_max_ns``
         #: (host end minus device end)
         self.clock_check: Optional[Dict[str, float]] = None
+        #: :func:`idle_by_span` over the window: the seconds of the
+        #: device's idle gaps under each span kind of the ledger (of
+        #: ``track`` alone if one was given), after ``offset_ns``
+        self.idle_by_span: Optional[Dict[str, float]] = None
 
     def to_trace_ns(self, t: float) -> int:
         return self.ledger.to_trace_ns(t) + self.offset_ns
+
+    def _join(self) -> None:
+        to_ns = self.to_trace_ns
+        spans = [(r[1], to_ns(r[3]), to_ns(r[4]))
+                 for r in self.ledger._snapshot() if r[4] is not None
+                 and (self.track is None or (r[5] or r[0]) == self.track)]
+        self.idle_by_span = idle_by_span(
+            self.programs, spans, (to_ns(self.window[0]),
+                                   to_ns(self.window[1])))
 
     def _align(self, span_kind: str, program: str) -> None:
         t_a, t_b = self.window
@@ -313,6 +381,7 @@ class DeviceTrace:
                  "ts": start / 1e3, "dur": (end - start) / 1e3,
                  "pid": pids[dev], "tid": 1})
         doc["metadata"]["clock"]["offset_ns"] = self.offset_ns
+        doc["metadata"]["idle_by_span"] = self.idle_by_span
         self.ledger_path = os.path.join(self.logdir, "ledger.trace.json")
         with open(self.ledger_path, "w") as f:
             json.dump(doc, f)
@@ -320,7 +389,8 @@ class DeviceTrace:
 
 @contextmanager
 def device_trace(logdir: str, ledger: Optional["Timeline"] = None,
-                 align: Optional[Tuple[str, str]] = None):
+                 align: Optional[Tuple[str, str]] = None,
+                 track: Optional[str] = None):
     """Run ``jax.profiler`` over the block with the DEVICE tracer only
     (the host and Python tracers slowed a traced pipeline five-fold:
     PERF.md, PR 24) and, when the block ends, write the ledger's spans
@@ -341,11 +411,17 @@ def device_trace(logdir: str, ledger: Optional["Timeline"] = None,
     host's (PERF.md, PR 25): without ``align`` a span shorter than that
     can lie beside the gap it caused.
 
+    After the correction every idle gap of the device inside the window
+    is put down to the spans over it (:func:`idle_by_span`): the seconds
+    under each span kind go to ``idle_by_span`` and into the file's
+    ``metadata``. ``track`` keeps that to the spans of one track (an
+    engine's ``obs_name``) where the ledger holds other threads' too.
+
     Yields a :class:`DeviceTrace`."""
     import jax
 
     ledger = ledger if ledger is not None else ACTIVE
-    out = DeviceTrace(logdir, ledger)
+    out = DeviceTrace(logdir, ledger, track)
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 0
@@ -365,6 +441,7 @@ def device_trace(logdir: str, ledger: Optional["Timeline"] = None,
     if ledger is not None:
         if align is not None:
             out._align(*align)
+        out._join()
         out._write()
 
 

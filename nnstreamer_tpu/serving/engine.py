@@ -56,6 +56,10 @@ log = get_logger("serving")
 #: loop thread belongs to exactly one (``ContinuousBatchingEngine._phase``)
 PHASES = ("admit", "first_token", "select", "dispatch", "emit", "idle",
           "other")
+#: the phases in which the device can stand with nothing queued while the
+#: engine has work (``ContinuousBatchingEngine._enqueue``): ``first_token``
+#: is entered with a prefill queued, ``idle`` has no work
+STARVED_PHASES = ("admit", "select", "dispatch", "emit", "other")
 #: the stall note: an iteration longer than ``STALL_MIN_S`` and than
 #: ``STALL_FACTOR`` x the running median is logged with its phases
 STALL_MIN_S = 1.0
@@ -555,10 +559,20 @@ class ContinuousBatchingEngine:
             # ``loop_us``. Every key exists from here on and stays an
             # ``int``: readers copy this dict from other threads
             "loop_us": 0, **{f"phase_{name}_us": 0 for name in PHASES},
+            # the part of each closed phase in which the loop thread had
+            # nothing queued on the device (``_enqueue``); the five sum
+            # to ``starved_us``
+            "starved_us": 0,
+            **{f"starved_{name}_us": 0 for name in STARVED_PHASES},
             # requests that reached their first token, and for them the
             # sums of submit -> admit and admit -> first token
             "admissions": 0, "admit_wait_us": 0, "first_token_us": 0,
             "stalls": 0,
+            # prompt tokens the prefill, prefix-extension and chunk programs
+            # were given, the rows they computed (the bucket, the chunk),
+            # and the loop iterations that admitted at least one request
+            "prefill_tokens": 0, "prefill_bucket_tokens": 0,
+            "admit_boundaries": 0,
             # blocks the paged decode steps had to read (every lane's
             # live blocks, step by step) and blocks their tables name
             "kv_blocks_live": 0, "kv_blocks_table": 0,
@@ -573,6 +587,14 @@ class ContinuousBatchingEngine:
         self.ledger = _timeline.Timeline(4096)
         self._phase_t = _time.monotonic()   # where the open phase began
         self._iter_us: Dict[str, int] = {}  # this iteration, by phase
+        self._iter_starved: Dict[str, int] = {}  # of which starved
+        #: what the loop thread knows of the device's queue (``_enqueue``):
+        #: programs it has enqueued, the newest of them whose result a
+        #: blocking fetch has returned, and the instant of the open phase
+        #: from which something has been queued (its start, if something
+        #: was queued then; None while nothing is)
+        self._dev_enq = self._dev_seen = 0
+        self._dev_busy_t: Optional[float] = None
         self._iter_median = P2Quantile(0.5)
         from nnstreamer_tpu.obs import (
             get_registry,
@@ -951,16 +973,61 @@ class ContinuousBatchingEngine:
         traced pipeline shows on the pipeline's ledger), else its own."""
         return _timeline.ACTIVE or self.ledger
 
+    def _enqueue(self, program, *args, **kwargs):
+        """Call a device program from the loop thread and return what it
+        returns: every program the loop enqueues goes through here, so
+        that the loop knows when the device has nothing queued.
+
+        The loop is synchronous and this thread alone enqueues, and the
+        device runs programs in order. So the queue is empty exactly when
+        the newest result a blocking fetch has returned (``_fetched``) is
+        that of the newest program enqueued here. It stops being empty
+        the instant this call RETURNS: the uploads (``jnp.asarray``) and
+        the program of its own that ``jnp.asarray([n], jnp.int32)`` runs
+        are evaluated before it as its arguments, host time in which the
+        chip does next to nothing. One clock read, and only for the first
+        enqueue into an empty queue; ``_phase`` does the sums."""
+        out = program(*args, **kwargs)
+        self._dev_enq += 1
+        if self._dev_busy_t is None:
+            self._dev_busy_t = _time.monotonic()
+        return out
+
+    def _fetched(self, ticket: int) -> None:
+        """A blocking fetch has returned the result of the ``ticket``-th
+        enqueued program (``_dev_enq`` when its call returned): it and
+        every program before it have run. Takes effect when the open
+        phase closes, so the bookkeeping after a fetch stays with the
+        wait."""
+        if ticket > self._dev_seen:
+            self._dev_seen = ticket
+
     def _phase(self, name: str, **args) -> float:
         """Close the loop's open phase as ``name`` and open the next, with
         one clock read: whatever the loop thread did since the last call
-        is ``name``. A counter and a span; returns the instant."""
+        is ``name``. A counter and a span; returns the instant. The part
+        of the phase in which nothing was queued on the device, from its
+        start to its first enqueue or its end (none of it, if something
+        was queued at its start), is ``starved_<name>_us`` and the span's
+        ``starved_us``."""
         now = _time.monotonic()
         t0, self._phase_t = self._phase_t, now
         us = int(now * 1e6) - int(t0 * 1e6)  # whole numbers tile exactly
         self.stats[f"phase_{name}_us"] += us
         self.stats["loop_us"] += us
         self._iter_us[name] = self._iter_us.get(name, 0) + us
+        if name in STARVED_PHASES:
+            until = now if self._dev_busy_t is None else self._dev_busy_t
+            starved = int(until * 1e6) - int(t0 * 1e6)
+            if starved:
+                self.stats[f"starved_{name}_us"] += starved
+                self.stats["starved_us"] += starved
+                self._iter_starved[name] = \
+                    self._iter_starved.get(name, 0) + starved
+                args["starved_us"] = starved
+        # a fetch takes effect here: what is still unread is queued from
+        # the next phase's first instant
+        self._dev_busy_t = None if self._dev_seen == self._dev_enq else now
         led = self._ledger()
         # consecutive waits are one record: an idle loop keeps its history
         if not (name == "idle" and led.extend_last("lm_idle", now)):
@@ -972,6 +1039,7 @@ class ContinuousBatchingEngine:
         """Top of the loop. The iteration that just ended is held against
         the running median of those before it: the stall note."""
         phases, self._iter_us = self._iter_us, {}
+        starved, self._iter_starved = self._iter_starved, {}
         total = sum(phases.values())
         if not total:
             return
@@ -983,7 +1051,10 @@ class ContinuousBatchingEngine:
             log.warning(
                 "serving: %s iteration %d ms: %s", self.obs_name,
                 total // 1000, ", ".join(
-                    f"{k} {v // 1000}" for k, v in sorted(
+                    f"{k} {v // 1000}" + (
+                        f" (starved {starved[k] // 1000})"
+                        if starved.get(k, 0) >= 1000 else "")
+                    for k, v in sorted(
                         phases.items(), key=lambda kv: -kv[1])))
 
     def _begin_admission(self, req: _PendingRequest) -> None:
@@ -1050,10 +1121,12 @@ class ContinuousBatchingEngine:
         chunk[0, :end - start] = prompt[start:end]
         who = req.who()
         try:
-            logits, cache1 = self._chunk_jitted(
-                self.params, jnp.asarray(chunk), cache1,
+            logits, cache1 = self._enqueue(
+                self._chunk_jitted, self.params, jnp.asarray(chunk), cache1,
                 jnp.asarray(start, jnp.int32))
             self.stats["prefill_chunks"] += 1
+            self.stats["prefill_tokens"] += end - start
+            self.stats["prefill_bucket_tokens"] += C
             if end < n:
                 self._partial = (req, cache1, k + 1)
                 self._phase("admit", **who)
@@ -1090,6 +1163,7 @@ class ContinuousBatchingEngine:
             self._finish_stream(self._held.stream, f"error: {e}")
             self._held = None
         self._lane = [None] * self.B
+        self._dev_seen = self._dev_enq  # nothing of the old queue is awaited
         # the arena may hold donated-away/error buffers; a fresh one is
         # the same bytes, so accounting is unchanged. Prefix entries hold
         # block ids into the dead allocation map — drop them with it.
@@ -1243,9 +1317,11 @@ class ContinuousBatchingEngine:
         bucket = self._bucket(n)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = req.prompt
-        _lg, dcache1 = sp["prefill"](sp["dparams"], jnp.asarray(padded),
-                                     lengths=jnp.asarray([n], jnp.int32))
-        sp["dcache"] = sp["insert"](sp["dcache"], dcache1, slot)
+        _lg, dcache1 = self._enqueue(
+            sp["prefill"], sp["dparams"], jnp.asarray(padded),
+            lengths=jnp.asarray([n], jnp.int32))
+        sp["dcache"] = self._enqueue(sp["insert"], sp["dcache"], dcache1,
+                                     slot)
 
     def _spec_step_paged(self) -> None:
         jnp = self._jnp
@@ -1269,13 +1345,14 @@ class ContinuousBatchingEngine:
             last[st["slot"]] = st["last"]
             pos[st["slot"]] = st["pos"]
         t0 = self._phase("select")
-        tgt, lps, n_emit, arena, dcache = sp["dispatch"](
-            self.params, sp["dparams"], jnp.asarray(last),
+        tgt, lps, n_emit, arena, dcache = self._enqueue(
+            sp["dispatch"], self.params, sp["dparams"], jnp.asarray(last),
             self._pool.arena, jnp.asarray(self._bt), sp["dcache"],
             jnp.asarray(pos))
         self._pool.arena = arena
         sp["dcache"] = dcache
         tgt = np.asarray(tgt)
+        self._fetched(self._dev_enq)
         lps = np.asarray(lps)
         n_emit = np.asarray(n_emit)
         self.invoke_stats.record(self._phase("dispatch") - t0)
@@ -1400,7 +1477,8 @@ class ContinuousBatchingEngine:
                 if n % T:
                     # COW fault: private copy of the entry's partial
                     # tail — the stream appends there from offset n % T
-                    self._pool.copy_block(eids[full], fresh[0])
+                    self._enqueue(self._pool.copy_block, eids[full],
+                                  fresh[0])
                 self.stats["prefix_hits"] += 1
                 self.stats["prefix_tokens_reused"] += n
                 return self._activate_begin_paged(req, cached_logits,
@@ -1428,11 +1506,14 @@ class ContinuousBatchingEngine:
                 toks[0, :rem] = prompt[q:]
                 bt = np.full((1, self.MB), self._pool.SENTINEL, np.int32)
                 bt[0, :len(blocks)] = blocks
-                logits, arena = self._paged_chunk_jitted(
+                logits, arena = self._enqueue(
+                    self._paged_chunk_jitted,
                     self.params, jnp.asarray(toks), self._pool.arena,
                     jnp.asarray(bt), jnp.asarray([q], jnp.int32),
                     jnp.asarray([rem], jnp.int32))
                 self._pool.arena = arena
+                self.stats["prefill_tokens"] += rem
+                self.stats["prefill_bucket_tokens"] += c
                 logits = logits[:, rem - 1]
                 self._prefix_store_paged(prompt, blocks, logits)
                 return self._activate_begin_paged(req, logits, blocks)
@@ -1452,11 +1533,13 @@ class ContinuousBatchingEngine:
             bucket = self._bucket(n)
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :n] = prompt
-            logits, cache1 = self._prefill_jitted(
-                self.params, jnp.asarray(padded),
+            logits, cache1 = self._enqueue(
+                self._prefill_jitted, self.params, jnp.asarray(padded),
                 lengths=jnp.asarray([n], jnp.int32))
-            self._pool.scatter_prefill(cache1, blocks[:(n + T - 1) // T],
-                                       lane=lane)
+            self.stats["prefill_tokens"] += n
+            self.stats["prefill_bucket_tokens"] += bucket
+            self._enqueue(self._pool.scatter_prefill, cache1,
+                          blocks[:(n + T - 1) // T], lane=lane)
             self._prefix_store_paged(prompt, blocks, logits)
             return self._activate_begin_paged(req, logits, blocks, lane)
         except Exception:
@@ -1475,7 +1558,8 @@ class ContinuousBatchingEngine:
         if blocks is None:
             return None
         try:
-            self._pool.scatter_prefill(cache1, blocks[:(n + T - 1) // T])
+            self._enqueue(self._pool.scatter_prefill, cache1,
+                          blocks[:(n + T - 1) // T])
             self._prefix_store_paged(req.prompt, blocks, logits)
             return self._activate_begin_paged(req, logits, blocks)
         except Exception:
@@ -1490,8 +1574,8 @@ class ContinuousBatchingEngine:
         from nnstreamer_tpu.models.transformer import init_cache
 
         self._begin_admission(req)
-        self._partial = (req, init_cache(self.cfg, 1, self.S,
-                                         kv_codec=self.kv_quant), 0)
+        self._partial = (req, self._enqueue(
+            init_cache, self.cfg, 1, self.S, kv_codec=self.kv_quant), 0)
 
     def _activate_begin_paged(self, req: _PendingRequest, logits, blocks,
                               lane: Optional[int] = None):
@@ -1506,8 +1590,9 @@ class ContinuousBatchingEngine:
         sid = stream.stream_id
         key = np.asarray([self.seed & 0xFFFFFFFF, sid & 0xFFFFFFFF],
                          np.uint32)[None]
-        first_d, key_d, lp_d = self._sample_first(logits,
-                                                  jnp.asarray(key))
+        first_d, key_d, lp_d = self._enqueue(self._sample_first, logits,
+                                             jnp.asarray(key))
+        ticket = self._dev_enq  # what the first token's fetch will prove run
         n = req.prompt.size
         slo_s = self._slo.budget_s if self._slo is not None else 60.0
         state = {
@@ -1528,12 +1613,15 @@ class ContinuousBatchingEngine:
             self._lane[slot] = sid
             state["slot"] = slot
             self._draft_prefill(req, slot)
-        return (req, state, first_d, key_d, lp_d)
+        return (req, state, first_d, key_d, lp_d, ticket)
 
     def _activate_commit_paged(self, rec) -> None:
-        req, state, first_d, key_d, lp_d = rec
+        req, state, first_d, key_d, lp_d, ticket = rec
         self.stats["prefills"] += 1
         first = int(np.asarray(first_d)[0])
+        # the last admitted record of a boundary empties the queue; the
+        # earlier ones' tickets lie behind programs still queued
+        self._fetched(ticket)
         state["last"] = first
         state["key"] = np.asarray(key_d)[0].copy()
         self._emit_first(req.stream, first, float(np.asarray(lp_d)[0]))
@@ -1676,11 +1764,13 @@ class ContinuousBatchingEngine:
         self.stats["kv_blocks_table"] += self.B * self.MB * self.K
         t0 = self._phase("select")
         toks, lps, arena, keys_d, _last_d, _pos_d, *counted = \
-            self._dispatch(
+            self._enqueue(
+                self._dispatch,
                 self.params, jnp.asarray(last), self._pool.arena,
                 jnp.asarray(self._bt), jnp.asarray(pos), jnp.asarray(keys))
         self._pool.arena = arena
         toks = np.asarray(toks)
+        self._fetched(self._dev_enq)
         lps = np.asarray(lps)
         keys_np = np.asarray(keys_d)
         if counted:  # ready with the tokens: the same program made them
@@ -1716,8 +1806,11 @@ class ContinuousBatchingEngine:
         concern instead of a device-state pipeline hazard."""
         self._phase_t = _time.monotonic()
         self._iter_us = {}
+        self._iter_starved = {}
+        self._dev_seen, self._dev_busy_t = self._dev_enq, None
         while not self._stop_evt.is_set():
             self._end_iteration()
+            admissions = self.stats["admissions"]
             busy = bool(self._sstate)
             for state in list(self._sstate.values()):
                 if state["stream"].cancelled:
@@ -1790,6 +1883,8 @@ class ContinuousBatchingEngine:
                         self._finish_stream(rec[0].stream, f"error: {e}")
                 # blocked on the prefill's result, then the first emit
                 self._phase("first_token", **rec[0].who())
+            if self.stats["admissions"] > admissions:
+                self.stats["admit_boundaries"] += 1
             if len(self._sstate) > self.stats["concurrent_streams_max"]:
                 self.stats["concurrent_streams_max"] = len(self._sstate)
             if not self._sstate:

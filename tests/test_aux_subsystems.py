@@ -1,47 +1,12 @@
-"""Aux subsystems: tracing, checkpoint/resume, native core, config system
+"""Aux subsystems: checkpoint/resume, native core, config system
 (SURVEY §5 parity tests)."""
 
-import json
 import os
 
 import numpy as np
 import pytest
 
 from nnstreamer_tpu import parse_launch
-
-
-class TestTracer:
-    def test_traces_pipeline(self, tmp_path):
-        from nnstreamer_tpu.utils.trace import Tracer
-
-        pipe = parse_launch(
-            "videotestsrc num-buffers=5 width=8 height=8 ! tensor_converter ! "
-            "tensor_transform mode=typecast option=float32 ! fakesink"
-        )
-        tracer = Tracer()
-        with tracer.attach(pipe):
-            pipe.run(timeout=20)
-        summary = tracer.summary()
-        assert any("tensor_converter" in k for k in summary)
-        conv = next(v for k, v in summary.items() if "tensor_converter" in k)
-        assert conv["count"] == 5
-        assert conv["proctime_us_avg"] > 0
-        out = tmp_path / "trace.json"
-        tracer.export_chrome(str(out))
-        data = json.loads(out.read_text())
-        assert len(data["traceEvents"]) >= 15  # 3 elements x 5 buffers
-
-    def test_detach_restores(self):
-        from nnstreamer_tpu.utils.trace import Tracer
-        from nnstreamer_tpu.elements.sink import FakeSink
-
-        s = FakeSink()
-        from nnstreamer_tpu.pipeline.pipeline import Pipeline
-
-        pipe = Pipeline().add(s)
-        with Tracer().attach(pipe):
-            assert "_chain_entry" in s.__dict__  # wrapped via instance attr
-        assert "_chain_entry" not in s.__dict__  # detached cleanly
 
 
 class TestCheckpoint:

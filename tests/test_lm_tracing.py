@@ -1,10 +1,14 @@
 """The LM serving path measures itself (PR 25): request stamps, engine-loop
 phase counters and spans, the stall note, one clock with the device trace,
 and the names inside the programs that the benchmark's readers look for.
+Since PR 35: the time the loop has nothing queued on the device, by phase;
+what the first-token path counts of itself; the device's idle gaps put
+down to the host spans over them.
 """
 
 import json
 import re
+import threading
 import time
 
 import numpy as np
@@ -208,43 +212,65 @@ def test_spans_go_to_the_installed_timeline_instead():
     assert "lm_dispatch" not in _kinds(eng.ledger)
 
 
-def test_stall_note_fires_once_with_the_phases(monkeypatch):
-    notes = []
-    monkeypatch.setattr(
-        engine_mod.log, "warning",
-        lambda msg, *args: notes.append(msg % args))
-    # The engine's clock, which one dispatch sets forward by 1000 s: a
-    # stall of a size no loaded machine makes by itself (every wait of
-    # these tests gives up sooner), so that the thresholds can stand where
-    # only it passes them and the count does not hang on the scheduler.
-    lost = []
+class _SteppedClock:
+    """The engine's clock, which a test sets forward by thousands of
+    seconds at chosen places: no loaded machine adds as much by itself,
+    so where a step lands can be read off the counters' thousands."""
 
-    class clock:
-        @staticmethod
-        def monotonic():
-            return time.monotonic() + sum(lost)
+    def __init__(self):
+        self.lost = 0.0
 
+    def monotonic(self):
+        return time.monotonic() + self.lost
+
+    def step_before(self, holder, name, seconds, when=lambda: True):
+        """``holder.name`` sets the clock forward, then runs."""
+        inner = getattr(holder, name)
+
+        def stepping(*args, **kwargs):
+            if when():
+                self.lost += seconds
+            return inner(*args, **kwargs)
+
+        setattr(holder, name, stepping)
+
+
+def _thousands(us):
+    """Whole thousands of seconds in a counter of microseconds."""
+    return us // 1_000_000_000
+
+
+@pytest.fixture
+def stepped(monkeypatch):
+    clock = _SteppedClock()
     monkeypatch.setattr(engine_mod, "_time", clock)
+    notes = []
+    monkeypatch.setattr(engine_mod.log, "warning",
+                        lambda msg, *args: notes.append(msg % args))
+    clock.notes = notes
+    return clock
+
+
+def test_stall_note_fires_once_with_the_phases(stepped, monkeypatch):
+    # One dispatch sets the engine's clock forward by 1000 s: a stall of a
+    # size no loaded machine makes by itself (every wait of these tests
+    # gives up sooner), so that the thresholds can stand where only it
+    # passes them and the count does not hang on the scheduler.
     eng = _engine(block_tokens=8).start()
     try:
         _serve(eng, [_prompt(5)], max_new=30)  # compiles; a median exists
         monkeypatch.setattr(engine_mod, "STALL_MIN_S", 500.0)
         monkeypatch.setattr(engine_mod, "STALL_FACTOR", 3.0)
         before = eng.stats["stalls"]
-        inner = eng._dispatch
-
-        def slow(*args):
-            if not lost:
-                lost.append(1000.0)
-            return inner(*args)
-
-        eng._dispatch = slow
-        del notes[:]
+        stepped.step_before(eng, "_dispatch", 1000.0,
+                            when=lambda: not stepped.lost)
+        del stepped.notes[:]
         # the stalled iteration is the request's first of eight: the loop
         # has held it against the median long before the request ends
         _serve(eng, [_prompt(6, 9)], max_new=30)
     finally:
         eng.stop()
+    notes = stepped.notes
     assert eng.stats["stalls"] - before == 1
     assert len(notes) == 1 and re.search(r"dispatch 1000\d\d\d\b", notes[0]), notes
     assert notes[0].startswith(f"serving: {eng.obs_name} iteration")
@@ -281,6 +307,269 @@ def test_collector_exports_the_phases_as_one_family():
         assert c is not None, phase
         assert c.value == pytest.approx(
             eng.stats[f"phase_{phase}_us"] / 1e6)
+
+
+# -- starved time: the loop thread's time with nothing queued ---------------
+
+STARVED_KEYS = ["starved_us"] + [f"starved_{p}_us"
+                                 for p in engine_mod.STARVED_PHASES]
+ENGINE_KINDS = {**ADMISSIONS, "speculative": {"speculate": 2}}
+
+
+def _programs_of(eng):
+    """``(holder, attribute or key)`` of every jitted program the loop
+    thread of ``eng`` can call."""
+    out = [(eng, "_dispatch"), (eng, "_prefill_jitted"),
+           (eng, "_sample_first"), (eng._pool, "_jit_scatter"),
+           (eng._pool, "_jit_copy")]
+    if eng._chunk_fn is not None:
+        out += [(eng, "_chunk_jitted"), (eng, "_paged_chunk_jitted")]
+    if eng._spec is not None:
+        out += [(eng._spec, k) for k in ("dispatch", "prefill", "insert")]
+    return out
+
+
+def _get(holder, key):
+    return holder[key] if isinstance(holder, dict) else getattr(holder, key)
+
+
+def _put(holder, key, value):
+    if isinstance(holder, dict):
+        holder[key] = value
+    else:
+        setattr(holder, key, value)
+
+
+def _check_invariants(stats):
+    assert all(type(stats[k]) is int for k in STARVED_KEYS)
+    for p in engine_mod.STARVED_PHASES:
+        assert 0 <= stats[f"starved_{p}_us"] <= stats[f"phase_{p}_us"], p
+    assert sum(stats[f"starved_{p}_us"]
+               for p in engine_mod.STARVED_PHASES) == stats["starved_us"]
+    assert stats["starved_us"] <= stats["loop_us"]
+    assert "starved_first_token_us" not in stats
+    assert "starved_idle_us" not in stats
+    assert sum(stats[f"phase_{p}_us"]
+               for p in engine_mod.PHASES) == stats["loop_us"]
+    assert 0 < stats["prefill_tokens"] <= stats["prefill_bucket_tokens"]
+    assert 0 < stats["admit_boundaries"] <= stats["admissions"]
+
+
+@pytest.mark.parametrize("kind", sorted(ENGINE_KINDS))
+def test_starved_counters_hold_their_invariants_on_every_path(kind):
+    eng = _engine(**ENGINE_KINDS[kind])
+    assert set(STARVED_KEYS) | {"prefill_tokens", "prefill_bucket_tokens",
+                                "admit_boundaries"} <= set(eng.stats)
+    assert all(eng.stats[k] == 0 and type(eng.stats[k]) is int
+               for k in STARVED_KEYS)
+    # every program the loop thread calls goes through the one helper
+    inside, called, inner = [], set(), eng._enqueue
+
+    def enqueue(program, *args, **kwargs):
+        inside.append(program)
+        try:
+            return inner(program, *args, **kwargs)
+        finally:
+            inside.pop()
+
+    def guarded(holder, key):
+        program = _get(holder, key)
+
+        def call(*args, **kwargs):
+            assert inside, f"{key} was called past _enqueue"
+            called.add(key)
+            return program(*args, **kwargs)
+
+        _put(holder, key, call)
+
+    eng._enqueue = enqueue
+    for holder, key in _programs_of(eng):
+        guarded(holder, key)
+    eng.start()
+    try:
+        base = _prompt(20)
+        _serve(eng, [base, _prompt(5, 40)])
+        _serve(eng, [base, np.concatenate([base, _prompt(6, 50)]),
+                     _prompt(12, 60)])
+        _idle_waits(eng)
+    finally:
+        eng.stop()
+    _check_invariants(eng.stats)
+    assert eng.stats["starved_us"] > 0
+    assert eng._dev_seen == eng._dev_enq > 0
+    want = {"_sample_first", "_jit_scatter"}
+    want |= {"dispatch", "prefill", "insert"} if kind == "speculative" \
+        else {"_dispatch"}
+    want |= {"_chunk_jitted"} if "chunked" in kind else {"_prefill_jitted"}
+    if "prefix_hit" in kind:
+        want |= {"_paged_chunk_jitted", "_jit_copy"}
+    assert want <= called, (kind, called)
+
+
+def test_an_engine_that_only_idles_books_no_starved_time():
+    eng = _engine(block_tokens=8).start()
+    try:
+        _idle_waits(eng, 4)
+    finally:
+        eng.stop()
+    assert eng.stats["phase_idle_us"] > 0
+    assert all(eng.stats[k] == 0 for k in STARVED_KEYS)
+    assert eng.stats["admit_boundaries"] == 0
+
+
+def test_time_between_a_fetch_and_the_next_enqueue_is_starved_by_phase(
+        stepped):
+    eng = _engine(block_tokens=8).start()
+    try:
+        _serve(eng, [_prompt(5)], max_new=30)  # compiles
+        before = dict(eng.stats)
+        armed = []   # one dispatch of the next request, not its first
+
+        def once(tag):
+            def when():
+                if armed and tag not in armed:
+                    armed.append(tag)
+                    return True
+                return False
+            return when
+
+        # each in the phase its caller belongs to, and only after the
+        # request's first dispatch has emptied the queue
+        stepped.step_before(eng, "_end_iteration", 2000.0, once("other"))
+        stepped.step_before(eng, "_topup", 4000.0, once("select"))
+        stepped.step_before(eng, "_dispatch", 8000.0, once("launch"))
+        stepped.step_before(eng, "_fetched", 16000.0, once("fetched"))
+        stepped.step_before(eng, "_post_emit_paged", 1000.0, once("emit"))
+        inner = eng._fetched
+
+        def arm(ticket):
+            inner(ticket)
+            if not armed and eng.stats["dispatches"] > before["dispatches"]:
+                armed.append("armed")
+
+        eng._fetched = arm
+        del stepped.notes[:]
+        _serve(eng, [_prompt(6, 9)], max_new=30)
+    finally:
+        eng.stop()
+    assert set(armed) == {"armed", "other", "select", "launch", "fetched",
+                          "emit"}
+    grew = {k: eng.stats[k] - before[k] for k in before
+            if type(before[k]) is int}
+    assert _thousands(grew["starved_emit_us"]) == 1
+    assert _thousands(grew["starved_other_us"]) == 2
+    assert _thousands(grew["starved_select_us"]) == 4
+    # the launch is starved, the wait and the bookkeeping after it are not
+    assert _thousands(grew["starved_dispatch_us"]) == 8
+    assert _thousands(grew["phase_dispatch_us"]) == 24
+    assert _thousands(grew["starved_admit_us"]) == 0
+    assert _thousands(grew["starved_us"]) == 15
+    _check_invariants(eng.stats)
+    # the stall note says which part of a long phase was starved
+    assert any(re.search(r"dispatch 24\d{6} \(starved 8\d{6}\)", n)
+               for n in stepped.notes), stepped.notes
+    # and the spans carry what the counters sum
+    spans = [r for r in eng.ledger._snapshot() if r[4] is not None]
+    carried = sum((r[6] or {}).get("starved_us", 0) for r in spans)
+    assert carried == eng.stats["starved_us"]
+    assert all((r[6] or {}).get("starved_us", 1) > 0 for r in spans)
+    assert not any("starved_us" in (r[6] or {}) for r in spans
+                   if r[1] in ("lm_first_token", "lm_idle"))
+
+
+def test_only_the_first_admission_of_a_boundary_is_starved(stepped):
+    eng = _engine(block_tokens=8, max_streams=4).start()
+    try:
+        # compile every shape, and leave a request decoding
+        _serve(eng, [_prompt(5), _prompt(12, 30)], max_new=5)
+        running = eng.submit(_prompt(7, 3), max_new_tokens=40)
+        gate, held = threading.Event(), threading.Event()
+        inner = eng._dispatch
+
+        def hold(*args):
+            if not gate.is_set():
+                held.set()
+                assert gate.wait(timeout=120)
+            return inner(*args)
+
+        eng._dispatch = hold
+        assert held.wait(timeout=120)
+        # the loop stands inside a dispatch: both land at one boundary
+        before = dict(eng.stats)
+        stepped.step_before(eng, "_prefill_jitted", 1000.0)
+        stepped.step_before(eng, "_activate_commit_paged", 4000.0)
+        pair = [eng.submit(_prompt(5, 50), max_new_tokens=5),
+                eng.submit(_prompt(12, 70), max_new_tokens=5)]
+        gate.set()
+        for s in pair + [running]:
+            s.result(timeout=120)
+    finally:
+        eng.stop()
+    grew = {k: eng.stats[k] - before[k] for k in before
+            if type(before[k]) is int}
+    assert grew["admissions"] == 2 and grew["admit_boundaries"] == 1
+    # two host halves of 1000 s each; the second ran with the first's
+    # prefill queued
+    assert _thousands(grew["phase_admit_us"]) == 2
+    assert _thousands(grew["starved_admit_us"]) == 1
+    # both waits for a first token, and none of it starved
+    assert _thousands(grew["phase_first_token_us"]) == 8
+    assert _thousands(grew["starved_us"]) == 1
+    _check_invariants(eng.stats)
+
+
+FIRST_TOKEN_LOADS = {
+    # kind: (engine options, prompts served one after another,
+    #        tokens given, rows computed)
+    "cold": ({}, [_prompt(5), _prompt(20), _prompt(12)],
+             5 + 20 + 12, 16 + 32 + 16),
+    "cold_min_bucket8": ({"min_bucket": 8},
+                         [_prompt(5), _prompt(20), _prompt(9)],
+                         5 + 20 + 9, 8 + 32 + 16),
+    # 20 cold; the same again is an exact hit (no program); 26 tokens of
+    # which the first two blocks of 8 are shared: 10 left, in a bucket of 16
+    "prefix_extension": ({"prefix_cache": 4, "block_tokens": 8},
+                         [_prompt(20), _prompt(20),
+                          np.concatenate([_prompt(20), _prompt(6, 50)])],
+                         20 + 0 + 10, 32 + 0 + 16),
+    # chunks of 8: 20 = 8 + 8 + 4, 5 = 5
+    "chunked": ({"prefill_chunk": 8}, [_prompt(20), _prompt(5)],
+                20 + 5, 24 + 8),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(FIRST_TOKEN_LOADS))
+def test_first_token_path_counts_tokens_rows_and_boundaries(kind):
+    options, prompts, tokens, rows = FIRST_TOKEN_LOADS[kind]
+    eng = _engine(**options).start()
+    try:
+        for p in prompts:
+            _serve(eng, [p], max_new=3)
+    finally:
+        eng.stop()
+    assert eng.stats["prefill_tokens"] == tokens
+    assert eng.stats["prefill_bucket_tokens"] == rows
+    # one request at a time: every admission is a boundary of its own
+    assert eng.stats["admit_boundaries"] == eng.stats["admissions"] \
+        == len(prompts)
+
+
+def test_collector_exports_the_starved_time_as_one_family():
+    eng = _engine(block_tokens=8).start()
+    try:
+        _serve(eng, [_prompt(5)])
+    finally:
+        eng.stop()
+    reg = get_registry()
+    reg.snapshot()  # runs the collectors
+    name = "nns_serving_loop_starved_seconds_total"
+    for phase in engine_mod.STARVED_PHASES:
+        c = reg.get(name, engine=eng.obs_name, phase=phase)
+        assert c is not None, phase
+        assert c.value == pytest.approx(
+            eng.stats[f"starved_{phase}_us"] / 1e6)
+    for phase in ("first_token", "idle", ""):
+        assert reg.get(name, engine=eng.obs_name, phase=phase) is None
 
 
 # -- one clock for the ledger and the device trace ---------------------------
@@ -346,6 +635,70 @@ def test_align_splits_the_slack_of_a_span_that_holds_its_device_event():
     assert tr.to_trace_ns(1.0) == tl.to_trace_ns(1.0) + tr.offset_ns
 
 
+MS = 1_000_000  # ns
+DEV = "/device:TPU:0"
+IDLE_BY_SPAN = {
+    # programs (device, start ms, end ms), spans (kind, start, end),
+    # window, what comes back in ms (kinds that read 0 left out)
+    "a_gap_wholly_under_one_span": (
+        [(DEV, 0, 10), (DEV, 14, 20)], [("lm_emit", 9, 15)], (0, 20),
+        {"lm_emit": 4, "gap_s": 4}),
+    "a_gap_split_over_two_spans": (
+        [(DEV, 0, 10), (DEV, 20, 30)],
+        [("lm_emit", 8, 13), ("lm_other", 13, 14), ("lm_admit", 14, 25)],
+        (0, 30), {"lm_emit": 3, "lm_other": 1, "lm_admit": 6, "gap_s": 10}),
+    "a_gap_past_the_spans_end_is_unattributed": (
+        [(DEV, 0, 10), (DEV, 20, 30)], [("lm_emit", 5, 12)], (0, 30),
+        {"lm_emit": 2, "gap_s": 10, "unattributed_s": 8}),
+    "two_devices_add_up": (
+        [(DEV, 0, 10), (DEV, 14, 20), ("/device:TPU:1", 0, 12),
+         ("/device:TPU:1", 13, 20)], [("lm_emit", 0, 20)], (0, 20),
+        {"lm_emit": 5, "gap_s": 5}),
+    "overlapping_programs_are_one_busy_stretch": (
+        [(DEV, 0, 10), (DEV, 5, 12), (DEV, 15, 20)], [("lm_select", 0, 20)],
+        (0, 20), {"lm_select": 3, "gap_s": 3}),
+    "the_window_cuts_programs_and_leaves_its_edges_out": (
+        [(DEV, 0, 10), (DEV, 14, 20), (DEV, 40, 50)],
+        [("lm_emit", 0, 50)], (5, 17), {"lm_emit": 4, "gap_s": 4}),
+    "overlapping_spans_each_get_the_gap_and_it_is_covered_once": (
+        [(DEV, 0, 10), (DEV, 20, 30)],
+        [("a", 8, 16), ("b", 12, 18)], (0, 30),
+        {"a": 6, "b": 6, "gap_s": 10, "unattributed_s": 2}),
+    "no_programs_no_gaps": ([], [("lm_idle", 0, 20)], (0, 20), {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(IDLE_BY_SPAN))
+def test_idle_by_span_puts_each_gap_down_to_the_spans_over_it(case):
+    programs, spans, window, want = IDLE_BY_SPAN[case]
+    got = timeline.idle_by_span(
+        [(d, "jit_dispatch", a * MS, b * MS) for d, a, b in programs],
+        [(k, a * MS, b * MS) for k, a, b in spans],
+        (window[0] * MS, window[1] * MS))
+    assert set(got) == {k for k, _, _ in spans} | {"gap_s", "unattributed_s"}
+    assert {k: round(v * 1e3, 6) for k, v in got.items() if v} == want
+
+
+def test_the_offset_moves_a_boundary_between_two_spans():
+    tl = timeline.Timeline(16)
+    tl.span("lm_emit", None, 1.000, 1.004, track="engine7")
+    tl.span("lm_other", None, 1.004, 1.010, track="engine7")
+    tl.span("queue_wait", 5, 1.000, 1.010, track="q0")  # another thread
+    tr = timeline.DeviceTrace("unused", tl, track="engine7")
+    tr.window = (0.5, 2.0)
+    base = tl.to_trace_ns(0.0)
+    at = lambda ms: base + 10**9 + ms * MS  # noqa: E731
+    tr.programs = [(DEV, "jit_dispatch", at(-50), at(2)),
+                   (DEV, "jit_prefill", at(8), at(60))]
+    tr._join()
+    assert {k: round(v * 1e3, 3) for k, v in tr.idle_by_span.items()} == {
+        "lm_emit": 2.0, "lm_other": 4.0, "gap_s": 6.0, "unattributed_s": 0.0}
+    tr.offset_ns = -1 * MS  # the device's clock 1 ms behind the host's
+    tr._join()
+    assert {k: round(v * 1e3, 3) for k, v in tr.idle_by_span.items()} == {
+        "lm_emit": 1.0, "lm_other": 5.0, "gap_s": 6.0, "unattributed_s": 0.0}
+
+
 def test_device_trace_writes_the_ledger_on_the_traces_clock(tmp_path):
     eng = _engine(block_tokens=8).start()
     try:
@@ -365,6 +718,12 @@ def test_device_trace_writes_the_ledger_on_the_traces_clock(tmp_path):
     # the CPU's trace has no device plane: nothing to match, no correction
     if not tr.programs:
         assert tr.clock_check is None and tr.offset_ns == 0
+    # the join is in the file, under every span kind the ledger holds
+    assert doc["metadata"]["idle_by_span"] == tr.idle_by_span
+    assert {"gap_s", "unattributed_s", "lm_dispatch", "lm_emit"} \
+        <= set(tr.idle_by_span)
+    if not tr.programs:
+        assert not any(tr.idle_by_span.values())
 
 
 # -- names inside the programs -----------------------------------------------

@@ -22,6 +22,7 @@ from nnstreamer_tpu.obs import (
 from nnstreamer_tpu.pipeline.element import Element, EosEvent, FlowReturn
 from nnstreamer_tpu.pipeline.pipeline import Pipeline, Queue, SourceElement
 from nnstreamer_tpu.tensors.buffer import TensorBuffer
+from nnstreamer_tpu.utils.stats import InvokeStats
 
 import numpy as np
 
@@ -395,3 +396,46 @@ class TestPipelineMetrics:
         h = get_registry().get("nns_tensor_mux_sync_wait_seconds",
                                pipeline="obs-mux", element="mux")
         assert h is not None and h.count == 4
+
+
+class TestInvokeStatsEdgeCases:
+    def test_empty_window_reads_zero(self):
+        s = InvokeStats()
+        assert s.latency_us == 0
+        assert s.throughput_milli == 0
+        snap = s.snapshot()
+        assert snap["latency_us"] == 0
+        assert snap["total_invokes"] == 0
+
+    def test_single_sample_throughput_zero(self):
+        s = InvokeStats()
+        s.record(0.001, now=100.0)
+        assert s.latency_us == 1000
+        assert s.throughput_milli == 0  # a rate needs two stamps
+
+    def test_stale_samples_pruned_from_throughput(self):
+        s = InvokeStats(max_age_s=10.0)
+        s.record(0.001, now=100.0)
+        s.record(0.001, now=150.0)  # 50 s later: the first stamp is stale
+        assert s.throughput_milli == 0  # only one live stamp remains
+        s.record(0.001, now=150.5)
+        s.record(0.001, now=151.0)
+        # 3 live stamps over 1 s → 2 intervals/s → 2000 milli-out/s
+        assert s.throughput_milli == 2000
+        assert s.total_invokes == 4  # cumulative count never prunes
+
+    def test_latency_window_bounded(self):
+        s = InvokeStats(window=3)
+        for lat in (1.0, 1.0, 0.001, 0.001, 0.001):
+            s.record(lat, now=100.0)
+        # only the last `window` samples feed the average
+        assert s.latency_us == 1000
+        assert s.total_invokes == 5
+        assert abs(s.total_latency_s - 2.003) < 1e-9
+
+    def test_measure_context_manager(self):
+        s = InvokeStats()
+        with s.measure():
+            pass
+        assert s.total_invokes == 1
+        assert s.latency_us >= 0
