@@ -12,7 +12,9 @@ weights from a seed, nothing downloaded):
   compared on the chip with their ``force="reference"`` results; and the
   routed experts' grouped matmul (``nns_expert_tiles``) against the tile
   loop at both benchmark configurations' real expert shapes, the decode
-  tile and the largest prefill tile of each;
+  tile and the largest prefill tile of each; and the recurrent mixers'
+  in-place state update (``nns_lane_state``) against its reference at
+  both recurrent configurations' real state arenas;
 - **stream leg** — MobileNetV2 (width 1.0, 224², bf16, batch 8) through
   ``parse_launch`` with the flagship topology; every label must equal the
   argmax of a plain ``jax.jit`` of the same model on the same frames, and
@@ -121,14 +123,20 @@ FULL = dict(
     # and 32 rows), 64 lanes and a 512-token prompt of
     # granite_4p0_h_small_ep2 (32 and 128)
     expert_shapes=(((10, 512, 256, 2048, 512), (128, 512)),
-                   ((10, 72, 36, 4096, 768), (64, 512))))
+                   ((10, 72, 36, 4096, 768), (64, 512))),
+    # rule, state arena [layers, lanes, heads, rows, cols]: 64 lanes of
+    # granite_4p0_h_small_ep2 (2.42 GB), 128 of qwen3_next_80b_a3b_ep2
+    lane_state_shapes=(("mamba2", (9, 64, 128, 64, 128)),
+                       ("gated_delta", (3, 128, 32, 128, 128))))
 REHEARSAL = dict(
     frames=16, lm_layers=2, max_new=4,
     prompts=((12, 260), (40,)),
     hybrid_prompts=(12, 40),
     flash_shapes=((1, 256, 8, 64),),
     expert_shapes=(((4, 64, 32, 256, 128), (16,)),
-                   ((4, 16, 8, 256, 256), (64,))))
+                   ((4, 16, 8, 256, 256), (64,))),
+    lane_state_shapes=(("mamba2", (2, 4, 16, 8, 128)),
+                       ("gated_delta", (2, 4, 4, 16, 128))))
 
 
 class SmokeFailure(AssertionError):
@@ -259,7 +267,98 @@ def kernel_leg(sizes: dict, on_chip: bool) -> dict:
     out["expert_tiles"] = [
         case for layer, calls in sizes["expert_shapes"]
         for case in _expert_tiles_cases(layer, calls, on_chip)]
+    out["lane_state"] = [_lane_state_case(rule, shape, on_chip)
+                         for rule, shape in sizes["lane_state_shapes"]]
     return out
+
+
+def _lane_state_case(rule: str, shape: tuple, on_chip: bool) -> dict:
+    """The in-place state update against its reference on one arena: the
+    last layer stepped once, every seventh lane empty, operands at the
+    sizes a mixer hands over. Both forms are float32 elementwise and
+    differ in the order of the sums behind an output alone: 1e-5 of the
+    largest value holds; an empty lane's slot and every other layer come
+    out bit for bit as they went in. Each arena is made, stepped in place
+    (donated) and reduced on the device: two of them live at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    from nnstreamer_tpu.models.gated_delta import l2norm
+    from nnstreamer_tpu.ops import lane_state as ls
+
+    layers, lanes, heads, rows, cols = shape
+    keys = jax.random.split(jax.random.PRNGKey(layers), 6)
+
+    def normal(i, *dims):
+        return jax.random.normal(keys[i], dims, jnp.float32)
+
+    if rule == ls.MAMBA2:
+        operands = (normal(0, lanes, heads, rows),
+                    jax.nn.softplus(normal(1, lanes, heads)),
+                    -jnp.exp(0.3 * normal(2, heads)), normal(3, lanes, cols),
+                    normal(4, lanes, cols))
+    else:
+        operands = (l2norm(normal(0, lanes, heads, rows)) * rows ** -0.5,
+                    l2norm(normal(1, lanes, heads, rows)),
+                    normal(2, lanes, heads, cols),
+                    -jax.nn.softplus(normal(3, lanes, heads)),
+                    jax.nn.sigmoid(normal(4, lanes, heads)))
+    live = jnp.arange(lanes) % 7 != 3
+    layer = layers - 1
+    make = jax.jit(lambda key: 0.5 * jax.random.normal(key, shape,
+                                                       jnp.float32))
+
+    def fresh():       # the key an argument: a program of constants is
+        return make(keys[5])    # evaluated, 2.4 GB of it, by the compiler
+
+    def step(force):   # the operands as arguments: constants get folded
+        return lambda arena, live, operands: ls.update(
+            rule, ls.LaneSlot(arena, layer), live, operands, force=force)
+
+    def stepped(force):
+        out, slot = jax.jit(step(force), donate_argnums=(0,))(
+            fresh(), live, operands)
+        return out, slot.arena
+
+    what = f"lane_state {rule} over {shape}"
+    if on_chip:
+        check(_mosaic_compiled(
+            step("pallas"), jax.ShapeDtypeStruct(shape, jnp.float32), live,
+            operands), f"{what}: no Mosaic call in the program")
+    want_out, want = stepped("reference")
+    got_out, got = stepped("pallas")
+
+    @jax.jit
+    def compare(got, want, got_out, want_out, was):
+        alive = live[:, None, None]
+        return {
+            "max_abs_delta_state": jnp.max(jnp.abs(got - want)),
+            "max_abs_state": jnp.max(jnp.abs(want[layer])),
+            "max_abs_delta_out": jnp.max(jnp.where(
+                alive, jnp.abs(got_out - want_out), 0.0)),
+            "max_abs_out": jnp.max(jnp.where(alive, jnp.abs(want_out), 0.0)),
+            "untouched": jnp.all(jnp.where(
+                live[:, None, None, None], True, got[layer] == was[layer]))
+            & jnp.all(got[:layer] == was[:layer]),
+            "moved": jnp.max(jnp.abs(want[layer] - was[layer])),
+        }
+
+    read = {k: v.item() for k, v in compare(got, want, got_out, want_out,
+                                            fresh()).items()}
+    check(got.shape == shape and got.dtype == jnp.float32
+          and got_out.shape == want_out.shape, f"{what}: got {got.shape}")
+    check(read["moved"] > 0.1 and read["max_abs_delta_state"]
+          <= 1e-5 * read["max_abs_state"],
+          f"{what}: max |kernel - reference| over the state = {read}")
+    check(read["max_abs_out"] > 0 and read["max_abs_delta_out"]
+          <= 1e-5 * read["max_abs_out"],
+          f"{what}: max |kernel - reference| over the outputs = {read}")
+    check(read["untouched"], f"{what}: an empty lane's slot or another "
+                             f"layer is not what it was")
+    return {"rule": rule, "arena": list(shape),
+            "head_block": ls.head_block(heads, rows * cols * 4)[0],
+            **{k: read[k] for k in ("max_abs_delta_state", "max_abs_state",
+                                    "max_abs_delta_out", "max_abs_out")}}
 
 
 def _expert_tiles_cases(layer: tuple, calls: tuple, on_chip: bool):
@@ -753,6 +852,7 @@ def hybrid_leg(sizes: dict, on_chip: bool, which: str = "hybrid") -> dict:
             "max_gap_to_argmax": round(worst_gap, 5)},
             "state_bytes": snap["state_bytes"],
             "expert_matmul": engine.expert_matmul,
+            "state_update": engine.state_update,
             "moe_tokens_held": int(engine.stats["moe_tokens_held"]),
             "moe_tokens_absent": int(engine.stats["moe_tokens_absent"])}
     finally:

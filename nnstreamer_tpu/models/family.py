@@ -60,6 +60,10 @@ class ModelFamily:
     #: routed experts run in for that many tokens a call
     #: (``ops/grouped_matmul.py``); None for a family with none
     expert_matmul: Optional[Callable] = None
+    #: ``state_update(cfg, lanes) -> form``: the form the decode step
+    #: updates that many lanes' recurrent state in
+    #: (``ops/lane_state.py``); None for a family with no lane state
+    state_update: Optional[Callable] = None
     #: the engine options the family's programs bring, of ``prefix_cache``,
     #: ``speculate``, ``prefill_chunk``, ``kv_quant`` and ``mesh``; any
     #: other is refused at construction with ``refusal``, which says what

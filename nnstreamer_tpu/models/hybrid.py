@@ -26,7 +26,8 @@ on it:
   chunks (the state-space-duality form, or the delta rule's triangular
   solve; PAPERS.md) and hands over the state after the prompt's last real
   token, whatever the bucket's padding; decode is the recurrence for one
-  token, written into the arena in place. A pattern holds attention and
+  token, written into the arena in place (``ops/lane_state.py``: one
+  Pallas pass over a layer's slots on a TPU). A pattern holds attention and
   ONE recurrent kind: the lanes' state arena has one shape.
 - **The expert layer holds a share** (``experts_held``): the router keeps
   its published width and its experts per token, gates are the softmax
@@ -58,13 +59,10 @@ import numpy as np
 from jax import lax
 
 from nnstreamer_tpu.models.family import ModelFamily
-from nnstreamer_tpu.models.gated_delta import (
-    gated_delta_chunked,
-    gated_delta_step,
-    l2norm,
-)
+from nnstreamer_tpu.models.gated_delta import gated_delta_chunked, l2norm
 from nnstreamer_tpu.models.transformer import _attend_cache, _kv_codec
 from nnstreamer_tpu.ops import grouped_matmul
+from nnstreamer_tpu.ops import lane_state as lane_ops
 
 MAMBA, ATTENTION, DELTA = "mamba", "attention", "linear_attention"
 #: the published pattern's period: five state-space layers, one attention
@@ -529,9 +527,23 @@ def _ssm_prefill(h, lp, lengths, cfg: HybridConfig):
     return out, state.astype(cfg.ssm_state_dtype), tail
 
 
+def _state_step(rule: str, state, live, operands):
+    """One token of every lane's recurrent state through
+    ``ops/lane_state.py``: ``(out, state)``. ``state`` is a
+    :class:`lane_ops.LaneSlot` (the arena and the layer: updated in place
+    and handed back as one) or one layer's ``[b, heads, rows, cols]``
+    alone, which is an arena of one layer."""
+    if isinstance(state, lane_ops.LaneSlot):
+        return lane_ops.update(rule, state, live, operands)
+    out, (states, _) = lane_ops.update(
+        rule, lane_ops.LaneSlot(state[None], 0), live, operands)
+    return out, states[0]
+
+
 def _ssm_decode(h, lp, state, tail, live, cfg: HybridConfig):
-    """One token for every lane: ``h [b, d]``, ``state [b, heads,
-    head_dim, n]``, ``tail [b, conv-1, conv_dim]`` → ``(out [b, d], state,
+    """One token for every lane: ``h [b, d]``, ``state`` the layer's slot
+    of the state arena (:func:`_state_step`: ``[b, heads, head_dim, n]``
+    a lane), ``tail [b, conv-1, conv_dim]`` → ``(out [b, d], state,
     tail)``. An empty lane (``live`` false) reads zeros and keeps what its
     slot holds."""
     with jax.named_scope("ssm_in"):
@@ -541,12 +553,8 @@ def _ssm_decode(h, lp, state, tail, live, cfg: HybridConfig):
         x, bm, cm, step, a = _ssm_split(jax.nn.silu(conv + lp["conv_b"]),
                                         dt, lp, cfg)
     with jax.named_scope("ssm_update"):
-        lane = live[:, None, None, None]
-        old = jnp.where(lane, state.astype(jnp.float32), 0.0)
-        kept = old * jnp.exp(step * a)[..., None, None]
-        new = kept + (x * step[..., None])[..., None] * bm[:, None, None, :]
-        y = jnp.einsum("bhpn,bn->bhp", new, cm)
-        new_state = jnp.where(lane, new.astype(state.dtype), state)
+        y, new_state = _state_step(lane_ops.MAMBA2, state, live,
+                                   (x, step, a, bm, cm))
     with jax.named_scope("ssm_out"):
         out = _ssm_finish(y, x, z, lp, cfg)
     return out, new_state, new_tail
@@ -614,18 +622,16 @@ def _la_prefill(h, lp, lengths, cfg: HybridConfig):
 
 def _la_decode(h, lp, state, tail, live, cfg: HybridConfig):
     """One token for every lane, as :func:`_ssm_decode`: ``h [b, d]``,
-    ``state [b, value heads, key, value]``, ``tail [b, conv-1, channels]``
-    → ``(out [b, d], state, tail)``."""
+    ``state`` the layer's slot (``[b, value heads, key, value]`` a lane),
+    ``tail [b, conv-1, channels]`` → ``(out [b, d], state, tail)``."""
     with jax.named_scope("la_in"):
         qkv, z, g, beta = _la_project(h, lp, cfg)
     with jax.named_scope("la_conv"):
         conv, new_tail = _conv_decode(qkv, lp["conv_w"], tail, live)
         q, k, v = _la_split(jax.nn.silu(conv), cfg)
     with jax.named_scope("la_update"):
-        lane = live[:, None, None, None]
-        o, new = gated_delta_step(
-            jnp.where(lane, state.astype(jnp.float32), 0.0), q, k, v, g, beta)
-        new_state = jnp.where(lane, new.astype(state.dtype), state)
+        o, new_state = _state_step(lane_ops.GATED_DELTA, state, live,
+                                   (q, k, v, g, beta))
     with jax.named_scope("la_out"):
         out = _la_finish(o, z, lp, cfg)
     return out, new_state, new_tail
@@ -702,6 +708,16 @@ def lane_state(cfg: HybridConfig) -> Dict[str, tuple]:
         state = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
     return {"layers": layers, "ssm": (state, cfg.ssm_state_dtype),
             "conv": ((w - 1, channels), cfg.dtype)}
+
+
+def state_update(cfg: HybridConfig, lanes: int) -> str:
+    """The form the decode step updates ``lanes`` lanes' recurrent state
+    in here: ``"lane_kernel"`` or ``"reference"`` (``ops/lane_state.py``)."""
+    spec = lane_state(cfg)
+    shape, dtype = spec["ssm"]
+    return lane_ops.state_update_form(
+        lane_ops.GATED_DELTA if cfg.la_layers else lane_ops.MAMBA2,
+        jax.ShapeDtypeStruct((spec["layers"], lanes) + tuple(shape), dtype))
 
 
 def build_prefill(cfg: HybridConfig, max_seq: Optional[int] = None,
@@ -806,11 +822,10 @@ def build_paged_decode_step(cfg: HybridConfig, block_tokens: int,
             if kind != ATTENTION:
                 mixer, scope = (_ssm_decode, "ssm") if kind == MAMBA \
                     else (_la_decode, "la")
-                out, new, tail = mixer(
-                    h[:, 0], lp, state["ssm"][i_ssm], state["conv"][i_ssm],
-                    live, cfg)
-                with jax.named_scope(scope + "_update"):
-                    state["ssm"] = state["ssm"].at[i_ssm].set(new)
+                out, slot, tail = mixer(
+                    h[:, 0], lp, lane_ops.LaneSlot(state["ssm"], i_ssm),
+                    state["conv"][i_ssm], live, cfg)
+                state["ssm"] = slot.arena
                 with jax.named_scope(scope + "_conv"):
                     state["conv"] = state["conv"].at[i_ssm].set(tail)
                 out = out[:, None]
@@ -866,6 +881,7 @@ HYBRID = ModelFamily(
     kv_entry=lambda cfg: (cfg.attn_layers, 2,
                           (cfg.n_kv_heads, cfg.head_dim)),
     lane_state=lane_state, counters=COUNTERS, expert_matmul=expert_matmul,
+    state_update=state_update,
     refusal="keeps recurrent state per decode lane, which nothing can copy, "
             "share, shard or narrow yet (ROADMAP.md R3; mesh: R2)",
     # every matrix but the embedding, whose lookup (``_embed``) widens the

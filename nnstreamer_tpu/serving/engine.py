@@ -685,6 +685,16 @@ class ContinuousBatchingEngine:
             self.stats["expert_matmul"] = self.expert_matmul
             log.info("serving: %s runs its routed experts as %s",
                      self.obs_name, self.expert_matmul)
+        #: the form the decode program updates its lanes' recurrent state
+        #: in: "lane_kernel" (ops/lane_state.py: one in-place Pallas pass
+        #: over the state arena a layer) or "reference"; None for a family
+        #: without lane state
+        self.state_update = None
+        if family.state_update is not None:
+            self.state_update = family.state_update(cfg, self.B)
+            self.stats["state_update"] = self.state_update
+            log.info("serving: %s updates its lane state as %s",
+                     self.obs_name, self.state_update)
         self.prefix_cache = int(prefix_cache)
         if self.prefix_cache < 0:
             raise ValueError(

@@ -76,6 +76,10 @@ def test_rehearsal_runs_every_leg_on_the_cpu():
         assert hybrid["tokens_vs_forward"]["tokens_each"] == 9
         assert hybrid["moe_tokens_held"] > 0
         assert hybrid["state_bytes"] == 4 * state_bytes  # four lanes
+        assert hybrid["state_update"] == "reference"     # off a TPU
+    assert [(c["rule"], c["head_block"])
+            for c in result["legs"]["kernel"]["lane_state"]] \
+        == [("mamba2", 16), ("gated_delta", 4)]
     assert result["compile_cache"]["dir"] is None and result["claim"] is None
 
 

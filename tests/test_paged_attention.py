@@ -17,6 +17,7 @@ cells' shapes.
 """
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -408,7 +409,6 @@ def _moves_of(text, elements):
     """The instructions of a compiled program that MOVE ``elements`` or
     more: a copy, a reshape that is no bitcast, a transpose (alone or as
     the root a fusion is named after)."""
-    import re
 
     moves = []
     for line in text.splitlines():
@@ -605,3 +605,63 @@ def test_mosaic_compiles_the_expert_kernel_at_the_cells_widths(
     assert "tpu_custom_call" in text and "nns_expert_tiles" in text
     assert not [line for line in text.splitlines()
                 if " copy(" in line and f"bf16[{n_held}," in line]
+
+
+@pytest.mark.parametrize("rule,arena", [
+    ("mamba2", (9, 64, 128, 64, 128)),          # granite_h_chat_closed
+    ("gated_delta", (3, 128, 32, 128, 128)),    # qwen3next_chat_closed
+], ids=["granite_mamba2", "qwen3_next_gated_delta"])
+def test_mosaic_compiles_the_lane_state_kernel_at_the_cells_shapes(
+        one_chip, rule, arena):
+    """The recurrent mixers' state update (``ops/lane_state.py``; here for
+    the reason above) with the head block its own rule gives for a v5e,
+    every layer a step, eight steps with the arena the scan's carry as the
+    decode program holds it (32 and 16 heads a grid step): Mosaic takes
+    both bodies, and nothing of the
+    arena's or a layer's shape is copied, selected or sliced round them
+    (2.42 GB and 805 MB: a copy would be the whole gain, and the memory)."""
+    from nnstreamer_tpu.ops import lane_state as ls
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    layers, lanes, heads, rows, cols = arena
+    hb, limit = ls.head_block(heads, rows * cols * 4)
+    assert hb * rows * cols * 4 == 1 << 20     # a MiB of tiles a step
+    f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
+    per_head, vector = f32((lanes, heads)), f32((lanes, heads, rows))
+    operands = (vector, per_head, f32((heads,)), f32((lanes, cols)),
+                f32((lanes, cols))) if rule == ls.MAMBA2 else (
+        vector, vector, f32((lanes, heads, cols)), per_head, per_head)
+    vectors = [shape(v.shape) for v in jax.eval_shape(
+        lambda ops: ls._RULES[rule].pack(ops, hb), operands)]
+
+    def steps(state, live, vectors):
+        def body(state, _):
+            total = 0.0
+            for layer in range(layers):
+                out, state = ls._lane_state(
+                    state, live, vectors, rule=rule, layer=layer, hb=hb,
+                    vmem_limit_bytes=limit, interpret=False)
+                total = total + out[0, 0, 0, 0]
+            return state, total
+        return jax.lax.scan(body, state, None, length=8)
+
+    compiled = _compiled_for_the_chip(
+        jax.jit(steps, donate_argnums=(0,)), shape(arena),
+        shape((lanes,), jnp.int32), vectors)
+    text = compiled.as_text()
+    assert text.count("nns_lane_state") >= layers
+    # the live lanes alone ride in by scalar prefetch: a float32 scalar a
+    # lane and head there (32-48 KB) ran, and then no later program that
+    # prefetches scalars came back from the chip (PERF.md, PR 36)
+    assert f"f32[{lanes * heads}]" not in text
+    sized = ["f32[" + ",".join(map(str, dims)) + "]"
+             for dims in (arena, arena[1:])]
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if " = " in line and any(
+                 s in line.split(" = ", 1)[1].split("(")[0] for s in sized)
+             and re.search(r"[\]})] (copy|select|dynamic-update-slice|"
+                           r"dynamic-slice|fusion)\(", line)]
+    assert not moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
