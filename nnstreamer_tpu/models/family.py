@@ -44,6 +44,17 @@ class ModelFamily:
     #: that decides the order (``models/transformer.py``
     #: ``kv_heads_major``, applied by the codec that makes the arena)
     kv_entry: Callable
+    #: ``kv_window(cfg) -> None``, or ``(layers, window)``: the family has
+    #: attention layers of TWO kinds. ``kv_entry``'s layers see everything
+    #: and keep every block of a stream; beside them, this many layers see
+    #: the last ``window`` positions only (key ``j`` iff ``0 <= i - j <
+    #: window``). They get a block arena of their own, of the same entry
+    #: form (``serving/kvpool.py`` ``BlockPool.win``), a table of their own
+    #: a lane, and the engine gives a lane's blocks back to that arena once
+    #: they lie wholly behind the window. The decode step then takes the
+    #: tables as ``{"kv": bt, "win": bt_w}`` and the arenas as ``{"kv":
+    #: pages, "win": pages_w}``, and the prefill's cache has both keys
+    kv_window: Callable = lambda cfg: None
     #: ``latent_value_width(cfg)``: for a one-part entry, how many of the
     #: row's first columns are the value (``ops.paged_attention``
     #: ``v_width``); None for keys and values per head

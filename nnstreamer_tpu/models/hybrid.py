@@ -126,6 +126,8 @@ class HybridConfig:
     #: renormalised, times ``routed_scaling_factor``
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    #: what a router output becomes before the choice (``moe_ffn``)
+    score_func: str = "softmax"
     #: the output head is the embedding (else a leaf of its own, ``lm_head``)
     tie_embeddings: bool = True
     embedding_multiplier: float = 12.0
@@ -320,7 +322,12 @@ def moe_ffn(h, lp, cfg: HybridConfig, live=None):
     Routing is over all ``num_experts`` router outputs; a token's gates
     are the softmax over its ``experts_per_token`` largest logits (or,
     where the configuration says ``norm_topk_prob`` false, the softmax
-    over all of them at the chosen, times ``routed_scaling_factor``). The
+    over all of them at the chosen, times ``routed_scaling_factor``).
+    Under ``score_func`` ``"sigmoid"`` a score is ``sigmoid(logit)``; the
+    chosen are the largest of ``score + expert_bias`` (``lp["expert_bias"]
+    [num_experts]`` float32: for the CHOICE only), and the gates the
+    chosen's scores, divided by their sum over all the chosen where
+    ``norm_topk_prob``, times ``routed_scaling_factor``. The
     (token, choice) pairs that fell on a held expert are sorted by expert,
     each expert's rows start on a tile boundary of a padded buffer, and
     the tiles that hold a row go one by one through their tile's expert
@@ -334,10 +341,18 @@ def moe_ffn(h, lp, cfg: HybridConfig, live=None):
     with jax.named_scope("router"):
         logits = jnp.dot(h, lp["router"].astype(dtype),
                          preferred_element_type=jnp.float32)
-        top, choice = lax.top_k(logits, k)                       # [t, k]
-        if cfg.norm_topk_prob:
+        if cfg.score_func == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            _, choice = lax.top_k(scores + lp["expert_bias"], k)
+            gates = jnp.take_along_axis(scores, choice, axis=-1)
+            if cfg.norm_topk_prob:
+                gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-20)
+            gates = gates * cfg.routed_scaling_factor
+        elif cfg.norm_topk_prob:
+            top, choice = lax.top_k(logits, k)                   # [t, k]
             gates = jax.nn.softmax(top, axis=-1)
         else:
+            _, choice = lax.top_k(logits, k)
             gates = jnp.take_along_axis(jax.nn.softmax(logits, axis=-1),
                                         choice, axis=-1) \
                 * cfg.routed_scaling_factor
@@ -656,12 +671,14 @@ def _rope(x, positions, rotary_dim: int, theta: float, freqs=None):
                             x32[..., rotary_dim:]], axis=-1).astype(x.dtype)
 
 
-def _qkv(h, lp, positions, cfg: HybridConfig):
+def _qkv(h, lp, positions, cfg: HybridConfig, rotary_dim=None):
     """``(q, k, v, gate)`` of ``h [b, s, d]`` at ``positions [b, s]``:
     the projections, then what the configuration asks of queries and keys
     (a norm over each head, rotary positions); ``gate`` is None without
-    ``attn_gate``."""
+    ``attn_gate``. ``rotary_dim``: this layer's, where the kinds of
+    attention layer differ in it (default: the configuration's)."""
     dtype = cfg.dtype
+    rotary_dim = cfg.rotary_dim if rotary_dim is None else rotary_dim
     q = jnp.einsum("bsd,dhc->bshc", h, lp["wq"].astype(dtype))
     k = jnp.einsum("bsd,dhc->bshc", h, lp["wk"].astype(dtype))
     v = jnp.einsum("bsd,dhc->bshc", h, lp["wv"].astype(dtype))
@@ -670,9 +687,9 @@ def _qkv(h, lp, positions, cfg: HybridConfig):
     if cfg.qk_norm:
         q = _rmsnorm(q, lp["q_norm"], cfg.rms_eps)
         k = _rmsnorm(k, lp["k_norm"], cfg.rms_eps)
-    if cfg.rotary_dim:
-        q = _rope(q, positions, cfg.rotary_dim, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rotary_dim, cfg.rope_theta)
+    if rotary_dim:
+        q = _rope(q, positions, rotary_dim, cfg.rope_theta)
+        k = _rope(k, positions, rotary_dim, cfg.rope_theta)
     return q, k, v, gate
 
 

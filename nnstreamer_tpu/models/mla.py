@@ -108,6 +108,7 @@ class MLAConfig:
     # what the routines shared with ``hybrid.py`` ask of a configuration
     # and this family has one value for
     shared_gate = False
+    score_func = "softmax"
     tie_embeddings = False
     embedding_multiplier = 1.0
     residual_multiplier = 1.0

@@ -67,6 +67,15 @@ def register_engine_collector(engine, registry: MetricsRegistry = None
                       "Weights as given to the engine, as it holds them "
                       "(serving_params), and the leaves it narrowed",
                       **labels).set(nbytes)
+        win = getattr(eng._pool, "win", None)
+        if win is not None:  # a family with window layers: its own arena
+            reg.gauge("nns_serving_kv_window_blocks",
+                      "Blocks of the window layers' arena",
+                      **labels).set(win.num_blocks)
+            reg.gauge("nns_serving_kv_window_blocks_live",
+                      "Blocks of the window layers' arena that streams "
+                      "hold (what lies behind a window has gone back)",
+                      **labels).set(win.live_blocks())
         slot_steps = eng.stats["slot_steps"]
         occupancy = (eng.stats["active_slot_steps"] / slot_steps
                      if slot_steps else 0.0)

@@ -8,11 +8,13 @@ VMEM holds O(block) state regardless of context length. Causally-dead
 k-tiles are skipped with predicated execution. bfloat16 in/out, fp32
 accumulation — the MXU-friendly shape of the computation.
 
-``flash_attention`` auto-selects: the Pallas kernel on TPU for aligned
-shapes, the jnp reference otherwise (CPU tests, ragged shapes). The same
-online-softmax math also runs *between* chips in
-``parallel.ring.ring_attention``; this kernel is the intra-chip tile of
-that scheme.
+``window``: query ``i`` sees key ``j`` iff ``0 <= i - j < window`` (a band
+under the diagonal). The k axis of the grid then has only as many steps as
+a q-tile's band can touch, and step ``ik`` of q-tile ``iq`` maps to the
+band's ``ik``-th k-tile: the tiles before the band are neither fetched nor
+multiplied (the few steps past a q-tile's last live tile name that tile
+again, so nothing is fetched for them either). The kernel is then called
+``nns_band_flash_prefill``.
 """
 
 from __future__ import annotations
@@ -34,14 +36,17 @@ log = get_logger("flash-attention")
 _NEG_BIG = -1e30
 
 
-def attention_reference(q, k, v, causal: bool = True, scale=None):
+def attention_reference(q, k, v, causal: bool = True, scale=None,
+                        window: int | None = None):
     """Plain XLA attention, [batch, seq, heads, dim] layout; fp32 softmax.
 
     The canonical single-device reference — parallel.ring re-exports this
     for its unsharded path. ``scale`` defaults to ``dim ** -0.5``; ``k``
     and ``v`` may have fewer heads than ``q`` (grouped queries: query head
-    ``i`` reads key-value head ``i // group``).
+    ``i`` reads key-value head ``i // group``). ``window``: query ``i``
+    sees key ``j`` iff ``0 <= i - j < window`` (causal only).
     """
+    _check_window(window, causal)
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     group = q.shape[2] // k.shape[2]
     if group > 1:
@@ -51,17 +56,36 @@ def attention_reference(q, k, v, causal: bool = True, scale=None):
     if causal:
         qi = jnp.arange(q.shape[1])[:, None]
         ki = jnp.arange(k.shape[1])[None, :]
-        s = jnp.where(qi >= ki, s, _NEG_BIG)
+        seen = qi >= ki if window is None \
+            else (qi >= ki) & (qi - ki < window)
+        s = jnp.where(seen, s, _NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     return o.astype(q.dtype)
 
 
+def _check_window(window, causal) -> None:
+    if window is not None and (window <= 0 or not causal):
+        raise ValueError(f"attention: window ({window}) must be positive "
+                         f"and goes with causal=True")
+
+
+def _band_tiles(iq, block_q: int, block_k: int, window: int):
+    """The first and the last k-tile that q-tile ``iq``'s band touches."""
+    lo = jnp.maximum(iq * block_q - (window - 1), 0) // block_k
+    hi = ((iq + 1) * block_q - 1) // block_k
+    return lo, hi
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            causal: bool, scale: float, block_q: int, block_k: int):
+            causal: bool, scale: float, block_q: int, block_k: int,
+            window: int | None = None):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
+    if window is not None:   # step ki is the band's ki-th k-tile
+        first, last = _band_tiles(qi, block_q, block_k, window)
+        kt = first + ki
 
     @pl.when(ki == 0)
     def _init():
@@ -72,6 +96,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     # a k-tile is causally dead when its first key comes after the last
     # query of this q-tile
     live = True if not causal else ki * block_k <= (qi + 1) * block_q - 1
+    if window is not None:
+        live = kt <= last
 
     @pl.when(live)
     def _step():
@@ -83,9 +109,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         if causal:
             q_pos = qi * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_BIG)
+            k_pos = (ki if window is None else kt) * block_k \
+                + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+            seen = q_pos >= k_pos if window is None \
+                else (q_pos >= k_pos) & (q_pos - k_pos < window)
+            s = jnp.where(seen, s, _NEG_BIG)
         m_prev = m_scr[:, :1]                              # [bq, 1]
         l_prev = l_scr[:, :1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -106,9 +134,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret", "scale"))
+                                             "interpret", "scale", "window"))
 def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
-                interpret: bool, scale=None):
+                interpret: bool, scale=None, window: int | None = None):
     """Kernel entry on [batch, heads, seq, dim] layout. ``k``/``v`` with
     fewer heads than ``q``: the grid walks the query heads and head ``i``
     streams the tiles of key-value head ``i // group``. ``v`` may be
@@ -118,9 +146,20 @@ def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
     group = h // k.shape[1]
     scale = d ** -0.5 if scale is None else scale
     kv_head = (lambda ih: ih) if group == 1 else (lambda ih: ih // group)
-    grid = (b, h, sq // block_q, sk // block_k)
+    nk = sk // block_k
+    if window is not None:  # the k-tiles a q-tile's band can touch
+        nk = min(nk, (window + block_q - 2) // block_k + 2)
+
+    def k_tile(iq, ik):
+        """The k-tile that step ``ik`` of q-tile ``iq`` reads."""
+        if window is None:
+            return ik
+        first, last = _band_tiles(iq, block_q, block_k, window)
+        return jnp.minimum(first + ik, last)
+
+    grid = (b, h, sq // block_q, nk)
     kern = functools.partial(_kernel, causal=causal, scale=scale,
-                             block_q=block_q, block_k=block_k)
+                             block_q=block_q, block_k=block_k, window=window)
     # batch/head/q-block axes are independent → declare them parallel so
     # the TPU distributes them instead of walking the whole grid
     # sequentially (measured 500x on a [4,512,8,64] prefill); only the
@@ -136,10 +175,10 @@ def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda ib, ih, iq, ik: (ib, kv_head(ih), ik, 0)),
-            pl.BlockSpec((1, 1, block_k, dv),
-                         lambda ib, ih, iq, ik: (ib, kv_head(ih), ik, 0)),
+            pl.BlockSpec((1, 1, block_k, d), lambda ib, ih, iq, ik: (
+                ib, kv_head(ih), k_tile(iq, ik), 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda ib, ih, iq, ik: (
+                ib, kv_head(ih), k_tile(iq, ik), 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, dv),
                                lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -149,7 +188,8 @@ def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, dv), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
-        name="nns_flash_prefill",
+        name="nns_flash_prefill" if window is None
+        else "nns_band_flash_prefill",
     )(q, k, v)
 
 
@@ -190,18 +230,22 @@ def _log_reference_choice(q_shape, k_shape, dtype, why: str) -> None:
 
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
                     block_k: int = 256, force: str | None = None,
-                    scale: float | None = None):
+                    scale: float | None = None, window: int | None = None):
     """Attention on [batch, seq, heads, dim] tensors; ``scale`` and fewer
     key-value heads as in :func:`attention_reference`; the values may
     have another width than queries and keys, which the output takes.
+    ``window``: the band ``0 <= i - j < window``, its dead k-tiles skipped.
 
     ``force``: None (auto: the Pallas kernel on a TPU for tileable
     shapes, else the XLA reference), "pallas" (always the kernel — Mosaic
     on a TPU, the Pallas interpreter elsewhere, which is how the CPU
     tests run it), or "reference".
     """
+    _check_window(window, causal)
+    ref = functools.partial(attention_reference, q, k, v, causal=causal,
+                            scale=scale, window=window)
     if force == "reference":
-        return attention_reference(q, k, v, causal=causal, scale=scale)
+        return ref()
     block_q = min(block_q, q.shape[1])
     block_k = min(block_k, k.shape[1])
     on_tpu = jax.default_backend() == "tpu"
@@ -212,14 +256,14 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
                 f"flash_attention: shapes {q.shape}/{k.shape} not tileable "
                 f"by ({block_q},{block_k}): {why_not}")
     elif not on_tpu:
-        return attention_reference(q, k, v, causal=causal, scale=scale)
+        return ref()
     elif why_not:
         _log_reference_choice(tuple(q.shape), tuple(k.shape), str(q.dtype),
                               why_not)
-        return attention_reference(q, k, v, causal=causal, scale=scale)
+        return ref()
     qt = q.swapaxes(1, 2)  # [b, h, s, d]
     kt = k.swapaxes(1, 2)
     vt = v.swapaxes(1, 2)
     out = _flash_bhsd(qt, kt, vt, causal, block_q, block_k,
-                      interpret=not on_tpu, scale=scale)
+                      interpret=not on_tpu, scale=scale, window=window)
     return out.swapaxes(1, 2)

@@ -47,6 +47,14 @@ column (``hk`` = 1: the mask above has nothing to mask), and the output is
 the name ``nns_mla_paged_decode``; its chunk is ``LATENT_CHUNK_BLOCKS``
 (a block is a fifth of a per-head block's bytes at 16 heads).
 
+A WINDOW (``window``: lane ``i`` attends over slots ``max(0, pos - window
++ 1) .. pos``) gives the loop a lower bound beside ``pos``: it starts at
+the block that holds the window's first slot, so what lies before it is
+neither fetched nor waited for (its table entries may be sentinel: the
+engine has given those blocks back), and the slots of that first block that
+are too old are masked as the slots past ``pos`` are. The same kernel, under
+the name ``nns_window_paged_decode``.
+
 ``paged_attention`` auto-selects like ``flash_attention``: the kernel on a
 TPU for shapes it takes, the gather form elsewhere.
 """
@@ -88,7 +96,8 @@ def _split3(p):
 def _kernel(layer_ref, bt_ref, pos_ref, q_ref, pages_ref, o_ref,
             buf, sem, m_scr, l_scr, acc_scr, *, scale: float,
             block_tokens: int, kv_heads: int, chunk: int,
-            v_width: int | None = None, heads_major: bool = False):
+            v_width: int | None = None, heads_major: bool = False,
+            window: int | None = None):
     lane = pl.program_id(0)
     layer = layer_ref[0]
     pos = pos_ref[lane]
@@ -97,7 +106,11 @@ def _kernel(layer_ref, bt_ref, pos_ref, q_ref, pages_ref, o_ref,
     group = hq // kv_heads
     rows = chunk * block_tokens * kv_heads       # key rows of a chunk
     n_blocks = pos // block_tokens + 1           # the lane's live blocks
-    n_chunks = (n_blocks + chunk - 1) // chunk
+    if window is not None:       # of which the window's are read: from
+        oldest = jnp.maximum(pos - (window - 1), 0)   # this slot's block
+        first = oldest // block_tokens
+    n_chunks = (n_blocks + chunk - 1) // chunk if window is None \
+        else (n_blocks - first + chunk - 1) // chunk
     exact = lax.Precision.HIGHEST if q_ref.dtype == jnp.float32 else None
 
     @pl.when(lane == 0)
@@ -109,7 +122,7 @@ def _kernel(layer_ref, bt_ref, pos_ref, q_ref, pages_ref, o_ref,
     def copies(c, slot):
         """(live?, copy) of each block of chunk ``c`` into ``buf[slot]``."""
         for i in range(chunk):
-            j = c * chunk + i
+            j = c * chunk + i if window is None else first + c * chunk + i
             blk = jnp.minimum(bt_ref[lane, jnp.minimum(j, bt_ref.shape[1] - 1)],
                               zero_block)
             yield j < n_blocks, pltpu.make_async_copy(
@@ -165,7 +178,11 @@ def _kernel(layer_ref, bt_ref, pos_ref, q_ref, pages_ref, o_ref,
             rest = buf[slot, :, 0, :, v_width:].reshape(rows, dh - v_width)
             s = (scores(q_ref[0, :, :v_width], v)
                  + scores(q_ref[0, :, v_width:], rest)) * scale
-        seen = slot_of <= pos - c * (chunk * block_tokens)
+        if window is None:
+            seen = slot_of <= pos - c * (chunk * block_tokens)
+        else:
+            at = (first + c * chunk) * block_tokens + slot_of
+            seen = (at <= pos) & (at >= oldest)
         s = jnp.where(own_head & seen, s, _NEG_BIG)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -188,10 +205,11 @@ def _kernel(layer_ref, bt_ref, pos_ref, q_ref, pages_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "chunk", "interpret",
-                                             "v_width", "heads_major"))
+                                             "v_width", "heads_major",
+                                             "window"))
 def _paged_decode(q, pages, layer, bt, pos_c, scale: float, chunk: int,
                   interpret: bool, v_width: int | None = None,
-                  heads_major: bool = False):
+                  heads_major: bool = False, window: int | None = None):
     """Kernel entry: ``q [b, hq, dh]``, the arena leaf whole."""
     b, hq, dh = q.shape
     if v_width is None:
@@ -207,7 +225,7 @@ def _paged_decode(q, pages, layer, bt, pos_c, scale: float, chunk: int,
     dv = dh if v_width is None else v_width
     kern = functools.partial(_kernel, scale=scale, block_tokens=T,
                              kv_heads=hk, chunk=chunk, v_width=v_width,
-                             heads_major=heads_major)
+                             heads_major=heads_major, window=window)
     return pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct((b, hq, dv), q.dtype),
@@ -227,14 +245,16 @@ def _paged_decode(q, pages, layer, bt, pos_c, scale: float, chunk: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="nns_paged_decode" if v_width is None
+        name="nns_window_paged_decode" if window is not None
+        else "nns_paged_decode" if v_width is None
         else "nns_mla_paged_decode",
     )(jnp.asarray(layer, jnp.int32).reshape(1), bt.astype(jnp.int32),
       pos_c.astype(jnp.int32), q, flat)
 
 
 def _pallas_reject(q, pages, bt, v_width: int | None = None,
-                   heads_major: bool = False) -> str | None:
+                   heads_major: bool = False,
+                   window: int | None = None) -> str | None:
     """Why these shapes cannot go to the kernel, or None when they can.
     ``heads_major`` says which of a six-axis arena's axes 3 and 4 is the
     block's tokens and which its heads; it is never guessed.
@@ -253,7 +273,12 @@ def _pallas_reject(q, pages, bt, v_width: int | None = None,
     proved: a head dim of 384 or a group of 16 would pass them untried.
     A latent arena (``v_width``) was compiled and served from at 16 heads
     over rows of 576 columns, the value the first 512
-    (``tests/test_paged_attention.py``; PERF.md, PR 33)."""
+    (``tests/test_paged_attention.py``; PERF.md, PR 33). With a ``window``
+    it was compiled and served from token-major at 48 query over 8
+    key-value heads of 128 (PERF.md, PR 37); a window over a latent arena
+    was never built."""
+    if window is not None and v_width is not None:
+        return "a window over a latent arena is not built"
     if not hasattr(pages, "shape") or \
             len(pages.shape) != (6 if v_width is None else 5):
         return "the arena is not one [L, NTOT, 2, T, h, dh] leaf (or " \
@@ -291,11 +316,12 @@ def _pallas_reject(q, pages, bt, v_width: int | None = None,
 
 
 def paged_attention_form(q, pages, bt, v_width: int | None = None,
-                         heads_major: bool = False) -> str:
+                         heads_major: bool = False,
+                         window: int | None = None) -> str:
     """Which form :func:`paged_attention` builds in auto mode for these
     arguments (arrays or shapes): ``"paged_kernel"`` or ``"gather"``."""
     if jax.default_backend() != "tpu" or \
-            _pallas_reject(q, pages, bt, v_width, heads_major):
+            _pallas_reject(q, pages, bt, v_width, heads_major, window):
         return "gather"
     return "paged_kernel"
 
@@ -306,29 +332,50 @@ def _log_reference_choice(q_shape, pages_shape, dtype, why: str) -> None:
                 "Pallas kernel: %s", q_shape, pages_shape, dtype, why)
 
 
+def _window_tables(pages, bt, pos_c, window: int, heads_major: bool):
+    """The blocks a window can touch, out of each lane's table: ``(bt_w [b,
+    nb], slots [b, nb * T])``, the table entries from the block of the
+    window's first slot on and the position each gathered slot holds."""
+    T = pages.shape[4 if heads_major else 3]
+    nb = min(bt.shape[1], (window - 1) // T + 2)
+    first = jnp.maximum(pos_c - (window - 1), 0) // T
+    idx = jnp.minimum(first[:, None] + jnp.arange(nb), bt.shape[1] - 1)
+    return jnp.take_along_axis(bt, idx, axis=1), \
+        first[:, None] * T + jnp.arange(nb * T)
+
+
 def paged_attention_reference(q, pages, layer, bt, pos_c, scale=None,
                               v_width: int | None = None,
-                              heads_major: bool = False):
+                              heads_major: bool = False,
+                              window: int | None = None):
     """The gather form: every lane's whole table copied out of the arena
     (into ``[b, MB * T, hk, dh]`` whichever the arena's order),
     masked to ``slot <= pos_c`` and attended over by ``_attend_cache``
     (a latent arena's rows as one key-value head whose value is the
-    row's first ``v_width`` columns)."""
+    row's first ``v_width`` columns). With a ``window``, the blocks the
+    window can touch alone, masked to ``pos_c - window < slot <= pos_c``."""
     from nnstreamer_tpu.models.transformer import (
         _attend_cache,
         _paged_gather,
     )
 
     with jax.named_scope("kv_gather"):
-        g = _paged_gather(pages, layer, bt, heads_major)
-        slots = jnp.arange(g.shape[2])
-        mask = slots[None, None, None, :] <= pos_c[:, None, None, None]
+        if window is None:
+            g = _paged_gather(pages, layer, bt, heads_major)
+            slots = jnp.arange(g.shape[2])
+            mask = slots[None, None, None, :] <= pos_c[:, None, None, None]
+        else:
+            bt_w, slots = _window_tables(pages, bt, pos_c, window,
+                                         heads_major)
+            g = _paged_gather(pages, layer, bt_w, heads_major)
+            mask = ((slots <= pos_c[:, None])
+                    & (slots > pos_c[:, None] - window))[:, None, None, :]
         if v_width is None:
             ck, cv = g[:, 0], g[:, 1]
         else:
             ck = g[:, 0, :, None]
             cv = ck[..., :v_width]
-    with jax.named_scope("attend"):
+    with jax.named_scope("attend" if window is None else "attend_window"):
         return _attend_cache(q, ck, cv, mask, q.shape[-1], q.dtype,
                              scale=scale)
 
@@ -337,7 +384,8 @@ def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
                     force: str | None = None,
                     chunk_blocks: int | None = None,
                     v_width: int | None = None,
-                    heads_major: bool = False):
+                    heads_major: bool = False,
+                    window: int | None = None):
     """Decode attention of ``q [b, 1, hq, dh]`` over a paged cache.
 
     ``pages`` is the arena's value leaf WHOLE, ``[L, NTOT, 2, T, hk, dh]``
@@ -358,14 +406,23 @@ def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
     columns: ``[b, 1, hq, v_width]`` comes back. ``chunk_blocks`` defaults
     to ``CHUNK_BLOCKS``, for a latent arena ``LATENT_CHUNK_BLOCKS``.
 
+    ``window``: lane ``i`` attends over slots ``max(0, pos_c[i] - window
+    + 1) .. pos_c[i]`` only, and the table entries of the blocks before the
+    one that holds the first of them are never read.
+
     ``force``: None (auto: the kernel on a TPU for shapes it takes, else
     the gather form), "pallas" (always the kernel: Mosaic on a TPU, the
     Pallas interpreter elsewhere, which is how the CPU tests run it) or
     "reference". The kernel's instructions lie under the scope
-    ``attend``; the gather form keeps ``kv_gather`` and ``attend``.
+    ``attend``; the gather form keeps ``kv_gather`` and ``attend``. With a
+    ``window`` the scope is ``attend_window`` in both, so that a trace
+    tells a model's two kinds of attention layer apart.
     """
     on_tpu = jax.default_backend() == "tpu"
-    why_not = _pallas_reject(q, pages, bt, v_width, heads_major)
+    if window is not None and window <= 0:
+        raise ValueError(f"paged_attention: window ({window}) must be "
+                         f"positive")
+    why_not = _pallas_reject(q, pages, bt, v_width, heads_major, window)
     if force == "pallas":
         if why_not:
             raise ValueError(
@@ -375,14 +432,15 @@ def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
             _log_reference_choice(tuple(q.shape), tuple(pages.shape),
                                   str(q.dtype), why_not)
         return paged_attention_reference(q, pages, layer, bt, pos_c, scale,
-                                         v_width, heads_major)
+                                         v_width, heads_major, window)
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     if chunk_blocks is None:
         chunk_blocks = CHUNK_BLOCKS if v_width is None \
             else LATENT_CHUNK_BLOCKS
-    with jax.named_scope("attend"):
+    with jax.named_scope("attend" if window is None else "attend_window"):
         out = _paged_decode(
             q[:, 0], pages, layer, bt, pos_c, scale=float(scale),
             chunk=min(int(chunk_blocks), bt.shape[1]),
-            interpret=not on_tpu, v_width=v_width, heads_major=heads_major)
+            interpret=not on_tpu, v_width=v_width, heads_major=heads_major,
+            window=window)
     return out[:, None]

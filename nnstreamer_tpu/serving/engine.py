@@ -144,6 +144,11 @@ class GenerationStream:
         #: ids only, released with the finish (``BlockPool.stream_rows``
         #: reads what they still hold, for a check on an idle engine)
         self.blocks: tuple = ()
+        #: for a family with window layers: ``(first, ids)``, the window
+        #: arena's blocks it held when it finished and the index of the
+        #: first of them in its table (block ``first + i`` holds positions
+        #: ``(first + i) * T ..``)
+        self.window_blocks: tuple = (0, ())
         self._q: _queue.Queue = _queue.Queue()
 
     def cancel(self) -> None:
@@ -307,7 +312,17 @@ class ContinuousBatchingEngine:
         holds beside its blocks and
         which options its programs bring: ``models.transformer`` is the
         dense member, ``models.hybrid`` the one with recurrent layers,
-        ``models.mla`` the one whose cache is one latent row a token.
+        ``models.mla`` the one whose cache is one latent row a token,
+        ``models.afmoe`` the one with window layers beside full ones
+        (``kv_window``): the window layers' keys and values live in a
+        second arena under a second table a lane, and after every
+        dispatch the engine gives back the blocks of that table that lie
+        wholly behind the window (blocks given back, not a ring addressed
+        in place: a block that goes back is any stream's next block, a
+        lane that finishes early leaves nothing reserved, and a block
+        another stream shares can be let go of where a ring would have to
+        copy it first; the table keeps the full table's indexing, so one
+        kernel reads both).
         With a family that has lane state every stream keeps its lane
         for life. Of ``prefix_cache``, ``speculate``, ``prefill_chunk``,
         ``kv_quant`` and ``mesh=`` the dense family brings all; what a
@@ -391,6 +406,10 @@ class ContinuousBatchingEngine:
         positive divisor of ``max_seq`` (``ValueError`` otherwise).
     kv_blocks: arena size in blocks. Defaults to ``max_streams * max_seq
         / block_tokens``: every lane's full context at once.
+    kv_window_blocks: the window arena's size in blocks, for a family
+        with window layers. Defaults to ``max_streams * (ceil((window +
+        steps_per_dispatch) / block_tokens) + 1)``: the most every lane
+        can hold at once.
     speculate: > 0 enables speculative decoding — a ``speculate_layers``
         -layer draft sliced from the target params
         (models/speculative.py) proposes K tokens per round inside the
@@ -415,6 +434,7 @@ class ContinuousBatchingEngine:
                  slo_budget_ms: float = 0.0,
                  block_tokens: int = 16,
                  kv_blocks: Optional[int] = None,
+                 kv_window_blocks: Optional[int] = None,
                  speculate: int = 0,
                  speculate_layers: Optional[int] = None):
         import jax
@@ -486,6 +506,16 @@ class ContinuousBatchingEngine:
             cfg, self.S, attention_fn=attention_fn, kv_codec=kv_quant)
         #: block-table width: blocks per stream at full context
         self.MB = self.S // self.block_tokens
+        #: the family's window layers, ``(layers, window)``, or None; and
+        #: the most blocks of the window arena a lane holds at a dispatch
+        self._window = family.kv_window(cfg)
+        if self._window is not None:
+            if self._auto_k:
+                raise ValueError("serving: a family with window layers "
+                                 "sizes its window arena by a fixed "
+                                 "steps_per_dispatch, not \"auto\"")
+            self.MBW = min(self.MB, -(-(self._window[1] + self.K)
+                                      // self.block_tokens) + 1)
         paged_attention_fn = None
         if attention == "auto" and mesh is None and kv_quant is None:
             # one chip, one raw arena leaf: as for prefill, a
@@ -576,6 +606,12 @@ class ContinuousBatchingEngine:
             # blocks the paged decode steps had to read (every lane's
             # live blocks, step by step) and blocks their tables name
             "kv_blocks_live": 0, "kv_blocks_table": 0,
+            # a family with window layers: blocks a window layer's
+            # attention had to read (every lane, step by step, as
+            # ``kv_blocks_live`` counts a full layer's) and blocks given
+            # back behind a window
+            **({"kv_window_blocks_live": 0, "kv_window_blocks_released": 0}
+               if self._window is not None else {}),
             # what the family's decode step counts of itself, summed over
             # the steps of every dispatch
             **{name: 0 for name in family.counters},
@@ -639,7 +675,8 @@ class ContinuousBatchingEngine:
         self._pool = _kvpool.BlockPool(
             cfg, nb, self.block_tokens,
             kv_codec=kv_quant, mesh=mesh, owner=self.obs_name,
-            lanes=self.B)
+            lanes=self.B, window_blocks=None if self._window is None
+            else int(kv_window_blocks or self.B * self.MBW))
         #: sid → per-stream decode state (stream, blocks, pos, last,
         #: key, budget, deadline_t, slot); engine thread only. Every
         #: ADMITTED stream lives here whether or not it currently
@@ -653,6 +690,11 @@ class ContinuousBatchingEngine:
         #: host mirror of the device block tables, one row per lane
         self._bt = np.full((self.B, self.MB), self._pool.SENTINEL,
                            np.int32)
+        #: the same for the window arena: entry ``j`` is the block that
+        #: holds positions ``j * T ..`` of the window layers, or that
+        #: arena's sentinel (not yet allocated, or given back)
+        self._bt_w = None if self._window is None else np.full(
+            (self.B, self.MB), self._pool.win.SENTINEL, np.int32)
         #: the form the decode program attends in: "paged_kernel"
         #: (ops/paged_attention.py: live blocks read in place) or "gather"
         self.decode_attention = "gather"
@@ -661,20 +703,27 @@ class ContinuousBatchingEngine:
                 paged_attention_form,
             )
 
-            kv = self._pool.arena
-            kv = kv["kv"] if self._lane_state else kv
+            kv = self._pool._kv(self._pool.arena)
             self.decode_attention = paged_attention_form(
                 jax.ShapeDtypeStruct(
                     (self.B, 1, cfg.n_heads, kv.shape[-1]), kv.dtype),
                 kv, self._bt, v_width=family.latent_value_width(cfg),
-                heads_major=self._pool.heads_major)
-        #: bytes of the arena's entry for one token, all layers, as held;
-        #: for a latent arena the form beside it (a dense engine's stats
-        #: stay integers: ``tests/test_lm_tracing.py``)
+                heads_major=self._pool.heads_major,
+                window=self._window and self._window[1])
+        #: bytes of the arena's entry for one token, all layers (of a
+        #: family with window layers: the full ones), as held; for a
+        #: latent arena or two arenas the form beside it (a dense engine's
+        #: stats stay integers: ``tests/test_lm_tracing.py``)
         self.stats["kv_bytes_per_token"] = (
-            self._pool.nbytes - self._pool.state_bytes) \
+            self._pool.nbytes - self._pool.state_bytes
+            - self._pool.window_bytes) \
             // (self._pool.ntot * self.block_tokens)
-        if family.latent_value_width(cfg) is not None:
+        if self._window is not None:
+            self.stats["kv_window_bytes_per_token"] = \
+                self._pool.window_bytes \
+                // (self._pool.win.ntot * self.block_tokens)
+        if family.latent_value_width(cfg) is not None \
+                or self._window is not None:
             self.stats["decode_attention"] = self.decode_attention
         #: the form the decode program runs its routed experts in:
         #: "grouped_kernel" (ops/grouped_matmul.py: a Pallas grid over the
@@ -798,10 +847,16 @@ class ContinuousBatchingEngine:
         _DECODE_PROGRAMS.pop(self.obs_name, None)
         _DECODE_PROGRAMS[self.obs_name] = (self._build_dispatch, k, (
             jax.tree.map(shape, self.params), host(self.B),
-            jax.tree.map(shape, self._pool.arena), host(self.B, self.MB),
+            jax.tree.map(shape, self._pool.arena),
+            self._tables(host(self.B, self.MB), host(self.B, self.MB)),
             host(self.B), host(self.B, 2, dtype=jnp.uint32)))
         while len(_DECODE_PROGRAMS) > _DECODE_PROGRAMS_KEPT:
             del _DECODE_PROGRAMS[next(iter(_DECODE_PROGRAMS))]
+
+    def _tables(self, bt, bt_w):
+        """The block tables as the decode program takes them: the one
+        table, or for a family with window layers both by arena."""
+        return bt if self._window is None else {"kv": bt, "win": bt_w}
 
     def _calibrate_k(self) -> None:
         """steps_per_dispatch="auto": pick K from MEASURED costs.
@@ -1179,6 +1234,8 @@ class ContinuousBatchingEngine:
         # block ids into the dead allocation map — drop them with it.
         self._pool.reset()
         self._bt[:] = self._pool.SENTINEL
+        if self._bt_w is not None:
+            self._bt_w[:] = self._pool.win.SENTINEL
         self._prefix.clear()
         self._prefix_trie = _PrefixTrie()
         if self._spec is not None:
@@ -1398,6 +1455,24 @@ class ContinuousBatchingEngine:
         the prompt ends exactly on a boundary)."""
         return n // self.block_tokens + 1
 
+    def _window_first(self, pos: int) -> int:
+        """The first block of the window table that a step at position
+        ``pos`` reads: the one that holds ``pos - window + 1``."""
+        if self._window is None:
+            return 0
+        return max(0, pos - self._window[1] + 1) // self.block_tokens
+
+    def _release_behind(self, state) -> None:
+        """Give back the blocks of ``state``'s window table that lie
+        wholly before ``pos - window + 1``: no step reads them again."""
+        first = self._window_first(state["pos"])
+        gone = min(first - state["wfirst"], len(state["wblocks"]))
+        if gone > 0:
+            self._pool.win.release(state["wblocks"][:gone])
+            del state["wblocks"][:gone]
+            state["wfirst"] += gone
+            self.stats["kv_window_blocks_released"] += gone
+
     def _alloc_blocks(self, k: int):
         """Pool alloc with the evict rung of the pressure ladder: LRU
         paged prefix entries are dropped until the allocation fits (or
@@ -1533,6 +1608,15 @@ class ContinuousBatchingEngine:
         blocks = self._alloc_blocks(self._blocks_for(n))
         if blocks is None:
             return None
+        # a family with window layers: the blocks of the window arena that
+        # the first decode step reads and writes, from the block that
+        # holds position n - window + 1 on; either arena defers
+        wfirst, wblocks = self._window_first(n), []
+        if self._window is not None:
+            wblocks = self._pool.win.alloc(self._blocks_for(n) - wfirst)
+            if wblocks is None:
+                self._pool.release(blocks)
+                return None
         # a family with lane state: the stream's lane is claimed here, and
         # the prefill's final state goes over that lane's slot whole
         lane = self._pool.alloc_lane() if self._lane_state else None
@@ -1549,11 +1633,17 @@ class ContinuousBatchingEngine:
             self.stats["prefill_tokens"] += n
             self.stats["prefill_bucket_tokens"] += bucket
             self._enqueue(self._pool.scatter_prefill, cache1,
-                          blocks[:(n + T - 1) // T], lane=lane)
+                          blocks[:(n + T - 1) // T], lane=lane,
+                          window_ids=wblocks[:(n + T - 1) // T - wfirst],
+                          window_first=wfirst)
             self._prefix_store_paged(prompt, blocks, logits)
-            return self._activate_begin_paged(req, logits, blocks, lane)
+            rec = self._activate_begin_paged(req, logits, blocks, lane)
+            rec[1].update(wblocks=wblocks, wfirst=wfirst)
+            return rec
         except Exception:
             self._pool.release(blocks)
+            if wblocks:
+                self._pool.win.release(wblocks)
             if lane is not None:
                 self._pool.release_lane(lane)
             raise
@@ -1660,6 +1750,8 @@ class ContinuousBatchingEngine:
         if slot is not None:
             self._lane[slot] = None
             self._bt[slot, :] = self._pool.SENTINEL
+            if self._bt_w is not None:
+                self._bt_w[slot, :] = self._pool.win.SENTINEL
             state["slot"] = None
             if self._lane_state:
                 self._pool.release_lane(slot)
@@ -1667,6 +1759,11 @@ class ContinuousBatchingEngine:
             state["stream"].blocks = tuple(state["blocks"])
             self._pool.release(state["blocks"])
             state["blocks"] = []
+        if state.get("wblocks"):
+            state["stream"].window_blocks = (state["wfirst"],
+                                             tuple(state["wblocks"]))
+            self._pool.win.release(state["wblocks"])
+            state["wblocks"] = []
         self._finish_stream(state["stream"], reason)
 
     def _shed_one(self, keep_sid: int) -> bool:
@@ -1717,6 +1814,17 @@ class ContinuousBatchingEngine:
                     return False
                 continue
             state["blocks"].extend(ids)
+        # the window table through the same block; what lies behind the
+        # window went back after the last dispatch (``_release_behind``)
+        while self._window is not None and \
+                state["wfirst"] + len(state["wblocks"]) <= hi:
+            ids = self._pool.win.alloc(
+                hi + 1 - state["wfirst"] - len(state["wblocks"]))
+            if ids is None:
+                if not self._shed_one(state["sid"]):
+                    return False
+                continue
+            state["wblocks"].extend(ids)
         return True
 
     def _decode_step_paged(self) -> None:
@@ -1743,6 +1851,8 @@ class ContinuousBatchingEngine:
                         parked["slot"] = None
                     self._lane[slot] = None
                     self._bt[slot, :] = self._pool.SENTINEL
+                    if self._bt_w is not None:
+                        self._bt_w[slot, :] = self._pool.win.SENTINEL
         else:
             selected = states
         run = []
@@ -1758,6 +1868,10 @@ class ContinuousBatchingEngine:
             slot = st["slot"]
             self._bt[slot, :] = self._pool.SENTINEL
             self._bt[slot, :len(st["blocks"])] = st["blocks"]
+            if self._bt_w is not None:
+                lo = st["wfirst"]
+                self._bt_w[slot, :] = self._pool.win.SENTINEL
+                self._bt_w[slot, lo:lo + len(st["wblocks"])] = st["wblocks"]
             run.append(st)
         if not run:
             return
@@ -1772,12 +1886,20 @@ class ContinuousBatchingEngine:
         self.stats["kv_blocks_live"] += int(
             (steps // self.block_tokens + 1).sum())
         self.stats["kv_blocks_table"] += self.B * self.MB * self.K
+        if self._window is not None:
+            oldest = np.maximum(steps - self._window[1] + 1, 0)
+            self.stats["kv_window_blocks_live"] += int(
+                (steps // self.block_tokens
+                 - oldest // self.block_tokens + 1).sum())
         t0 = self._phase("select")
         toks, lps, arena, keys_d, _last_d, _pos_d, *counted = \
             self._enqueue(
                 self._dispatch,
                 self.params, jnp.asarray(last), self._pool.arena,
-                jnp.asarray(self._bt), jnp.asarray(pos), jnp.asarray(keys))
+                self._tables(jnp.asarray(self._bt),
+                             None if self._bt_w is None
+                             else jnp.asarray(self._bt_w)),
+                jnp.asarray(pos), jnp.asarray(keys))
         self._pool.arena = arena
         toks = np.asarray(toks)
         self._fetched(self._dev_enq)
@@ -1805,6 +1927,9 @@ class ContinuousBatchingEngine:
                 self._post_emit_paged(st, tok)
                 if self._sstate.get(st["sid"]) is not st:
                     break  # EOS/length/shed mid-block: drop the tail
+            if self._window is not None and \
+                    self._sstate.get(st["sid"]) is st:
+                self._release_behind(st)
         self._phase("emit")
         self.stats["dispatches"] += 1
 
@@ -1840,9 +1965,12 @@ class ContinuousBatchingEngine:
                     progressed = True
             admitted = []
             while self._partial is None:
-                if (self._spec is not None or self._lane_state) and \
+                if (self._spec is not None or self._lane_state
+                        or self._window is not None) and \
                         len(self._sstate) >= self.B:
-                    break  # a stream pinned to a lane: B at most
+                    # a stream pinned to a lane, or a window arena sized
+                    # for the lanes' windows: B at most
+                    break
                 if self._held is not None:
                     req, self._held = self._held, None
                 else:

@@ -57,6 +57,23 @@ KV store:
   the reset), updated in place by the decode program under the lane's
   own index, and given back with ``release_lane``. An empty lane's slot is
   masked inside the program: read as zeros, never written.
+- **Window arena** — a family whose attention layers are of two kinds
+  (``models/family.py`` ``kv_window``: beside the layers that see
+  everything, layers that see the last ``window`` positions only) gets a
+  SECOND block arena of the same leaf form for the window layers,
+  ``[window layers, NTOT_w, parts, T, *entry]``, with a free list,
+  refcounts, sentinel and zero block of its own (``pool.win``: ``alloc``,
+  ``release``, ``SENTINEL`` as the pool's). ``self.arena`` is then
+  ``{"kv": blocks, "win": window blocks}``, one pytree donated into the
+  same programs. WHO DECIDES: the family says which layers have a window
+  and how wide; the engine owns a second table a lane, indexed by a
+  position's block like the first, and gives a lane's blocks back to
+  ``pool.win`` once they lie wholly behind the window (an entry of the
+  window table is then the window arena's sentinel again, and no program
+  reads it); ``scatter_prefill`` hands ALL of a prompt's blocks to the
+  full arena and to the window arena the table's blocks that are held
+  (the caller holds the last ``window``-covering ones; the rest of the
+  window table is sentinel and drops).
 - **Accounting** — the arena registers its bytes with the PR-12 HBM
   accountant under the ``kvcache`` category at construction, so cache
   pressure shows up in ``nns_mem_used_bytes{category="kvcache"}`` and
@@ -111,6 +128,16 @@ def _scatter_prefill_impl(arena, cache1, bids, heads_major=False):
         return jax.tree.map(leaf, arena, cache1)
 
 
+def _scatter_two_impl(arena, cache1, bids, bids_w, heads_major=False):
+    """The hand-over of a prefill whose family has window layers: every
+    kind's rows into its own arena under its own table (``bids_w`` names
+    the blocks of the window's last positions; the rest drop)."""
+    return {"kv": _scatter_prefill_impl(arena["kv"], cache1["kv"], bids,
+                                        heads_major),
+            "win": _scatter_prefill_impl(arena["win"], cache1["win"], bids_w,
+                                         heads_major)}
+
+
 def _scatter_with_state_impl(arena, cache1, bids, lane, heads_major=False):
     """The hand-over of a prefill whose family keeps lane state: keys and
     values into blocks as above, and each state leaf ``[layers, 1, ...]``
@@ -134,7 +161,70 @@ def _copy_block_impl(arena, src, dst):
     return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), arena)
 
 
-class BlockPool:
+class _Blocks:
+    """Host-side bookkeeping of ONE arena's blocks: a LIFO free list and
+    refcounts under a lock. ``SENTINEL`` (``num_blocks + 1``, out of
+    bounds) is the arena's table entry for "no block"; index
+    ``num_blocks`` is its zero block."""
+
+    def __init__(self, num_blocks: int, what: str = "BlockPool"):
+        if num_blocks <= 0:
+            raise ValueError(f"{what}: num_blocks must be positive, "
+                             f"got {num_blocks}")
+        self.what = what
+        self.num_blocks = int(num_blocks)
+        self.ntot = self.num_blocks + 1       # + the permanent zero block
+        self.SENTINEL = self.ntot             # out of bounds on purpose
+        self._lock = threading.Lock()
+        self._free: List[int] = list(range(self.num_blocks))
+        self._ref = np.zeros(self.num_blocks, np.int64)
+
+    @property
+    def free_blocks(self) -> int:
+        with self._lock:
+            return len(self._free)
+
+    def alloc(self, k: int) -> Optional[List[int]]:
+        """All-or-nothing: ``k`` fresh blocks (refcount 1 each) or None."""
+        if k <= 0:
+            return []
+        with self._lock:
+            if len(self._free) < k:
+                return None
+            ids = [self._free.pop() for _ in range(k)]
+            for i in ids:
+                self._ref[i] = 1
+            return ids
+
+    def retain(self, ids: Sequence[int]) -> None:
+        with self._lock:
+            for i in ids:
+                if self._ref[i] <= 0:
+                    raise RuntimeError(
+                        f"{self.what}.retain: block {i} is not live")
+                self._ref[i] += 1
+
+    def release(self, ids: Sequence[int]) -> None:
+        with self._lock:
+            for i in ids:
+                if self._ref[i] <= 0:
+                    raise RuntimeError(
+                        f"{self.what}.release: block {i} over-released")
+                self._ref[i] -= 1
+                if self._ref[i] == 0:
+                    self._free.append(i)
+
+    def live_blocks(self) -> int:
+        with self._lock:
+            return int(np.count_nonzero(self._ref))
+
+    def reset(self) -> None:
+        with self._lock:
+            self._free = list(range(self.num_blocks))
+            self._ref[:] = 0
+
+
+class BlockPool(_Blocks):
     """Allocator + device arena for one engine's paged KV cache.
 
     Host-side state (free list, refcounts) is guarded by a lock so the
@@ -145,20 +235,16 @@ class BlockPool:
 
     def __init__(self, cfg, num_blocks: int, block_tokens: int,
                  kv_codec: Optional[str] = None, mesh=None,
-                 owner: str = "kvpool", lanes: int = 0):
+                 owner: str = "kvpool", lanes: int = 0,
+                 window_blocks: Optional[int] = None):
         from nnstreamer_tpu.models.transformer import _kv_codec
 
-        if num_blocks <= 0:
-            raise ValueError(f"BlockPool: num_blocks must be positive, "
-                             f"got {num_blocks}")
+        super().__init__(num_blocks)
         if block_tokens <= 0:
             raise ValueError(f"BlockPool: block_tokens must be positive, "
                              f"got {block_tokens}")
         self.cfg = cfg
-        self.num_blocks = int(num_blocks)
         self.block_tokens = int(block_tokens)
-        self.ntot = self.num_blocks + 1       # + the permanent zero block
-        self.SENTINEL = self.ntot             # out of bounds on purpose
         self.kv_codec = kv_codec
         self.mesh = mesh
         self.owner = owner
@@ -167,13 +253,20 @@ class BlockPool:
         #: the arena states it: ``[.., h, T, dh]`` (True) or ``[.., T,
         #: *entry]``
         self.heads_major = self._codec.heads_major
-        self._lock = threading.Lock()
-        self._free: List[int] = list(range(self.num_blocks))
-        self._ref = np.zeros(self.num_blocks, np.int64)
         #: what each lane holds beside its blocks (None: nothing), and
         #: which lanes' slots are claimed
         self._family = cfg.family
         self._lane_state = self._family.lane_state(cfg)
+        #: the window layers' ``(layers, window)`` and their arena's own
+        #: blocks (None: the family's attention layers are of one kind)
+        self._window = self._family.kv_window(cfg)
+        self.win: Optional[_Blocks] = None
+        if self._window is not None:
+            if self._lane_state or mesh is not None or not window_blocks:
+                raise ValueError(
+                    "BlockPool: a window arena needs window_blocks > 0 and "
+                    "goes with neither lane state nor a mesh")
+            self.win = _Blocks(window_blocks, "BlockPool.win")
         self.lanes = int(lanes) if self._lane_state else 0
         if self._lane_state and self.lanes <= 0:
             raise ValueError("BlockPool: a model with lane state needs "
@@ -185,8 +278,11 @@ class BlockPool:
         self.nbytes = _memory.pytree_nbytes(self.arena)
         self.state_bytes = _memory.pytree_nbytes(self.arena["state"]) \
             if self._lane_state else 0
+        self.window_bytes = _memory.pytree_nbytes(self.arena["win"]) \
+            if self.win else 0
         self._jit_scatter = jax.jit(
             _scatter_with_state_impl if self._lane_state
+            else _scatter_two_impl if self.win
             else _scatter_prefill_impl, donate_argnums=(0,),
             static_argnames=("heads_major",))
         self._jit_copy = jax.jit(_copy_block_impl, donate_argnums=(0,))
@@ -209,6 +305,10 @@ class BlockPool:
                                        *entry, parts=parts)
         if self.mesh is not None:
             arena = self._place(arena)
+        if self.win:
+            return {"kv": arena, "win": self._codec.paged_init(
+                self._window[0], self.win.ntot, self.block_tokens, *entry,
+                parts=parts)}
         if not self._lane_state:
             return arena
         spec = dict(self._lane_state)
@@ -243,45 +343,6 @@ class BlockPool:
 
     # -- host-side bookkeeping ----------------------------------------
 
-    @property
-    def free_blocks(self) -> int:
-        with self._lock:
-            return len(self._free)
-
-    def alloc(self, k: int) -> Optional[List[int]]:
-        """All-or-nothing: ``k`` fresh blocks (refcount 1 each) or None."""
-        if k <= 0:
-            return []
-        with self._lock:
-            if len(self._free) < k:
-                return None
-            ids = [self._free.pop() for _ in range(k)]
-            for i in ids:
-                self._ref[i] = 1
-            return ids
-
-    def retain(self, ids: Sequence[int]) -> None:
-        with self._lock:
-            for i in ids:
-                if self._ref[i] <= 0:
-                    raise RuntimeError(
-                        f"BlockPool.retain: block {i} is not live")
-                self._ref[i] += 1
-
-    def release(self, ids: Sequence[int]) -> None:
-        with self._lock:
-            for i in ids:
-                if self._ref[i] <= 0:
-                    raise RuntimeError(
-                        f"BlockPool.release: block {i} over-released")
-                self._ref[i] -= 1
-                if self._ref[i] == 0:
-                    self._free.append(i)
-
-    def live_blocks(self) -> int:
-        with self._lock:
-            return int(np.count_nonzero(self._ref))
-
     def alloc_lane(self) -> Optional[int]:
         """Claim the lowest free lane's state slot, or None when every
         lane is taken. The slot still holds its last owner's state until
@@ -307,11 +368,13 @@ class BlockPool:
         return {name: np.asarray(a[:, lane])
                 for name, a in self.arena["state"].items()}
 
-    def stream_rows(self, block_ids: Sequence[int], tokens: int):
+    def stream_rows(self, block_ids: Sequence[int], tokens: int,
+                    window: bool = False):
         """Host copies of the first ``tokens`` entries that the blocks
         ``block_ids`` hold, in table order: per arena leaf ``[layers,
         parts, tokens, ...]``, a token's entry as the family states it
         (``[h, dh]``) whichever order the arena keeps a block's rows in.
+        ``window``: the blocks are the window arena's.
         For checks and tests, as ``lane_state``:
         the caller sees to it that no program holds the arena meanwhile,
         and that no other stream was given the blocks since (a released
@@ -319,7 +382,7 @@ class BlockPool:
         import jax
 
         ids = np.asarray(block_ids, np.int32)
-        kv = self.arena["kv"] if self._lane_state else self.arena
+        kv = self.arena["win"] if window else self._kv(self.arena)
 
         def leaf(a):
             rows = np.asarray(a[:, ids])                 # [L,n,parts,T,...]
@@ -333,19 +396,32 @@ class BlockPool:
 
     # -- device-side helpers ------------------------------------------
 
+    def _kv(self, tree):
+        """The full layers' part of an arena or of a prefill's cache."""
+        return tree["kv"] if self._lane_state or self.win else tree
+
     def scatter_prefill(self, cache1, block_ids: Sequence[int],
-                        lane: Optional[int] = None) -> None:
+                        lane: Optional[int] = None,
+                        window_ids: Sequence[int] = (),
+                        window_first: int = 0) -> None:
         """Move a batch-1 prefill cache into ``block_ids`` (padded with
         the sentinel up to S/T) and, for a family with lane state, the
-        prefill's final state over slot ``lane``. Mutates ``self.arena``
-        in place (the old arena buffer is donated)."""
+        prefill's final state over slot ``lane``; for a family with window
+        layers, their rows of the prompt's blocks ``window_first ..
+        window_first + len(window_ids) - 1`` into the window arena's
+        ``window_ids`` (the blocks before them lie behind the window and
+        are handed to nobody). Mutates ``self.arena`` in place (the old
+        arena buffer is donated)."""
         import jax.numpy as jnp
 
-        kv = cache1["kv"] if self._lane_state else cache1
-        mb = _leaf_slots(kv) // self.block_tokens
+        mb = _leaf_slots(self._kv(cache1)) // self.block_tokens
         bids = np.full(mb, self.SENTINEL, np.int32)
         bids[:len(block_ids)] = block_ids
         extra = (jnp.asarray(lane, jnp.int32),) if self._lane_state else ()
+        if self.win:
+            bids_w = np.full(mb, self.win.SENTINEL, np.int32)
+            bids_w[window_first:window_first + len(window_ids)] = window_ids
+            extra = (jnp.asarray(bids_w),)
         self.arena = self._jit_scatter(self.arena, cache1,
                                        jnp.asarray(bids), *extra,
                                        heads_major=self.heads_major)
@@ -361,10 +437,11 @@ class BlockPool:
     def reset(self) -> None:
         """Drop every allocation and rebuild a zeroed arena — the engine
         recovery path. Accounting is unchanged: same bytes."""
+        super().reset()
         with self._lock:
-            self._free = list(range(self.num_blocks))
-            self._ref[:] = 0
             self._lane_live.clear()
+        if self.win:
+            self.win.reset()
         self.arena = self._make_arena()
 
     def snapshot(self) -> dict:
@@ -379,6 +456,9 @@ class BlockPool:
                 "state_slots_live": len(self._lane_live),
                 "state_bytes": self.state_bytes,
                 "heads_major": int(self.heads_major),
+                **({"window_blocks": self.win.num_blocks,
+                    "window_blocks_live": self.win.live_blocks(),
+                    "window_bytes": self.window_bytes} if self.win else {}),
             }
 
 

@@ -175,3 +175,78 @@ class TestEngineAuto:
         with pytest.raises(ValueError, match="attention"):
             ContinuousBatchingEngine(CFG, init_params(CFG),
                                      attention="fast")
+
+
+class TestBand:
+    """``window=``: the band mask ``0 <= i - j < window``, its dead key
+    tiles skipped by the grid."""
+
+    @staticmethod
+    def _qkv(s, hq=4, hk=2, d=16, seed=0, dtype=jnp.float32):
+        keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+        return [jax.random.normal(k, (2, s, h, d), dtype)
+                for k, h in zip(keys, (hq, hk, hk))]
+
+    @staticmethod
+    def _masked(q, k, v, window):
+        """The band written out over the whole score matrix."""
+        group = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+        i = jnp.arange(q.shape[1])[:, None]
+        j = jnp.arange(k.shape[1])[None, :]
+        p = jax.nn.softmax(jnp.where((i >= j) & (i - j < window), s, -1e30),
+                           axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+    @pytest.mark.parametrize("s,window,bq,bk", [
+        (64, 1, 8, 8), (64, 8, 16, 16), (64, 20, 16, 8), (128, 33, 32, 16),
+        (48, 16, 16, 16), (64, 17, 8, 32), (64, 64, 16, 16),
+        (64, 100, 16, 16)],
+        ids=lambda x: str(x))
+    def test_skipped_tiles_give_the_masked_result(self, s, window, bq, bk):
+        q, k, v = self._qkv(s, seed=s + window)
+        want = self._masked(q, k, v, window)
+        ref = flash_attention(q, k, v, force="reference", window=window)
+        got = flash_attention(q, k, v, block_q=bq, block_k=bk,
+                              force="pallas", window=window)
+        np.testing.assert_allclose(np.asarray(ref), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        if window >= s:   # a window wider than the prompt is plain causal
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(flash_attention(
+                    q, k, v, block_q=bq, block_k=bk, force="pallas")),
+                rtol=1e-6, atol=1e-6)
+
+    def test_the_grid_has_the_bands_tiles_only(self):
+        """At 16 tiles of keys and a window of two tiles, a q-tile's k axis
+        has 4 steps (the band can touch 3 tiles; one spare), not 16."""
+        from nnstreamer_tpu.ops.flash_attention import _flash_bhsd
+
+        q, k, v = (x.swapaxes(1, 2) for x in self._qkv(256))
+
+        def grid_of(**kw):
+            return str(jax.make_jaxpr(lambda a, b, c: _flash_bhsd(
+                a, b, c, True, 16, 16, interpret=True, **kw))(q, k, v))
+
+        band = grid_of(window=32)
+        assert "nns_band_flash_prefill" in band and "(2, 4, 16, 4)" in band
+        assert "(2, 4, 16, 16)" in grid_of()
+
+    def test_bfloat16_band_stays_near_the_float32_one(self):
+        q, k, v = self._qkv(128, dtype=jnp.bfloat16, seed=3)
+        want = self._masked(*(x.astype(jnp.float32) for x in (q, k, v)), 40)
+        got = flash_attention(q, k, v, block_q=32, block_k=32,
+                              force="pallas", window=40)
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), rtol=3e-2, atol=3e-2)
+
+    @pytest.mark.parametrize("kw", [dict(window=0), dict(window=-3),
+                                    dict(window=8, causal=False)])
+    def test_a_window_goes_with_causal_and_is_positive(self, kw):
+        q, k, v = self._qkv(32)
+        with pytest.raises(ValueError, match="window"):
+            flash_attention(q, k, v, **kw)

@@ -297,3 +297,87 @@ def test_tp_shards_the_head_axis_wherever_the_order_puts_it(heads, spec):
                                                        ("tp", 2)]))
     assert tuple(pool.arena.sharding.spec) == spec
     assert pool.arena.shape[spec.index("tp")] == heads
+
+
+# -- a second arena for window layers -----------------------------------------
+
+def _window_cfg(kv_heads=2):
+    from nnstreamer_tpu.models.afmoe import FULL, SLIDING, AfmoeConfig
+
+    return AfmoeConfig(
+        vocab=97, d_model=64, layer_types=(SLIDING, FULL, SLIDING, SLIDING),
+        n_heads=8, n_kv_heads=kv_heads, head_dim=16, window=12,
+        num_experts=8, experts_per_token=2, expert_width=16, shared_width=16,
+        experts_held=(0, 8), max_seq=64, dtype=jnp.float32,
+        param_dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("kv_heads,heads_major", [(2, True), (8, False)])
+def test_window_arena_has_its_own_blocks_sentinel_and_zero_block(
+        kv_heads, heads_major):
+    cfg = _window_cfg(kv_heads)
+    budget = memory.activate(1 << 30)
+    pool = kvpool.BlockPool(cfg, 10, T, window_blocks=6)
+    block = (kv_heads, T) if heads_major else (T, kv_heads)
+    assert pool.heads_major == heads_major
+    assert pool.arena["kv"].shape == (1, 11, 2) + block + (16,)
+    assert pool.arena["win"].shape == (3, 7, 2) + block + (16,)
+    assert (pool.SENTINEL, pool.win.SENTINEL) == (11, 7)
+    # two free lists: a block id means something in its own arena only
+    a, w = pool.alloc(10), pool.win.alloc(6)
+    assert sorted(a) == list(range(10)) and sorted(w) == list(range(6))
+    assert pool.alloc(1) is None and pool.win.alloc(1) is None
+    pool.win.release(w[:2])
+    assert (pool.free_blocks, pool.win.free_blocks) == (0, 2)
+    with pytest.raises(RuntimeError, match="BlockPool.win.release"):
+        pool.win.release(w[:1])
+    snap = pool.snapshot()
+    assert (snap["window_blocks"], snap["window_blocks_live"]) == (6, 4)
+    assert (snap["num_blocks"], snap["live_blocks"]) == (10, 10)
+    # bytes and the accountant's kvcache category cover both arenas
+    both = sum(int(x.size) * 4 for x in jax.tree_util.tree_leaves(pool.arena))
+    assert pool.nbytes == snap["nbytes"] == both
+    assert snap["window_bytes"] == pool.window_bytes \
+        == int(pool.arena["win"].size) * 4
+    assert budget.snapshot()["used_by_category"]["kvcache"] == both
+    pool.reset()
+    assert (pool.free_blocks, pool.win.free_blocks) == (10, 6)
+    assert "window_blocks" not in kvpool.BlockPool(CFG, 4, T).snapshot()
+
+
+def test_window_arena_needs_its_size_and_no_mesh():
+    with pytest.raises(ValueError, match="window_blocks"):
+        kvpool.BlockPool(_window_cfg(), 10, T)
+    with pytest.raises(ValueError, match="num_blocks must be positive"):
+        kvpool.BlockPool(_window_cfg(), 10, T, window_blocks=-1)
+
+
+@pytest.mark.parametrize("kv_heads", [2, 8])
+def test_scatter_hands_the_last_blocks_to_the_window_arena_and_all_to_the_full(
+        kv_heads):
+    """A prompt of 29 tokens under a window of 12: the first decode step
+    reads positions 18.. of a window layer, so the window arena is handed
+    the prompt's blocks 2 and 3 (of 8 tokens) and the full arena all four;
+    ``stream_rows`` reads either back in the order of the tokens."""
+    cfg = _window_cfg(kv_heads)
+    pool = kvpool.BlockPool(cfg, 10, T, window_blocks=6)
+    rng = np.random.default_rng(kv_heads)
+    cache1 = {"kv": jnp.asarray(rng.standard_normal(
+                  (1, 2, 1, 32, kv_heads, 16)), jnp.float32),
+              "win": jnp.asarray(rng.standard_normal(
+                  (3, 2, 1, 32, kv_heads, 16)), jnp.float32)}
+    blocks, wblocks = pool.alloc(5), pool.win.alloc(3)  # + the decode block
+    first = (29 - 12 + 1) // T
+    assert first == 2
+    pool.scatter_prefill(cache1, blocks[:4], window_ids=wblocks[:2],
+                         window_first=first)
+    np.testing.assert_array_equal(
+        pool.stream_rows(blocks[:4], 29), np.asarray(cache1["kv"])[:, :, 0, :29])
+    np.testing.assert_array_equal(
+        pool.stream_rows(wblocks[:2], 29 - first * T, window=True),
+        np.asarray(cache1["win"])[:, :, 0, first * T:29])
+    # nothing else of the window arena was written: its other blocks and
+    # both zero blocks are zeros still
+    rest = [b for b in range(7) if b not in wblocks[:2]]
+    assert not np.asarray(pool.arena["win"])[:, rest].any()
+    assert not np.asarray(pool.arena["kv"])[:, 10].any()

@@ -865,3 +865,76 @@ def test_decode_program_text_has_this_codes_names_past_a_stale_cache(
         return re.findall(r"^\s*(?:ROOT )?(%[^\s=]+) = ", t, re.M)
 
     assert instructions(text) == instructions(ran)
+
+
+# -- a family with window layers: its scopes, counters and gauges -------------
+
+def _window_engine(**kw):
+    from nnstreamer_tpu.models.afmoe import FULL, SLIDING, AfmoeConfig
+
+    cfg = AfmoeConfig(
+        vocab=97, d_model=64, layer_types=(SLIDING, SLIDING, FULL),
+        num_dense_layers=1, n_heads=8, n_kv_heads=2, head_dim=16, window=8,
+        dense_width=96, num_experts=8, experts_per_token=2, expert_width=16,
+        shared_width=16, experts_held=(0, 4), max_seq=64, dtype=jnp.float32,
+        param_dtype=jnp.float32)
+    return cfg, ContinuousBatchingEngine(
+        cfg, cfg.family.init_params(cfg, 3), max_streams=2,
+        steps_per_dispatch=2, block_tokens=4, min_bucket=8, **kw)
+
+
+WINDOW_SCOPES = ("qkv", "kv_write", "kv_gather", "attend", "attend_window",
+                 "attn_out", "dense_ffn", "router", "experts", "shared_ffn",
+                 "logits", "sample")
+
+
+def test_window_familys_programs_hold_every_scope_of_both_kinds():
+    cfg, eng = _window_engine()
+    _, _, shapes = engine_mod._DECODE_PROGRAMS[eng.obs_name]
+    assert set(shapes[3]) == {"kv", "win"}  # both tables, by arena
+    compiled = engine_mod.decode_program_text(eng.obs_name)
+    for scope in WINDOW_SCOPES:
+        assert f"/{scope}/" in compiled, scope
+    text = _lowered_text(jax.jit(cfg.family.build_prefill(cfg)), eng.params,
+                         jax.ShapeDtypeStruct((1, 16), jnp.int32))
+    assert "module @jit_prefill" in text and "nns.prefill" in text
+    for scope in set(WINDOW_SCOPES) - {"kv_write", "kv_gather", "sample"}:
+        assert _has_scope(text, scope), scope
+
+
+def test_window_counters_count_what_the_positions_say_and_a_dense_engine_has_none():
+    _, eng = _window_engine()
+    assert eng.stats["kv_window_blocks_live"] == 0 \
+        and eng.stats["kv_window_blocks_released"] == 0
+    assert eng.stats["decode_attention"] == "gather"
+    assert type(eng.stats["kv_window_bytes_per_token"]) is int
+    eng.start()
+    try:
+        out = _serve(eng, [_prompt(5)], max_new=13)
+    finally:
+        eng.stop()
+    assert len(out[0].tokens) == 13
+    # 6 dispatches of 2 steps from position 5: a window layer reads the
+    # blocks (of 4) from the one that holds pos - 7 to the one that holds pos
+    # (the other lane is empty: it reads its zero block, one a step, in
+    # both counts, as ``kv_blocks_live`` always counted an empty lane)
+    want_w = sum(p // 4 - max(0, p - 7) // 4 + 1 for p in range(5, 17)) + 12
+    want = sum(p // 4 + 1 for p in range(5, 17)) + 12
+    assert eng.stats["kv_window_blocks_live"] == want_w < want \
+        == eng.stats["kv_blocks_live"]
+    # after the last whole dispatch the lane stood at 17: blocks 0 and 1
+    # (positions 0..7) lay wholly before 17 - 7 and went back, block 2 not
+    assert eng.stats["kv_window_blocks_released"] == 2
+    reg = get_registry()
+    reg.snapshot()  # runs the collectors
+    assert reg.get("nns_serving_kv_window_blocks",
+                   engine=eng.obs_name).value == 2 * 4
+    assert reg.get("nns_serving_kv_window_blocks_live",
+                   engine=eng.obs_name).value == 0
+    dense = _engine()
+    assert not [k for k in dense.stats if "window" in k]
+    assert "decode_attention" not in dense.stats
+    assert all(type(v) is int for v in dense.stats.values())
+    reg.snapshot()
+    assert reg.get("nns_serving_kv_window_blocks",
+                   engine=dense.obs_name) is None

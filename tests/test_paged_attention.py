@@ -665,3 +665,166 @@ def test_mosaic_compiles_the_lane_state_kernel_at_the_cells_shapes(
                            r"dynamic-slice|fusion)\(", line)]
     assert not moved
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
+
+
+# -- a window: the lower bound beside pos -------------------------------------
+
+def _window_form(q, pages, layer, bt, pos, scale, window, heads_major=False):
+    """Every slot of the whole table under the band mask ``pos - window <
+    slot <= pos``: what a window means, with nothing skipped."""
+    g = _paged_gather(pages, layer, bt, heads_major)
+    slots = jnp.arange(g.shape[2])[None, None, None, :]
+    at = pos[:, None, None, None]
+    return _attend_cache(q, g[:, 0], g[:, 1], (slots <= at)
+                         & (slots > at - window), q.shape[-1], q.dtype,
+                         scale=scale)
+
+
+def _behind_the_window_given_back(bt, pos, window, t):
+    """The table as the engine leaves it: the entries of the blocks that
+    lie wholly before ``pos - window + 1`` are the sentinel again."""
+    bt = np.array(bt)
+    first = np.maximum(np.asarray(pos) - window + 1, 0) // t
+    for lane in range(bt.shape[0]):
+        bt[lane, :first[lane]] = bt.max()     # the sentinel
+    return jnp.asarray(bt)
+
+
+@pytest.mark.parametrize("chunk", [2, 8], ids=["chunk2", "chunk8"])
+@pytest.mark.parametrize("window", [1, 5, T, 2 * T + 3, 4 * T, 1000],
+                         ids=lambda w: f"w{w}")
+@pytest.mark.parametrize("hq,hk,dtype,tol,heads_major", [
+    (16, 8, jnp.float32, 1e-5, False), (48, 8, jnp.bfloat16, 2e-2, False),
+    (16, 2, jnp.float32, 1e-5, True)],
+    ids=["16over8_f32", "48over8_bf16", "16over2_heads_major"])
+def test_window_kernel_equals_the_band_mask_over_the_whole_table(
+        hq, hk, dtype, tol, heads_major, window, chunk):
+    """Ragged positions (the edges of a block, mid-table, an empty lane), a
+    window that starts mid-block, at a block's edge, and one wider than any
+    context; the blocks behind the window GIVEN BACK (their entries the
+    sentinel): the kernel and the gather form read none of them."""
+    pages, bt, pos, rng = _pool(hk, dtype, seed=hq + window,
+                                heads_major=heads_major)
+    q = jnp.asarray(rng.standard_normal((len(HELD), 1, hq, DH)), dtype)
+    given_back = _behind_the_window_given_back(bt, pos, window, T)
+    for layer in range(LAYERS):
+        want = _window_form(q, pages, layer, bt, pos, 0.3, window,
+                            heads_major)
+        for form in ("pallas", "reference"):
+            got = paged_attention(q, pages, layer, given_back, pos,
+                                  scale=0.3, force=form, chunk_blocks=chunk,
+                                  heads_major=heads_major, window=window)
+            assert got.shape == want.shape and got.dtype == want.dtype
+            np.testing.assert_allclose(np.asarray(got, np.float32),
+                                       np.asarray(want, np.float32),
+                                       rtol=tol, atol=tol)
+            assert not np.asarray(got, np.float32)[-1].any()  # empty lane
+    if window >= MB * T:   # wider than any context: the plain kernel's
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(paged_attention(
+                q, pages, LAYERS - 1, bt, pos, scale=0.3, force="reference",
+                heads_major=heads_major), np.float32), rtol=tol, atol=tol)
+
+
+def test_a_window_is_refused_where_it_is_not_built():
+    pages, bt, pos, rng = _pool(8, jnp.float32, seed=1)
+    q = jnp.asarray(rng.standard_normal((len(HELD), 1, 16, DH)), jnp.float32)
+    with pytest.raises(ValueError, match="positive"):
+        paged_attention(q, pages, 0, bt, pos, window=0)
+    latent, lbt, lpos, _ = _latent_pool(jnp.float32, seed=2)
+    lq = jnp.zeros((len(HELD), 1, 8, latent.shape[-1]), jnp.float32)
+    with pytest.raises(ValueError, match="window over a latent arena"):
+        paged_attention(lq, latent, 0, lbt, lpos, v_width=128, window=4,
+                        force="pallas")
+    assert paged_attention_form(q, pages, bt, window=4) == "gather"  # CPU
+
+
+@pytest.mark.parametrize("name,layers,blocks,window", [
+    ("nns_window_paged_decode", 4, 258, 4096),
+    ("nns_paged_decode", 1, 896, None)], ids=["window", "full"])
+def test_mosaic_compiles_both_trinity_kernels_with_the_112_kb_table(
+        one_chip, name, layers, blocks, window):
+    """``trinity_longctx_closed``: 32 lanes, 48 query over 8 key-value
+    heads of 128, token-major blocks of 16; both kernels take a table of
+    32 x 896 int32 (112 KB of scalar prefetch: the window table keeps the
+    full table's indexing); the window arena holds 258 blocks a lane, the
+    full arena 896. Each arena goes in as it lies."""
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    ntot = 32 * blocks + 1
+    text = _compile_for_the_chip(
+        jax.jit(functools.partial(_paged_decode, scale=0.088, chunk=8,
+                                  interpret=False, window=window)),
+        shape((32, 48, 128), jnp.bfloat16),
+        shape((layers, ntot, 2, 16, 8, 128), jnp.bfloat16),
+        shape((), jnp.int32), shape((32, 896), jnp.int32),
+        shape((32,), jnp.int32))
+    assert "tpu_custom_call" in text and name in text
+    assert "s32[32,896]" in text
+    assert not _moves_of(text, layers * ntot * 2 * 16 * 8 * 128)
+
+
+def test_mosaic_compiles_the_trinity_decode_program(one_chip):
+    """The whole K-step decode program of the cell's configuration for the
+    chip, both tables and both arenas its arguments: 4 window layers and 1
+    full layer go through their kernels, and nothing arena-sized is copied
+    or transposed on the way (the arenas are donated carries)."""
+    from benchmark import run as bench_run
+    from benchmark.drivers import lm_afmoe
+
+    def shape(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    cfg = lm_afmoe.afmoe_config(
+        bench_run.load_cell("trinity_longctx_closed")["config"])
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: cfg.family.init_params(cfg, 0)))
+
+    def attend(q, pages, layer, bt, pos_c, scale=None, heads_major=False,
+               window=None):   # the chip's choice, made here: no TPU
+        return _paged_decode(q[:, 0], pages, layer, bt, pos_c,
+                             scale=float(scale), chunk=8, interpret=False,
+                             heads_major=heads_major, window=window)[:, None]
+
+    step = cfg.family.build_paged_decode_step(cfg, 16, 14336,
+                                              paged_attention_fn=attend)
+
+    def dispatch(params, token, arenas, bt, pos):
+        def body(carry, _):
+            token, arenas, pos = carry
+            logits, arenas, _ = step(params, token, arenas, bt, pos)
+            token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (token, arenas, pos + 1), token
+        return jax.lax.scan(body, (token, arenas, pos), None, length=8)
+
+    arenas = {"kv": shape((1, 32 * 896 + 1, 2, 16, 8, 128), jnp.bfloat16),
+              "win": shape((4, 32 * 258 + 1, 2, 16, 8, 128), jnp.bfloat16)}
+    compiled = _compiled_for_the_chip(
+        jax.jit(dispatch, donate_argnums=(2,)), params, shape((32,)), arenas,
+        {"kv": shape((32, 896)), "win": shape((32, 896))}, shape((32,)))
+    text = compiled.as_text()
+    assert text.count("nns_window_paged_decode") >= 4
+    assert "nns_paged_decode" in text.replace("nns_window_paged_decode", "")
+    assert not _moves_of(text, 32 * 258 * 2 * 16 * 8 * 128)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+@pytest.mark.parametrize("s", [6144, 12288])
+def test_mosaic_compiles_the_band_flash_prefill_at_the_cells_buckets(
+        one_chip, s):
+    """48 query heads over 8 key-value heads of 128, window 4096, at the
+    two prefill buckets the cell's traffic runs: the k axis of the grid is
+    the band's 18 tiles, not the bucket's 24 or 48."""
+    from nnstreamer_tpu.ops.flash_attention import _flash_bhsd
+
+    def shape(heads):
+        return jax.ShapeDtypeStruct((1, heads, s, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = _compile_for_the_chip(
+        _flash_bhsd, shape(48), shape(8), shape(8), causal=True, block_q=256,
+        block_k=256, interpret=False, scale=0.088, window=4096)
+    assert "tpu_custom_call" in text and "nns_band_flash_prefill" in text
+    assert f"bf16[1,48,{s},128]" in text
