@@ -4,22 +4,39 @@ Grid is (batch, heads, q_blocks, k_blocks); the TPU executes the trailing
 grid axis sequentially on one core, so the running max/sum/accumulator
 live in VMEM scratch across k-steps while K/V stream through VMEM one
 ``block_k`` tile at a time — the [seq, seq] score matrix never exists and
-VMEM holds O(block) state regardless of context length. Causally-dead
-k-tiles are skipped with predicated execution. bfloat16 in/out, fp32
-accumulation — the MXU-friendly shape of the computation.
+VMEM holds O(block) state regardless of context length. bfloat16 in/out,
+float32 softmax and accumulation.
+
+The tiles come from the shapes (:func:`tile_plan`): what a k-step costs
+beside its two products — the accumulator read, rescaled and written, the
+running max and sum stored, two cross-lane reductions a row group, the
+grid step itself — does not shrink with the tile, so a step should hold as
+many scores as VMEM likes: up to 1024 x 1024, the whole prompt under that.
+A live tile takes one of two steps: one that no edge of the mask crosses
+(wholly under the diagonal and, with a window, wholly inside the band) is
+multiplied, exponentiated and summed with no iota, compare or select; the
+diagonal's tiles and those the band's lower edge crosses are masked first.
+Queries and keys go to the MXU as they are stored (a product of two
+bfloat16 values is exact in float32); the scale is applied to the float32
+scores, once; the probabilities are float32 in the kernel's text (what
+the MXU makes of a float32 operand at default precision is its own:
+on a v5e it rounds it to bfloat16, one pass — PERF.md, PR 38).
+
+The causally dead k-tiles of a q-tile are skipped with predicated
+execution and not fetched: the steps past a q-tile's last live tile name
+that tile again, and equal consecutive block indices move nothing.
 
 ``window``: query ``i`` sees key ``j`` iff ``0 <= i - j < window`` (a band
 under the diagonal). The k axis of the grid then has only as many steps as
 a q-tile's band can touch, and step ``ik`` of q-tile ``iq`` maps to the
 band's ``ik``-th k-tile: the tiles before the band are neither fetched nor
-multiplied (the few steps past a q-tile's last live tile name that tile
-again, so nothing is fetched for them either). The kernel is then called
-``nns_band_flash_prefill``.
+multiplied. The kernel is then called ``nns_band_flash_prefill``.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -70,11 +87,120 @@ def _check_window(window, causal) -> None:
                          f"and goes with causal=True")
 
 
-def _band_tiles(iq, block_q: int, block_k: int, window: int):
-    """The first and the last k-tile that q-tile ``iq``'s band touches."""
-    lo = jnp.maximum(iq * block_q - (window - 1), 0) // block_k
-    hi = ((iq + 1) * block_q - 1) // block_k
-    return lo, hi
+def _live_tiles(iq, block_q: int, block_k: int, window: int | None,
+                maximum=jnp.maximum):
+    """The first and the last k-tile that q-tile ``iq`` sees a key of: from
+    the first tile, or the one its band starts in, to the diagonal's
+    (``maximum``: ``max`` where ``iq`` is a plain integer)."""
+    last = ((iq + 1) * block_q - 1) // block_k
+    if window is None:
+        return 0, last
+    return maximum(iq * block_q - (window - 1), 0) // block_k, last
+
+
+def _edge_crosses(q0, k0, block_q: int, block_k: int, window: int | None):
+    """Whether the tile of queries ``q0 …`` and keys ``k0 …`` holds a pair
+    the mask hides: a key after its query (the diagonal crosses it) or,
+    under a window, one that far behind it (the band's lower edge does).
+    A live tile that no edge crosses is seen whole and takes the step
+    without a mask. Integers and traced values alike."""
+    crosses = k0 + block_k - 1 > q0
+    if window is not None:
+        crosses |= q0 + block_q - 1 - k0 >= window
+    return crosses
+
+
+#: the most rows and the most keys of a tile the plan asks for. Measured on
+#: a v5e (PERF.md, PR 38: every cell's shapes, tiles of 256 to 2048): a
+#: 1024 x 1024 step costs 4.1 us where its scores in 256 x 256 steps cost
+#: 16 x 1.04 us; wider keys (1536, 2048) win nothing more and cost a band
+#: its edges.
+_TILE = 1024
+#: what the plan lets :func:`_vmem_bytes` reach. A Mosaic kernel may use
+#: 16 MiB of VMEM on a v5e unless it asks for more, and this one does not
+#: ask: past 1024 x 1024 there is nothing to win. The estimate is within
+#: an eighth of what the compiler said it needed where it refused (16.48 MB
+#: at 1024 x 1024 over float32 heads of 256, estimate 16.0), so the plan
+#: keeps an eighth under the limit.
+_VMEM_BUDGET = 14 << 20
+
+
+def _vmem_bytes(block_q: int, block_k: int, d: int, dv: int,
+                size: int) -> int:
+    """What a step holds in VMEM, roughly: the four blocks double-buffered,
+    the three scratch arrays, the float32 score tile, and the second
+    product's result beside the rescaled accumulator."""
+    blocks = 2 * size * (block_q + block_k) * (d + dv)
+    scratch = 4 * block_q * (2 * 128 + dv)
+    return blocks + scratch + 4 * block_q * block_k + 8 * block_q * dv
+
+
+class TilePlan(NamedTuple):
+    """How :func:`flash_attention` tiles one shape. ``masked + unmasked``
+    are the live steps of one head's grid, ``q_tiles * k_steps`` all of
+    them: the rest do nothing and fetch nothing."""
+    block_q: int
+    block_k: int
+    q_tiles: int
+    k_steps: int      # the k axis of the grid: steps a q-tile takes
+    masked: int       # live steps an edge of the mask crosses
+    unmasked: int     # live steps that need no mask
+
+    @property
+    def dead(self) -> int:
+        return self.q_tiles * self.k_steps - self.masked - self.unmasked
+
+
+def _fit(s: int, want: int) -> int:
+    """The largest block of whole 128-row groups, at most ``want`` rows,
+    that tiles ``s``; all of ``s`` when it is no longer than ``want``.
+    Where none does the answer is ``want``, which :func:`_pallas_reject`
+    turns down: such a sequence runs the reference, as it always did."""
+    if s <= want:
+        return s
+    return next((block for block in range(want, 0, -128) if s % block == 0),
+                want)
+
+
+def _k_steps(sk: int, block_q: int, block_k: int, window: int | None) -> int:
+    """The k axis of the grid: every k-tile, or under a window the most a
+    q-tile's band can touch."""
+    nk = sk // block_k
+    if window is not None:
+        nk = min(nk, (window + block_q - 2) // block_k + 2)
+    return nk
+
+
+@functools.lru_cache(maxsize=256)
+def tile_plan(sq: int, sk: int, d: int, dv: int, window: int | None = None,
+              dtype=jnp.bfloat16, block_q: int | None = None,
+              block_k: int | None = None, causal: bool = True) -> TilePlan:
+    """The tiles for these shapes and what the grid does with them, from
+    the static sizes alone. ``block_q`` / ``block_k``: the caller's own
+    tiles, counted the same way. The rule (one for the band and the full
+    triangle and every head width: the sweep found no shape that wants
+    another): both blocks the largest divisor of the sequence up to
+    ``_TILE``, the rows halved while :func:`_vmem_bytes` is over
+    ``_VMEM_BUDGET`` (float32 heads of 256: 512 rows)."""
+    want_q = _TILE
+    while want_q > 128 and _vmem_bytes(
+            want_q, _TILE, d, dv, jnp.dtype(dtype).itemsize) > _VMEM_BUDGET:
+        want_q //= 2
+    block_q = _fit(sq, want_q) if block_q is None else min(block_q, sq)
+    block_k = _fit(sk, _TILE) if block_k is None else min(block_k, sk)
+    q_tiles, k_steps = sq // block_q, _k_steps(sk, block_q, block_k, window)
+    if not causal:
+        return TilePlan(block_q, block_k, q_tiles, k_steps, 0,
+                        q_tiles * k_steps)
+    masked = unmasked = 0
+    for iq in range(q_tiles):
+        first, last = _live_tiles(iq, block_q, block_k, window, max)
+        for kt in range(first, min(last, first + k_steps - 1) + 1):
+            crosses = _edge_crosses(iq * block_q, kt * block_k, block_q,
+                                    block_k, window)
+            masked += crosses
+            unmasked += not crosses
+    return TilePlan(block_q, block_k, q_tiles, k_steps, masked, unmasked)
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -83,9 +209,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
-    if window is not None:   # step ki is the band's ki-th k-tile
-        first, last = _band_tiles(qi, block_q, block_k, window)
-        kt = first + ki
+    # step ki reads k-tile kt; past a q-tile's last live tile the index
+    # map names that tile again and the step does nothing
+    first, last = _live_tiles(qi, block_q, block_k, window)
+    kt = first + ki
+    live = kt <= last if causal else True
+    crosses = _edge_crosses(qi * block_q, kt * block_k, block_q, block_k,
+                            window) if causal else False
 
     @pl.when(ki == 0)
     def _init():
@@ -93,26 +223,20 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # a k-tile is causally dead when its first key comes after the last
-    # query of this q-tile
-    live = True if not causal else ki * block_k <= (qi + 1) * block_q - 1
-    if window is not None:
-        live = kt <= last
-
-    @pl.when(live)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32) * scale       # [bq, d]
-        k = k_ref[0, 0].astype(jnp.float32)               # [bk, d]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        if causal:
-            q_pos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = (ki if window is None else kt) * block_k \
-                + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            seen = q_pos >= k_pos if window is None \
-                else (q_pos >= k_pos) & (q_pos - k_pos < window)
+    def _step(masked: bool):
+        # queries and keys go to the MXU as stored: a product of two
+        # bfloat16 values is exact in float32, so casting them first buys
+        # passes and no digits; the scale is applied to the float32 scores
+        s = lax.dot_general(q_ref[0, 0], k_ref[0, 0],
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        if masked:
+            # i - j of the tile's corner; the rest is a constant of the shape
+            behind = (qi * block_q - kt * block_k) + (
+                lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+                - lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1))
+            seen = behind >= 0 if window is None \
+                else (behind >= 0) & (behind < window)
             s = jnp.where(seen, s, _NEG_BIG)
         m_prev = m_scr[:, :1]                              # [bq, 1]
         l_prev = l_scr[:, :1]
@@ -121,16 +245,35 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        # the probabilities stay float32 into the second product
         acc_scr[:] = acc_scr[:] * corr + lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    if causal:
+        pl.when(live & crosses)(functools.partial(_step, True))
+        pl.when(live & ~crosses)(functools.partial(_step, False))
+    else:
+        _step(False)
 
     @pl.when(ki == nk - 1)
     def _finish():
         l = jnp.maximum(l_scr[:, :1], 1e-30)
         o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
+
+
+def _k_tile(iq, ik, *, causal: bool, block_q: int, block_k: int,
+            window: int | None):
+    """The k-tile that step ``ik`` of q-tile ``iq`` reads. The steps past
+    the q-tile's last live tile name that tile again: equal consecutive
+    block indices are not fetched again, so the dead part of the grid
+    moves nothing."""
+    if not causal:
+        return ik
+    first, last = _live_tiles(iq, block_q, block_k, window)
+    return jnp.minimum(first + ik, last)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
@@ -146,17 +289,9 @@ def _flash_bhsd(q, k, v, causal: bool, block_q: int, block_k: int,
     group = h // k.shape[1]
     scale = d ** -0.5 if scale is None else scale
     kv_head = (lambda ih: ih) if group == 1 else (lambda ih: ih // group)
-    nk = sk // block_k
-    if window is not None:  # the k-tiles a q-tile's band can touch
-        nk = min(nk, (window + block_q - 2) // block_k + 2)
-
-    def k_tile(iq, ik):
-        """The k-tile that step ``ik`` of q-tile ``iq`` reads."""
-        if window is None:
-            return ik
-        first, last = _band_tiles(iq, block_q, block_k, window)
-        return jnp.minimum(first + ik, last)
-
+    nk = _k_steps(sk, block_q, block_k, window)
+    k_tile = functools.partial(_k_tile, causal=causal, block_q=block_q,
+                               block_k=block_k, window=window)
     grid = (b, h, sq // block_q, nk)
     kern = functools.partial(_kernel, causal=causal, scale=scale,
                              block_q=block_q, block_k=block_k, window=window)
@@ -202,7 +337,13 @@ def _pallas_reject(q, k, block_q: int, block_k: int, v=None) -> str | None:
     8 to 256 (the head dim is the blocks' lane dimension, legal at any
     size because it spans the whole array dimension). Values of another
     width than the keys (128 beside 192: PERF.md, PR 33) are held to the
-    same bounds."""
+    same bounds. Since PR 38 (the same libtpu, compiled and run on the
+    chip, bf16): q blocks of 64 to 1536 rows against k blocks of 64 to
+    2048 at heads of 128, 64 to 1536 both at 192/128, 64 to 1024 both at
+    heads of 64 and 256; what is refused there is VMEM, not a shape
+    (2048 x 1024 at heads of 128; for a described v5e also 1024 x 1024
+    over float32 heads of 256: 16.48 MB asked of 16), which is
+    :func:`tile_plan`'s to stay under, not this function's."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     dv = d if v is None else v.shape[-1]
@@ -228,13 +369,27 @@ def _log_reference_choice(q_shape, k_shape, dtype, why: str) -> None:
                 "Pallas kernel: %s", q_shape, k_shape, dtype, why)
 
 
-def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
-                    block_k: int = 256, force: str | None = None,
+@functools.lru_cache(maxsize=256)
+def _log_tile_plan(q_shape, k_shape, dv: int, dtype, window,
+                   plan: TilePlan) -> None:
+    """Say once a shape how the kernel tiles it and how many of a head's
+    steps are live, masked and dead: static, so it costs a run nothing."""
+    log.info("flash_attention%s/%s values %d %s window %s: tiles %d x %d, "
+             "%d q-tiles of %d k-steps a head: %d unmasked, %d masked, "
+             "%d dead", q_shape, k_shape, dv, dtype, window, plan.block_q,
+             plan.block_k, plan.q_tiles, plan.k_steps, plan.unmasked,
+             plan.masked, plan.dead)
+
+
+def flash_attention(q, k, v, causal: bool = True, block_q: int | None = None,
+                    block_k: int | None = None, force: str | None = None,
                     scale: float | None = None, window: int | None = None):
     """Attention on [batch, seq, heads, dim] tensors; ``scale`` and fewer
     key-value heads as in :func:`attention_reference`; the values may
     have another width than queries and keys, which the output takes.
     ``window``: the band ``0 <= i - j < window``, its dead k-tiles skipped.
+    ``block_q`` / ``block_k``: the tiles; left out, :func:`tile_plan`
+    makes them from the shapes.
 
     ``force``: None (auto: the Pallas kernel on a TPU for tileable
     shapes, else the XLA reference), "pallas" (always the kernel — Mosaic
@@ -246,24 +401,25 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
                             scale=scale, window=window)
     if force == "reference":
         return ref()
-    block_q = min(block_q, q.shape[1])
-    block_k = min(block_k, k.shape[1])
     on_tpu = jax.default_backend() == "tpu"
-    why_not = _pallas_reject(q, k, block_q, block_k, v)
-    if force == "pallas":
-        if why_not:
-            raise ValueError(
-                f"flash_attention: shapes {q.shape}/{k.shape} not tileable "
-                f"by ({block_q},{block_k}): {why_not}")
-    elif not on_tpu:
+    if force != "pallas" and not on_tpu:
         return ref()
-    elif why_not:
+    plan = tile_plan(q.shape[1], k.shape[1], q.shape[-1], v.shape[-1],
+                     window, jnp.dtype(q.dtype), block_q, block_k, causal)
+    why_not = _pallas_reject(q, k, plan.block_q, plan.block_k, v)
+    if why_not and force == "pallas":
+        raise ValueError(
+            f"flash_attention: shapes {q.shape}/{k.shape} not tileable "
+            f"by ({plan.block_q},{plan.block_k}): {why_not}")
+    if why_not:
         _log_reference_choice(tuple(q.shape), tuple(k.shape), str(q.dtype),
                               why_not)
         return ref()
+    _log_tile_plan(tuple(q.shape), tuple(k.shape), v.shape[-1], str(q.dtype),
+                   window, plan)
     qt = q.swapaxes(1, 2)  # [b, h, s, d]
     kt = k.swapaxes(1, 2)
     vt = v.swapaxes(1, 2)
-    out = _flash_bhsd(qt, kt, vt, causal, block_q, block_k,
+    out = _flash_bhsd(qt, kt, vt, causal, plan.block_q, plan.block_k,
                       interpret=not on_tpu, scale=scale, window=window)
     return out.swapaxes(1, 2)

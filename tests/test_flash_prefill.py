@@ -8,6 +8,10 @@ against the materialized math, including a ≥2k-token prompt, so the
 TPU fast path computes the same function the fallback does.
 """
 
+import functools
+import importlib
+import logging
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,6 +26,10 @@ from nnstreamer_tpu.models.transformer import (
 from nnstreamer_tpu.ops import flash_attention
 
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
+
+# the module: ``nnstreamer_tpu.ops.flash_attention`` the attribute is the
+# function of that name
+fa = importlib.import_module("nnstreamer_tpu.ops.flash_attention")
 
 
 def _flash_forced(q, k, v):
@@ -250,3 +258,157 @@ class TestBand:
         q, k, v = self._qkv(32)
         with pytest.raises(ValueError, match="window"):
             flash_attention(q, k, v, **kw)
+
+
+class TestTilePlan:
+    """The tiles come from the shapes (``tile_plan``), a live tile that no
+    edge of the mask crosses takes the step without a mask, and the dead
+    steps of the plain causal grid fetch nothing (PR 38)."""
+
+    _qkv = staticmethod(TestBand._qkv)
+    _masked = staticmethod(TestBand._masked)
+
+    @pytest.mark.parametrize("s,window,bq,bk", [
+        (128, 24, 16, 64),      # a key tile wider than the window
+        (128, 40, 32, 64),      # the window's edge inside a tile
+        (128, 64, 32, 64),      # ... on a tile boundary
+        (128, 33, 64, 16),      # q tiles wider than k tiles
+        (96, 33, 8, 48),
+        (128, 200, 16, 64),     # window >= s
+        (128, 128, 32, 128),    # one key tile, the window the prompt
+        (192, 64, 64, 192)],
+        ids=lambda x: str(x))
+    def test_wide_key_tiles_give_the_masked_result(self, s, window, bq, bk):
+        q, k, v = self._qkv(s, seed=s + window + bk)
+        got = flash_attention(q, k, v, block_q=bq, block_k=bk,
+                              force="pallas", window=window)
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(self._masked(q, k, v, window)),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("s,window", [
+        (3072, None), (3072, 2100), (3072, 2048), (1280, None), (1280, 1280),
+        (2048, 4096)],
+        ids=lambda x: str(x))
+    def test_the_plans_tiles_give_the_masked_result(self, s, window):
+        """No blocks given: 1024 x 1024 tiles (640 x 640 at 1280), masked
+        and unmasked steps both taken."""
+        q, k, v = (x[:1] for x in self._qkv(s, hq=2, hk=1, seed=s))
+        plan = fa.tile_plan(s, s, 16, 16, window, q.dtype)
+        assert plan.block_q == plan.block_k == (640 if s == 1280 else 1024)
+        assert plan.masked >= 2 and plan.unmasked >= 1
+        got = flash_attention(q, k, v, force="pallas", window=window)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(self._masked(q, k, v, window or s)),
+            rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("hq,hk,d,dv,s", [
+        (4, 2, 192, 128, 1280), (4, 2, 64, 64, 2048), (4, 1, 256, 256, 1280),
+        (2, 2, 192, 128, 96), (4, 2, 64, 64, 64), (4, 1, 256, 256, 128)],
+        ids=lambda x: str(x))
+    def test_grouped_heads_and_other_widths_under_the_plan(self, hq, hk, d,
+                                                           dv, s):
+        keys = jax.random.split(jax.random.PRNGKey(d + s), 3)
+        q, k, v = (jax.random.normal(key, (1, s, h, w), jnp.float32)
+                   for key, h, w in zip(keys, (hq, hk, hk), (d, d, dv)))
+        got = flash_attention(q, k, v, force="pallas")
+        assert got.shape == (1, s, hq, dv)
+        np.testing.assert_allclose(
+            np.asarray(got),
+            np.asarray(flash_attention(q, k, v, force="reference")),
+            rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("window", [None, 40, 64])
+    def test_both_steps_equal_the_all_masked_kernel(self, monkeypatch,
+                                                    window):
+        """With every live tile sent through the masked step the kernel
+        gives the same numbers: the unmasked step leaves out only what
+        changes nothing."""
+        plan = fa.tile_plan(128, 128, 16, 16, window, jnp.float32, 16, 16)
+        assert plan.masked >= 4 and plan.unmasked >= 4
+        q, k, v = (x.swapaxes(1, 2) for x in self._qkv(128, seed=9))
+        run = functools.partial(
+            fa._flash_bhsd.__wrapped__, q, k, v, True, 16, 16,
+            interpret=True, window=window)
+        both = run()
+        monkeypatch.setattr(fa, "_edge_crosses",
+                            lambda *a: jnp.bool_(True))
+        np.testing.assert_array_equal(np.asarray(run()), np.asarray(both))
+
+    @pytest.mark.parametrize("bq,bk,nq,nk", [
+        (16, 16, 8, 8), (16, 64, 8, 2), (64, 16, 2, 8), (32, 128, 4, 1)])
+    def test_dead_steps_of_the_causal_grid_name_the_last_live_tile(
+            self, bq, bk, nq, nk):
+        """The index map of keys and values: a live step names its own
+        tile, every step past the diagonal's the diagonal's again, so
+        nothing is fetched for it."""
+        q, k, v = (x.swapaxes(1, 2) for x in self._qkv(128))
+        program = jax.make_jaxpr(lambda a, b, c: fa._flash_bhsd(
+            a, b, c, True, bq, bk, interpret=True))(q, k, v)
+        call, = program.eqns[0].params["jaxpr"].eqns
+        mapping = call.params["grid_mapping"]
+        assert mapping.grid == (2, 4, nq, nk)
+        for keys_or_values in mapping.block_mappings[1:3]:
+            index_map = keys_or_values.index_map_jaxpr
+            for iq in range(nq):
+                last = ((iq + 1) * bq - 1) // bk
+                named = [int(jax.core.eval_jaxpr(
+                    index_map.jaxpr, index_map.consts, 1, 3, iq, ik)[2])
+                    for ik in range(nk)]
+                assert named == [min(ik, last) for ik in range(nk)]
+
+    @pytest.mark.parametrize("s,window,bq,bk", [
+        (12288, 4096, None, None), (12288, None, None, None),
+        (6144, 4096, None, None), (12288, 4096, 256, 256),
+        (12288, None, 512, 2048), (3072, None, None, None),
+        (1536, None, None, None), (1536, 1000, 256, 768)],
+        ids=lambda x: str(x))
+    def test_the_plan_counts_what_the_mask_says(self, s, window, bq, bk):
+        """Live, masked and unmasked steps against a count made over the
+        mask itself, tile by tile."""
+        plan = fa.tile_plan(s, s, 128, 128, window, jnp.bfloat16, bq, bk)
+        if bq is None:
+            want = 768 if s == 1536 else 1024
+            assert (plan.block_q, plan.block_k) == (want, want)
+        i = np.arange(s)[:, None]
+        j = np.arange(s)[None, :]
+        seen = (i >= j) if window is None else (i >= j) & (i - j < window)
+        tiles = seen.reshape(s // plan.block_q, plan.block_q,
+                             s // plan.block_k, plan.block_k)
+        live = tiles.any(axis=(1, 3))
+        whole = tiles.all(axis=(1, 3))
+        assert plan.unmasked == int(whole.sum())
+        assert plan.masked == int((live & ~whole).sum())
+        assert plan.k_steps >= int(live.sum(axis=1).max())
+        assert plan.dead == plan.q_tiles * plan.k_steps - int(live.sum())
+
+    def test_the_plan_is_logged_once_a_shape(self):
+        records = []
+        handler = logging.Handler()
+        handler.emit = records.append
+        level = fa.log.level
+        fa.log.addHandler(handler)
+        fa.log.setLevel(logging.INFO)
+        fa._log_tile_plan.cache_clear()
+        try:
+            q, k, v = self._qkv(64)
+            for _ in range(2):
+                flash_attention(q, k, v, block_q=16, block_k=32,
+                                force="pallas", window=24)
+        finally:
+            fa.log.removeHandler(handler)
+            fa.log.setLevel(level)
+            fa._log_tile_plan.cache_clear()
+        said = [r.getMessage() for r in records]
+        plan = fa.tile_plan(64, 64, 16, 16, 24, jnp.dtype(jnp.float32), 16,
+                            32)
+        assert len(said) == 1 and "tiles 16 x 32" in said[0]
+        assert (f"{plan.unmasked} unmasked, {plan.masked} masked, "
+                f"{plan.dead} dead") in said[0]
+
+    def test_float32_heads_of_256_get_half_the_rows(self):
+        """The one shape whose 1024 x 1024 step Mosaic refused for VMEM."""
+        plan = fa.tile_plan(2048, 2048, 256, 256, None, jnp.float32)
+        assert (plan.block_q, plan.block_k) == (512, 1024)
+        assert fa.tile_plan(2048, 2048, 256, 256, None,
+                         jnp.bfloat16)[:2] == (1024, 1024)

@@ -555,18 +555,45 @@ def test_mosaic_compiles_the_latent_kernel_at_the_cells_widths(one_chip):
 @pytest.mark.parametrize("s", [1536, 3072])
 def test_mosaic_compiles_flash_prefill_with_narrower_values(one_chip, s):
     """Queries and keys 192 wide against values of 128, 16 heads, at the
-    two prefill buckets the latent cell's traffic runs."""
-    from nnstreamer_tpu.ops.flash_attention import _flash_bhsd
+    two prefill buckets the latent cell's traffic runs, under the tiles
+    the plan makes of them (768 and 1024 square)."""
+    from nnstreamer_tpu.ops.flash_attention import _flash_bhsd, tile_plan
 
     def shape(dims):
         return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
 
+    plan = tile_plan(s, s, 192, 128, None, jnp.bfloat16)
+    assert plan.block_q == plan.block_k == {1536: 768, 3072: 1024}[s]
     text = _compile_for_the_chip(
         _flash_bhsd, shape((1, 16, s, 192)), shape((1, 16, s, 192)),
-        shape((1, 16, s, 128)), causal=True, block_q=256, block_k=256,
-        interpret=False, scale=0.1147)
+        shape((1, 16, s, 128)), causal=True, block_q=plan.block_q,
+        block_k=plan.block_k, interpret=False, scale=0.1147)
     assert "tpu_custom_call" in text and "nns_flash_prefill" in text
     assert f"bf16[1,16,{s},128]" in text
+
+
+@pytest.mark.parametrize("dtype,hq,hk,d,s", [
+    (jnp.bfloat16, 16, 2, 256, 512),     # qwen3next_chat_closed's longest
+    (jnp.bfloat16, 16, 2, 256, 4096),
+    (jnp.float32, 16, 2, 256, 4096),     # 512 rows: 1024 do not fit VMEM
+    (jnp.float32, 8, 8, 128, 4096),      # bench.py measure_attention
+    (jnp.bfloat16, 16, 16, 64, 2048),
+], ids=lambda x: str(getattr(x, "__name__", x)))
+def test_mosaic_compiles_the_flash_prefill_under_the_plans_tiles(
+        one_chip, dtype, hq, hk, d, s):
+    """Whatever tiles ``tile_plan`` makes of a head width and a dtype,
+    Mosaic takes them: its VMEM estimate errs on the safe side."""
+    from nnstreamer_tpu.ops.flash_attention import _flash_bhsd, tile_plan
+
+    def shape(heads):
+        return jax.ShapeDtypeStruct((1, heads, s, d), dtype,
+                                    sharding=one_chip)
+
+    plan = tile_plan(s, s, d, d, None, dtype)
+    text = _compile_for_the_chip(
+        _flash_bhsd, shape(hq), shape(hk), shape(hk), causal=True,
+        block_q=plan.block_q, block_k=plan.block_k, interpret=False)
+    assert "tpu_custom_call" in text and "nns_flash_prefill" in text
 
 
 @pytest.mark.parametrize("n_held,d,f,tile,rows", [
@@ -811,20 +838,27 @@ def test_mosaic_compiles_the_trinity_decode_program(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+@pytest.mark.parametrize("window", [4096, None], ids=["band", "full"])
 @pytest.mark.parametrize("s", [6144, 12288])
 def test_mosaic_compiles_the_band_flash_prefill_at_the_cells_buckets(
-        one_chip, s):
-    """48 query heads over 8 key-value heads of 128, window 4096, at the
-    two prefill buckets the cell's traffic runs: the k axis of the grid is
-    the band's 18 tiles, not the bucket's 24 or 48."""
-    from nnstreamer_tpu.ops.flash_attention import _flash_bhsd
+        one_chip, s, window):
+    """48 query heads over 8 key-value heads of 128, window 4096 and none,
+    at the two prefill buckets the cell's traffic runs, under the plan's
+    1024 x 1024 tiles: the k axis of the band's grid is the 6 tiles a
+    band can touch, not the longer bucket's 12."""
+    from nnstreamer_tpu.ops.flash_attention import _flash_bhsd, tile_plan
 
     def shape(heads):
         return jax.ShapeDtypeStruct((1, heads, s, 128), jnp.bfloat16,
                                     sharding=one_chip)
 
+    plan = tile_plan(s, s, 128, 128, window, jnp.bfloat16)
+    assert plan[:3] == (1024, 1024, s // 1024)
+    assert plan.k_steps == (6 if window else s // 1024)
     text = _compile_for_the_chip(
-        _flash_bhsd, shape(48), shape(8), shape(8), causal=True, block_q=256,
-        block_k=256, interpret=False, scale=0.088, window=4096)
-    assert "tpu_custom_call" in text and "nns_band_flash_prefill" in text
+        _flash_bhsd, shape(48), shape(8), shape(8), causal=True,
+        block_q=plan.block_q, block_k=plan.block_k, interpret=False,
+        scale=0.088, window=window)
+    name = "nns_band_flash_prefill" if window else "nns_flash_prefill"
+    assert "tpu_custom_call" in text and name in text
     assert f"bf16[1,48,{s},128]" in text
