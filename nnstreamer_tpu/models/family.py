@@ -39,7 +39,8 @@ class ModelFamily:
     #: keys and values per head are ``(layers, 2, (heads, head_dim))``, one
     #: latent row shared by every head ``(layers, 1, (width,))``. The
     #: arena leaf is ``[layers, blocks, parts, T, *shape]``, or, for a
-    #: shape ``(heads, head_dim)`` of fewer than 8 heads, heads-major
+    #: shape ``(heads, head_dim)`` whose heads are no multiple of 8,
+    #: heads-major
     #: ``[layers, blocks, parts, heads, T, head_dim]``: this shape is ALL
     #: that decides the order (``models/transformer.py``
     #: ``kv_heads_major``, applied by the codec that makes the arena)
@@ -53,7 +54,9 @@ class ModelFamily:
     #: a lane, and the engine gives a lane's blocks back to that arena once
     #: they lie wholly behind the window. The decode step then takes the
     #: tables as ``{"kv": bt, "win": bt_w}`` and the arenas as ``{"kv":
-    #: pages, "win": pages_w}``, and the prefill's cache has both keys
+    #: pages, "win": pages_w}``, and the prefill's cache has both keys. A
+    #: family may state ``lane_state`` as well: the arenas and the cache
+    #: then have a third key, ``"state"`` (``models/sambay.py``)
     kv_window: Callable = lambda cfg: None
     #: ``latent_value_width(cfg)``: for a one-part entry, how many of the
     #: row's first columns are the value (``ops.paged_attention``
@@ -63,10 +66,18 @@ class ModelFamily:
     #: its blocks: ``{"layers": n, leaf: (shape, dtype), ...}``. A family
     #: with lane state is served on the paged path only, a stream keeps
     #: its lane for life, and options that need such state copied, shared
-    #: or sharded are refused (``ContinuousBatchingEngine``)
+    #: or sharded are refused (``ContinuousBatchingEngine``). The decode
+    #: step's arenas are then ``{"kv": pages, "state": {leaf: array}}``
+    #: (with ``kv_window`` also ``"win"``), and the prefill's cache has the
+    #: same keys
     lane_state: Callable = lambda cfg: None
     #: names of the per-step counts the decode step returns
     counters: Tuple[str, ...] = ()
+    #: ``prefill_counters(cfg, rows) -> {name: n}``: what ONE prompt's
+    #: prefill over a bucket of ``rows`` positions computed, for a family
+    #: whose prefill does not run every layer over every row (``rows`` 0
+    #: names the counters); the engine sums them into ``stats``
+    prefill_counters: Optional[Callable] = None
     #: ``expert_matmul(cfg, tokens) -> form``: the form the family's
     #: routed experts run in for that many tokens a call
     #: (``ops/grouped_matmul.py``); None for a family with none

@@ -28,7 +28,10 @@ on it:
   token, whatever the bucket's padding; decode is the recurrence for one
   token, written into the arena in place (``ops/lane_state.py``: one
   Pallas pass over a layer's slots on a TPU). A pattern holds attention and
-  ONE recurrent kind: the lanes' state arena has one shape.
+  ONE recurrent kind: the lanes' state arena has one shape. (A third
+  recurrent kind, Mamba-1, whose decay is a matrix and whose state lies
+  state-major, is ``models/sambay.py``'s, under the same kernel's third
+  rule.)
 - **The expert layer holds a share** (``experts_held``): the router keeps
   its published width and its experts per token, gates are the softmax
   over the chosen experts and are not renormalised over the held ones,

@@ -260,17 +260,21 @@ _TILE_ROWS = 8
 def kv_heads_major(entry) -> bool:
     """THE rule for the order of the rows inside a block, from the shape of
     a token's entry (``ModelFamily.kv_entry``) and nothing else: an entry
-    ``(heads, head_dim)`` with fewer heads than one tile has rows is stored
-    HEADS-MAJOR, ``[.., heads, T, head_dim]``, so that a head's ``T`` tokens
-    are whole tiles; 8 heads or more, and a latent row ``(width,)``, stay
-    token-major, ``[.., T, *entry]``, where a token's entry is whole tiles
-    already and the decode write is one contiguous entry. With 2 heads
-    token-major the chip tiles the arena two rows to a tile and every flat
-    view or scatter of it is a copy of the WHOLE arena (PERF.md, PR 31 and
-    PR 34). The order cannot be read back off a shape (``[2, 16, dh]`` is 2
-    heads of 16 tokens and 2 tokens of 16 heads): the codec that made the
-    arena states it (``heads_major``) and hands it on."""
-    return len(entry) == 2 and entry[0] < _TILE_ROWS
+    ``(heads, head_dim)`` whose heads are NOT whole tiles of 8 rows (fewer
+    than 8: 2; or 10, between two tiles) is stored HEADS-MAJOR, ``[..,
+    heads, T, head_dim]``, so that a head's ``T`` tokens are whole tiles; 8
+    heads, 16, and a latent row ``(width,)`` stay token-major, ``[.., T,
+    *entry]``, where a token's entry is whole tiles already and the decode
+    write is one contiguous entry. With 2 heads token-major the chip tiles
+    the arena two rows to a tile and every flat view or scatter of it is a
+    copy of the WHOLE arena (PERF.md, PR 31 and PR 34); with 10 it pads
+    each token's ten rows to sixteen and copies both arenas into ``{5,2,4,3,
+    1,0:T(2,128)}`` and back every step (3.3 GB of temporaries: compiled
+    for a described v5e, PERF.md PR 39). The order cannot be read back off a
+    shape (``[2, 16, dh]`` is 2 heads of 16 tokens and 2 tokens of 16
+    heads): the codec that made the arena states it (``heads_major``) and
+    hands it on."""
+    return len(entry) == 2 and entry[0] % _TILE_ROWS != 0
 
 
 def _paged_gather(pages, layer, bt, heads_major: bool = False):

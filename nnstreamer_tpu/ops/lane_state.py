@@ -14,7 +14,7 @@ static block index; each tile is fetched once, updated in VMEM, its output
 reduced from the tile in hand, and written back once. An empty lane's tile
 is written back as read, bit for bit, and its output is zero.
 
-Two rules share the grid, the aliasing, the block rule, the reject rule
+Three rules share the grid, the aliasing, the block rule, the reject rule
 and the name ``nns_lane_state``; each brings a reference (the tests'
 oracle, and the form off a TPU) and a kernel body of the same arithmetic,
 float32 elementwise with float32 sums, nothing narrowed:
@@ -26,6 +26,15 @@ cm [b, n])``.
 ``"gated_delta"`` (tile ``[key, value]``): ``models/gated_delta.py
 gated_delta_step``'s own order — operands ``(q [b, h, k], k [b, h, k], v
 [b, h, v], g [b, h], beta [b, h])``.
+
+``"mamba1"`` (tile STATE-MAJOR ``[state, channels]``, the channels of
+``models/sambay.py``'s selective scan cut into ``heads`` blocks so that a
+tile's columns are whole lanes): the decay is a matrix, not a scalar a head:
+``S <- S exp(dt[c] A[n, c]) + B[n] (x dt)[c]``, ``y[c] = sum_n S[n, c] C[n]``
+— operands ``(x [b, h, c], step [b, h, c], a [h, n, c], bm [b, n], cm [b,
+n])``. ``a`` is a PARAMETER, the same block for every lane: it has no lane
+axis, its block index does not move from lane to lane and the pipeline
+fetches it once a head block. The sum over ``n`` runs down the sublanes.
 
 Vectors that multiply a tile along its rows come in transposed, ``[rows,
 heads]``, so that a head's column is a static lane slice; the decay, a
@@ -57,7 +66,7 @@ from nnstreamer_tpu.ops.grouped_matmul import VMEM_BYTES
 
 log = get_logger("lane-state")
 
-MAMBA2, GATED_DELTA = "mamba2", "gated_delta"
+MAMBA2, GATED_DELTA, MAMBA1 = "mamba2", "gated_delta", "mamba1"
 
 
 class LaneSlot(NamedTuple):
@@ -75,6 +84,15 @@ def mamba2_step(state, x, step, a, bm, cm):
     kept = state * jnp.exp(step * a)[..., None, None]
     new = kept + (x * step[..., None])[..., None] * bm[:, None, None, :]
     return jnp.einsum("bhpn,bn->bhp", new, cm), new
+
+
+def mamba1_step(state, x, step, a, bm, cm):
+    """One token of the selective scan, state-major: ``state [b, heads, n,
+    c]``, ``x``/``step [b, heads, c]``, ``a [heads, n, c]`` (negative),
+    ``bm``/``cm [b, n]``, all float32 → ``(y [b, heads, c], state)``."""
+    kept = state * jnp.exp(step[:, :, None, :] * a)
+    new = kept + bm[:, None, :, None] * (x * step)[:, :, None, :]
+    return jnp.sum(new * cm[:, None, :, None], axis=2), new
 
 
 def lane_state_reference(rule: str, slot: LaneSlot, live, operands):
@@ -189,16 +207,42 @@ def _delta_body(vectors, s_in, s_out, o, hb: int):
         s_out[h] = kept + k_col * d
 
 
+def _mamba1_pack(operands, hb: int):
+    x, step, a, bm, cm = operands
+    lanes, heads, cols = x.shape
+
+    def rows(v):
+        return v.reshape(lanes, heads // hb, hb, cols)
+
+    return [rows(step), rows(x * step), bm[:, :, None], cm[:, :, None],
+            a.reshape((heads // hb, hb) + a.shape[1:])]
+
+
+def _mamba1_body(vectors, s_in, s_out, y, hb: int):
+    """``hb`` channel blocks of one lane: the step and ``x dt`` a row each
+    ``[hb, c]``, B and C a column ``[n, 1]``, ``a [hb, n, c]`` the
+    parameter; the tiles ``[hb, n, c]``; y ``[hb, c]``."""
+    step, xdt, bm, cm, a = vectors
+    b_col, c_col = bm[...], cm[...]
+    for h in range(hb):
+        new = s_in[h] * jnp.exp(step[h:h + 1, :] * a[h]) \
+            + b_col * xdt[h:h + 1, :]
+        s_out[h] = new
+        y[h:h + 1, :] = jnp.sum(new * c_col, axis=0, keepdims=True)
+
+
 class _Rule(NamedTuple):
     step: Callable      # the reference: (state, *operands) -> (out, state)
     pack: Callable      # (operands, hb) -> the kernel's vectors
     body: Callable      # the kernel's: hb heads of one lane
     out_by_rows: bool   # a head's output lies along its tile's rows
+    shared: int = 0     # the pack's last vectors with no lane axis
 
 
 _RULES = {
     MAMBA2: _Rule(mamba2_step, _mamba2_pack, _mamba2_body, True),
     GATED_DELTA: _Rule(gated_delta_step, _delta_pack, _delta_body, False),
+    MAMBA1: _Rule(mamba1_step, _mamba1_pack, _mamba1_body, False, shared=1),
 }
 
 
@@ -220,12 +264,14 @@ def _kernel(live, *refs, body, hb: int):
 def _lane_state(arena, live, vectors, *, rule: str, layer: int, hb: int,
                 vmem_limit_bytes: int, interpret: bool):
     """Kernel entry. ``vectors``: ``[lanes, heads // hb, r, c]`` (a block a
-    grid step) or ``[lanes, 1, c]`` (one row a lane). Returns ``(out,
-    arena)``, ``out [lanes, heads // hb, r, c]`` as the rule's body writes
-    it."""
+    grid step) or ``[lanes, r, c]`` (one block a lane); the rule's last
+    ``shared`` of them ``[heads // hb, ...]``, the same for every lane.
+    Returns ``(out, arena)``, ``out [lanes, heads // hb, r, c]`` as the
+    rule's body writes it."""
     _, lanes, heads, rows, cols = arena.shape
     out_shape = (lanes, heads // hb) + (
         (rows, hb) if _RULES[rule].out_by_rows else (hb, cols))
+    own = len(vectors) - _RULES[rule].shared
 
     def blocked(shape):
         if len(shape) == 3:
@@ -233,6 +279,11 @@ def _lane_state(arena, live, vectors, *, rule: str, layer: int, hb: int,
                                 lambda i, j, *_: (i, 0, 0))
         return pl.BlockSpec((None, None) + tuple(shape[2:]),
                             lambda i, j, *_: (i, j, 0, 0))
+
+    def shared(shape):
+        zeros = (0,) * (len(shape) - 1)
+        return pl.BlockSpec((None,) + tuple(shape[1:]),
+                            lambda i, j, *_: (j,) + zeros)
 
     tile = pl.BlockSpec((None, None, hb, rows, cols),
                         lambda i, j, *_: (layer, i, j, 0, 0))
@@ -243,7 +294,8 @@ def _lane_state(arena, live, vectors, *, rule: str, layer: int, hb: int,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,          # the live lanes
             grid=(lanes, heads // hb),
-            in_specs=[blocked(v.shape) for v in vectors] + [tile],
+            in_specs=[blocked(v.shape) for v in vectors[:own]]
+            + [shared(v.shape) for v in vectors[own:]] + [tile],
             out_specs=(blocked(out_shape), tile)),
         input_output_aliases={1 + len(vectors): 1},
         compiler_params=pltpu.CompilerParams(
@@ -258,10 +310,12 @@ def _lane_state(arena, live, vectors, *, rule: str, layer: int, hb: int,
 def _pallas_reject(rule: str, arena, operands=()) -> str | None:
     """Why this arena cannot go to the kernel, or None when it can.
 
-    What was proved: Mosaic (libtpu 0.0.34, for a TPU v5e) compiles both
-    bodies at the two cells' shapes, ``f32[9, 64, 128, 64, 128]`` and
-    ``f32[3, 128, 32, 128, 128]`` (``tests/test_paged_attention.py``), and
-    the chip held both to the reference (``chip_smoke.py``); the
+    What was proved: Mosaic (libtpu 0.0.34, for a TPU v5e) compiles the
+    bodies at the cells' shapes, ``f32[9, 64, 128, 64, 128]``, ``f32[3,
+    128, 32, 128, 128]`` and state-major ``f32[9, 64, 1, 16, 5120]``
+    (``tests/test_paged_attention.py``), and the chip held the first two to
+    the reference (``chip_smoke.py``) and the third through its cell's
+    check (PERF.md, PR 39); the
     interpreter holds cut-down twins (``tests/test_lane_state.py``). The
     checks below are what the layout needs: a float32 tile of whole
     sublanes and whole lanes."""

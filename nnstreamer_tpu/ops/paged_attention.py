@@ -276,7 +276,13 @@ def _pallas_reject(q, pages, bt, v_width: int | None = None,
     (``tests/test_paged_attention.py``; PERF.md, PR 33). With a ``window``
     it was compiled and served from token-major at 48 query over 8
     key-value heads of 128 (PERF.md, PR 37); a window over a latent arena
-    was never built."""
+    was never built. Since PR 39 the query rows need be whole FLOAT32
+    sublanes only (8: the scores, the running max and sum and the
+    accumulator are float32 ``[hq, ..]``), not whole sublanes of the
+    arena's dtype: a bfloat16 query block of 40 rows, which is the whole
+    array's extent, Mosaic takes, and both kernels were compiled and
+    served from at 40 query rows over 10 key-value heads of 128,
+    heads-major (a differential-attention pair entry: PERF.md, PR 39)."""
     if window is not None and v_width is not None:
         return "a window over a latent arena is not built"
     if not hasattr(pages, "shape") or \
@@ -307,8 +313,9 @@ def _pallas_reject(q, pages, bt, v_width: int | None = None,
         return f"head dim {dh} is not a multiple of 128 lanes"
     if hq % hk:
         return f"{hq} query heads are no multiple of {hk} key-value heads"
-    if hq % sublanes or (T * hk) % sublanes:
-        return (f"{hq} query heads or {T} x {hk} rows a block are no "
+    if hq % 8 or (T * hk) % sublanes:
+        return (f"{hq} query heads are no multiple of 8 float32 sublanes "
+                f"(the scores' rows) or {T} x {hk} rows a block no "
                 f"multiple of {sublanes} sublanes")
     if bt.shape[0] != b:
         return f"{bt.shape[0]} block tables for {b} lanes"
@@ -344,10 +351,15 @@ def _window_tables(pages, bt, pos_c, window: int, heads_major: bool):
         first[:, None] * T + jnp.arange(nb * T)
 
 
+def _scope(window, scope):
+    return scope or ("attend" if window is None else "attend_window")
+
+
 def paged_attention_reference(q, pages, layer, bt, pos_c, scale=None,
                               v_width: int | None = None,
                               heads_major: bool = False,
-                              window: int | None = None):
+                              window: int | None = None,
+                              scope: str | None = None):
     """The gather form: every lane's whole table copied out of the arena
     (into ``[b, MB * T, hk, dh]`` whichever the arena's order),
     masked to ``slot <= pos_c`` and attended over by ``_attend_cache``
@@ -375,7 +387,7 @@ def paged_attention_reference(q, pages, layer, bt, pos_c, scale=None,
         else:
             ck = g[:, 0, :, None]
             cv = ck[..., :v_width]
-    with jax.named_scope("attend" if window is None else "attend_window"):
+    with jax.named_scope(_scope(window, scope)):
         return _attend_cache(q, ck, cv, mask, q.shape[-1], q.dtype,
                              scale=scale)
 
@@ -385,7 +397,8 @@ def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
                     chunk_blocks: int | None = None,
                     v_width: int | None = None,
                     heads_major: bool = False,
-                    window: int | None = None):
+                    window: int | None = None,
+                    scope: str | None = None):
     """Decode attention of ``q [b, 1, hq, dh]`` over a paged cache.
 
     ``pages`` is the arena's value leaf WHOLE, ``[L, NTOT, 2, T, hk, dh]``
@@ -416,7 +429,9 @@ def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
     "reference". The kernel's instructions lie under the scope
     ``attend``; the gather form keeps ``kv_gather`` and ``attend``. With a
     ``window`` the scope is ``attend_window`` in both, so that a trace
-    tells a model's two kinds of attention layer apart.
+    tells a model's two kinds of attention layer apart; ``scope`` names
+    another in their place (a layer that reads another layer's blocks:
+    ``attend_cross``).
     """
     on_tpu = jax.default_backend() == "tpu"
     if window is not None and window <= 0:
@@ -432,12 +447,13 @@ def paged_attention(q, pages, layer, bt, pos_c, scale: float | None = None,
             _log_reference_choice(tuple(q.shape), tuple(pages.shape),
                                   str(q.dtype), why_not)
         return paged_attention_reference(q, pages, layer, bt, pos_c, scale,
-                                         v_width, heads_major, window)
+                                         v_width, heads_major, window,
+                                         scope)
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     if chunk_blocks is None:
         chunk_blocks = CHUNK_BLOCKS if v_width is None \
             else LATENT_CHUNK_BLOCKS
-    with jax.named_scope("attend" if window is None else "attend_window"):
+    with jax.named_scope(_scope(window, scope)):
         out = _paged_decode(
             q[:, 0], pages, layer, bt, pos_c, scale=float(scale),
             chunk=min(int(chunk_blocks), bt.shape[1]),

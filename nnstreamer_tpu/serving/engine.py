@@ -322,7 +322,11 @@ class ContinuousBatchingEngine:
         lane that finishes early leaves nothing reserved, and a block
         another stream shares can be let go of where a ring would have to
         copy it first; the table keeps the full table's indexing, so one
-        kernel reads both).
+        kernel reads both), ``models.sambay`` the one that states a window
+        AND lane state: a stream takes a lane, full blocks and window
+        blocks at admission and gives all three back at its end, and its
+        cross layers read the one full layer's blocks, so ``kv_entry``
+        says one layer and nothing here knows of the others.
         With a family that has lane state every stream keeps its lane
         for life. Of ``prefix_cache``, ``speculate``, ``prefill_chunk``,
         ``kv_quant`` and ``mesh=`` the dense family brings all; what a
@@ -613,10 +617,14 @@ class ContinuousBatchingEngine:
             **({"kv_window_blocks_live": 0, "kv_window_blocks_released": 0}
                if self._window is not None else {}),
             # what the family's decode step counts of itself, summed over
-            # the steps of every dispatch
+            # the steps of every dispatch, and what its prefill says it
+            # computed of each bucket's rows
             **{name: 0 for name in family.counters},
+            **(family.prefill_counters(cfg, 0)
+               if family.prefill_counters else {}),
         }
         self._counters = tuple(family.counters)
+        self._prefill_counters = family.prefill_counters
         #: the engine's own after-the-fact record of what its loop did:
         #: one span per closed phase, one async span per request. Used
         #: while no process-wide timeline is installed (``_ledger``).
@@ -1632,6 +1640,10 @@ class ContinuousBatchingEngine:
                 lengths=jnp.asarray([n], jnp.int32))
             self.stats["prefill_tokens"] += n
             self.stats["prefill_bucket_tokens"] += bucket
+            if self._prefill_counters:
+                for name, rows in self._prefill_counters(self.cfg,
+                                                         bucket).items():
+                    self.stats[name] += rows
             self._enqueue(self._pool.scatter_prefill, cache1,
                           blocks[:(n + T - 1) // T], lane=lane,
                           window_ids=wblocks[:(n + T - 1) // T - wfirst],

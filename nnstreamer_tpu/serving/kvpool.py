@@ -14,8 +14,9 @@ KV store:
   a latent cache ONE part of ``[width]``, a row every head shares — and
   allocation, refcounts, sentinel, scatter and block copy are the same
   code for either. OR HEADS-MAJOR, ``[L, NTOT, parts, h, T, dh]`` (scale
-  leaf ``[L, NTOT, 2, h, T]``), where the entry has fewer heads than a
-  tile has rows (``h < 8``): a head's ``T`` tokens are then whole tiles,
+  leaf ``[L, NTOT, 2, h, T]``), where the entry's heads are not whole
+  tiles of 8 rows (``h % 8``: 2, 10): a head's ``T`` tokens are then whole
+  tiles,
   where two rows of ``[T, 2, dh]`` to a tile made every flat view and
   every scatter a copy of the whole arena. Who decides: the codec that
   makes the arena (``models/transformer.py`` ``_kv_codec`` by
@@ -65,8 +66,10 @@ KV store:
   refcounts, sentinel and zero block of its own (``pool.win``: ``alloc``,
   ``release``, ``SENTINEL`` as the pool's). ``self.arena`` is then
   ``{"kv": blocks, "win": window blocks}``, one pytree donated into the
-  same programs. WHO DECIDES: the family says which layers have a window
-  and how wide; the engine owns a second table a lane, indexed by a
+  same programs; a family that keeps lane state as well (``models/
+  sambay.py``) has all three, ``{"kv", "win", "state"}``, and ONE scatter
+  hands a prefill's three kinds over (``_scatter_kinds_impl``). WHO
+  DECIDES: the family says which layers have a window and how wide; the engine owns a second table a lane, indexed by a
   position's block like the first, and gives a lane's blocks back to
   ``pool.win`` once they lie wholly behind the window (an entry of the
   window table is then the window arena's sentinel again, and no program
@@ -128,28 +131,27 @@ def _scatter_prefill_impl(arena, cache1, bids, heads_major=False):
         return jax.tree.map(leaf, arena, cache1)
 
 
-def _scatter_two_impl(arena, cache1, bids, bids_w, heads_major=False):
-    """The hand-over of a prefill whose family has window layers: every
-    kind's rows into its own arena under its own table (``bids_w`` names
-    the blocks of the window's last positions; the rest drop)."""
-    return {"kv": _scatter_prefill_impl(arena["kv"], cache1["kv"], bids,
-                                        heads_major),
-            "win": _scatter_prefill_impl(arena["win"], cache1["win"], bids_w,
-                                         heads_major)}
-
-
-def _scatter_with_state_impl(arena, cache1, bids, lane, heads_major=False):
-    """The hand-over of a prefill whose family keeps lane state: keys and
-    values into blocks as above, and each state leaf ``[layers, 1, ...]``
-    over the whole of slot ``lane``."""
+def _scatter_kinds_impl(arena, cache1, bids, bids_w, lane,
+                        heads_major=False):
+    """The hand-over of a prefill whose family keeps more than the full
+    layers' blocks, every kind in one program: keys and values into blocks
+    as above; the window layers' rows into their own arena under their own
+    table (``bids_w`` names the blocks of the window's last positions; the
+    rest drop); each state leaf ``[layers, 1, ...]`` over the whole of slot
+    ``lane``. ``bids_w`` / ``lane`` are None for a kind the arena lacks."""
     import jax
 
-    kv = _scatter_prefill_impl(arena["kv"], cache1["kv"], bids, heads_major)
-    with jax.named_scope("nns.state_scatter"):
-        state = jax.tree.map(
-            lambda a, c: a.at[:, lane].set(c[:, 0].astype(a.dtype)),
-            arena["state"], cache1["state"])
-    return {"kv": kv, "state": state}
+    out = {"kv": _scatter_prefill_impl(arena["kv"], cache1["kv"], bids,
+                                       heads_major)}
+    if "win" in arena:
+        out["win"] = _scatter_prefill_impl(arena["win"], cache1["win"],
+                                           bids_w, heads_major)
+    if "state" in arena:
+        with jax.named_scope("nns.state_scatter"):
+            out["state"] = jax.tree.map(
+                lambda a, c: a.at[:, lane].set(c[:, 0].astype(a.dtype)),
+                arena["state"], cache1["state"])
+    return out
 
 
 def _copy_block_impl(arena, src, dst):
@@ -262,16 +264,19 @@ class BlockPool(_Blocks):
         self._window = self._family.kv_window(cfg)
         self.win: Optional[_Blocks] = None
         if self._window is not None:
-            if self._lane_state or mesh is not None or not window_blocks:
+            if mesh is not None or not window_blocks:
                 raise ValueError(
                     "BlockPool: a window arena needs window_blocks > 0 and "
-                    "goes with neither lane state nor a mesh")
+                    "does not go with a mesh")
             self.win = _Blocks(window_blocks, "BlockPool.win")
         self.lanes = int(lanes) if self._lane_state else 0
         if self._lane_state and self.lanes <= 0:
             raise ValueError("BlockPool: a model with lane state needs "
                              "lanes > 0")
         self._lane_live: set = set()
+        #: the arena is a dict by kind (``"kv"`` and ``"win"``, ``"state"``
+        #: or both) and not the full layers' leaves alone
+        self._kinds = bool(self._lane_state or self.win)
         self.arena = self._make_arena()
 
         import jax
@@ -281,10 +286,8 @@ class BlockPool(_Blocks):
         self.window_bytes = _memory.pytree_nbytes(self.arena["win"]) \
             if self.win else 0
         self._jit_scatter = jax.jit(
-            _scatter_with_state_impl if self._lane_state
-            else _scatter_two_impl if self.win
-            else _scatter_prefill_impl, donate_argnums=(0,),
-            static_argnames=("heads_major",))
+            _scatter_kinds_impl if self._kinds else _scatter_prefill_impl,
+            donate_argnums=(0,), static_argnames=("heads_major",))
         self._jit_copy = jax.jit(_copy_block_impl, donate_argnums=(0,))
 
         acct = _memory.ACTIVE
@@ -305,17 +308,20 @@ class BlockPool(_Blocks):
                                        *entry, parts=parts)
         if self.mesh is not None:
             arena = self._place(arena)
-        if self.win:
-            return {"kv": arena, "win": self._codec.paged_init(
-                self._window[0], self.win.ntot, self.block_tokens, *entry,
-                parts=parts)}
-        if not self._lane_state:
+        if not self._kinds:
             return arena
-        spec = dict(self._lane_state)
-        n = spec.pop("layers")
-        return {"kv": arena, "state": {
-            name: jnp.zeros((n, self.lanes) + tuple(shape), dtype)
-            for name, (shape, dtype) in spec.items()}}
+        arena = {"kv": arena}
+        if self.win:
+            arena["win"] = self._codec.paged_init(
+                self._window[0], self.win.ntot, self.block_tokens, *entry,
+                parts=parts)
+        if self._lane_state:
+            spec = dict(self._lane_state)
+            n = spec.pop("layers")
+            arena["state"] = {
+                name: jnp.zeros((n, self.lanes) + tuple(shape), dtype)
+                for name, (shape, dtype) in spec.items()}
+        return arena
 
     def _place(self, arena):
         from jax.sharding import PartitionSpec as P
@@ -398,7 +404,7 @@ class BlockPool(_Blocks):
 
     def _kv(self, tree):
         """The full layers' part of an arena or of a prefill's cache."""
-        return tree["kv"] if self._lane_state or self.win else tree
+        return tree["kv"] if self._kinds else tree
 
     def scatter_prefill(self, cache1, block_ids: Sequence[int],
                         lane: Optional[int] = None,
@@ -407,7 +413,7 @@ class BlockPool(_Blocks):
         """Move a batch-1 prefill cache into ``block_ids`` (padded with
         the sentinel up to S/T) and, for a family with lane state, the
         prefill's final state over slot ``lane``; for a family with window
-        layers, their rows of the prompt's blocks ``window_first ..
+        layers (it may be the same family), their rows of the prompt's blocks ``window_first ..
         window_first + len(window_ids) - 1`` into the window arena's
         ``window_ids`` (the blocks before them lie behind the window and
         are handed to nobody). Mutates ``self.arena`` in place (the old
@@ -417,11 +423,16 @@ class BlockPool(_Blocks):
         mb = _leaf_slots(self._kv(cache1)) // self.block_tokens
         bids = np.full(mb, self.SENTINEL, np.int32)
         bids[:len(block_ids)] = block_ids
-        extra = (jnp.asarray(lane, jnp.int32),) if self._lane_state else ()
-        if self.win:
-            bids_w = np.full(mb, self.win.SENTINEL, np.int32)
-            bids_w[window_first:window_first + len(window_ids)] = window_ids
-            extra = (jnp.asarray(bids_w),)
+        extra = ()
+        if self._kinds:
+            bids_w = None
+            if self.win:
+                bids_w = np.full(mb, self.win.SENTINEL, np.int32)
+                bids_w[window_first:window_first + len(window_ids)] = \
+                    window_ids
+                bids_w = jnp.asarray(bids_w)
+            extra = (bids_w, jnp.asarray(lane, jnp.int32)
+                     if self._lane_state else None)
         self.arena = self._jit_scatter(self.arena, cache1,
                                        jnp.asarray(bids), *extra,
                                        heads_major=self.heads_major)

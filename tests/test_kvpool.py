@@ -221,9 +221,11 @@ def test_one_pool_serves_both_token_entries(case):
     ((2, 256), True, (2, 16, 256)),
     ((4, 128), True, (4, 16, 128)),
     ((8, 128), False, (16, 8, 128)),
+    ((10, 128), True, (10, 16, 128)),
     ((16, 128), False, (16, 16, 128)),
     ((640,), False, (16, 640)),
-], ids=["1_head", "2_heads", "4_heads", "8_heads", "16_heads", "latent_row"])
+], ids=["1_head", "2_heads", "4_heads", "8_heads", "10_heads", "16_heads",
+        "latent_row"])
 @pytest.mark.parametrize("codec", ["raw", "int8"])
 def test_the_rule_fewer_heads_than_a_tiles_rows_are_heads_major(
         entry, heads_major, tail, codec):

@@ -7,8 +7,10 @@ kernel runs through the Pallas interpreter when forced, which is how these
 tests hold it to the reference: in a call of its own, as the carry of a
 K-step scan, and inside a hybrid engine with the mixer, the pool and the
 hand-over round it. The shapes are cut-down twins of the two recurrent
-cells': many heads of ``[8, 128]`` (Mamba-2) and few of ``[16, 128]`` (the
-gated delta rule), whole lanes a grid step and split ones. What ``auto``
+cells': many heads of ``[8, 128]`` (Mamba-2), few of ``[16, 128]`` (the
+gated delta rule) and state-major ``[16, channels]`` (Mamba-1: one channel
+block a lane, as its cell has it, and sixteen), whole lanes a grid step and
+split ones. What ``auto``
 builds is the reference wherever the kernel does not run, and says why
 once.
 
@@ -39,7 +41,10 @@ CASES = pytest.mark.parametrize("rule,shape,hb", [
     (ls.MAMBA2, (3, 4, 16, 8, 128), 8),
     (ls.GATED_DELTA, (3, 4, 8, 16, 128), None),
     (ls.GATED_DELTA, (3, 4, 16, 16, 128), 8),
-], ids=["mamba2_whole", "mamba2_split", "delta_whole", "delta_split"])
+    (ls.MAMBA1, (3, 4, 1, 16, 256), None),
+    (ls.MAMBA1, (3, 4, 16, 16, 128), 8),
+], ids=["mamba2_whole", "mamba2_split", "delta_whole", "delta_split",
+        "mamba1_whole", "mamba1_split"])
 LANES = pytest.mark.parametrize("lanes", ["all", "half", "none"])
 
 
@@ -56,6 +61,11 @@ def _operands(rule, shape, rng):
                 jax.nn.softplus(normal(lanes, heads)),
                 -jnp.exp(0.3 * normal(heads)), normal(lanes, cols),
                 normal(lanes, cols))
+    if rule == ls.MAMBA1:   # the tile is state-major: rows are the state
+        return (normal(lanes, heads, cols),
+                jax.nn.softplus(normal(lanes, heads, cols)),
+                -jnp.exp(0.3 * normal(heads, rows, cols)),
+                normal(lanes, rows), normal(lanes, rows))
     return (l2norm(normal(lanes, heads, rows)) * rows ** -0.5,
             l2norm(normal(lanes, heads, rows)), normal(lanes, heads, cols),
             -jax.nn.softplus(normal(lanes, heads)),

@@ -637,7 +637,8 @@ def test_mosaic_compiles_the_expert_kernel_at_the_cells_widths(
 @pytest.mark.parametrize("rule,arena", [
     ("mamba2", (9, 64, 128, 64, 128)),          # granite_h_chat_closed
     ("gated_delta", (3, 128, 32, 128, 128)),    # qwen3next_chat_closed
-], ids=["granite_mamba2", "qwen3_next_gated_delta"])
+    ("mamba1", (9, 64, 1, 16, 5120)),           # phi4flash_reason_closed
+], ids=["granite_mamba2", "qwen3_next_gated_delta", "phi4flash_mamba1"])
 def test_mosaic_compiles_the_lane_state_kernel_at_the_cells_shapes(
         one_chip, rule, arena):
     """The recurrent mixers' state update (``ops/lane_state.py``; here for
@@ -654,12 +655,17 @@ def test_mosaic_compiles_the_lane_state_kernel_at_the_cells_shapes(
 
     layers, lanes, heads, rows, cols = arena
     hb, limit = ls.head_block(heads, rows * cols * 4)
-    assert hb * rows * cols * 4 == 1 << 20     # a MiB of tiles a step
+    # a MiB of tiles a step; a Mamba-1 lane's one tile is 320 KiB
+    assert hb * rows * cols * 4 == (320 << 10 if rule == ls.MAMBA1
+                                    else 1 << 20)
     f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
     per_head, vector = f32((lanes, heads)), f32((lanes, heads, rows))
+    by_col = f32((lanes, heads, cols))
     operands = (vector, per_head, f32((heads,)), f32((lanes, cols)),
                 f32((lanes, cols))) if rule == ls.MAMBA2 else (
-        vector, vector, f32((lanes, heads, cols)), per_head, per_head)
+        by_col, by_col, f32((heads, rows, cols)), f32((lanes, rows)),
+        f32((lanes, rows))) if rule == ls.MAMBA1 else (
+        vector, vector, by_col, per_head, per_head)
     vectors = [shape(v.shape) for v in jax.eval_shape(
         lambda ops: ls._RULES[rule].pack(ops, hb), operands)]
 
@@ -836,6 +842,77 @@ def test_mosaic_compiles_the_trinity_decode_program(one_chip):
     assert "nns_paged_decode" in text.replace("nns_window_paged_decode", "")
     assert not _moves_of(text, 32 * 258 * 2 * 16 * 8 * 128)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_mosaic_compiles_the_phi4flash_decode_program(one_chip, monkeypatch):
+    """The whole K-step decode program of ``phi4flash_reason_closed`` for
+    the chip, all 32 layers, its three kinds of lane memory its arguments:
+    8 window layers through ``nns_window_paged_decode``, the ONE full layer
+    and the 7 cross layers that own no cache through ``nns_paged_decode``
+    over the same arena index (40 query rows ``[q1 | 0]`` / ``[0 | q2]``
+    over 10 key-value pairs of 128, heads-major: 10 heads are not whole
+    tiles, and token-major XLA relaid both arenas out every step, 3.3 GB
+    of temporaries that do not fit: PERF.md, PR 39), 9 Mamba-1 layers
+    through ``nns_lane_state``; both tables are 64 x 400 int32 (102 KB of
+    scalar prefetch); nothing arena-sized is copied or transposed, and the
+    program plans next to no temporaries beside 10.7 GB of arguments."""
+    from benchmark import run as bench_run
+    from benchmark.drivers import lm_sambay
+    from nnstreamer_tpu.ops import lane_state as ls
+
+    def shape(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    cfg = lm_sambay.sambay_config(
+        bench_run.load_cell("phi4flash_reason_closed")["config"])
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: cfg.family.init_params(cfg, 0)))
+
+    def attend(q, pages, layer, bt, pos_c, scale=None, heads_major=False,
+               window=None, scope=None):   # the chip's choice, made here
+        assert heads_major
+        return _paged_decode(q[:, 0], pages, layer, bt, pos_c,
+                             scale=float(scale), chunk=8, interpret=False,
+                             heads_major=True, window=window)[:, None]
+
+    def update(rule, slot, live, operands, force=None):   # likewise
+        arena, layer = slot
+        _, lanes, heads, rows, cols = arena.shape
+        hb, limit = ls.head_block(heads, rows * cols * 4)
+        out, new = ls._lane_state(
+            arena, live, ls._RULES[rule].pack(operands, hb), rule=rule,
+            layer=int(layer), hb=hb, vmem_limit_bytes=limit,
+            interpret=False)
+        return out.reshape(lanes, heads, -1), ls.LaneSlot(new, layer)
+
+    monkeypatch.setattr(ls, "update", update)
+    step = cfg.family.build_paged_decode_step(cfg, 16, 6400,
+                                              paged_attention_fn=attend)
+
+    def dispatch(params, token, arenas, bt, pos):
+        def body(carry, _):
+            token, arenas, pos = carry
+            logits, arenas, _ = step(params, token, arenas, bt, pos)
+            token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (token, arenas, pos + 1), token
+        return jax.lax.scan(body, (token, arenas, pos), None, length=8)
+
+    arenas = {"kv": shape((1, 64 * 400 + 1, 2, 10, 16, 128), jnp.bfloat16),
+              "win": shape((8, 64 * 34 + 1, 2, 10, 16, 128), jnp.bfloat16),
+              "state": {"ssm": shape((9, 64, 1, 16, 5120), jnp.float32),
+                        "conv": shape((9, 64, 3, 5120), jnp.bfloat16)}}
+    compiled = _compiled_for_the_chip(
+        jax.jit(dispatch, donate_argnums=(2,)), params, shape((64,)), arenas,
+        {"kv": shape((64, 400)), "win": shape((64, 400))}, shape((64,)))
+    text = compiled.as_text()
+    assert text.count("nns_window_paged_decode") >= 8
+    assert text.replace("nns_window_paged_decode", "").count(
+        "nns_paged_decode") >= 8
+    assert text.count("nns_lane_state") >= 9
+    assert "s32[64,400]" in text
+    assert not _moves_of(text, 64 * 34 * 2 * 16 * 10 * 128)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
 
 
 @pytest.mark.parametrize("window", [4096, None], ids=["band", "full"])

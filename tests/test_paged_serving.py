@@ -483,3 +483,94 @@ def test_arena_is_a_carry_of_both_scans_and_is_updated_in_place(codec):
         # the empty lane wrote nowhere and the zero block is still zero
         assert leaf[:, [0, 1, 2, 3]].any()
         assert not leaf[:, 4:].any()
+
+
+# -- a pool of three kinds: full blocks, window blocks, lane state ---------
+
+def _three_kinds_pool(mesh=None):
+    from nnstreamer_tpu.models.sambay import SambaYConfig
+    from nnstreamer_tpu.serving import kvpool
+
+    cfg = SambaYConfig(
+        vocab=64, d_model=32, n_layers=8, n_heads=8, n_kv_heads=4,
+        head_dim=8, window=8, d_ff=48, ssm_inner=64, ssm_state=16,
+        dt_rank=2, max_seq=32, dtype=jnp.float32, param_dtype=jnp.float32)
+    return cfg, kvpool.BlockPool(cfg, 6, 4, lanes=2, window_blocks=5,
+                                 mesh=mesh)
+
+
+def test_a_pool_of_three_kinds_allocs_scatters_and_releases():
+    """A family that states ``kv_window`` AND ``lane_state``: one arena
+    pytree ``{"kv", "win", "state"}``, ONE scatter for a prefill's three
+    kinds, each kind's bookkeeping its own."""
+    cfg, pool = _three_kinds_pool()
+    assert set(pool.arena) == {"kv", "win", "state"}
+    assert pool.heads_major                       # 2 pairs: heads-major
+    assert pool.arena["kv"].shape == (1, 7, 2, 2, 4, 16)
+    assert pool.arena["win"].shape == (2, 6, 2, 2, 4, 16)
+    assert pool.arena["state"]["ssm"].shape == (3, 2, 1, 16, 64)
+    assert pool.arena["state"]["conv"].shape == (3, 2, 3, 64)
+    snap = pool.snapshot()
+    assert (snap["num_blocks"], snap["window_blocks"], snap["state_slots"]) \
+        == (6, 5, 2)
+    assert snap["nbytes"] == sum(
+        int(a.size) * 4 for a in jax.tree_util.tree_leaves(pool.arena))
+    assert snap["window_bytes"] + snap["state_bytes"] < snap["nbytes"]
+    # a prompt of 11 tokens in a bucket of 16: 3 full blocks, and of the
+    # window layers' the last two (the first lies behind the window)
+    rng = np.random.default_rng(0)
+    cache = {
+        "kv": jnp.asarray(rng.standard_normal((1, 2, 1, 16, 2, 16)),
+                          jnp.float32),
+        "win": jnp.asarray(rng.standard_normal((2, 2, 1, 16, 2, 16)),
+                           jnp.float32),
+        "state": {"ssm": jnp.asarray(rng.standard_normal((3, 1, 1, 16, 64)),
+                                     jnp.float32),
+                  "conv": jnp.asarray(rng.standard_normal((3, 1, 3, 64)),
+                                      jnp.float32)}}
+    blocks, wblocks, lane = pool.alloc(3), pool.win.alloc(2), pool.alloc_lane()
+    other = pool.alloc_lane()
+    assert (lane, other) == (0, 1) and pool.alloc_lane() is None
+    before = {k: np.asarray(v) for k, v in pool.arena["state"].items()}
+    pool.scatter_prefill(cache, blocks, lane=other, window_ids=wblocks,
+                         window_first=1)
+    np.testing.assert_array_equal(pool.stream_rows(blocks, 11),
+                                  np.asarray(cache["kv"][:, :, 0, :11]))
+    np.testing.assert_array_equal(
+        pool.stream_rows(wblocks, 7, window=True),
+        np.asarray(cache["win"][:, :, 0, 4:11]))
+    got = pool.lane_state(other)
+    for name in ("ssm", "conv"):
+        np.testing.assert_array_equal(got[name],
+                                      np.asarray(cache["state"][name][:, 0]))
+        # the other lane's slot is what it was
+        np.testing.assert_array_equal(pool.lane_state(lane)[name],
+                                      before[name][:, lane])
+    # every block no one holds is still zero, in both arenas
+    held = set(blocks)
+    for i in range(pool.ntot):
+        if i not in held:
+            assert not np.asarray(pool.arena["kv"][:, i]).any()
+    for i in set(range(pool.win.ntot)) - set(wblocks):
+        assert not np.asarray(pool.arena["win"][:, i]).any()
+    snap = pool.snapshot()
+    assert (snap["live_blocks"], snap["window_blocks_live"],
+            snap["state_slots_live"]) == (3, 2, 2)
+    pool.release(blocks)
+    pool.win.release(wblocks)
+    pool.release_lane(lane)
+    pool.release_lane(other)
+    snap = pool.snapshot()
+    assert (snap["live_blocks"], snap["window_blocks_live"],
+            snap["state_slots_live"], snap["free_blocks"]) == (0, 0, 0, 6)
+    pool.reset()
+    assert not any(np.asarray(a).any()
+                   for a in jax.tree_util.tree_leaves(pool.arena))
+
+
+def test_a_window_arena_still_refuses_a_mesh():
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
+    with pytest.raises(ValueError, match="mesh"):
+        _three_kinds_pool(mesh=mesh)
