@@ -102,6 +102,11 @@ def register_engine_collector(engine, registry: MetricsRegistry = None
                     "work hidden behind device work",
                     phase=key[len("starved_"):-len("_us")],
                     **labels).set_total(us / 1e6)
+        reg.counter("nns_serving_emit_blocks_total",
+                    "Hand-overs of a program's tokens to a stream, one "
+                    "block and one wake-up each: one a stream a dispatch, "
+                    "one a first token", **labels).set_total(
+                        eng.stats["emit_blocks"])
         return True
 
     reg.register_collector(collect)

@@ -74,6 +74,15 @@ _DECODE_PROGRAMS: Dict[str, tuple] = {}
 _DECODE_PROGRAMS_KEPT = 4
 
 
+def _start_host_copies(*arrays) -> None:
+    """Start the copy of each device array to the host, so that the
+    blocking reads that follow wait once for all of them."""
+    for a in arrays:
+        start = getattr(a, "copy_to_host_async", None)
+        if start is not None:
+            start()
+
+
 def decode_program_text(engine: Optional[str] = None) -> Optional[str]:
     """The optimized HLO text of an engine's K-step decode program (the
     newest engine's unless named), or None if there is none.
@@ -150,6 +159,7 @@ class GenerationStream:
         #: ``(first + i) * T ..``)
         self.window_blocks: tuple = (0, ())
         self._q: _queue.Queue = _queue.Queue()
+        self._unsent: List[int] = []  # emitted, not yet in the queue
 
     def cancel(self) -> None:
         """Request cancellation (client gone, timeout, user abort): the
@@ -164,7 +174,7 @@ class GenerationStream:
             item = self._q.get()
             if item is self._DONE:
                 return
-            yield item
+            yield from item
 
     def result(self, timeout: Optional[float] = None) -> List[int]:
         """Block until the stream finishes; returns all generated ids."""
@@ -186,15 +196,27 @@ class GenerationStream:
                     f"stream {self.stream_id}: no token within {timeout}s")
             if item is self._DONE:
                 return out
-            out.append(item)
+            out.extend(item)
 
     # engine-side
-    def _emit(self, tok: int, logprob: float = 0.0):
+    def _emit_block(self, toks: List[int], logprobs: List[float],
+                    wake: bool = True):
+        """One program's tokens for this stream, in order. They reach the
+        queue as ONE item, so a reader blocked on it wakes once for the
+        block: now, or with ``wake=False`` at the next ``_wake`` (the
+        end mark's at the latest)."""
         if not self.tokens:
             self.first_t = _time.monotonic()
-        self.tokens.append(tok)
-        self.logprobs.append(logprob)
-        self._q.put(tok)
+        self.tokens.extend(toks)
+        self.logprobs.extend(logprobs)
+        self._unsent.extend(toks)
+        if wake:
+            self._wake()
+
+    def _wake(self):
+        if self._unsent:
+            self._q.put(self._unsent)
+            self._unsent = []
 
     def _finish(self, reason: str):
         if self.finished:
@@ -203,6 +225,7 @@ class GenerationStream:
         self.finish_reason = reason
         if self.finish_t is None:  # the engine stamps before its book-keeping
             self.finish_t = _time.monotonic()
+        self._wake()
         self._q.put(self._DONE)
 
 
@@ -584,6 +607,9 @@ class ContinuousBatchingEngine:
         self._thread: Optional[threading.Thread] = None
         self.stats: Dict[str, Any] = {
             "tokens_generated": 0, "dispatches": 0, "prefills": 0,
+            # hand-overs of a program's tokens to a stream (``_hand_over``):
+            # one a stream a dispatch, one a first token
+            "emit_blocks": 0,
             "prefill_chunks": 0, "slot_steps": 0, "active_slot_steps": 0,
             "prefix_hits": 0, "prefix_tokens_reused": 0,
             "concurrent_streams_max": 0, "kv_sheds": 0, "kv_defers": 0,
@@ -690,6 +716,9 @@ class ContinuousBatchingEngine:
         #: ADMITTED stream lives here whether or not it currently
         #: holds one of the B decode lanes.
         self._sstate: Dict[int, dict] = {}
+        #: streams that go on whose block the last hand-over held back:
+        #: put once the next dispatch is queued (``_wake_streams``)
+        self._unwoken: List[GenerationStream] = []
         #: admission head deferred on block exhaustion (FIFO order
         #: is preserved: nothing behind it admits until it fits)
         self._held: Optional[_PendingRequest] = None
@@ -1136,12 +1165,9 @@ class ContinuousBatchingEngine:
         st.admit_t = _time.monotonic()
         self._m_queue_wait.observe(st.admit_t - st.submit_t)
 
-    def _emit_first(self, st: GenerationStream, tok: int,
-                    logprob: float) -> None:
-        """The first token reaches its stream: the third stamp, and what
+    def _read_first_stamps(self, st: GenerationStream) -> None:
+        """The first token has reached its stream (the third stamp): what
         reads the first three."""
-        st._emit(tok, logprob)
-        self.stats["tokens_generated"] += 1
         self._lm_stats.observe_ttft(st.first_t - st.submit_t)
         self.stats["admissions"] += 1
         self.stats["admit_wait_us"] += int((st.admit_t - st.submit_t) * 1e6)
@@ -1413,6 +1439,7 @@ class ContinuousBatchingEngine:
             self._bt[slot, :len(st["blocks"])] = st["blocks"]
             run.append(st)
         if not run:
+            self._wake_streams()
             return
         last = np.zeros(self.B, np.int32)
         pos = np.zeros(self.B, np.int32)
@@ -1426,32 +1453,21 @@ class ContinuousBatchingEngine:
             jnp.asarray(pos))
         self._pool.arena = arena
         sp["dcache"] = dcache
-        tgt = np.asarray(tgt)
+        _start_host_copies(tgt, lps, n_emit)
+        self._wake_streams()
+        tgt, lps, n_emit = np.asarray(tgt), np.asarray(lps), np.asarray(n_emit)
         self._fetched(self._dev_enq)
-        lps = np.asarray(lps)
-        n_emit = np.asarray(n_emit)
         self.invoke_stats.record(self._phase("dispatch") - t0)
         self.stats["slot_steps"] += self.B * (g + 1)
-        for st in run:
-            if self._sstate.get(st["sid"]) is not st:
-                continue
-            slot = st["slot"]
-            m = int(n_emit[slot])
-            self.stats["spec_drafted"] += g
-            self.stats["spec_accepted"] += m - 1
-            # rejected drafts roll the block-table tail pointer back by
-            # construction: pos advances only m, and the stale kv above
-            # it is overwritten before it is ever attended
-            st["pos"] += m
-            st["last"] = int(tgt[slot, m - 1])
-            for j in range(m):
-                tok = int(tgt[slot, j])
-                self.stats["tokens_generated"] += 1
-                self.stats["active_slot_steps"] += 1
-                st["stream"]._emit(tok, float(lps[slot, j]))
-                self._post_emit_paged(st, tok)
-                if self._sstate.get(st["sid"]) is not st:
-                    break
+        run = [st for st in run if self._sstate.get(st["sid"]) is st]
+        rows = [st["slot"] for st in run]
+        m = n_emit[rows]
+        self.stats["spec_drafted"] += g * len(run)
+        self.stats["spec_accepted"] += int(m.sum()) - len(run)
+        # rejected drafts roll the block-table tail pointer back by
+        # construction: pos advances only m, and the stale kv above it is
+        # overwritten before it is ever attended
+        self._hand_over(run, rows, tgt, lps, m)
         self._phase("emit")
         self.stats["dispatches"] += 1
 
@@ -1730,28 +1746,79 @@ class ContinuousBatchingEngine:
     def _activate_commit_paged(self, rec) -> None:
         req, state, first_d, key_d, lp_d, ticket = rec
         self.stats["prefills"] += 1
-        first = int(np.asarray(first_d)[0])
+        first, lp, key = (np.asarray(first_d), np.asarray(lp_d),
+                          np.asarray(key_d))
         # the last admitted record of a boundary empties the queue; the
         # earlier ones' tickets lie behind programs still queued
         self._fetched(ticket)
-        state["last"] = first
-        state["key"] = np.asarray(key_d)[0].copy()
-        self._emit_first(req.stream, first, float(np.asarray(lp_d)[0]))
-        self._post_emit_paged(state, first)
+        self._hand_over([state], [0], first[:, None], lp[:, None], 1,
+                        keys=key, first=True)
 
-    def _post_emit_paged(self, state, tok: int) -> None:
-        state["budget"] -= 1
-        done_eos = self.eos_id is not None and tok == self.eos_id
-        done = done_eos or state["budget"] <= 0
-        if done and self._slo is not None:
-            now = _time.monotonic()
-            t0 = state["stream"].submit_t
-            self._slo.observe_completion(now - t0, now, frames=1)
-            self._slo.observe_service(now - t0, frames=1)
-        if done_eos:
-            self._finish_paged(state, "eos")
-        elif state["budget"] <= 0:
-            self._finish_paged(state, "length")
+    def _hand_over(self, run, rows, toks, lps, counts, keys=None,
+                   first: bool = False) -> None:
+        """Give each stream of ``run`` what one program made for it, as
+        ONE block: row ``rows[i]`` of ``toks`` / ``lps`` (``[b, n]``), of
+        which the first ``counts[i]`` (or ``counts`` for every row) are
+        tokens. A stream keeps them up to and including the first
+        ``eos_id`` and at most its budget, found with numpy over the
+        block; then one put a stream, so that its client wakes once. Its
+        last token, budget and position follow (a first token moves no
+        position), its key is row ``rows[i]`` of ``keys`` where given. A
+        stream that ends finishes ``eos`` or ``length`` with its block
+        put before its end mark, its blocks and lane back in the pool
+        before the end mark wakes the client; one of a family with window
+        layers that goes on gives back what now lies behind its window.
+        The block of a stream that goes on is put once the next dispatch
+        is queued (``_wake_streams``): its client's thread then runs
+        while the device works, not between two dispatches. ``first``:
+        the first token of each stream, put at once, with the stamps it
+        completes."""
+        rows = np.asarray(rows, np.int64)
+        toks, lps = toks[rows], lps[rows]
+        counts = np.broadcast_to(counts, rows.shape)
+        keep = np.minimum(counts, np.fromiter(
+            (st["budget"] for st in run), np.int64, len(run)))
+        eos = np.zeros(rows.shape, bool)
+        if self.eos_id is not None:
+            hit = toks == self.eos_id
+            at = np.where(hit.any(axis=1), hit.argmax(axis=1), toks.shape[1])
+            eos = at < keep
+            keep = np.where(eos, at + 1, keep)
+        total = int(keep.sum())
+        self.stats["tokens_generated"] += total
+        if not first:
+            self.stats["active_slot_steps"] += total
+        self.stats["emit_blocks"] += len(run)
+        last = toks[np.arange(len(run)), counts - 1].tolist()
+        toks, lps = toks.tolist(), lps.tolist()
+        for i, st in enumerate(run):
+            k = int(keep[i])
+            st["stream"]._emit_block(toks[i][:k], lps[i][:k], wake=first)
+            st["budget"] -= k
+            st["last"] = last[i]
+            if keys is not None:
+                st["key"] = keys[rows[i]].copy()
+            if first:
+                self._read_first_stamps(st["stream"])
+            else:
+                st["pos"] += int(counts[i])
+            if eos[i] or st["budget"] <= 0:
+                if self._slo is not None:
+                    now = _time.monotonic()
+                    t0 = st["stream"].submit_t
+                    self._slo.observe_completion(now - t0, now, frames=1)
+                    self._slo.observe_service(now - t0, frames=1)
+                self._finish_paged(st, "eos" if eos[i] else "length")
+            elif not first:
+                self._unwoken.append(st["stream"])
+                if self._window is not None:
+                    self._release_behind(st)
+
+    def _wake_streams(self) -> None:
+        """Put the blocks the last hand-over held back."""
+        streams, self._unwoken = self._unwoken, []
+        for stream in streams:
+            stream._wake()
 
     def _finish_paged(self, state, reason: str) -> None:
         """Stream teardown: blocks return to the pool BEFORE the client
@@ -1886,6 +1953,7 @@ class ContinuousBatchingEngine:
                 self._bt_w[slot, lo:lo + len(st["wblocks"])] = st["wblocks"]
             run.append(st)
         if not run:
+            self._wake_streams()
             return
         last = np.zeros(self.B, np.int32)
         pos = np.zeros(self.B, np.int32)
@@ -1913,10 +1981,12 @@ class ContinuousBatchingEngine:
                              else jnp.asarray(self._bt_w)),
                 jnp.asarray(pos), jnp.asarray(keys))
         self._pool.arena = arena
-        toks = np.asarray(toks)
+        # every copy started before the first blocking read: one wait;
+        # the last dispatch's clients run while the device works
+        _start_host_copies(toks, lps, keys_d, *counted)
+        self._wake_streams()
+        toks, lps, keys = np.asarray(toks), np.asarray(lps), np.asarray(keys_d)
         self._fetched(self._dev_enq)
-        lps = np.asarray(lps)
-        keys_np = np.asarray(keys_d)
         if counted:  # ready with the tokens: the same program made them
             for name, n in zip(self._counters, np.asarray(counted[0])):
                 self.stats[name] += int(n)
@@ -1924,24 +1994,10 @@ class ContinuousBatchingEngine:
         # phase in which the device works for decoding
         self.invoke_stats.record(self._phase("dispatch") - t0)
         self.stats["slot_steps"] += self.B * self.K
-        for st in run:
-            if self._sstate.get(st["sid"]) is not st:
-                continue
-            slot = st["slot"]
-            st["key"] = keys_np[slot].copy()
-            st["pos"] += self.K
-            st["last"] = int(toks[slot, -1])
-            for j in range(self.K):
-                tok = int(toks[slot, j])
-                self.stats["tokens_generated"] += 1
-                self.stats["active_slot_steps"] += 1
-                st["stream"]._emit(tok, float(lps[slot, j]))
-                self._post_emit_paged(st, tok)
-                if self._sstate.get(st["sid"]) is not st:
-                    break  # EOS/length/shed mid-block: drop the tail
-            if self._window is not None and \
-                    self._sstate.get(st["sid"]) is st:
-                self._release_behind(st)
+        # a stream shed while a later one topped up has no tokens here
+        run = [st for st in run if self._sstate.get(st["sid"]) is st]
+        self._hand_over(run, [st["slot"] for st in run], toks, lps, self.K,
+                        keys=keys)
         self._phase("emit")
         self.stats["dispatches"] += 1
 
@@ -2016,10 +2072,7 @@ class ContinuousBatchingEngine:
                 # first-token sample
                 self._phase("admit", **req.who())
             for rec in admitted:  # start all fetches before blocking
-                for d in (rec[2], rec[3], rec[4]):
-                    start_async = getattr(d, "copy_to_host_async", None)
-                    if start_async is not None:
-                        start_async()
+                _start_host_copies(*rec[2:5])
             for rec in admitted:
                 try:
                     self._activate_commit_paged(rec)

@@ -439,7 +439,7 @@ def test_time_between_a_fetch_and_the_next_enqueue_is_starved_by_phase(
         stepped.step_before(eng, "_topup", 4000.0, once("select"))
         stepped.step_before(eng, "_dispatch", 8000.0, once("launch"))
         stepped.step_before(eng, "_fetched", 16000.0, once("fetched"))
-        stepped.step_before(eng, "_post_emit_paged", 1000.0, once("emit"))
+        stepped.step_before(eng, "_hand_over", 1000.0, once("emit"))
         inner = eng._fetched
 
         def arm(ticket):
