@@ -378,6 +378,7 @@ AFMOE = ModelFamily(
                           (cfg.n_kv_heads, cfg.head_dim)),
     kv_window=lambda cfg: (cfg.window_layers, cfg.window),
     counters=COUNTERS, expert_matmul=hybrid.expert_matmul,
+    token_share=hybrid.routed_share,
     refusal="keeps the window layers' keys and values in a block arena of "
             "their own whose blocks go back behind the window: nothing "
             "shares, chunks, narrows or shards two arenas yet (ROADMAP.md "

@@ -15,6 +15,7 @@ tree a server's programs will read from the tree it was given.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 
@@ -73,10 +74,11 @@ class ModelFamily:
     lane_state: Callable = lambda cfg: None
     #: names of the per-step counts the decode step returns
     counters: Tuple[str, ...] = ()
-    #: ``prefill_counters(cfg, rows) -> {name: n}``: what ONE prompt's
-    #: prefill over a bucket of ``rows`` positions computed, for a family
+    #: ``prefill_counters(cfg, rows) -> {name: n}``: what a prefill over a
+    #: bucket of ``rows`` positions computed of ONE row, for a family
     #: whose prefill does not run every layer over every row (``rows`` 0
-    #: names the counters); the engine sums them into ``stats``
+    #: names the counters); the engine sums them into ``stats`` for every
+    #: row of a program, a row that holds no prompt too
     prefill_counters: Optional[Callable] = None
     #: ``expert_matmul(cfg, tokens) -> form``: the form the family's
     #: routed experts run in for that many tokens a call
@@ -104,6 +106,70 @@ class ModelFamily:
     #: other width; what a program reads as stored (norm scales, a head's
     #: float32 table) is not named
     read_in_dtype: Tuple[str, ...] = ()
+    #: ``token_share(cfg) -> {leaf name: share}``: for a leaf that one
+    #: token's matmuls read only a share of (routed experts: ``experts per
+    #: token / router outputs`` of the experts held), that share; a matrix
+    #: not named is read whole (:func:`token_params`)
+    token_share: Callable = lambda cfg: {}
+
+
+#: the chip's published peaks, the ones ``PERF.md``'s rooflines divide by
+#: (Google Cloud, "TPU v5e": 197 TFLOP/s bfloat16, 819 GB/s of HBM; the
+#: benchmark's ``benchmark/peaks.json`` holds the same)
+PEAK_FLOPS = 197e12
+PEAK_HBM_BYTES_PER_S = 819e9
+#: the share of that arithmetic peak a prefill program reaches, measured
+#: on the chip: a qwen3-next prefill of R x s tokens adds 17.5-18.5 ms a
+#: thousand tokens where its 2 x 208 M parameters a token take 2.1 ms at
+#: the peak (11-12 %: the delta rule's row-by-row solve and its float32
+#: products are in it), a granite one 52-64 us a token against 16.5 (26-32
+#: %). A program's rows cost the same whether they hold a prompt or not, so
+#: the rows rule takes the lower share: one that is too high batches past
+#: the point where the rows' own work outgrows the weight read it shares
+PREFILL_PEAK_SHARE = 0.12
+#: the most prompts one prefill program takes (:func:`prefill_rows`)
+PREFILL_ROWS_MAX = 8
+#: leaves a prompt's tokens do not multiply by: the embedding is looked up
+#: a row a token, the head multiplies one row a prompt
+_ONCE_A_PROMPT = ("embed", "lm_head")
+
+
+def token_params(cfg, params) -> float:
+    """The parameters one prompt token's matmuls read, from the shapes of
+    the tree ``params`` alone (arrays or ``jax.ShapeDtypeStruct``): every
+    matrix (a leaf of two or more axes) but the embedding and the head, a
+    leaf the family names in ``token_share`` at its share."""
+    import jax
+
+    shares = cfg.family.token_share(cfg)
+    total = 0.0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        name = getattr(path[-1], "key", None)
+        if len(leaf.shape) >= 2 and name not in _ONCE_A_PROMPT:
+            total += math.prod(leaf.shape) * shares.get(name, 1.0)
+    return total
+
+
+def balance_tokens(cfg, params, weight_bytes: int) -> float:
+    """T_w: the prompt tokens whose matmuls take as long, at the rate a
+    prefill reaches, as one read of ``weight_bytes`` (the weights held) at
+    the HBM peak: ``weight_bytes / 819e9 x 197e12 x PREFILL_PEAK_SHARE /
+    (2 x token_params)``. A prefill over fewer tokens than this waits on
+    its weights, not on its arithmetic."""
+    return (weight_bytes / PEAK_HBM_BYTES_PER_S * PEAK_FLOPS
+            * PREFILL_PEAK_SHARE
+            / (2.0 * max(token_params(cfg, params), 1.0)))
+
+
+def prefill_rows(balance: float, bucket: int) -> int:
+    """Prompts one prefill program over ``bucket`` positions takes: the
+    largest power of two at most ``min(PREFILL_ROWS_MAX, balance /
+    bucket)``, and 1 below two. Each row then costs its share of ONE read
+    of the weights where, alone, it would cost a whole read."""
+    rows = 1
+    while rows < PREFILL_ROWS_MAX and 2 * rows * bucket <= balance:
+        rows *= 2
+    return rows
 
 
 def serving_params(cfg, params) -> Tuple[Any, Dict[str, int]]:

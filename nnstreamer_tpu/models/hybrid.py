@@ -318,6 +318,14 @@ def expert_matmul(cfg: HybridConfig, t: int) -> str:
         jax.ShapeDtypeStruct((cfg.n_held, f, d), cfg.dtype), tile)
 
 
+def routed_share(cfg) -> Dict[str, float]:
+    """The share of the held experts' leaves one token's matmuls read:
+    ``experts_per_token`` of ``num_experts`` router outputs
+    (``ModelFamily.token_share``)."""
+    return dict.fromkeys(("w_in", "w_out"),
+                         cfg.experts_per_token / cfg.num_experts)
+
+
 def moe_ffn(h, lp, cfg: HybridConfig, live=None):
     """The routed experts' part of the layer that is held here, for tokens
     ``h [t, d]`` (already normed): ``(out [t, d] float32, counts)``.
@@ -778,6 +786,9 @@ def _prompt_layers(params, tokens, lengths, cfg: HybridConfig,
     dtype, r = cfg.dtype, cfg.residual_multiplier
     x = _embed(params, tokens, cfg)
     positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
+    # a prompt's padding, and a row that holds no prompt, are left out of
+    # the routing
+    real = positions < lengths[:, None]
     kv, ssm, conv = [], [], []
     for kind, lp in zip(cfg.layer_types, params["layers"]):
         h = _rmsnorm(x, lp["ln1"], cfg.rms_eps)
@@ -794,7 +805,7 @@ def _prompt_layers(params, tokens, lengths, cfg: HybridConfig,
                 out = _attn_out(a, gate, lp, dtype)
             kv.append(jnp.stack([k, v]))
         x = x + r * out
-        x, _ = _expert_layer(x, lp, cfg)
+        x, _ = _expert_layer(x, lp, cfg, real)
     return x, {"kv": jnp.stack(kv), "state": {
         "ssm": jnp.stack(ssm), "conv": jnp.stack(conv)}}
 
@@ -901,7 +912,7 @@ HYBRID = ModelFamily(
     kv_entry=lambda cfg: (cfg.attn_layers, 2,
                           (cfg.n_kv_heads, cfg.head_dim)),
     lane_state=lane_state, counters=COUNTERS, expert_matmul=expert_matmul,
-    state_update=state_update,
+    state_update=state_update, token_share=routed_share,
     refusal="keeps recurrent state per decode lane, which nothing can copy, "
             "share, shard or narrow yet (ROADMAP.md R3; mesh: R2)",
     # every matrix but the embedding, whose lookup (``_embed``) widens the

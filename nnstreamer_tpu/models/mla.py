@@ -412,6 +412,7 @@ MLA = ModelFamily(
     kv_entry=lambda cfg: (cfg.n_layers, 1, (cfg.row_store,)),
     latent_value_width=lambda cfg: cfg.kv_lora_rank,
     counters=COUNTERS, expert_matmul=hybrid.expert_matmul,
+    token_share=hybrid.routed_share,
     refusal="keeps one latent row a token that every head shares: no paged "
             "chunk of several query positions a lane reads such rows and "
             "no codec narrows one (ROADMAP.md R5; mesh: R2, R11)",

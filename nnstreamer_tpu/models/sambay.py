@@ -706,9 +706,9 @@ def state_update(cfg: SambaYConfig, lanes: int) -> str:
 
 
 def prefill_counters(cfg: SambaYConfig, rows: int) -> dict:
-    """What a prefill over a bucket of ``rows`` positions a prompt computed:
-    rows the self-decoder ran (stage 1) and rows the cross-decoder ran
-    (stage 2: one a prompt)."""
+    """What a prefill over a bucket of ``rows`` positions computed of one
+    row of its batch: rows the self-decoder ran (stage 1) and rows the
+    cross-decoder ran (stage 2: one a row)."""
     return {"prefill_rows_self": rows,
             "prefill_rows_cross": min(rows, 1)}
 

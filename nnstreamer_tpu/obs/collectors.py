@@ -102,6 +102,10 @@ def register_engine_collector(engine, registry: MetricsRegistry = None
                     "work hidden behind device work",
                     phase=key[len("starved_"):-len("_us")],
                     **labels).set_total(us / 1e6)
+        reg.counter("nns_serving_prefill_programs_total",
+                    "Prefill programs of cold admissions; admissions less "
+                    "this are the prompts that rode in another's program",
+                    **labels).set_total(eng.stats["prefill_programs"])
         reg.counter("nns_serving_emit_blocks_total",
                     "Hand-overs of a program's tokens to a stream, one "
                     "block and one wake-up each: one a stream a dispatch, "

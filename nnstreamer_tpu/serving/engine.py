@@ -24,7 +24,12 @@ The TPU-first serving design, contrasted with the reference's query server
   so prefill compiles once per bucket, not once per prompt length. Logits
   come from the true last position (``build_prefill`` lengths arg), and
   pad kv entries are provably unreachable (see models/transformer.py
-  build_prefill docstring).
+  build_prefill docstring). A bucket's ONE program takes a fixed number
+  of prompts, set by the model's shapes (``models/family.py
+  prefill_rows``): where one prompt's tokens are too few to keep the
+  chip's arithmetic busy while the weights stream in, the cold prompts
+  admitted at one loop boundary share a program, and its one read of the
+  weights.
 - **Stream-local determinism.** Each stream's PRNG key is derived from
   (engine seed, stream id), so sampled output is reproducible regardless
   of which other streams share the batch — per-stream results never
@@ -314,6 +319,33 @@ class _PendingRequest:
                 "prompt": int(self.prompt.size)}
 
 
+class _ColdAdmission:
+    """A cold admission whose blocks (and lane, window blocks) are held and
+    whose prefill is not yet enqueued: it waits for the other prompts of
+    its loop boundary, to share their prefill program."""
+
+    __slots__ = ("req", "blocks", "wblocks", "wfirst", "lane", "bucket")
+
+    def __init__(self, req, blocks, wblocks, wfirst, lane, bucket):
+        self.req, self.blocks, self.lane = req, blocks, lane
+        self.wblocks, self.wfirst, self.bucket = wblocks, wfirst, bucket
+
+    def scatter_row(self, block_tokens: int) -> tuple:
+        """Where its prefill's row goes (``BlockPool.scatter_rows``): the
+        blocks that hold the prompt, its lane, and the window blocks that
+        hold the prompt's positions from ``wfirst``'s block on."""
+        held = -(-self.req.prompt.size // block_tokens)
+        return (self.blocks[:held], self.lane,
+                self.wblocks[:held - self.wfirst], self.wfirst)
+
+    def release(self, pool) -> None:
+        pool.release(self.blocks)
+        if self.wblocks:
+            pool.win.release(self.wblocks)
+        if self.lane is not None:
+            pool.release_lane(self.lane)
+
+
 class ContinuousBatchingEngine:
     """Batched multi-stream generation over one transformer model.
 
@@ -489,6 +521,19 @@ class ContinuousBatchingEngine:
         #: (``serving_params``; the class docstring, "cfg, params")
         self.params, self.weights = serving_params(cfg, params)
         del params  # not kept: nothing below may read the given tree
+        from nnstreamer_tpu.models.family import balance_tokens
+
+        #: T_w, the prompt tokens whose matmuls take the chip as long as one
+        #: read of the held weights (``_prefill_rows``). 0 where a cold
+        #: prompt always has a program of its own: a ``mesh=`` engine (its
+        #: programs are sharded, its weights read a shard a chip) and the
+        #: options whose admissions bind a prompt's own results at once
+        #: (a prefix entry keeps its logits and a later prompt of the same
+        #: boundary may read its blocks; a chunk is one prompt's)
+        self._balance = 0.0 if (mesh is not None or prefix_cache
+                                or prefill_chunk is not None) \
+            else balance_tokens(cfg, self.params,
+                                self.weights["weight_bytes_held"])
         self.B = int(max_streams)
         self.S = int(max_seq or cfg.max_seq)
         self.block_tokens = int(block_tokens or 0)
@@ -629,10 +674,14 @@ class ContinuousBatchingEngine:
             "admissions": 0, "admit_wait_us": 0, "first_token_us": 0,
             "stalls": 0,
             # prompt tokens the prefill, prefix-extension and chunk programs
-            # were given, the rows they computed (the bucket, the chunk),
+            # were given, the rows they computed (the bucket, the chunk;
+            # every row of a cold prefill, rows that hold no prompt too),
             # and the loop iterations that admitted at least one request
             "prefill_tokens": 0, "prefill_bucket_tokens": 0,
             "admit_boundaries": 0,
+            # the cold admissions' prefill programs: ``admissions`` less
+            # this is the prompts that rode in another's program
+            "prefill_programs": 0,
             # blocks the paged decode steps had to read (every lane's
             # live blocks, step by step) and blocks their tables name
             "kv_blocks_live": 0, "kv_blocks_table": 0,
@@ -1084,10 +1133,9 @@ class ContinuousBatchingEngine:
         device runs programs in order. So the queue is empty exactly when
         the newest result a blocking fetch has returned (``_fetched``) is
         that of the newest program enqueued here. It stops being empty
-        the instant this call RETURNS: the uploads (``jnp.asarray``) and
-        the program of its own that ``jnp.asarray([n], jnp.int32)`` runs
-        are evaluated before it as its arguments, host time in which the
-        chip does next to nothing. One clock read, and only for the first
+        the instant this call RETURNS: the uploads (``jnp.asarray``) are
+        evaluated before it as its arguments, host time in which the chip
+        does next to nothing. One clock read, and only for the first
         enqueue into an empty queue; ``_phase`` does the sums."""
         out = program(*args, **kwargs)
         self._dev_enq += 1
@@ -1199,6 +1247,16 @@ class ContinuousBatchingEngine:
         while b < n:
             b *= 2
         return min(b, self.S)
+
+    def _prefill_rows(self, bucket: int) -> int:
+        """The prompts the ONE prefill program of ``bucket`` takes
+        (``models/family.py prefill_rows``); 1 under speculation, whose
+        admission binds a draft slot a prompt."""
+        from nnstreamer_tpu.models.family import prefill_rows
+
+        if self._spec is not None:
+            return 1
+        return prefill_rows(self._balance, bucket)
 
     #: process-wide sequence behind ``obs_name`` (engine0, engine1, ...)
     _OBS_SEQ = itertools.count()
@@ -1420,7 +1478,7 @@ class ContinuousBatchingEngine:
         padded[0, :n] = req.prompt
         _lg, dcache1 = self._enqueue(
             sp["prefill"], sp["dparams"], jnp.asarray(padded),
-            lengths=jnp.asarray([n], jnp.int32))
+            lengths=jnp.asarray(np.asarray([n], np.int32)))
         sp["dcache"] = self._enqueue(sp["insert"], sp["dcache"], dcache1,
                                      slot)
 
@@ -1590,8 +1648,8 @@ class ContinuousBatchingEngine:
                                   fresh[0])
                 self.stats["prefix_hits"] += 1
                 self.stats["prefix_tokens_reused"] += n
-                return self._activate_begin_paged(req, cached_logits,
-                                                  blocks)
+                return self._activate_begin_paged([req], cached_logits,
+                                                  [blocks])
             except Exception:
                 self._pool.release(blocks)
                 raise
@@ -1618,14 +1676,14 @@ class ContinuousBatchingEngine:
                 logits, arena = self._enqueue(
                     self._paged_chunk_jitted,
                     self.params, jnp.asarray(toks), self._pool.arena,
-                    jnp.asarray(bt), jnp.asarray([q], jnp.int32),
-                    jnp.asarray([rem], jnp.int32))
+                    jnp.asarray(bt), jnp.asarray(np.asarray([q], np.int32)),
+                    jnp.asarray(np.asarray([rem], np.int32)))
                 self._pool.arena = arena
                 self.stats["prefill_tokens"] += rem
                 self.stats["prefill_bucket_tokens"] += c
                 logits = logits[:, rem - 1]
                 self._prefix_store_paged(prompt, blocks, logits)
-                return self._activate_begin_paged(req, logits, blocks)
+                return self._activate_begin_paged([req], logits, [blocks])
             except Exception:
                 self._pool.release(blocks)
                 raise
@@ -1646,34 +1704,85 @@ class ContinuousBatchingEngine:
         lane = self._pool.alloc_lane() if self._lane_state else None
         if self._lane_state and lane is None:  # every lane taken: defer
             self._pool.release(blocks)
-            return None
-        try:
-            bucket = self._bucket(n)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :n] = prompt
-            logits, cache1 = self._enqueue(
-                self._prefill_jitted, self.params, jnp.asarray(padded),
-                lengths=jnp.asarray([n], jnp.int32))
-            self.stats["prefill_tokens"] += n
-            self.stats["prefill_bucket_tokens"] += bucket
-            if self._prefill_counters:
-                for name, rows in self._prefill_counters(self.cfg,
-                                                         bucket).items():
-                    self.stats[name] += rows
-            self._enqueue(self._pool.scatter_prefill, cache1,
-                          blocks[:(n + T - 1) // T], lane=lane,
-                          window_ids=wblocks[:(n + T - 1) // T - wfirst],
-                          window_first=wfirst)
-            self._prefix_store_paged(prompt, blocks, logits)
-            rec = self._activate_begin_paged(req, logits, blocks, lane)
-            rec[1].update(wblocks=wblocks, wfirst=wfirst)
-            return rec
-        except Exception:
-            self._pool.release(blocks)
             if wblocks:
                 self._pool.win.release(wblocks)
-            if lane is not None:
-                self._pool.release_lane(lane)
+            return None
+        cold = _ColdAdmission(req, blocks, wblocks, wfirst, lane,
+                              self._bucket(n))
+        if self._prefill_rows(cold.bucket) > 1:
+            return cold  # prefilled with the boundary's others
+        return self._prefill_group([cold], 1)
+
+    def _prefill_groups(self, cold: List[_ColdAdmission]) -> List[tuple]:
+        """A loop boundary's cold admissions as prefill programs:
+        ``[(admissions, rows)]``. A program's bucket is its longest
+        prompt's and it takes up to that bucket's rows; the admissions are
+        taken greedily, shortest bucket first (in admission order within a
+        bucket), so that a short prompt rides with prompts of its own
+        bucket: a row costs its program's bucket whether or not it holds a
+        prompt that long."""
+        groups: List[tuple] = []
+        for c in sorted(cold, key=lambda c: c.bucket):
+            if groups:
+                group, _ = groups[-1]
+                rows = self._prefill_rows(
+                    max(c.bucket, max(g.bucket for g in group)))
+                if len(group) < rows:
+                    group.append(c)
+                    groups[-1] = (group, rows)
+                    continue
+            groups.append(([c], self._prefill_rows(c.bucket)))
+        return groups
+
+    def _prefill_group(self, group: List[_ColdAdmission], rows: int):
+        """ONE prefill program over ``rows`` rows, of which the first hold
+        ``group``'s prompts right-padded to the bucket of the longest (a
+        row past them holds no prompt: length 0, no block, no lane); ONE
+        scatter of every row's cache and state; ONE first-token sample over
+        the rows. The activation record of the group's streams."""
+        jnp = self._jnp
+        T = self.block_tokens
+        bucket = max(c.bucket for c in group)
+        tokens = np.zeros((rows, bucket), np.int32)
+        lengths = np.zeros(rows, np.int32)
+        for i, c in enumerate(group):
+            lengths[i] = c.req.prompt.size
+            tokens[i, :lengths[i]] = c.req.prompt
+        try:
+            logits, cache = self._enqueue(
+                self._prefill_jitted, self.params, jnp.asarray(tokens),
+                lengths=jnp.asarray(lengths))
+            self.stats["prefill_programs"] += 1
+            self.stats["prefill_tokens"] += int(lengths.sum())
+            self.stats["prefill_bucket_tokens"] += rows * bucket
+            if self._prefill_counters:
+                for name, n in self._prefill_counters(self.cfg,
+                                                      bucket).items():
+                    self.stats[name] += rows * n
+            if rows == 1:
+                # the pool's one-prompt hand-over, as before rows (the
+                # benchmark's window control, ``benchmark/controls_afmoe.py``,
+                # wraps it); a prefix entry keeps a one-prompt program's
+                # logits
+                c = group[0]
+                block_ids, lane, window_ids, wfirst = c.scatter_row(T)
+                self._enqueue(self._pool.scatter_prefill, cache, block_ids,
+                              lane=lane, window_ids=window_ids,
+                              window_first=wfirst)
+                self._prefix_store_paged(c.req.prompt, c.blocks, logits)
+            else:
+                self._enqueue(self._pool.scatter_rows, cache,
+                              [c.scatter_row(T) for c in group]
+                              + [None] * (rows - len(group)))
+            rec = self._activate_begin_paged(
+                [c.req for c in group], logits, [c.blocks for c in group],
+                [c.lane for c in group])
+            for state, c in zip(rec[1], group):
+                state.update(wblocks=c.wblocks, wfirst=c.wfirst)
+            return rec
+        except Exception:
+            for c in group:
+                c.release(self._pool)
             raise
 
     def _activate_paged_from_cache1(self, req: _PendingRequest, logits,
@@ -1689,7 +1798,7 @@ class ContinuousBatchingEngine:
             self._enqueue(self._pool.scatter_prefill, cache1,
                           blocks[:(n + T - 1) // T])
             self._prefix_store_paged(req.prompt, blocks, logits)
-            return self._activate_begin_paged(req, logits, blocks)
+            return self._activate_begin_paged([req], logits, [blocks])
         except Exception:
             self._pool.release(blocks)
             raise
@@ -1705,54 +1814,61 @@ class ContinuousBatchingEngine:
         self._partial = (req, self._enqueue(
             init_cache, self.cfg, 1, self.S, kv_codec=self.kv_quant), 0)
 
-    def _activate_begin_paged(self, req: _PendingRequest, logits, blocks,
-                              lane: Optional[int] = None):
-        """Device half of an activation: sample the first token,
-        create the stream's decode state. No lane is claimed (EDF
-        binds lanes per dispatch) — except where a stream is pinned to
-        a lane for life: in speculative mode (the draft cache is
-        slot-structured) and for a family with lane state, whose
-        admission claimed ``lane`` before its prefill was scattered."""
+    def _activate_begin_paged(self, reqs: List[_PendingRequest], logits,
+                              blocks: List[list], lanes=None):
+        """Device half of an activation: sample the first token of each
+        of ``reqs`` (row i of ``logits``; a row past them holds no prompt)
+        in one program, create the streams' decode states. No lane is
+        claimed (EDF binds lanes per dispatch) — except where a stream is
+        pinned to a lane for life: in speculative mode (the draft cache is
+        slot-structured) and for a family with lane state, whose admission
+        claimed ``lanes[i]`` before its prefill was scattered. The record
+        ``(reqs, states, first, keys, logprobs, ticket)``."""
         jnp = self._jnp
-        stream = req.stream
-        sid = stream.stream_id
-        key = np.asarray([self.seed & 0xFFFFFFFF, sid & 0xFFFFFFFF],
-                         np.uint32)[None]
+        keys = np.zeros((logits.shape[0], 2), np.uint32)
+        keys[:len(reqs), 0] = self.seed & 0xFFFFFFFF
+        keys[:len(reqs), 1] = [r.stream.stream_id & 0xFFFFFFFF
+                               for r in reqs]
         first_d, key_d, lp_d = self._enqueue(self._sample_first, logits,
-                                             jnp.asarray(key))
+                                             jnp.asarray(keys))
         ticket = self._dev_enq  # what the first token's fetch will prove run
-        n = req.prompt.size
         slo_s = self._slo.budget_s if self._slo is not None else 60.0
-        state = {
-            "sid": sid, "stream": stream, "blocks": list(blocks),
-            "pos": n, "last": 0, "key": np.zeros(2, np.uint32),
-            # cap writes inside S (a verify chunk writes through pos+K)
-            "budget": min(req.max_new, self.S - n - self.speculate),
-            #: absolute deadline feeding the per-token EDF key
-            "deadline_t": stream.submit_t + slo_s,
-            "slot": None,
-        }
-        self._sstate[sid] = state
-        if lane is not None:
-            self._lane[lane] = sid
-            state["slot"] = stream.lane = lane
-        elif self._spec is not None:
-            slot = self._lane.index(None)
-            self._lane[slot] = sid
-            state["slot"] = slot
-            self._draft_prefill(req, slot)
-        return (req, state, first_d, key_d, lp_d, ticket)
+        states = []
+        for req, ids, lane in zip(reqs, blocks, lanes or [None] * len(reqs)):
+            stream = req.stream
+            sid = stream.stream_id
+            n = req.prompt.size
+            state = {
+                "sid": sid, "stream": stream, "blocks": list(ids),
+                "pos": n, "last": 0, "key": np.zeros(2, np.uint32),
+                # cap writes inside S (a verify chunk writes through pos+K)
+                "budget": min(req.max_new, self.S - n - self.speculate),
+                #: absolute deadline feeding the per-token EDF key
+                "deadline_t": stream.submit_t + slo_s,
+                "slot": None,
+            }
+            self._sstate[sid] = state
+            if lane is not None:
+                self._lane[lane] = sid
+                state["slot"] = stream.lane = lane
+            elif self._spec is not None:
+                slot = self._lane.index(None)
+                self._lane[slot] = sid
+                state["slot"] = slot
+                self._draft_prefill(req, slot)
+            states.append(state)
+        return (reqs, states, first_d, key_d, lp_d, ticket)
 
     def _activate_commit_paged(self, rec) -> None:
-        req, state, first_d, key_d, lp_d, ticket = rec
-        self.stats["prefills"] += 1
+        reqs, states, first_d, key_d, lp_d, ticket = rec
+        self.stats["prefills"] += len(states)
         first, lp, key = (np.asarray(first_d), np.asarray(lp_d),
                           np.asarray(key_d))
         # the last admitted record of a boundary empties the queue; the
         # earlier ones' tickets lie behind programs still queued
         self._fetched(ticket)
-        self._hand_over([state], [0], first[:, None], lp[:, None], 1,
-                        keys=key, first=True)
+        self._hand_over(states, range(len(states)), first[:, None],
+                        lp[:, None], 1, keys=key, first=True)
 
     def _hand_over(self, run, rows, toks, lps, counts, keys=None,
                    first: bool = False) -> None:
@@ -2032,10 +2148,12 @@ class ContinuousBatchingEngine:
                     self._advance_partial()
                     progressed = True
             admitted = []
+            # cold admissions whose prefill waits for the boundary's others
+            cold: List[_ColdAdmission] = []
             while self._partial is None:
                 if (self._spec is not None or self._lane_state
                         or self._window is not None) and \
-                        len(self._sstate) >= self.B:
+                        len(self._sstate) + len(cold) >= self.B:
                     # a stream pinned to a lane, or a window arena sized
                     # for the lanes' windows: B at most
                     break
@@ -2066,26 +2184,39 @@ class ContinuousBatchingEngine:
                     self.stats["kv_defers"] += 1
                     self._held = req
                     break
-                admitted.append(rec)
+                if isinstance(rec, _ColdAdmission):
+                    cold.append(rec)
+                else:
+                    admitted.append(rec)
                 progressed = True
                 # host work up to the enqueue of prefill, scatter and
-                # first-token sample
+                # first-token sample (of a cold admission that waits for
+                # the boundary's others: up to its blocks)
                 self._phase("admit", **req.who())
+            for group, rows in self._prefill_groups(cold):
+                try:
+                    admitted.append(self._prefill_group(group, rows))
+                except Exception as e:  # noqa: BLE001 — a bad program
+                    # must not kill the engine loop
+                    log.warning("serving: admit failed: %s", e)
+                    for c in group:
+                        self._finish_stream(c.req.stream, f"error: {e}")
+                self._phase("admit", **group[0].req.who(), rows=len(group))
             for rec in admitted:  # start all fetches before blocking
                 _start_host_copies(*rec[2:5])
             for rec in admitted:
                 try:
                     self._activate_commit_paged(rec)
-                except Exception as e:  # noqa: BLE001 — fail only this
-                    # stream
+                except Exception as e:  # noqa: BLE001 — fail only these
+                    # streams
                     log.warning("serving: activate failed: %s", e)
-                    state = rec[1]
-                    if self._sstate.get(state["sid"]) is state:
-                        self._finish_paged(state, f"error: {e}")
-                    else:
-                        self._finish_stream(rec[0].stream, f"error: {e}")
+                    for req, state in zip(rec[0], rec[1]):
+                        if self._sstate.get(state["sid"]) is state:
+                            self._finish_paged(state, f"error: {e}")
+                        else:
+                            self._finish_stream(req.stream, f"error: {e}")
                 # blocked on the prefill's result, then the first emit
-                self._phase("first_token", **rec[0].who())
+                self._phase("first_token", **rec[0][0].who())
             if self.stats["admissions"] > admissions:
                 self.stats["admit_boundaries"] += 1
             if len(self._sstate) > self.stats["concurrent_streams_max"]:

@@ -105,9 +105,10 @@ from nnstreamer_tpu.tensors import memory as _memory
 
 
 def _scatter_prefill_impl(arena, cache1, bids, heads_major=False):
-    """Scatter a prefill's batch-1 contiguous cache ([L, parts, 1, S, ...]
-    leaves) into arena blocks ``bids`` ([S/T] int32, sentinel entries
-    drop). Block i receives slots [i*T, (i+1)*T) — including any trailing
+    """Scatter a prefill's contiguous cache ([L, parts, b, S, ...] leaves,
+    a row a prompt) into arena blocks ``bids`` ([b * S/T] int32: row 0's
+    S/T entries, then row 1's; sentinel entries drop). Block i of a row
+    receives its slots [i*T, (i+1)*T) — including any trailing
     bucket-pad garbage in the last data block, which stays masked until
     the owning stream overwrites it (the padded-prefill contract of
     ``build_prefill``). For a ``heads_major`` arena the prompt's rows are
@@ -116,11 +117,9 @@ def _scatter_prefill_impl(arena, cache1, bids, heads_major=False):
     import jax.numpy as jnp
 
     def leaf(a, c):
-        L, parts = c.shape[:2]
-        S = c.shape[3]
+        L, parts, b, S = c.shape[:4]
         T = a.shape[4 if heads_major else 3]
-        u = c[:, :, 0]                                   # [L,parts,S,...]
-        u = u.reshape((L, parts, S // T, T) + u.shape[3:])
+        u = c.reshape((L, parts, b * S // T, T) + c.shape[4:])
         if heads_major:                              # [L,MB,parts,h,T,.]
             u = jnp.transpose(u, (0, 2, 1, 4, 3) + tuple(range(5, u.ndim)))
         else:
@@ -131,14 +130,15 @@ def _scatter_prefill_impl(arena, cache1, bids, heads_major=False):
         return jax.tree.map(leaf, arena, cache1)
 
 
-def _scatter_kinds_impl(arena, cache1, bids, bids_w, lane,
+def _scatter_kinds_impl(arena, cache1, bids, bids_w, lanes,
                         heads_major=False):
     """The hand-over of a prefill whose family keeps more than the full
     layers' blocks, every kind in one program: keys and values into blocks
     as above; the window layers' rows into their own arena under their own
     table (``bids_w`` names the blocks of the window's last positions; the
-    rest drop); each state leaf ``[layers, 1, ...]`` over the whole of slot
-    ``lane``. ``bids_w`` / ``lane`` are None for a kind the arena lacks."""
+    rest drop); row i of each state leaf ``[layers, b, ...]`` over the
+    whole of slot ``lanes[i]`` (``[b]``; a lane past the arena's drops).
+    ``bids_w`` / ``lanes`` are None for a kind the arena lacks."""
     import jax
 
     out = {"kv": _scatter_prefill_impl(arena["kv"], cache1["kv"], bids,
@@ -149,7 +149,8 @@ def _scatter_kinds_impl(arena, cache1, bids, bids_w, lane,
     if "state" in arena:
         with jax.named_scope("nns.state_scatter"):
             out["state"] = jax.tree.map(
-                lambda a, c: a.at[:, lane].set(c[:, 0].astype(a.dtype)),
+                lambda a, c: a.at[:, lanes].set(c.astype(a.dtype),
+                                                mode="drop"),
                 arena["state"], cache1["state"])
     return out
 
@@ -410,7 +411,7 @@ class BlockPool(_Blocks):
                         lane: Optional[int] = None,
                         window_ids: Sequence[int] = (),
                         window_first: int = 0) -> None:
-        """Move a batch-1 prefill cache into ``block_ids`` (padded with
+        """Move a one-prompt prefill cache into ``block_ids`` (padded with
         the sentinel up to S/T) and, for a family with lane state, the
         prefill's final state over slot ``lane``; for a family with window
         layers (it may be the same family), their rows of the prompt's blocks ``window_first ..
@@ -418,23 +419,39 @@ class BlockPool(_Blocks):
         ``window_ids`` (the blocks before them lie behind the window and
         are handed to nobody). Mutates ``self.arena`` in place (the old
         arena buffer is donated)."""
+        self.scatter_rows(cache1, [(block_ids, lane, window_ids,
+                                    window_first)])
+
+    def scatter_rows(self, cache, rows: Sequence) -> None:
+        """:meth:`scatter_prefill` for a prefill of ``len(rows)`` prompts,
+        in ONE program: row i of ``cache`` goes where ``rows[i] =
+        (block_ids, lane, window_ids, window_first)`` says or, for
+        ``rows[i]`` None (a row that holds no prompt), nowhere: its block
+        entries are the sentinel and its lane lies past the arena's, so it
+        writes nothing."""
         import jax.numpy as jnp
 
-        mb = _leaf_slots(self._kv(cache1)) // self.block_tokens
-        bids = np.full(mb, self.SENTINEL, np.int32)
-        bids[:len(block_ids)] = block_ids
+        mb = _leaf_slots(self._kv(cache)) // self.block_tokens
+        bids = np.full((len(rows), mb), self.SENTINEL, np.int32)
+        bids_w = None if not self.win else np.full(
+            (len(rows), mb), self.win.SENTINEL, np.int32)
+        lanes = np.full(len(rows), self.lanes, np.int32)
+        for i, row in enumerate(rows):
+            if row is None:
+                continue
+            block_ids, lane, window_ids, window_first = row
+            bids[i, :len(block_ids)] = block_ids
+            if bids_w is not None:
+                bids_w[i, window_first:window_first + len(window_ids)] = \
+                    window_ids
+            if lane is not None:
+                lanes[i] = lane
         extra = ()
         if self._kinds:
-            bids_w = None
-            if self.win:
-                bids_w = np.full(mb, self.win.SENTINEL, np.int32)
-                bids_w[window_first:window_first + len(window_ids)] = \
-                    window_ids
-                bids_w = jnp.asarray(bids_w)
-            extra = (bids_w, jnp.asarray(lane, jnp.int32)
-                     if self._lane_state else None)
-        self.arena = self._jit_scatter(self.arena, cache1,
-                                       jnp.asarray(bids), *extra,
+            extra = (None if bids_w is None else jnp.asarray(bids_w.ravel()),
+                     jnp.asarray(lanes) if self._lane_state else None)
+        self.arena = self._jit_scatter(self.arena, cache,
+                                       jnp.asarray(bids.ravel()), *extra,
                                        heads_major=self.heads_major)
 
     def copy_block(self, src: int, dst: int) -> None:
@@ -474,7 +491,7 @@ class BlockPool(_Blocks):
 
 
 def _leaf_slots(cache1) -> int:
-    """Sequence length S of a batch-1 contiguous cache pytree."""
+    """Sequence length S of a prefill's contiguous cache pytree."""
     import jax
 
     return jax.tree_util.tree_leaves(cache1)[0].shape[3]

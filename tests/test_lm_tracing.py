@@ -353,6 +353,7 @@ def _check_invariants(stats):
                for p in engine_mod.PHASES) == stats["loop_us"]
     assert 0 < stats["prefill_tokens"] <= stats["prefill_bucket_tokens"]
     assert 0 < stats["admit_boundaries"] <= stats["admissions"]
+    assert 0 <= stats["prefill_programs"] <= stats["admissions"]
 
 
 @pytest.mark.parametrize("kind", sorted(ENGINE_KINDS))
@@ -478,7 +479,11 @@ def test_time_between_a_fetch_and_the_next_enqueue_is_starved_by_phase(
 
 
 def test_only_the_first_admission_of_a_boundary_is_starved(stepped):
-    eng = _engine(block_tokens=8, max_streams=4).start()
+    eng = _engine(block_tokens=8, max_streams=4)
+    # a prompt a program (the rows rule at 1): each prefill is enqueued
+    # before the next request's host work
+    eng._balance = 0.0
+    eng.start()
     try:
         # compile every shape, and leave a request decoding
         _serve(eng, [_prompt(5), _prompt(12, 30)], max_new=5)
@@ -515,32 +520,79 @@ def test_only_the_first_admission_of_a_boundary_is_starved(stepped):
     # both waits for a first token, and none of it starved
     assert _thousands(grew["phase_first_token_us"]) == 8
     assert _thousands(grew["starved_us"]) == 1
+    assert grew["prefill_programs"] == 2
+    _check_invariants(eng.stats)
+
+
+def test_a_boundary_of_cold_prompts_shares_one_program(stepped):
+    eng = _engine(block_tokens=8, max_streams=4)
+    # the rows rule at these shapes: both prompts' buckets take several
+    assert eng._prefill_rows(16) >= 2
+    eng.start()
+    try:
+        _serve(eng, [_prompt(5), _prompt(12, 30)], max_new=5)
+        running = eng.submit(_prompt(7, 3), max_new_tokens=40)
+        gate, held = threading.Event(), threading.Event()
+        inner = eng._dispatch
+
+        def hold(*args):
+            if not gate.is_set():
+                held.set()
+                assert gate.wait(timeout=120)
+            return inner(*args)
+
+        eng._dispatch = hold
+        assert held.wait(timeout=120)
+        before = dict(eng.stats)
+        stepped.step_before(eng, "_prefill_jitted", 1000.0)
+        stepped.step_before(eng, "_activate_commit_paged", 4000.0)
+        pair = [eng.submit(_prompt(5, 50), max_new_tokens=5),
+                eng.submit(_prompt(12, 70), max_new_tokens=5)]
+        gate.set()
+        for s in pair + [running]:
+            s.result(timeout=120)
+    finally:
+        eng.stop()
+    grew = {k: eng.stats[k] - before[k] for k in before
+            if type(before[k]) is int}
+    assert grew["admissions"] == 2 and grew["admit_boundaries"] == 1
+    # ONE program for both: one host half before it, all of it starved
+    # (nothing is queued until the group's prefill), one wait for both
+    # first tokens
+    assert grew["prefill_programs"] == 1
+    assert _thousands(grew["phase_admit_us"]) == 1
+    assert _thousands(grew["starved_admit_us"]) == 1
+    assert _thousands(grew["phase_first_token_us"]) == 4
+    assert _thousands(grew["starved_us"]) == 1
+    assert grew["prefill_bucket_tokens"] == eng._prefill_rows(16) * 16
     _check_invariants(eng.stats)
 
 
 FIRST_TOKEN_LOADS = {
     # kind: (engine options, prompts served one after another,
-    #        tokens given, rows computed)
+    #        tokens given, the programs' widths, cold prefill programs);
+    # a cold prefill computes its bucket's rows a prompt it can take
+    # (``engine._prefill_rows``), the others one
     "cold": ({}, [_prompt(5), _prompt(20), _prompt(12)],
-             5 + 20 + 12, 16 + 32 + 16),
+             5 + 20 + 12, [16, 32, 16], 3),
     "cold_min_bucket8": ({"min_bucket": 8},
                          [_prompt(5), _prompt(20), _prompt(9)],
-                         5 + 20 + 9, 8 + 32 + 16),
+                         5 + 20 + 9, [8, 32, 16], 3),
     # 20 cold; the same again is an exact hit (no program); 26 tokens of
     # which the first two blocks of 8 are shared: 10 left, in a bucket of 16
     "prefix_extension": ({"prefix_cache": 4, "block_tokens": 8},
                          [_prompt(20), _prompt(20),
                           np.concatenate([_prompt(20), _prompt(6, 50)])],
-                         20 + 0 + 10, 32 + 0 + 16),
+                         20 + 0 + 10, [32, 16], 1),
     # chunks of 8: 20 = 8 + 8 + 4, 5 = 5
     "chunked": ({"prefill_chunk": 8}, [_prompt(20), _prompt(5)],
-                20 + 5, 24 + 8),
+                20 + 5, [8, 8, 8, 8], 0),
 }
 
 
 @pytest.mark.parametrize("kind", sorted(FIRST_TOKEN_LOADS))
 def test_first_token_path_counts_tokens_rows_and_boundaries(kind):
-    options, prompts, tokens, rows = FIRST_TOKEN_LOADS[kind]
+    options, prompts, tokens, widths, programs = FIRST_TOKEN_LOADS[kind]
     eng = _engine(**options).start()
     try:
         for p in prompts:
@@ -548,7 +600,9 @@ def test_first_token_path_counts_tokens_rows_and_boundaries(kind):
     finally:
         eng.stop()
     assert eng.stats["prefill_tokens"] == tokens
-    assert eng.stats["prefill_bucket_tokens"] == rows
+    assert eng.stats["prefill_bucket_tokens"] == sum(
+        eng._prefill_rows(w) * w for w in widths)
+    assert eng.stats["prefill_programs"] == programs
     # one request at a time: every admission is a boundary of its own
     assert eng.stats["admit_boundaries"] == eng.stats["admissions"] \
         == len(prompts)

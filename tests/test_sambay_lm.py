@@ -462,8 +462,12 @@ def test_engine_reports_three_kinds_and_what_the_cross_layers_read():
     assert stats["kv_shared_reads"] == CFG.cross_layers \
         * stats["kv_blocks_live"] > 0
     assert 0 < stats["kv_window_blocks_live"] < stats["kv_blocks_live"]
+    # one prompt in its bucket's one program: every row of it computed,
+    # the rows that hold no prompt too
+    rows = eng._prefill_rows(16)
+    assert stats["prefills"] == stats["prefill_programs"] == 1
     assert (stats["prefill_rows_self"], stats["prefill_rows_cross"]) \
-        == (stats["prefill_bucket_tokens"], stats["prefills"]) == (16, 1)
+        == (stats["prefill_bucket_tokens"], rows) == (16 * rows, rows)
     arena = eng._pool.arena
     assert set(arena) == {"kv", "win", "state"}
     assert arena["kv"].shape[0] == 1 and arena["win"].shape[0] == 2
