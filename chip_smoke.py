@@ -8,7 +8,7 @@ user calls, at the full width of the models the repo serves (random
 weights from a seed, nothing downloaded):
 
 - **kernel leg** — the three Pallas kernels compiled by Mosaic (never
-  interpreted) at the shapes the other legs and ``bench.py attn`` use,
+  interpreted) at the shapes the other legs use,
   compared on the chip with their ``force="reference"`` results; and the
   routed experts' grouped matmul (``nns_expert_tiles``) against the tile
   loop at both benchmark configurations' real expert shapes, the decode
@@ -58,7 +58,7 @@ about the chip; the argument-less run can never reach it.
 
 The pipeline strings below are the smoke's own (``tests/test_chip_smoke``
 parses them on the CPU so a renamed property fails there, not on chip
-time); ``bench.py`` is deliberately not imported.
+time).
 """
 
 from __future__ import annotations

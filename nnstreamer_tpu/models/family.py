@@ -94,12 +94,10 @@ class ModelFamily:
     #: the family lacks for them and where ``ROADMAP.md`` queues it
     brings: Tuple[str, ...] = ()
     refusal: str = ""
-    #: ``build_chunk_decode(cfg, max_seq, kv_codec=)`` and
-    #: ``build_paged_chunk(cfg, block_tokens, max_seq, kv_codec=)``:
-    #: several query positions a lane (chunked ingestion, prefix
-    #: extension, speculation's verification); None where ``brings`` names
-    #: none of them
-    build_chunk_decode: Optional[Callable] = None
+    #: ``build_paged_chunk(cfg, block_tokens, max_seq, kv_codec=)``: the
+    #: one builder of several query positions a lane, written into the
+    #: block arena (chunked ingestion, prefix extension, speculation's
+    #: verification); None where ``brings`` names none of them
     build_paged_chunk: Optional[Callable] = None
     #: names (a leaf's own key in the parameter tree) of the leaves every
     #: program of the family reads as ``leaf.astype(cfg.dtype)`` and in no

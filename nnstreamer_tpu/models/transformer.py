@@ -545,72 +545,6 @@ def build_decode_step(cfg: TransformerConfig,
     return step
 
 
-def build_chunk_decode(cfg: TransformerConfig,
-                       max_seq: Optional[int] = None,
-                       kv_codec: Optional[str] = None) -> Callable:
-    """KV-cached decode of a WHOLE chunk of c tokens in one pass:
-    ``chunk(params, tokens[int32 b,c], cache, pos0[int32 scalar]) ->
-    (logits[b,c,vocab], new_cache)``.
-
-    Generalizes :func:`build_decode_step` (c=1) to the shape speculative
-    verification needs (models/speculative.py): the target model scores c
-    candidate positions in ONE program — a [c, d_model] matmul per layer
-    instead of c sequential single-row dispatches, which is exactly what
-    the MXU wants. Position ``pos0+i`` writes cache slot ``pos0+i`` and
-    attends under a ``slot <= pos0+i`` mask (write-before-attend, so
-    stale kv beyond an accepted prefix is unreachable — the rewind-free
-    speculative cache contract; see speculative.py docstring).
-
-    ``pos0`` is clamped so the chunk's writes stay inside the cache
-    (same bounded-degradation contract as build_decode_step).
-
-    Like build_decode_step, ``pos0`` may also be a ``[b]`` vector — one
-    chunk origin per batch row (the batched speculative-verify shape:
-    every stream scores its own γ+1 candidates at its own depth in ONE
-    program). The scalar path traces exactly as before.
-    """
-    dtype = cfg.dtype
-    s_max = max_seq or cfg.max_seq
-    codec = _kv_codec(cfg, kv_codec)
-
-    def chunk(params, tokens, cache, pos0):
-        b, c = tokens.shape
-        pos0 = jnp.asarray(pos0, jnp.int32)
-        per_stream = pos0.ndim == 1
-        pos0 = jnp.minimum(pos0, s_max - c)
-        if per_stream:
-            positions = pos0[:, None] + jnp.arange(c)[None, :]   # [b,c]
-            # query i of row r (global position pos0[r]+i) sees
-            # slots <= pos0[r]+i
-            qpos = positions[:, None, :, None]                # [b,1,c,1]
-        else:
-            positions = pos0 + jnp.arange(c)[None, :] * jnp.ones(
-                (b, 1), jnp.int32)                               # [b,c]
-            # query i (global position pos0+i) sees slots <= pos0+i
-            qpos = (pos0 + jnp.arange(c))[None, None, :, None]
-        x = params["embed"][tokens].astype(dtype)
-        layer_params = {k: v for k, v in params.items()
-                        if k not in ("embed", "ln_f")}
-
-        def layer(carry, lp_and_cache):
-            x, = carry
-            lp, layer_cache = lp_and_cache
-            q, k, v = _block_qkv(x, lp, positions, dtype)  # [b,c,h,dh]
-            new_cache = codec.write(layer_cache, jnp.stack([k, v]), pos0,
-                                    per_stream)
-            slots = jnp.arange(s_max)
-            mask = slots[None, None, None, :] <= qpos
-            ck, cv = codec.read(new_cache)
-            a = _attend_cache(q, ck, cv, mask, cfg.head_dim, dtype)
-            x = _block_tail(x, a, lp, cfg)
-            return (x,), new_cache
-
-        (x,), new_cache = lax.scan(layer, (x,), (layer_params, cache))
-        return _final_logits(x, params), new_cache
-
-    return chunk
-
-
 def build_paged_decode_step(cfg: TransformerConfig,
                             block_tokens: int,
                             max_seq: Optional[int] = None,
@@ -703,19 +637,24 @@ def build_paged_chunk(cfg: TransformerConfig,
                       block_tokens: int,
                       max_seq: Optional[int] = None,
                       kv_codec: Optional[str] = None) -> Callable:
-    """Chunk decode against a paged KV cache — build_chunk_decode's paged
-    twin: ``chunk(params, tokens[int32 b,c], arena, bt[int32 b,MB],
-    pos0[int32 b], limit[int32 b]) -> (logits[b,c,vocab], new_arena)``.
+    """KV-cached decode of a WHOLE chunk of c tokens a row in one pass,
+    against a paged KV cache: ``chunk(params, tokens[int32 b,c], arena,
+    bt[int32 b,MB], pos0[int32 b], limit[int32 b]) -> (logits[b,c,vocab],
+    new_arena)`` — :func:`build_decode_step` (c = 1) generalized to a
+    [c, d_model] matmul a layer, which is what the MXU wants.
 
     Row r's token i sits at global position ``pos0[r]+i``, writes physical
     slot ``(bt[r, p//T], p%T)`` and attends under a ``slot <= p`` mask.
     ``limit[r]`` is the row's REAL chunk length: positions ≥ limit (bucket
     padding) redirect their writes to the sentinel and drop, so a padded
     warm prefix extension never smears pad k/v into pool blocks another
-    stream could inherit. Used for prefix-cache extension and speculative
-    verification on the paged path. The arena is addressed as in
-    :func:`build_paged_decode_step`: whole, a carry of the layer scan, the
-    layer an index.
+    stream could inherit. The engine's one program of several query
+    positions a lane: chunked ingestion, prefix-cache extension and
+    speculative verification (write-before-attend: stale kv past an
+    accepted prefix is unreachable until overwritten). ``pos0`` is
+    clamped so the chunk's writes stay inside the cache. The arena is
+    addressed as in :func:`build_paged_decode_step`: whole, a carry of the
+    layer scan, the layer an index.
     """
     dtype = cfg.dtype
     s_max = max_seq or cfg.max_seq
@@ -949,7 +888,6 @@ DENSE = ModelFamily(
     kv_entry=lambda cfg: (cfg.n_layers, 2, (cfg.n_heads, cfg.head_dim)),
     brings=("prefix_cache", "speculate", "prefill_chunk", "kv_quant",
             "mesh"),
-    build_chunk_decode=build_chunk_decode,
     build_paged_chunk=build_paged_chunk,
     # the router is read in float32 and the embedding has two readers: the
     # lookup gathers rows and casts those, the head multiplies the stored

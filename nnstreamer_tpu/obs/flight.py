@@ -2,8 +2,8 @@
 
 The Timeline (``obs/timeline.py``) answers "where did this frame's time
 go?" — but only when someone turned tracing on BEFORE the outlier
-happened. BENCH_r05's warm runs swing 141–479 fps and the saturation
-p99 sits near 5 s; by the time anyone re-runs with ``NNSTPU_TRACE`` the
+happened. A flagship stream's warm runs once swung 141–479 fps with the
+saturation p99 near 5 s; by the time anyone re-runs with ``NNSTPU_TRACE`` the
 offending frame is gone. This module keeps a black-box recorder running
 on every pipeline, always:
 

@@ -5,12 +5,12 @@ before the device finishes. The pipeline previously consumed that
 asynchrony one frame at a time — the next frame's host work only started
 once the previous frame's downstream chain returned, and any downstream
 materialization point fenced every frame individually, so the device sat
-idle between dispatches (BENCH_r05: flagship at 13.4% of the device
-ceiling). The overlap layer's contract instead allows up to ``inflight=K``
-device batches outstanding per dispatching element: host work for frame
-N+1 proceeds while the device computes frame N, and the producer thread
-only blocks (fences the OLDEST outstanding batch) when the window is full
-— bounded pipelining, same ordering.
+idle between dispatches (the flagship stream once ran at 13.4% of the
+device ceiling). The overlap layer's contract instead allows up to
+``inflight=K`` device batches outstanding per dispatching element: host
+work for frame N+1 proceeds while the device computes frame N, and the
+producer thread only blocks (fences the OLDEST outstanding batch) when
+the window is full — bounded pipelining, same ordering.
 
 The window also owns the staging-buffer recycle point: a pooled host
 array consumed by an H2D transfer (``tensors/pool.py``, carried in

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import collections
 import itertools
+import numbers
 import queue as _queue
 import threading
 import time as _time
@@ -406,11 +407,8 @@ class ContinuousBatchingEngine:
     max_streams: decode lanes (B). Static — sizes the programs and, by
         default, the arena.
     max_seq: a stream's longest context S (defaults to ``cfg.max_seq``).
-    steps_per_dispatch: decode steps fused into one device dispatch (K),
-        or "auto" — start() measures the per-dispatch sync round trip
-        and per-step decode time and picks K so the fixed dispatch cost
-        amortizes to ≤~20% of a block (small on PCIe, large over a
-        high-RTT link; see _calibrate_k).
+    steps_per_dispatch: decode steps fused into one device dispatch (K):
+        a positive integer (``ValueError`` otherwise).
     temperature / top_k / min_p: sampling config (``temperature<=0`` →
         greedy; see ``models.transformer.make_sampler``).
     eos_id: generation stops when the model emits this id (None → length
@@ -429,9 +427,12 @@ class ContinuousBatchingEngine:
         decode dispatches — admitting a long prompt then adds at most
         one chunk's latency per block to running streams instead of a
         whole-prompt stall (and prefill compiles exactly once, at shape
-        ``[1, chunk]``, instead of once per length bucket). Padded tail
-        positions are unreachable-before-overwrite exactly like bucket
-        padding. Requires ``prompt length <= max_seq - prefill_chunk``.
+        ``[1, chunk]``, instead of once per length bucket). A chunked
+        prompt takes its blocks before its first chunk, as every
+        admission does, and each chunk writes into them through the
+        program prefix extension runs (`build_paged_chunk`); a chunk's
+        padded tail writes nowhere. Requires ``ceil(prompt length /
+        prefill_chunk) * prefill_chunk <= max_seq``.
     kv_quant: ``"int8"`` stores the arena quantized (per-vector absmax
         scales) — ~2× blocks per HBM byte, at a small, bounded numeric
         cost (models/transformer._Int8KVCodec).
@@ -541,12 +542,13 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"serving: block_tokens must be a positive divisor of "
                 f"max_seq ({self.S}), got {block_tokens}")
-        #: steps_per_dispatch="auto": start() measures the per-dispatch
-        #: sync round trip and the per-step decode time, then picks K so
-        #: the fixed dispatch cost amortizes (see _calibrate_k) — on a
-        #: PCIe-attached chip that lands small, on a high-RTT link large
-        self._auto_k = steps_per_dispatch == "auto"
-        self.K = 8 if self._auto_k else int(steps_per_dispatch)
+        if isinstance(steps_per_dispatch, bool) or not isinstance(
+                steps_per_dispatch, numbers.Integral) \
+                or steps_per_dispatch < 1:
+            raise ValueError(
+                f"serving: steps_per_dispatch must be a positive integer, "
+                f"got {steps_per_dispatch!r}")
+        self.K = int(steps_per_dispatch)
         self.temperature = float(temperature)
         self.top_k = int(top_k)
         self.min_p = float(min_p)
@@ -582,10 +584,6 @@ class ContinuousBatchingEngine:
         #: the most blocks of the window arena a lane holds at a dispatch
         self._window = family.kv_window(cfg)
         if self._window is not None:
-            if self._auto_k:
-                raise ValueError("serving: a family with window layers "
-                                 "sizes its window arena by a fixed "
-                                 "steps_per_dispatch, not \"auto\"")
             self.MBW = min(self.MB, -(-(self._window[1] + self.K)
                                       // self.block_tokens) + 1)
         paged_attention_fn = None
@@ -598,16 +596,14 @@ class ContinuousBatchingEngine:
         self._paged_decode = family.build_paged_decode_step(
             cfg, self.block_tokens, self.S, kv_codec=kv_quant,
             paged_attention_fn=paged_attention_fn)
-        # chunked ingestion (a batch-1 contiguous cache, scattered into
-        # blocks by its last chunk), prefix extension and speculative
-        # verification: a family's own builders, where it brings them
-        self._chunk_fn = self._paged_chunk_fn = None
-        if family.build_chunk_decode is not None:
-            self._chunk_fn = family.build_chunk_decode(
-                cfg, self.S, kv_codec=kv_quant)
+        # chunked ingestion, prefix extension and speculative
+        # verification: the family's one program of several query
+        # positions a lane, where it brings one
+        self._paged_chunk_fn = None
+        if family.build_paged_chunk is not None:
             self._paged_chunk_fn = family.build_paged_chunk(
                 cfg, self.block_tokens, self.S, kv_codec=kv_quant)
-        #: in-progress chunked admission: (request, cache1, k) with
+        #: in-progress chunked admission: (request, blocks, k) with
         #: k = next chunk index; one at a time, advanced between dispatches
         self._partial = None
 
@@ -893,6 +889,8 @@ class ContinuousBatchingEngine:
 
         self._build_dispatch = build_paged_dispatch
         if self._paged_chunk_fn is not None:
+            # one executable a chunk width: a prefix extension's bucket,
+            # the chunk of chunked ingestion
             self._paged_chunk_jitted = jax.jit(self._paged_chunk_fn,
                                                donate_argnums=(2,))
         self._set_dispatch(self.K)
@@ -900,10 +898,6 @@ class ContinuousBatchingEngine:
 
         # one jitted prefill; XLA caches one executable per bucket shape
         self._prefill_jitted = jax.jit(self._prefill_fn)
-        # chunked-prefill program: ONE executable at shape [1, chunk]
-        if self._chunk_fn is not None:
-            self._chunk_jitted = jax.jit(self._chunk_fn,
-                                         donate_argnums=(2,))
         self._jnp = jnp
         self._jax = jax
 
@@ -944,60 +938,6 @@ class ContinuousBatchingEngine:
         table, or for a family with window layers both by arena."""
         return bt if self._window is None else {"kv": bt, "win": bt_w}
 
-    def _calibrate_k(self) -> None:
-        """steps_per_dispatch="auto": pick K from MEASURED costs.
-
-        A decode block costs ``rtt + K·s`` wall time for ``rtt`` = the
-        fixed dispatch+sync overhead (dominated by the host↔device link;
-        under a millisecond on a locally attached chip) and ``s`` = one
-        batched decode step. ``rtt`` is timed with a trivial synced
-        device program; ``s`` falls out of one timed block at the
-        initial K. K is then chosen so the fixed cost is ≤ ~20% of the
-        block (K ≥ 4·rtt/s), clamped to [8, 128] and rounded down to a
-        power of two (bucketed executables). Runs once, before the
-        engine loop starts, on the LIVE arena (see below)."""
-        import numpy as _np
-        import time as _time
-
-        jax, jnp = self._jax, self._jnp
-        tiny = jax.jit(lambda x: x + 1)
-        x = jnp.zeros((8,), jnp.int32)
-        _np.asarray(tiny(x))  # compile off the clock
-        rtt = min(
-            (lambda t0: (_np.asarray(tiny(x)), _time.monotonic() - t0)[1])(
-                _time.monotonic()) for _ in range(3))
-        # calibrate on the LIVE arena: a throwaway one would transiently
-        # double KV HBM and OOM exactly the memory-tight configs auto-K
-        # serves. All-sentinel block tables: writes drop, reads hit the
-        # zero block — a pure timing run that cannot corrupt the arena
-        token = jnp.zeros((self.B,), jnp.int32)
-        pos = jnp.zeros((self.B,), jnp.int32)
-        keys = jnp.zeros((self.B, 2), jnp.uint32)
-        bt = jnp.full((self.B, self.MB), self._pool.SENTINEL, jnp.int32)
-
-        def run():
-            # dispatch DONATES the arena: reassign immediately after
-            # each call so a failure mid-calibration never leaves it
-            # pointing at deleted buffers (start() also resets on error)
-            out = self._dispatch(self.params, token, self._pool.arena, bt,
-                                 pos, keys)
-            self._pool.arena = out[2]
-            return out
-
-        out = run()
-        _np.asarray(out[0])  # compile + warm
-        t0 = _time.monotonic()
-        out = run()
-        _np.asarray(out[0])
-        block = _time.monotonic() - t0
-        step = max((block - rtt) / self.K, 1e-5)
-        k = max(8, min(128, int(4 * rtt / step)))
-        k = 1 << (k.bit_length() - 1)  # round down to a power of two
-        log.info("serving: auto K — rtt %.2f ms, step %.3f ms → K=%d",
-                 rtt * 1e3, step * 1e3, k)
-        if k != self.K:
-            self._set_dispatch(k)
-
     # -- public API -----------------------------------------------------------
     def start(self) -> "ContinuousBatchingEngine":
         if self._thread is not None and not self._thread.is_alive():
@@ -1011,17 +951,6 @@ class ContinuousBatchingEngine:
                     "serving: previous engine loop is still shutting "
                     "down; retry start() after it exits")
             return self  # already running
-        if self._auto_k:
-            self._auto_k = False  # calibrate once, not per restart
-            try:
-                self._calibrate_k()
-            except Exception as e:  # noqa: BLE001 — auto-tune is an
-                # optimization; the initial K always works
-                log.warning("serving: K auto-calibration failed (%s); "
-                            "keeping K=%d", e, self.K)
-                # the failed dispatch may have donated (deleted) the
-                # live arena's buffers or left error arrays in it
-                self._pool.reset()
         self._stop_evt.clear()
         self._thread = threading.Thread(target=self._loop,
                                         name="cb-engine", daemon=True)
@@ -1045,9 +974,7 @@ class ContinuousBatchingEngine:
         # lock serializes with submit()'s running-check + enqueue, so a
         # request can't slip into _pending after this drain
         with self._lock:
-            if self._partial is not None:
-                self._finish_stream(self._partial[0].stream, "engine-stopped")
-                self._partial = None
+            self._drop_partial("engine-stopped")
             for state in list(self._sstate.values()):
                 self._finish_paged(state, "engine-stopped")
             if self._held is not None:
@@ -1265,54 +1192,87 @@ class ContinuousBatchingEngine:
     #: admission; exact whole-prompt hits are never thresholded
     PREFIX_MIN_REUSE = 4
 
-    def _advance_partial(self):
-        """Run ONE prefill chunk; the last one scatters the finished
-        batch-1 cache into fresh blocks and activates the stream."""
+    def _extend(self, tokens: np.ndarray, width: int, blocks,
+                pos0: int):
+        """Run the paged chunk program over ``tokens`` right-padded to
+        ``width``, at positions ``pos0 ..`` of the one-row table
+        ``blocks``: their keys and values go into the arena (the padding's
+        nowhere). Prefix extension and each chunk of chunked ingestion
+        call it. Returns the logits at the last token, ``[1, vocab]``."""
         jnp = self._jnp
-        req, cache1, k = self._partial
+        m = tokens.size
+        toks = np.zeros((1, width), np.int32)
+        toks[0, :m] = tokens
+        bt = np.full((1, self.MB), self._pool.SENTINEL, np.int32)
+        bt[0, :len(blocks)] = blocks
+        logits, self._pool.arena = self._enqueue(
+            self._paged_chunk_jitted, self.params, jnp.asarray(toks),
+            self._pool.arena, jnp.asarray(bt),
+            jnp.asarray(np.asarray([pos0], np.int32)),
+            jnp.asarray(np.asarray([m], np.int32)))
+        self.stats["prefill_tokens"] += m
+        self.stats["prefill_bucket_tokens"] += width
+        return logits[:, m - 1]
+
+    def _begin_partial_paged(self, req: _PendingRequest):
+        """Chunked prompt ingestion: take the prompt's blocks before any
+        device work, as every admission does, and return the record of
+        the admission in progress ``(request, blocks, 0)`` — or None to
+        DEFER when the pool cannot cover the prompt (the caller holds the
+        request). Chunked prompts ingest from position 0: a prefix entry
+        is stored (``_advance_partial``) but not reused here."""
+        self._begin_admission(req)
+        blocks = self._alloc_blocks(self._blocks_for(req.prompt.size))
+        return None if blocks is None else (req, blocks, 0)
+
+    def _advance_partial(self):
+        """Run ONE prefill chunk into the stream's blocks; the last one
+        activates the stream."""
+        req, blocks, k = self._partial
         C = self.prefill_chunk
         prompt, n = req.prompt, req.prompt.size
         start = k * C
         end = min(start + C, n)
-        chunk = np.zeros((1, C), np.int32)
-        chunk[0, :end - start] = prompt[start:end]
         who = req.who()
         try:
-            logits, cache1 = self._enqueue(
-                self._chunk_jitted, self.params, jnp.asarray(chunk), cache1,
-                jnp.asarray(start, jnp.int32))
+            logits = self._extend(prompt[start:end], C, blocks, start)
             self.stats["prefill_chunks"] += 1
-            self.stats["prefill_tokens"] += end - start
-            self.stats["prefill_bucket_tokens"] += C
             if end < n:
-                self._partial = (req, cache1, k + 1)
+                self._partial = (req, blocks, k + 1)
                 self._phase("admit", **who)
                 return
-            # final chunk: logits at the prompt's true last position
+            self._prefix_store_paged(prompt, blocks, logits)
+            rec = self._activate_begin_paged([req], logits, [blocks])
             self._partial = None
-            logits_last = logits[:, (n - 1) - start]
-            rec = self._activate_paged_from_cache1(req, logits_last, cache1)
-            if rec is None:  # pool exhausted: re-ingest when it isn't
-                self.stats["kv_defers"] += 1
-                self._held = req
-            else:
-                self._phase("admit", **who)
-                self._activate_commit_paged(rec)
-                self._phase("first_token", **who)
+            self._phase("admit", **who)
+            self._activate_commit_paged(rec)
+            self._phase("first_token", **who)
         except Exception as e:  # noqa: BLE001 — a failed chunk must fail
             # only this request
             log.warning("serving: chunked prefill failed: %s", e)
-            self._partial = None
-            self._finish_stream(req.stream, f"error: {e}")
+            state = self._sstate.get(req.stream.stream_id)
+            if state is not None:  # activated: its blocks are the state's
+                self._partial = None
+                self._finish_paged(state, f"error: {e}")
+            else:
+                self._drop_partial(f"error: {e}")
+
+    def _drop_partial(self, reason: str) -> None:
+        """End the half-ingested prompt, if there is one: its blocks go
+        back to the pool before its client wakes."""
+        if self._partial is None:
+            return
+        req, blocks, _k = self._partial
+        self._partial = None
+        self._pool.release(blocks)
+        self._finish_stream(req.stream, reason)
 
     def _recover(self, e) -> None:
         """Device failure: fail every admitted stream, the held request
         and any half-ingested prompt, rebuild the (possibly donated-away)
         arena, and keep serving."""
         log.error("serving: dispatch failed: %s", e)
-        if self._partial is not None:
-            self._finish_stream(self._partial[0].stream, f"error: {e}")
-            self._partial = None
+        self._drop_partial(f"error: {e}")
         for state in list(self._sstate.values()):
             self._finish_stream(state["stream"], f"error: {e}")
         self._sstate.clear()
@@ -1626,7 +1586,6 @@ class ContinuousBatchingEngine:
         batch slots; the caller holds the request so FIFO order keeps).
         Deferral is cheap: every path allocates before device work."""
         self._begin_admission(req)
-        jnp = self._jnp
         prompt = req.prompt
         n = prompt.size
         T = self.block_tokens
@@ -1667,21 +1626,8 @@ class ContinuousBatchingEngine:
             try:
                 self.stats["prefix_hits"] += 1
                 self.stats["prefix_tokens_reused"] += q
-                rem = n - q
-                c = self._bucket(rem)
-                toks = np.zeros((1, c), np.int32)
-                toks[0, :rem] = prompt[q:]
-                bt = np.full((1, self.MB), self._pool.SENTINEL, np.int32)
-                bt[0, :len(blocks)] = blocks
-                logits, arena = self._enqueue(
-                    self._paged_chunk_jitted,
-                    self.params, jnp.asarray(toks), self._pool.arena,
-                    jnp.asarray(bt), jnp.asarray(np.asarray([q], np.int32)),
-                    jnp.asarray(np.asarray([rem], np.int32)))
-                self._pool.arena = arena
-                self.stats["prefill_tokens"] += rem
-                self.stats["prefill_bucket_tokens"] += c
-                logits = logits[:, rem - 1]
+                logits = self._extend(prompt[q:], self._bucket(n - q),
+                                      blocks, q)
                 self._prefix_store_paged(prompt, blocks, logits)
                 return self._activate_begin_paged([req], logits, [blocks])
             except Exception:
@@ -1784,35 +1730,6 @@ class ContinuousBatchingEngine:
             for c in group:
                 c.release(self._pool)
             raise
-
-    def _activate_paged_from_cache1(self, req: _PendingRequest, logits,
-                                    cache1):
-        """Chunked-prefill commit: scatter the finished batch-1 cache
-        into fresh blocks. None = pool exhausted (caller re-holds)."""
-        n = req.prompt.size
-        T = self.block_tokens
-        blocks = self._alloc_blocks(self._blocks_for(n))
-        if blocks is None:
-            return None
-        try:
-            self._enqueue(self._pool.scatter_prefill, cache1,
-                          blocks[:(n + T - 1) // T])
-            self._prefix_store_paged(req.prompt, blocks, logits)
-            return self._activate_begin_paged([req], logits, [blocks])
-        except Exception:
-            self._pool.release(blocks)
-            raise
-
-    def _begin_partial_paged(self, req: _PendingRequest) -> None:
-        """Chunked prompt ingestion: chunks build a batch-1 contiguous
-        cache that the FINAL chunk scatters into fresh blocks — no lane
-        is reserved, blocks allocate at activation. (Prefix reuse is
-        not wired on this path; chunked prompts ingest from 0.)"""
-        from nnstreamer_tpu.models.transformer import init_cache
-
-        self._begin_admission(req)
-        self._partial = (req, self._enqueue(
-            init_cache, self.cfg, 1, self.S, kv_codec=self.kv_quant), 0)
 
     def _activate_begin_paged(self, reqs: List[_PendingRequest], logits,
                               blocks: List[list], lanes=None):
@@ -2142,8 +2059,7 @@ class ContinuousBatchingEngine:
             progressed = False
             if self._partial is not None:
                 if self._partial[0].stream.cancelled:
-                    self._finish_stream(self._partial[0].stream, "cancelled")
-                    self._partial = None
+                    self._drop_partial("cancelled")
                 else:
                     self._advance_partial()
                     progressed = True
@@ -2168,11 +2084,9 @@ class ContinuousBatchingEngine:
                     self._finish_stream(req.stream, "cancelled")
                     continue
                 try:
-                    if self.prefill_chunk is not None:
-                        self._begin_partial_paged(req)
-                        progressed = True
-                        break
-                    rec = self._admit_paged(req)
+                    rec = self._admit_paged(req) \
+                        if self.prefill_chunk is None \
+                        else self._begin_partial_paged(req)
                 except Exception as e:  # noqa: BLE001 — a bad request
                     # must not kill the engine loop
                     log.warning("serving: admit failed: %s", e)
@@ -2183,6 +2097,11 @@ class ContinuousBatchingEngine:
                     # (completions free blocks; FIFO order preserved)
                     self.stats["kv_defers"] += 1
                     self._held = req
+                    break
+                if self.prefill_chunk is not None:
+                    # its first chunk runs at the next iteration
+                    self._partial = rec
+                    progressed = True
                     break
                 if isinstance(rec, _ColdAdmission):
                     cold.append(rec)

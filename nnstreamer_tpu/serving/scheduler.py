@@ -1,7 +1,7 @@
 """SloScheduler — deadline admission, EDF ordering, and feedback-tuned
 batch forming for the serving plane.
 
-BENCH_r05's saturation p99 was ~5 s against a 50 ms budget: the only
+A saturated flagship stream's p99 was ~5 s against a 50 ms budget: the only
 overload defense was the leaky ingress queue's blind tail-drop, which
 sheds whichever frame happens to be oldest with no notion of deadline.
 This module owns the request population between ingress and device

@@ -49,7 +49,7 @@ def residency_enabled() -> bool:
 # Process-wide tallies of explicit host<->device copies plus the pad-entry
 # residency split, mirrored into obs/ as nns_transfer_h2d_bytes_total /
 # nns_transfer_d2h_bytes_total counters and the nns_buffer_resident_ratio
-# gauge. bench.py reads transfer_snapshot() deltas per run (d2h_per_frame).
+# gauge. transfer_snapshot() deltas over a run give its d2h_per_frame.
 _xfer_lock = threading.Lock()
 _xfer: Dict[str, float] = {
     "h2d_bytes": 0.0, "h2d_events": 0.0,

@@ -336,6 +336,6 @@ def test_engine_reports_both_arenas():
     assert eng.stats["kv_window_bytes_per_token"] == 3 * 256
     assert eng._bt_w.shape == eng._bt.shape == (3, 16)
     assert (eng._bt_w == eng._pool.win.SENTINEL).all()
-    with pytest.raises(ValueError, match="auto"):
+    with pytest.raises(ValueError, match="steps_per_dispatch"):
         ContinuousBatchingEngine(CFG, PARAMS, steps_per_dispatch="auto",
                                  block_tokens=T)

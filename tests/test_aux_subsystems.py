@@ -126,38 +126,13 @@ class TestConfig:
 class TestNoCpuFallback:
     """A process that wants the chip fails without it: nothing probes in
     a child process, caches a verdict or switches to the CPU on its own
-    (PR 21). bench.py and chip_smoke.py refuse a CPU backend outright."""
+    — chip_smoke.py refuses a CPU backend outright."""
 
     REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
     @pytest.fixture
     def repo_on_path(self, monkeypatch):
         monkeypatch.syspath_prepend(self.REPO)
-
-    def test_bench_entry_refuses_cpu(self, repo_on_path):
-        import bench
-
-        with pytest.raises(SystemExit) as e:
-            bench._require_tpu()
-        assert "backend 'cpu'" in str(e.value)
-
-    def test_bench_process_exits_nonzero_without_a_result(self):
-        import subprocess
-        import sys
-
-        proc = subprocess.run(
-            [sys.executable, "bench.py"], cwd=self.REPO,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=120)
-        assert proc.returncode != 0
-        assert "needs a TPU" in proc.stderr, proc.stderr
-        assert proc.stdout.strip() == "", "a refused bench printed a result"
-
-    def test_unknown_device_kind_is_an_error_not_none(self, repo_on_path):
-        import bench
-
-        with pytest.raises(RuntimeError, match="peaks table"):
-            bench._peak_flops()  # device_kind "cpu" has no bf16 peak
 
     def test_smoke_entry_refuses_cpu_and_unknown_chips(self, repo_on_path,
                                                        monkeypatch):

@@ -361,7 +361,7 @@ def test_the_record_says_what_each_family_brings():
 
     assert set(DENSE.brings) == {"prefix_cache", "speculate",
                                  "prefill_chunk", "kv_quant", "mesh"}
-    assert DENSE.build_chunk_decode and DENSE.build_paged_chunk
+    assert DENSE.build_paged_chunk
     for family in (hybrid.HYBRID, mla.MLA):
         assert family.brings == () and family.build_paged_chunk is None
         assert "ROADMAP.md R" in family.refusal
@@ -369,7 +369,7 @@ def test_the_record_says_what_each_family_brings():
     assert mla.MLA.kv_entry(CFG) == (3, 1, (256,))
     assert mla.MLA.latent_value_width(CFG) == 128
     eng = _engine()
-    assert eng._chunk_fn is None and eng._paged_chunk_fn is None
+    assert eng._paged_chunk_fn is None
 
 
 def test_serving_params_narrows_the_families_leaves_bit_equal():

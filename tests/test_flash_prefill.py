@@ -152,31 +152,6 @@ class TestEngineAuto:
                 eng.stop()
         assert outs["auto"] == outs["reference"]
 
-    def test_engine_auto_k_calibrates_and_generates(self):
-        """steps_per_dispatch='auto' measures rtt/step and picks a
-        power-of-two K in [8,128]; tokens match a fixed-K engine."""
-        from nnstreamer_tpu.serving import ContinuousBatchingEngine
-
-        params = init_params(CFG, seed=5)
-        prompt = np.random.default_rng(5).integers(
-            1, CFG.vocab, 10).tolist()
-        auto = ContinuousBatchingEngine(
-            CFG, params, max_streams=2, steps_per_dispatch="auto",
-            temperature=0.0).start()
-        try:
-            assert auto.K in (8, 16, 32, 64, 128)
-            got = auto.generate(prompt, max_new_tokens=12, timeout=120)
-        finally:
-            auto.stop()
-        fixed = ContinuousBatchingEngine(
-            CFG, params, max_streams=2, steps_per_dispatch=4,
-            temperature=0.0).start()
-        try:
-            want = fixed.generate(prompt, max_new_tokens=12, timeout=120)
-        finally:
-            fixed.stop()
-        assert got == want
-
     def test_engine_rejects_unknown_attention(self):
         from nnstreamer_tpu.serving import ContinuousBatchingEngine
 

@@ -14,8 +14,10 @@ import jax.numpy as jnp  # noqa: E402
 
 from nnstreamer_tpu.models.transformer import (  # noqa: E402
     TransformerConfig,
-    build_chunk_decode,
+    _kv_codec,
+    _paged_gather,
     build_decode_step,
+    build_paged_chunk,
     build_prefill,
     init_cache,
     init_params,
@@ -74,26 +76,33 @@ def test_q8_decode_close_to_exact():
 
 
 def test_q8_chunk_matches_sequential_q8_exactly():
-    """Same cache content → same quantization: the chunk path and the
-    step path must agree bitwise given identical inputs."""
-    prefill_q = jax.jit(build_prefill(CFG, kv_codec="int8"))
+    """Same cache content → same quantization: the paged chunk program
+    and the step path run position by position must agree bitwise given
+    identical inputs."""
+    T = 8
+    codec = _kv_codec(CFG, "int8")
     decode_q = jax.jit(build_decode_step(CFG, kv_codec="int8"))
-    chunk_q = jax.jit(build_chunk_decode(CFG, kv_codec="int8"))
-    prompt = jnp.asarray([[3, 1, 4]], jnp.int32)
-    _, cache_a = prefill_q(PARAMS, prompt)
-    _, cache_b = prefill_q(PARAMS, prompt)
-    toks = jnp.asarray([[9, 2, 6, 5]], jnp.int32)
-    cl, cache_a = chunk_q(PARAMS, toks, cache_a, 3)
+    chunk_q = jax.jit(build_paged_chunk(CFG, T, kv_codec="int8"))
+    mb = CFG.max_seq // T
+    arena = codec.paged_init(CFG.n_layers, mb + 1, T, CFG.n_heads,
+                             CFG.head_dim)
+    bt = jnp.arange(mb, dtype=jnp.int32)[None]
+    cache = init_cache(CFG, 1, kv_codec="int8")
+    toks = jnp.asarray([[3, 1, 4, 9, 2, 6, 5]], jnp.int32)
+    cl, arena = chunk_q(PARAMS, toks, arena, bt, jnp.asarray([0], jnp.int32),
+                        jnp.asarray([7], jnp.int32))
     seq = []
-    for i in range(4):
-        lg, cache_b = decode_q(PARAMS, toks[:, i], cache_b,
-                               jnp.asarray(3 + i, jnp.int32))
+    for i in range(7):
+        lg, cache = decode_q(PARAMS, toks[:, i], cache,
+                             jnp.asarray(i, jnp.int32))
         seq.append(lg)
     np.testing.assert_allclose(np.asarray(cl),
                                np.asarray(jnp.stack(seq, 1)),
                                rtol=2e-5, atol=2e-5)
-    np.testing.assert_array_equal(np.asarray(cache_a["q"]),
-                                  np.asarray(cache_b["q"]))
+    for layer in range(CFG.n_layers):  # [1, 2, S, h, dh] against [2, 1, ..]
+        got = _paged_gather(arena["q"], layer, bt, codec.heads_major)
+        np.testing.assert_array_equal(
+            np.asarray(got)[0, :, :7], np.asarray(cache["q"])[layer, :, 0, :7])
 
 
 def test_engine_with_q8_cache_generates_deterministically():

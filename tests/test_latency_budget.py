@@ -3,7 +3,7 @@ latency-budget-ms`` (round-5 VERDICT #1).
 
 A micro-batched stream trades per-frame latency for throughput: with
 batch=8 at 30 fps, a frame's p50 latency IS the batch window (~264 ms
-measured in BENCH_r04). Budget mode bounds the admission wait: a window
+measured on the flagship stream). Budget mode bounds the admission wait: a window
 holding frames past the budget flushes early, padded to the compiled
 batch shape (meta["valid_frames"]), and the sink trims the padding.
 The reference's per-frame path (tensor_filter.c:349-423) has no window

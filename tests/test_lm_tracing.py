@@ -322,8 +322,8 @@ def _programs_of(eng):
     out = [(eng, "_dispatch"), (eng, "_prefill_jitted"),
            (eng, "_sample_first"), (eng._pool, "_jit_scatter"),
            (eng._pool, "_jit_copy")]
-    if eng._chunk_fn is not None:
-        out += [(eng, "_chunk_jitted"), (eng, "_paged_chunk_jitted")]
+    if eng._paged_chunk_fn is not None:
+        out += [(eng, "_paged_chunk_jitted")]
     if eng._spec is not None:
         out += [(eng._spec, k) for k in ("dispatch", "prefill", "insert")]
     return out
@@ -398,10 +398,13 @@ def test_starved_counters_hold_their_invariants_on_every_path(kind):
     _check_invariants(eng.stats)
     assert eng.stats["starved_us"] > 0
     assert eng._dev_seen == eng._dev_enq > 0
-    want = {"_sample_first", "_jit_scatter"}
+    want = {"_sample_first"}
     want |= {"dispatch", "prefill", "insert"} if kind == "speculative" \
         else {"_dispatch"}
-    want |= {"_chunk_jitted"} if "chunked" in kind else {"_prefill_jitted"}
+    # a chunked prompt is written into its blocks by the paged chunk
+    # program; every other cold prompt's prefill is scattered into them
+    want |= {"_paged_chunk_jitted"} if "chunked" in kind \
+        else {"_prefill_jitted", "_jit_scatter"}
     if "prefix_hit" in kind:
         want |= {"_paged_chunk_jitted", "_jit_copy"}
     assert want <= called, (kind, called)

@@ -576,7 +576,7 @@ def test_mosaic_compiles_flash_prefill_with_narrower_values(one_chip, s):
     (jnp.bfloat16, 16, 2, 256, 512),     # qwen3next_chat_closed's longest
     (jnp.bfloat16, 16, 2, 256, 4096),
     (jnp.float32, 16, 2, 256, 4096),     # 512 rows: 1024 do not fit VMEM
-    (jnp.float32, 8, 8, 128, 4096),      # bench.py measure_attention
+    (jnp.float32, 8, 8, 128, 4096),      # eight heads of 128, f32
     (jnp.bfloat16, 16, 16, 64, 2048),
 ], ids=lambda x: str(getattr(x, "__name__", x)))
 def test_mosaic_compiles_the_flash_prefill_under_the_plans_tiles(

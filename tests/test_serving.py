@@ -149,6 +149,16 @@ def test_invalid_prompts_rejected(engine):
         engine.submit([1, 2], max_new_tokens=0)
 
 
+@pytest.mark.parametrize("steps", ["auto", 2.5, 0, True])
+def test_steps_per_dispatch_is_a_positive_integer(steps):
+    """K sizes the decode program and the window arena: a number the
+    caller chooses, checked at construction."""
+    with pytest.raises(ValueError, match="steps_per_dispatch"):
+        ContinuousBatchingEngine(CFG, PARAMS, steps_per_dispatch=steps)
+    assert ContinuousBatchingEngine(
+        CFG, PARAMS, steps_per_dispatch=np.int64(3)).K == 3
+
+
 def test_stop_finishes_inflight_streams():
     eng = ContinuousBatchingEngine(
         CFG, PARAMS, max_streams=1, steps_per_dispatch=2,

@@ -7,7 +7,8 @@ speculation only changes how many positions a round advances, never the
 values. That makes byte-parity with ``reference_greedy`` the whole
 correctness story, whatever the arena's block size."""
 
-import numpy as np
+import dataclasses
+
 import pytest
 
 jax = pytest.importorskip("jax")
@@ -19,6 +20,7 @@ from nnstreamer_tpu.serving import (  # noqa: E402
     register_engine,
     unregister_engine,
 )
+from nnstreamer_tpu.models.transformer import init_params  # noqa: E402
 from tests.test_serving import CFG, PARAMS, reference_greedy  # noqa: E402
 
 PROMPTS = [[5, 11, 23, 42, 7], [4, 8, 15], [16, 23], [2, 2, 2, 2, 2]]
@@ -32,9 +34,10 @@ def spec_engine(**kw):
     return ContinuousBatchingEngine(CFG, PARAMS, **kw)
 
 
+@pytest.mark.parametrize("speculate", [1, 3, 5])
 @pytest.mark.parametrize("block_tokens", [8, 16])
-def test_speculative_greedy_parity(block_tokens):
-    eng = spec_engine(block_tokens=block_tokens).start()
+def test_speculative_greedy_parity(block_tokens, speculate):
+    eng = spec_engine(block_tokens=block_tokens, speculate=speculate).start()
     try:
         assert eng._pool.block_tokens == block_tokens
         for p in PROMPTS:
@@ -53,9 +56,67 @@ def test_speculative_greedy_parity(block_tokens):
         assert got == reference_greedy(p, 9), f"prompt={p}"
 
 
+def test_full_depth_draft_accepts_every_draft():
+    """A draft as deep as the target proposes the target's own tokens:
+    every draft is accepted, which runs the full-acceptance path (the
+    draft cache's catch-up write of the last draft) round after round."""
+    eng = spec_engine(speculate=4, speculate_layers=CFG.n_layers).start()
+    try:
+        got = [eng.generate(p, max_new_tokens=21, timeout=240)
+               for p in PROMPTS[:2]]
+    finally:
+        eng.stop()
+    assert got == [reference_greedy(p, 21) for p in PROMPTS[:2]]
+    assert eng.stats["spec_drafted"] > 0
+    assert eng.stats["spec_accepted"] == eng.stats["spec_drafted"]
+
+
+def test_speculation_respects_the_cache_window():
+    """A verify chunk writes positions pos .. pos+K: a prompt may take at
+    most S - 1 - K tokens, and a stream's budget stops it before a round
+    would write past the cache. Near the end it stays exact."""
+    k = 3
+    eng = spec_engine(speculate=k).start()
+    try:
+        with pytest.raises(ValueError, match="prompt length"):
+            eng.submit(list(range(1, CFG.max_seq - k + 1)), max_new_tokens=4)
+        longest = list(range(1, CFG.max_seq - k))       # S - 1 - K tokens
+        assert len(eng.generate(longest, max_new_tokens=8,
+                                timeout=240)) == 1
+        prompt = [(i * 7 + 3) % CFG.vocab for i in range(56)]
+        got = eng.generate(prompt, max_new_tokens=64, timeout=240)
+    finally:
+        eng.stop()
+    assert len(got) == CFG.max_seq - len(prompt) - k      # the budget
+    assert got == reference_greedy(prompt, len(got))
+
+
+def test_moe_target_is_served_exactly():
+    """A dense mixture-of-experts target and its depth-pruned draft: the
+    verify chunk routes experts as one-at-a-time decoding does."""
+    moe = dataclasses.replace(CFG, d_ff=64, num_experts=4)
+    moe_params = init_params(moe, seed=9)
+    eng = ContinuousBatchingEngine(moe, moe_params, max_streams=2,
+                                   steps_per_dispatch=4, temperature=0.0,
+                                   speculate=3).start()
+    try:
+        got = [eng.generate(p, max_new_tokens=15, timeout=240)
+               for p in PROMPTS[:3]]
+    finally:
+        eng.stop()
+    assert got == [reference_greedy(p, 15, cfg=moe, params=moe_params)
+                   for p in PROMPTS[:3]]
+    assert eng.stats["spec_drafted"] > 0
+
+
 def test_speculate_requires_greedy():
     with pytest.raises(ValueError, match="greedy"):
         spec_engine(temperature=0.8)
+
+
+def test_draft_deeper_than_the_target_is_refused():
+    with pytest.raises(ValueError, match="n_layers"):
+        spec_engine(speculate_layers=CFG.n_layers + 1)
 
 
 def test_set_speculate_guards():
